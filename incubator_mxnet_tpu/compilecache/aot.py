@@ -46,7 +46,7 @@ __all__ = ["compile_key", "serialize_compiled", "deserialize_compiled",
 
 log = logging.getLogger(__name__)
 
-_BLOB_VERSION = 1
+_BLOB_VERSION = 2   # 2: the blob names the devices it was compiled for
 
 
 # ----------------------------------------------------------------- keying
@@ -87,18 +87,33 @@ def serialize_compiled(compiled):
     serialize executables — callers treat that as 'cache this one not')."""
     from jax.experimental import serialize_executable as _se
     payload, in_tree, out_tree = _se.serialize(compiled)
-    return pickle.dumps((_BLOB_VERSION, payload, in_tree, out_tree),
-                        protocol=pickle.HIGHEST_PROTOCOL)
+    # ids in device-assignment order: one device for a plain jit, the
+    # mesh's for a sharded program
+    device_ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps((_BLOB_VERSION, payload, in_tree, out_tree,
+                         device_ids), protocol=pickle.HIGHEST_PROTOCOL)
 
 
 def deserialize_compiled(blob):
-    """bytes -> callable ``jax.stages.Compiled`` loaded onto this
-    process's devices (raises on version/backend mismatch)."""
+    """bytes -> callable ``jax.stages.Compiled`` loaded onto the devices
+    it was compiled for, found by id among this process's devices (raises
+    on version/backend mismatch or a device this process does not have).
+    ``deserialize_and_load`` would otherwise load onto ALL local devices,
+    and a one-device program then refuses its arguments."""
+    import jax
     from jax.experimental import serialize_executable as _se
-    version, payload, in_tree, out_tree = pickle.loads(blob)
+    version, *rest = pickle.loads(blob)
     if version != _BLOB_VERSION:
         raise ValueError("unsupported executable blob version %r" % version)
-    return _se.deserialize_and_load(payload, in_tree, out_tree)
+    payload, in_tree, out_tree, device_ids = rest
+    by_id = {d.id: d for d in jax.devices()}
+    missing = [i for i in device_ids if i not in by_id]
+    if missing:
+        raise ValueError("executable was compiled for device ids %r; this "
+                         "process has no %r" % (device_ids, missing))
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 # -------------------------------------------------------- cached_compile

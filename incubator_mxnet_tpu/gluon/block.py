@@ -110,6 +110,17 @@ def current_trace():
     return getattr(_trace_state, "ctx", None)
 
 
+def trace_on_one_device():
+    """False inside a ShardedTrainer step traced over a mesh of several
+    devices. jit refuses to partition a Mosaic kernel over a mesh ("wrap
+    the call in a shard_map"), whatever its operands' shardings, so the
+    Pallas fast paths are taken only in programs for one device and a
+    step over a mesh takes the XLA forms."""
+    ctx = current_trace()
+    mesh = getattr(ctx, "mesh_ctx", None) if ctx is not None else None
+    return mesh is None or mesh.size == 1
+
+
 # ---------------------------------------------------------------------------
 # Block
 # ---------------------------------------------------------------------------

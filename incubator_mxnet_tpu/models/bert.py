@@ -9,7 +9,8 @@ parallelism comes from ShardedTrainer rules (bert_sharding_rules below).
 
 import math
 
-from ..gluon.block import HybridBlock, current_trace
+from ..gluon.block import (HybridBlock, current_trace,
+                           trace_on_one_device)
 from ..gluon import nn
 
 __all__ = ["BERTModel", "BERTEncoder", "TransformerEncoderLayer",
@@ -84,7 +85,7 @@ class MultiHeadAttention(HybridBlock):
         if (in_trace and self.dropout._rate == 0
                 and _os.environ.get("MXTPU_DISABLE_FLASH", "0") != "1"
                 and T >= min_t and T % 128 == 0
-                and flash_attention_available()):
+                and flash_attention_available() and trace_on_one_device()):
             return flash_attention(q, k, v, scale=1.0 / math.sqrt(D),
                                    kv_mask=mask)
         scores = F.batch_dot(q, k, transpose_b=True) * (1.0 / math.sqrt(D))
@@ -131,10 +132,9 @@ class MultiHeadAttention(HybridBlock):
         except Exception:  # mxlint: disable=broad-except — abstract
             # mesh probe across jax versions; concrete mesh fallback
             pass
-        from ..compat import shard_map
-        return shard_map(fn, mesh=use_mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, axis_names={"sp"},
-                         check_vma=False)(q, k, v)
+        return jax.shard_map(fn, mesh=use_mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, axis_names={"sp"},
+                             check_vma=False)(q, k, v)
 
 
 class PositionwiseFFN(HybridBlock):

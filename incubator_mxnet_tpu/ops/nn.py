@@ -382,8 +382,9 @@ def _make_bn_train(axis, eps):
 def layer_norm(data, gamma, beta, axis=-1, eps=1e-5, **_ignored):
     """Layer normalization over `axis` (reference: layer_norm.cc)."""
     if axis in (-1, data.ndim - 1):
+        from ..gluon.block import trace_on_one_device
         from .pallas import fused_layer_norm, fused_norm_available
-        if fused_norm_available():
+        if fused_norm_available() and trace_on_one_device():
             out = fused_layer_norm(data, gamma, beta, eps)
             if out is not None:
                 return out
@@ -500,8 +501,9 @@ def softmax(data, axis=-1, temperature=None, length=None, use_length=False):
             [length.shape[0]] + [1] * (data.ndim - 1))
         data = jnp.where(mask, data, -jnp.inf)
     if axis in (-1, data.ndim - 1):
+        from ..gluon.block import trace_on_one_device
         from .pallas import fused_softmax, fused_norm_available
-        if fused_norm_available():
+        if fused_norm_available() and trace_on_one_device():
             out = fused_softmax(data, axis=axis)
             if out is not None:
                 return out

@@ -25,18 +25,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover — mxlint: disable=broad-except (pallas/TPU availability probe: any import or lowering failure means fall back to the XLA path)
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
 
 def flash_attention_available():
-    return _PALLAS_OK and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, acc_ref,

@@ -32,12 +32,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover — mxlint: disable=broad-except (pallas/TPU availability probe: any import or lowering failure means fall back to the XLA path)
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -46,7 +42,7 @@ __all__ = ["paged_flash_decode", "paged_causal_attention",
 
 
 def flash_decode_available():
-    return _PALLAS_OK and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------- lax ref
@@ -197,7 +193,7 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, lengths,
         scale = 1.0 / math.sqrt(D)
     if use_kernel is None:
         use_kernel = flash_decode_available()
-    if use_kernel and _PALLAS_OK and C == 1:
+    if use_kernel and C == 1:
         o, m, l = _kernel_call(q[:, 0], k_pool, v_pool,
                                jnp.asarray(block_tables, jnp.int32),
                                jnp.asarray(lengths, jnp.int32),

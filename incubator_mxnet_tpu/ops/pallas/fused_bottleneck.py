@@ -30,19 +30,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover — mxlint: disable=broad-except (pallas/TPU availability probe: any import or lowering failure means fall back to the XLA path)
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_bottleneck", "fused_bottleneck_available",
            "bottleneck_reference"]
 
 
 def fused_bottleneck_available():
-    return _PALLAS_OK and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def _kernel(x_ref, w1_ref, s1_ref, b1_ref, w2_ref, s2_ref, b2_ref,
@@ -75,10 +71,6 @@ def fused_bottleneck(x, w1, s1, b1, w2, s2, b2, w3, s3, b3,
     w3 (M, C); s*/b* folded BN scale/bias per channel (fp32).
     Returns relu(bn3(conv3(relu(bn2(conv2(relu(bn1(conv1(x)))))))) + x).
     One grid step per image; all intermediates VMEM-resident."""
-    if not _PALLAS_OK:
-        raise RuntimeError(
-            "Pallas unavailable in this environment — "
-            "use bottleneck_reference (check fused_bottleneck_available())")
     B, H, W, C = x.shape
     M = w1.shape[1]
     spec_w = lambda shape: pl.BlockSpec(shape, lambda b: (0,) * len(shape))

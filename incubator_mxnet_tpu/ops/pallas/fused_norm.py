@@ -20,16 +20,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover — mxlint: disable=broad-except (pallas/TPU availability probe: any import or lowering failure means fall back to the XLA path)
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
 
 
 def fused_norm_available():
-    return _PALLAS_OK and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 _VMEM_BUDGET = 8 * 1024 * 1024   # block + fp32 working copy must fit

@@ -12,8 +12,11 @@ Three flavors are fused — SGD-momentum, Adam, and AdamW — matching the
 ``_sgd_mom_update`` / ``_adam_update`` / ``_adamw_update`` kernels in
 ``ops/_optim_kernels.py`` bit-for-bit (the scalar arithmetic stays in
 float32 and is cast to the buffer dtype exactly where jax weak-type
-promotion would cast it in the per-parameter kernels). The lazy/sparse
-update kernels stay on the per-parameter path.
+promotion would cast it in the per-parameter kernels). The Adam bias
+corrections ``1 - b**t`` are taken in the caller and ride SMEM with the
+other scalars: Mosaic has no lowering for ``math.powf``, so a power in a
+kernel body passes interpret mode and is refused by the chip's compiler.
+The lazy/sparse update kernels stay on the per-parameter path.
 
 Dispatch lives behind the ``_optim_kernels`` seam (``_multi_*`` wrappers):
 real Pallas on TPU, interpret mode for CPU tier-1 tests, and a lax fallback
@@ -27,16 +30,12 @@ import os
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover — mxlint: disable=broad-except (pallas/TPU availability probe: any import or lowering failure means fall back to the XLA path)
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def fused_optim_available():
-    return _PALLAS_OK and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def fused_optim_enabled():
@@ -111,12 +110,12 @@ def _sgd_mom_kernel(s_ref, w_ref, m_ref, g_ref, ow_ref, om_ref):
     om_ref[...] = mom
 
 
-def _adam_kernel(s_ref, t_ref, w_ref, m_ref, v_ref, g_ref,
+def _adam_kernel(s_ref, w_ref, m_ref, v_ref, g_ref,
                  ow_ref, om_ref, ov_ref):
     dt = w_ref.dtype
-    lr, wd, b1, b2 = s_ref[0, 0], s_ref[0, 1], s_ref[0, 2], s_ref[0, 3]
-    eps, rescale, clip = s_ref[0, 4], s_ref[0, 5], s_ref[0, 6]
-    t = t_ref[0, 0]
+    wd, b1, b2 = s_ref[0, 1], s_ref[0, 2], s_ref[0, 3]
+    eps, rescale, clip, coef = (s_ref[0, 4], s_ref[0, 5], s_ref[0, 6],
+                                s_ref[0, 7])
     one = jnp.float32(1)
     w, g, m, v = w_ref[...], g_ref[...], m_ref[...], v_ref[...]
     g = g * rescale.astype(dt)
@@ -124,27 +123,26 @@ def _adam_kernel(s_ref, t_ref, w_ref, m_ref, v_ref, g_ref,
     g = g + wd.astype(dt) * w
     m = b1.astype(dt) * m + (one - b1).astype(dt) * g
     v = b2.astype(dt) * v + (one - b2).astype(dt) * g * g
-    coef = lr * jnp.sqrt(one - b2 ** t) / (one - b1 ** t)
     ow_ref[...] = w - coef.astype(dt) * m / (jnp.sqrt(v) + eps.astype(dt))
     om_ref[...] = m
     ov_ref[...] = v
 
 
-def _adamw_kernel(s_ref, t_ref, w_ref, m_ref, v_ref, g_ref,
+def _adamw_kernel(s_ref, w_ref, m_ref, v_ref, g_ref,
                   ow_ref, om_ref, ov_ref):
     dt = w_ref.dtype
     lr, wd, b1, b2 = s_ref[0, 0], s_ref[0, 1], s_ref[0, 2], s_ref[0, 3]
     eps, rescale, clip, eta = (s_ref[0, 4], s_ref[0, 5], s_ref[0, 6],
                                s_ref[0, 7])
-    t = t_ref[0, 0]
+    c1, c2 = s_ref[0, 8], s_ref[0, 9]
     one = jnp.float32(1)
     w, g, m, v = w_ref[...], g_ref[...], m_ref[...], v_ref[...]
     g = g * rescale.astype(dt)
     g = jnp.where(clip > 0, jnp.clip(g, -clip.astype(dt), clip.astype(dt)), g)
     m = b1.astype(dt) * m + (one - b1).astype(dt) * g
     v = b2.astype(dt) * v + (one - b2).astype(dt) * g * g
-    mhat = m / (one - b1 ** t).astype(dt)
-    vhat = v / (one - b2 ** t).astype(dt)
+    mhat = m / c1.astype(dt)
+    vhat = v / c2.astype(dt)
     ow_ref[...] = w - eta.astype(dt) * (
         lr.astype(dt) * mhat / (jnp.sqrt(vhat) + eps.astype(dt))
         + wd.astype(dt) * w)
@@ -156,7 +154,7 @@ def _adamw_kernel(s_ref, t_ref, w_ref, m_ref, v_ref, g_ref,
 # launch plumbing
 # ---------------------------------------------------------------------------
 
-def _launch(kernel, scalars, t, bufs, n_out, interpret):
+def _launch(kernel, scalars, bufs, n_out, interpret):
     """One pallas_call over the packed (R, 128) buffers. ``bufs[:n_out]``
     are aliased to the outputs (in-place update in HBM) on the real-TPU
     path; weight/state buffers must therefore come first."""
@@ -165,30 +163,22 @@ def _launch(kernel, scalars, t, bufs, n_out, interpret):
     block_r = _row_block(R)
     tile_spec = pl.BlockSpec((block_r, _LANE), lambda i: (i, 0))
     smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    inputs = [scalars]
-    in_specs = [smem_spec]
-    if t is not None:
-        inputs.append(t)
-        in_specs.append(smem_spec)
-    n_scalar = len(inputs)
-    inputs += tiles
-    in_specs += [tile_spec] * len(tiles)
     dt = bufs[0].dtype
     aliases = {}
     if not interpret:
-        # w/m(/v) inputs sit right after the scalar operands and map 1:1
+        # w/m(/v) inputs sit right after the scalar operand and map 1:1
         # onto the outputs; g (never aliased) is passed last.
-        aliases = {n_scalar + j: j for j in range(n_out)}
+        aliases = {1 + j: j for j in range(n_out)}
     outs = pl.pallas_call(
         kernel,
         grid=(R // block_r,),
-        in_specs=in_specs,
+        in_specs=[smem_spec] + [tile_spec] * len(tiles),
         out_specs=tuple([tile_spec] * n_out),
         out_shape=tuple(jax.ShapeDtypeStruct((R, _LANE), dt)
                         for _ in range(n_out)),
         input_output_aliases=aliases,
         interpret=interpret,
-    )(*inputs)
+    )(scalars, *tiles)
     n = bufs[0].shape[0]
     return tuple(o.reshape(-1)[:n] for o in outs)
 
@@ -197,27 +187,38 @@ def _scalars(*vals):
     return jnp.asarray([vals], jnp.float32)
 
 
+def _bias_corrections(b1, b2, t):
+    """``(1 - b1**t, 1 - b2**t)`` in f32, taken outside the kernel (no
+    ``powf`` in Mosaic): the same f32 ops in the same order as the
+    per-parameter kernels evaluate them."""
+    one = jnp.float32(1)
+    tf = jnp.asarray(t, jnp.float32)
+    return (one - jnp.asarray(b1, jnp.float32) ** tf,
+            one - jnp.asarray(b2, jnp.float32) ** tf)
+
+
 def fused_sgd_mom_flat(w, g, mom, lr, wd, momentum, rescale, clip,
                        interpret=False):
     """One-launch SGD-momentum over packed 1-D buffers -> (w, mom)."""
     s = _scalars(lr, wd, momentum, 0.0, 0.0, rescale, clip, 0.0)
-    return _launch(_sgd_mom_kernel, s, None, [w, mom, g], 2, interpret)
+    return _launch(_sgd_mom_kernel, s, [w, mom, g], 2, interpret)
 
 
 def fused_adam_flat(w, g, m, v, lr, wd, b1, b2, eps, t, rescale, clip,
                     interpret=False):
     """One-launch Adam over packed 1-D buffers -> (w, m, v)."""
-    s = _scalars(lr, wd, b1, b2, eps, rescale, clip, 0.0)
-    tf = jnp.asarray(t, jnp.float32).reshape(1, 1)
-    return _launch(_adam_kernel, s, tf, [w, m, v, g], 3, interpret)
+    c1, c2 = _bias_corrections(b1, b2, t)
+    coef = jnp.asarray(lr, jnp.float32) * jnp.sqrt(c2) / c1
+    s = _scalars(lr, wd, b1, b2, eps, rescale, clip, coef)
+    return _launch(_adam_kernel, s, [w, m, v, g], 3, interpret)
 
 
 def fused_adamw_flat(w, g, m, v, lr, wd, eta, b1, b2, eps, t, rescale, clip,
                      interpret=False):
     """One-launch AdamW over packed 1-D buffers -> (w, m, v)."""
-    s = _scalars(lr, wd, b1, b2, eps, rescale, clip, eta)
-    tf = jnp.asarray(t, jnp.float32).reshape(1, 1)
-    return _launch(_adamw_kernel, s, tf, [w, m, v, g], 3, interpret)
+    c1, c2 = _bias_corrections(b1, b2, t)
+    s = _scalars(lr, wd, b1, b2, eps, rescale, clip, eta, c1, c2)
+    return _launch(_adamw_kernel, s, [w, m, v, g], 3, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -228,20 +229,19 @@ def fused_adamw_flat(w, g, m, v, lr, wd, eta, b1, b2, eps, t, rescale, clip,
 # per-param `lr * wd * p` evaluation order.
 # ---------------------------------------------------------------------------
 
-def _trainer_adam_kernel(s_ref, t_ref, w_ref, m_ref, v_ref, g_ref,
+def _trainer_adam_kernel(s_ref, w_ref, m_ref, v_ref, g_ref,
                          ow_ref, om_ref, ov_ref, *, adamw):
     dt = w_ref.dtype
     lr, wd, b1, b2 = s_ref[0, 0], s_ref[0, 1], s_ref[0, 2], s_ref[0, 3]
-    eps, lrwd = s_ref[0, 4], s_ref[0, 5]
-    t = t_ref[0, 0]
+    eps, lrwd, c1, c2 = s_ref[0, 4], s_ref[0, 5], s_ref[0, 6], s_ref[0, 7]
     one = jnp.float32(1)
     w, g, m, v = w_ref[...], g_ref[...], m_ref[...], v_ref[...]
     if not adamw:
         g = g + wd.astype(dt) * w
     m = b1.astype(dt) * m + (one - b1).astype(dt) * g
     v = b2.astype(dt) * v + (one - b2).astype(dt) * g * g
-    mhat = m / (one - b1 ** t).astype(dt)
-    vhat = v / (one - b2 ** t).astype(dt)
+    mhat = m / c1.astype(dt)
+    vhat = v / c2.astype(dt)
     upd = lr.astype(dt) * mhat / (jnp.sqrt(vhat) + eps.astype(dt))
     if adamw:
         upd = upd + lrwd.astype(dt) * w
@@ -260,8 +260,8 @@ def multi_trainer_sgd_mom(ws, gs, moms, lr, wd, momentum, interpret=False):
         # the per-param math is the kernel's with rescale=1, clip off
         # (both prologue ops are bitwise no-ops at those values)
         s = _scalars(lr, wd, momentum, 0.0, 0.0, 1.0, -1.0, 0.0)
-        nw, nm = _launch(_sgd_mom_kernel, s, None, [wflat, mflat, gflat],
-                         2, interpret)
+        nw, nm = _launch(_sgd_mom_kernel, s, [wflat, mflat, gflat], 2,
+                         interpret)
     else:
         nm = momentum * mflat - lr * (gflat + wd * wflat)
         nw = wflat + nm
@@ -278,10 +278,10 @@ def multi_trainer_adam(ws, gs, ms, vs, lr, wd, b1, b2, eps, t, adamw=False,
     mflat, _ = flatten_group(ms)
     vflat, _ = flatten_group(vs)
     if interpret or fused_optim_available():
-        s = _scalars(lr, wd, b1, b2, eps, lr * wd, 0.0, 0.0)
-        tf = jnp.asarray(t, jnp.float32).reshape(1, 1)
+        c1, c2 = _bias_corrections(b1, b2, t)
+        s = _scalars(lr, wd, b1, b2, eps, lr * wd, c1, c2)
         kern = functools.partial(_trainer_adam_kernel, adamw=adamw)
-        nw, nm, nv = _launch(kern, s, tf, [wflat, mflat, vflat, gflat], 3,
+        nw, nm, nv = _launch(kern, s, [wflat, mflat, vflat, gflat], 3,
                              interpret)
     else:
         g = gflat if adamw else gflat + wd * wflat

@@ -15,18 +15,13 @@ wins come from backend int8 kernels, mkldnn/cuDNN).
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover — mxlint: disable=broad-except (pallas/TPU availability probe: any import or lowering failure means fall back to the XLA path)
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
 
 __all__ = ["int8_matmul", "int8_matmul_available"]
 
 
 def int8_matmul_available():
-    return _PALLAS_OK and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def _kernel(a_ref, b_ref, o_ref):
@@ -37,8 +32,6 @@ def _kernel(a_ref, b_ref, o_ref):
 def int8_matmul(a, b, block_m=512, block_n=512, interpret=False):
     """a: (M, K) int8, b: (K, N) int8 -> (M, N) int32. K is unsplit
     (one contraction per program); M/N tile the grid."""
-    if not _PALLAS_OK:
-        raise RuntimeError("Pallas unavailable in this environment")
     M, K = a.shape
     K2, N = b.shape
     assert K == K2 and a.dtype == jnp.int8 and b.dtype == jnp.int8

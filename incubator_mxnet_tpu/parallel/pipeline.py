@@ -225,8 +225,7 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis="pp",
         pass
     rep_specs = jax.tree_util.tree_map(lambda a: P(), (pre_params,
                                                        post_params))
-    from ..compat import shard_map
-    out = shard_map(
+    out = jax.shard_map(
         manual, mesh=use_mesh,
         in_specs=(param_specs, rep_specs[0], rep_specs[1], P()),
         out_specs=P(),

@@ -244,7 +244,6 @@ def make_ring_attention(mesh, seq_axis="sp", causal=False, impl="auto",
     impl: 'flash' (Pallas per-hop kernel), 'dense' (einsum per hop), or
     'auto' — flash on TPU when the local shard length satisfies the
     kernel's tiling contract, dense otherwise."""
-    from ..compat import shard_map
     from ..ops.pallas import flash_attention_available
 
     spec = P(None, None, seq_axis, None)
@@ -254,7 +253,7 @@ def make_ring_attention(mesh, seq_axis="sp", causal=False, impl="auto",
             return t_local % 128 == 0
         return t_local % 8 == 0
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     def fn(q, k, v):
         t_local = q.shape[2]

@@ -249,7 +249,6 @@ def test_ckpt_manager_sigterm_final_save(tmp_path):
                                   np.full((2, 2), 8.0, np.float32))
 
 
-@pytest.mark.needs_shard_map
 def test_sharded_trainer_checkpoint_resume(tmp_path):
     """Distributed checkpoint/resume: a zero1 ShardedTrainer's full state
     (params + dp-sharded adam slots + step) round-trips through

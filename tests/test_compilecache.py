@@ -206,6 +206,28 @@ def test_cached_compile_hit_is_zero_compile_events(cache_dir, tele):
     assert h >= 1
 
 
+@pytest.mark.parametrize("over", ["one_device", "mesh"])
+def test_blob_loads_onto_the_devices_it_was_compiled_for(over):
+    """The blob names its devices: a one-device program is not spread over
+    all eight local devices, and a mesh program comes back on ITS four
+    (here not the first four, and not in id order)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    d = jax.devices()
+    if over == "mesh":
+        devs = [d[7], d[5], d[6], d[4]]
+        mesh = jax.sharding.Mesh(np.array(devs).reshape(2, 2), ("a", "b"))
+        x = jax.device_put(jnp.arange(16.0).reshape(4, 4),
+                           NamedSharding(mesh, P("a", "b")))
+    else:
+        devs = [d[3]]
+        x = jax.device_put(jnp.arange(16.0).reshape(4, 4), d[3])
+    compiled = jax.jit(lambda a: (a + 1).sum(0)).lower(x).compile()
+    loaded = aot.deserialize_compiled(aot.serialize_compiled(compiled))
+    assert loaded.runtime_executable().local_devices() == devs
+    np.testing.assert_array_equal(np.asarray(loaded(x)),
+                                  np.asarray(compiled(x)))
+
+
 def test_cached_compile_deserialize_failure_recompiles(cache_dir, tele):
     lowered = jax.jit(lambda x: x - 5).lower(jnp.ones((4,)))
     aot.cached_compile(lowered, name="t.g")

@@ -15,8 +15,6 @@ import numpy as np
 import pytest
 
 
-@pytest.mark.needs_multiprocess_cpu
-@pytest.mark.needs_shard_map
 def test_two_process_mesh_training():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ, PYTHONPATH=root)

@@ -214,7 +214,6 @@ def test_bf16_params_checkpoint_configured_precision(tmp_path):
         assert tr2._param_vals[n].dtype == jnp.bfloat16
 
 
-@pytest.mark.needs_shard_map
 def test_bf16_params_zero1_manual_step_scan():
     """zero1(manual) x param_dtype: bf16-SR params compose with the
     dp shard_map region (SR keys derive from the PRE-rank-fold key so

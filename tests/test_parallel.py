@@ -25,7 +25,6 @@ def test_make_mesh_infer():
         make_mesh({"dp": 16})
 
 
-@pytest.mark.needs_shard_map
 def test_ring_attention_matches_local():
     mesh = make_mesh({"sp": 4}, devices=jax.devices()[:4])
     B, H, T, D = 2, 2, 16, 8
@@ -43,7 +42,6 @@ def test_ring_attention_matches_local():
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_ring_attention_causal():
     mesh = make_mesh({"sp": 4}, devices=jax.devices()[:4])
     B, H, T, D = 1, 1, 8, 4
@@ -142,14 +140,12 @@ def test_sharded_trainer_sync_to_block():
     assert not np.allclose(before, after)
 
 
-@pytest.mark.needs_shard_map
 def test_collectives_in_shard_map():
-    from incubator_mxnet_tpu.compat import shard_map
     from incubator_mxnet_tpu.parallel import collectives as C
     import functools
     mesh = make_mesh({"x": 8})
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
                        check_vma=False)
     def f(v):
         s = C.all_reduce(v, "x")
@@ -166,7 +162,6 @@ def test_sharding_rules_matcher():
     assert match("layer0_bias") == P()
 
 
-@pytest.mark.needs_shard_map
 def test_ring_attention_differentiable_on_mesh():
     """Gradients flow through the ring (scan + ppermute) — the long-context
     training path, on a 4-device slice of the virtual CPU mesh."""
@@ -347,7 +342,6 @@ def test_tp_forward_single_allreduce():
 # ZeRO-1 (reduce-scatter sharded optimizer) + gradient accumulation
 # ---------------------------------------------------------------------------
 
-@pytest.mark.needs_shard_map
 def test_zero1_emits_reduce_scatter():
     """HLO audit: zero1=True must lower the dp gradient reduction to
     reduce-scatter (+ param all-gather), replacing plain all-reduce."""
@@ -367,7 +361,6 @@ def test_zero1_emits_reduce_scatter():
     assert c["all-gather"] >= 1, c
 
 
-@pytest.mark.needs_shard_map
 def test_zero1_matches_unsharded_adam():
     """ZeRO-1 is a memory layout, not an algorithm change: training with
     dp-sharded optimizer state must produce the same weights."""
@@ -421,7 +414,6 @@ def test_grad_accum_matches_full_batch():
                                    rtol=2e-5, atol=1e-6)
 
 
-@pytest.mark.needs_shard_map
 def test_multidevice_convergence_lenet():
     """VERDICT r2 #2: train LeNet 50 steps on the 8-device mesh (with
     zero1 + grad accumulation) vs 1 device — same final weights."""
@@ -479,7 +471,6 @@ def _rand_qkv(B, H, T, D, seed=0):
                  for _ in range(3))
 
 
-@pytest.mark.needs_shard_map
 def test_ring_flash_matches_dense_ring():
     mesh = make_mesh({"sp": 4}, devices=jax.devices()[:4])
     B, H, T, D = 2, 2, 64, 8          # T_local = 16: flash tiling contract
@@ -495,7 +486,6 @@ def test_ring_flash_matches_dense_ring():
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_ring_flash_causal_matches_dense_ring():
     mesh = make_mesh({"sp": 4}, devices=jax.devices()[:4])
     B, H, T, D = 1, 2, 64, 8
@@ -507,7 +497,6 @@ def test_ring_flash_causal_matches_dense_ring():
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.needs_shard_map
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_flash_gradients_match_dense(causal):
     """The ring-flash custom VJP (dK/dV accumulators riding the ring) must
@@ -531,8 +520,6 @@ def test_ring_flash_gradients_match_dense(causal):
                                    err_msg="d%s mismatch" % name)
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_sp_axis_routes_through_ring_attention(monkeypatch):
     """VERDICT r4 #3: with sp>1 in the trainer mesh, BERT attention runs
     RING attention (ppermute KV rotation inside shard_map) instead of a

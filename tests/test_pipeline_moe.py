@@ -28,7 +28,6 @@ def _make_stages(S, d, seed=0):
             for _ in range(S)]
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_matches_serial_forward():
     S, d, B = 4, 16, 8
     mesh = make_mesh({"pp": S}, devices=jax.devices()[:S])
@@ -43,7 +42,6 @@ def test_pipeline_matches_serial_forward():
                                atol=2e-6)
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_gradients_match_serial():
     """jax.grad THROUGH the pipelined scan == grads of serial execution
     (ppermute transposes give the backward pipeline for free)."""
@@ -72,7 +70,6 @@ def test_pipeline_gradients_match_serial():
                                    atol=1e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_emits_collective_permute():
     S, d, B = 4, 8, 8
     mesh = make_mesh({"pp": S}, devices=jax.devices()[:S])
@@ -84,7 +81,6 @@ def test_pipeline_emits_collective_permute():
     assert c["collective-permute"] >= 1, c
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_more_microbatches():
     S, d, B = 2, 8, 12
     mesh = make_mesh({"pp": S}, devices=jax.devices()[:S])
@@ -259,8 +255,6 @@ def _xent(out, label):
                                 axis=-1).mean()
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_trainer_dp_pp_composed_loss_parity():
     """FULL train step on a composed dp x pp mesh (embed/head outside the
     pipelined trunk, GPipe inside) matches the single-device run."""
@@ -289,8 +283,6 @@ def test_trainer_dp_pp_composed_loss_parity():
     assert counts["all-reduce"] >= 1, counts
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_trainer_pp_tp_composed_runs():
     """pp composes with a tp axis in the same step (trunk pipelined, tp
     sharding rules on the embed/head outside it)."""
@@ -307,8 +299,6 @@ def test_trainer_pp_tp_composed_runs():
     assert losses[1] < losses[0] + 1.0
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_trainer_zero1_manual_pp_raises_auto_composes():
     """zero1='manual' cannot nest a pp shard_map under its dp region and
     says so; zero1=True auto-selects the constraint formulation, which
@@ -352,7 +342,6 @@ def test_trainer_zero1_manual_pp_raises_auto_composes():
     np.testing.assert_allclose(l1, l2, rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_trainer_zero1_auto_matches_manual():
     """The two ZeRO-1 formulations are the same optimizer: identical loss
     trajectories on a pure-dp mesh."""
@@ -493,7 +482,6 @@ def test_moe_block_top_k_param():
                              else aux))
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_remat_matches_and_more_microbatches():
     """remat=True (the scanned-SPMD answer to 1F1B's memory bound) must be
     numerically identical in forward AND gradients; n_microbatch > S cuts
@@ -524,7 +512,6 @@ def test_pipeline_remat_matches_and_more_microbatches():
     assert dots(True) > dots(False), (dots(True), dots(False))
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_stack_remat_param():
     from incubator_mxnet_tpu.parallel import PipelineStack, ShardedTrainer
     np.random.seed(5)
@@ -550,7 +537,6 @@ def test_pipeline_stack_remat_param():
 # interleaved (virtual-pipeline) schedule + heterogeneous end stages
 # ---------------------------------------------------------------------------
 
-@pytest.mark.needs_shard_map
 def test_pipeline_interleave_matches_serial():
     """interleave=v: v*S round-robin chunks, forward == serial execution."""
     S, v, d, B, M = 4, 2, 8, 24, 8
@@ -574,7 +560,6 @@ def test_pipeline_interleave_matches_serial():
                                atol=2e-6)
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_interleave_gradients_match_serial():
     S, v, d, B, M = 2, 3, 8, 12, 6
     mesh = make_mesh({"pp": S}, devices=jax.devices()[:S])
@@ -604,7 +589,6 @@ def test_pipeline_interleave_gradients_match_serial():
                                    atol=1e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_interleave_cuts_bubble_work():
     """The measurable bubble claim: over the same v*S layers, the
     interleaved schedule's forward HLO carries v*M + S - 1 one-chunk
@@ -655,7 +639,6 @@ def test_pipeline_interleave_cuts_bubble_work():
     assert n_gp - n_inter == (v - 1) * (S - 1)
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_heterogeneous_ends_inside_region():
     """pre_fn (embedding) at the injection point and post_fn (head) at
     the stash point run inside the scanned region, once per microbatch;
@@ -694,7 +677,6 @@ def test_pipeline_heterogeneous_ends_inside_region():
                                    atol=1e-5)
 
 
-@pytest.mark.needs_shard_map
 def test_pipeline_per_microbatch_loss_head():
     """A post_fn that reduces to a per-microbatch scalar comes back as the
     (M,) stack — the loss-in-pipeline pattern bounding logits memory at
@@ -716,8 +698,6 @@ def test_pipeline_per_microbatch_loss_head():
                                atol=2e-6)
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_pipeline_stack_interleave_with_embed_head_under_trainer():
     """PipelineStack(interleave=2, embed=..., head=...) under a composed
     dp x pp ShardedTrainer: loss parity vs single device, het ends INSIDE
@@ -756,8 +736,6 @@ def test_pipeline_stack_interleave_with_embed_head_under_trainer():
     np.testing.assert_allclose(l1, l2, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_dp_tp_pp_three_axis_composition():
     """VERDICT r4 #5: tp INSIDE PipelineStack stages (stage_rules), dp
     gradient reduction outside, one pjit step — pipeline permutes AND
@@ -770,8 +748,6 @@ def test_dp_tp_pp_three_axis_composition():
     assert counts["collective-permute"] >= 1 and counts["all-reduce"] >= 1
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_dp_sp_pp_ring_in_pipeline_composition():
     """r5 stretch: RING attention (sp bound manual, KV rotated by
     ppermute) nested INSIDE the scanned GPipe stages (pp bound manual)
@@ -786,8 +762,6 @@ def test_dp_sp_pp_ring_in_pipeline_composition():
     assert counts["collective-permute"] >= 8
 
 
-@pytest.mark.needs_shard_map_partial
-@pytest.mark.needs_shard_map
 def test_dp_ep_pp_moe_in_pipeline_composition():
     """r5 stretch #2: Switch-MoE blocks AS pipeline stages on a
     dp x ep x pp mesh — ep-sharded expert weights/optimizer state

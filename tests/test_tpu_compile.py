@@ -1,0 +1,215 @@
+"""The main path's kernels and one whole BERT-base train step, compiled for
+a DESCRIBED TPU v5e — no chip attached, nothing runs.
+
+Interpret mode says nothing about Mosaic: the fused AdamW kernel passed
+every interpret-mode test and was refused by the chip's compiler
+(``math.powf`` has no Mosaic lowering). These cases hand real widths to
+that compiler on every tier-1 run.
+
+The topology is described inside a fixture of THIS file, never at import:
+only one process may load libtpu, and every xdist worker imports every
+test file. A compile that passes is not a chip run.
+"""
+
+import functools
+import importlib
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from incubator_mxnet_tpu.ops import pallas
+from incubator_mxnet_tpu.ops.pallas import fused_norm, fused_optim
+
+# the package re-exports the function under the module's own name
+flash_mod = importlib.import_module(
+    "incubator_mxnet_tpu.ops.pallas.flash_attention")
+
+BERT_BASE_PARAMS = 110_000_000      # ≈ the packed f32 buffer of BERT-base
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing a chip here
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+# ------------------------------------------------------------ fused optimizer
+def _adamw(w, g, m, v, t):
+    return fused_optim.fused_adamw_flat(w, g, m, v, 1e-4, 0.01, 1.0, 0.9,
+                                        0.999, 1e-8, t, 1.0, -1.0)
+
+
+def _adam(w, g, m, v, t):
+    return fused_optim.fused_adam_flat(w, g, m, v, 1e-4, 0.01, 0.9, 0.999,
+                                       1e-8, t, 1.0, -1.0)
+
+
+def _sgd_mom(w, g, m, v, t):
+    return fused_optim.fused_sgd_mom_flat(w, g, m, 0.1, 1e-4, 0.9, 1.0, -1.0)
+
+
+def _trainer_adam(w, g, m, v, t):
+    # the ShardedTrainer flavor; adamw=True rides the whole-step case
+    kern = functools.partial(fused_optim._trainer_adam_kernel, adamw=False)
+    c1, c2 = fused_optim._bias_corrections(0.9, 0.999, t)
+    s = fused_optim._scalars(1e-4, 0.01, 0.9, 0.999, 1e-8, 1e-6, c1, c2)
+    return fused_optim._launch(kern, s, [w, m, v, g], 3, False)
+
+
+@pytest.mark.parametrize("update", [_adamw, _adam, _sgd_mom, _trainer_adam],
+                         ids=["adamw", "adam", "sgd_mom", "trainer_adam"])
+def test_fused_optimizer_compiles_for_v5e(one_chip, update):
+    buf = jax.ShapeDtypeStruct((BERT_BASE_PARAMS,), jnp.float32,
+                               sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    text = _compile(update, buf, buf, buf, buf, t)
+    # the bias-correction powers are taken outside the kernel
+    assert "powf" not in text
+
+
+# ----------------------------------------------------------- fused LayerNorm
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_fused_layer_norm_compiles_for_v5e(one_chip, direction):
+    x = jax.ShapeDtypeStruct((64, 128, 768), jnp.bfloat16, sharding=one_chip)
+    gb = jax.ShapeDtypeStruct((768,), jnp.bfloat16, sharding=one_chip)
+
+    def fwd(x, g, b):
+        return fused_norm.fused_layer_norm(x, g, b, 1e-12)
+
+    def bwd(x, g, b):
+        # the backward is plain jnp over the saved input; squaring keeps
+        # the forward kernel's output live in the same program
+        return jax.grad(
+            lambda *a: jnp.square(fwd(*a).astype(jnp.float32)).sum(),
+            argnums=(0, 1, 2))(x, g, b)
+
+    _compile(fwd if direction == "fwd" else bwd, x, gb, gb)
+
+
+# ------------------------------------------------------------ flash attention
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles_for_v5e(one_chip, direction):
+    qkv = jax.ShapeDtypeStruct((16, 12, 512, 64), jnp.bfloat16,
+                               sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((16, 512), jnp.float32, sharding=one_chip)
+
+    def fwd(q, k, v, mask):
+        return flash_mod.flash_attention(q, k, v, kv_mask=mask)
+
+    def bwd(q, k, v, mask):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v, mask).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    _compile(fwd if direction == "fwd" else bwd, qkv, qkv, qkv, mask)
+
+
+# ------------------------------------------------- one whole BERT-base step
+def _answer_tpu(monkeypatch):
+    """The kernel gates ask the backend, which is the CPU here, so the test
+    answers for them; the program gets no option."""
+    def on_tpu():
+        return True
+
+    for name in ("fused_norm_available", "flash_attention_available",
+                 "fused_optim_available"):
+        monkeypatch.setattr(pallas, name, on_tpu)
+    monkeypatch.setattr(fused_norm, "fused_norm_available", on_tpu)
+    monkeypatch.setattr(flash_mod, "flash_attention_available", on_tpu)
+    monkeypatch.setattr(fused_optim, "fused_optim_available", on_tpu)
+
+
+def test_bert_base_train_step_compiles_for_v5e(one_chip, monkeypatch):
+    """chip_smoke.py's shape A: the default (fused-optimizer-on) BERT-base
+    AdamW step at B=64,T=128, built on the CPU mesh and lowered for the
+    described chip."""
+    import chip_smoke
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    tr = chip_smoke.bert_trainer(mesh)
+    host_data, host_label = chip_smoke.bert_batch(64, 128)
+    fn, args = tr._inspection_step([mx.nd.array(a) for a in host_data],
+                                   [mx.nd.array(a) for a in host_label])
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+
+    # from here on the trace is for the chip (the trainer's own eager
+    # shape-materializing forward above ran on the CPU, kernels off)
+    _answer_tpu(monkeypatch)
+    compiled = fn.lower(*shapes).compile()
+    text = compiled.as_text()
+    # fused LayerNorm (2 a layer + embedding + MLM head) and the one fused
+    # AdamW launch; nothing gave way to a lax reference
+    assert text.count("tpu_custom_call") >= 2 * 12 + 3
+    assert tr._fused_launches == 1
+    mem = compiled.memory_analysis()
+    need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert need < 16e9, "does not fit one v5e chip: %d bytes" % need
+
+
+def test_bert_train_step_compiles_for_v5e_mesh(topo, monkeypatch):
+    """chip_smoke.py --chips 4: the dp2 x tp2 step (depth cut to 2 layers
+    here; every layer shards alike). jit refuses a Mosaic kernel in a
+    program for several devices, so over a mesh the step must take the XLA
+    forms of LayerNorm and the per-param optimizer, decided from its mesh —
+    with every gate answering "tpu" it still lowers, and holds no kernel."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import chip_smoke
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.models.bert import bert_sharding_rules
+    from incubator_mxnet_tpu.parallel import make_mesh
+
+    tr = chip_smoke.bert_trainer(
+        make_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4]),
+        rules=bert_sharding_rules("tp"), data_spec=P("dp"), num_layers=2)
+    host_data, host_label = chip_smoke.bert_batch(64, 128)
+    _fn, args = tr._inspection_step([mx.nd.array(a) for a in host_data],
+                                    [mx.nd.array(a) for a in host_label])
+    # the same mesh and shardings over the described chips: nothing can be
+    # placed on them, so the trainer is built on the CPU mesh and handed
+    # the described one for the trace
+    chips = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+
+    def described(a):
+        spec = a.sharding.spec if isinstance(a.sharding, NamedSharding) \
+            else P()
+        return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                    sharding=NamedSharding(chips, spec))
+
+    shapes = jax.tree_util.tree_map(described, args)
+    monkeypatch.setattr(tr, "_mesh", chips)
+    monkeypatch.setattr(tr, "_param_shardings", {
+        n: NamedSharding(chips, s.spec)
+        for n, s in tr._param_shardings.items()})
+    _answer_tpu(monkeypatch)
+    compiled = jax.jit(tr._build_raw(3)).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert tr._fused_launches == 0
+    assert "all-reduce" in text          # dp grads, tp activations
+    # a tp-ruled weight is held in halves
+    name = next(n for n in shapes[0] if n.endswith("ffn1_weight"))
+    assert shapes[0][name].sharding.shard_shape(shapes[0][name].shape) == \
+        (3072 // 2, 768)

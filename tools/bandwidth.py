@@ -26,7 +26,6 @@ def main():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from incubator_mxnet_tpu.compat import shard_map
 
     devs = jax.devices()
     n = len(devs)
@@ -38,7 +37,7 @@ def main():
     def allreduce(v):
         def f(s):
             return jax.lax.psum(s, "dp")
-        return shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))(v)
+        return jax.shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))(v)
 
     jax.block_until_ready(allreduce(x))  # compile
     t0 = time.perf_counter()
