@@ -1700,24 +1700,6 @@ def _cold_child(plane, workdir):
                       "compile_events": int(events)}))
 
 
-def _emit_telemetry_summary():
-    """Closing JSON line: what the run itself observed — step-time
-    histogram stats and the XLA compile tax — so a perf number can be
-    read next to the compile/step behavior that produced it."""
-    from incubator_mxnet_tpu.telemetry import catalog as cat
-    steps_snap = cat.trainer_step_seconds.snapshot()
-    count = sum(int(v[0]) for v in steps_snap.values())
-    total = sum(float(v[1]) for v in steps_snap.values())
-    line = {"metric": "telemetry_summary", "steps_observed": count,
-            "jit_compiles": int(cat.trainer_jit_compiles.value()),
-            "jit_compile_seconds": round(
-                float(cat.trainer_jit_compile_seconds.value()), 3)}
-    if count:
-        line["step_seconds_avg"] = round(total / count, 5)
-        line["step_seconds_total"] = round(total, 3)
-    print(json.dumps(line))
-
-
 # --------------------------------------------------------------------------
 # MFU A/B (r15): overlap + fused optimizer, on vs off, SAME config in the
 # SAME round — the acceptance rows for the comm/compute-overlap +
@@ -1979,10 +1961,7 @@ def main():
     if model != "cold_start":   # that mode measures compilation itself
         compilecache.use_jax_cache()
     telemetry.enable()
-    try:
-        return _dispatch(model, batch, steps, dtype)
-    finally:
-        _emit_telemetry_summary()
+    return _dispatch(model, batch, steps, dtype)
 
 
 # modes whose measured work runs in child processes: a chip belongs to one

@@ -43,6 +43,19 @@ def _env_int(name, default):
         return default
 
 
+def _host_bytes(args):
+    """Bytes of the host (numpy) arrays among `args`, lists looked into:
+    what a jitted call given them has to ship to the device. An array
+    that already lives on the device counts 0."""
+    total = 0
+    for a in args:
+        if isinstance(a, (list, tuple)):
+            total += _host_bytes(a)
+        elif isinstance(a, np.ndarray):
+            total += a.nbytes
+    return total
+
+
 class GPTPagedLM:
     """Shape-cached jit adapter over ``gpt_forward_paged``.
 
@@ -83,10 +96,15 @@ class GPTPagedLM:
                             max_len=max_len or self.config["max_len"], **kw)
 
     def forward(self, tokens, lengths, tables, k_pools, v_pools):
-        logits, nk, nv = self._fn(self.params, tokens, lengths, tables,
-                                  k_pools, v_pools)
-        return (np.asarray(logits), [np.asarray(a) for a in nk],
-                [np.asarray(a) for a in nv])
+        args = (tokens, lengths, tables, k_pools, v_pools)
+        sp = _tr.span("lm.dispatch")
+        if sp is not _tr.NULL_SPAN:     # counted only for a real span
+            sp.set_attr("h2d_bytes", _host_bytes(args))
+        with sp:
+            logits, nk, nv = self._fn(self.params, *args)
+        with _tr.span("lm.fetch"):
+            return (np.asarray(logits), [np.asarray(a) for a in nk],
+                    [np.asarray(a) for a in nv])
 
 
 class GenerateEngine:
@@ -128,28 +146,31 @@ class GenerateEngine:
     def _forward(self, adapter, cache, slots, tokens):
         """One adapter forward for `slots` (list) feeding `tokens`
         (S, C); returns (logits, new_k, new_v) WITHOUT committing."""
-        lengths = np.asarray([int(cache.lengths[s]) for s in slots],
-                             np.int32)
-        tables = cache.tables_array(slots)
-        kps = [cache.pool("k%d" % i) for i in range(adapter.num_layers)]
-        vps = [cache.pool("v%d" % i) for i in range(adapter.num_layers)]
+        with _tr.span("kv.gather"):
+            lengths = np.asarray([int(cache.lengths[s]) for s in slots],
+                                 np.int32)
+            tables = cache.tables_array(slots)
+            kps = [cache.pool("k%d" % i) for i in range(adapter.num_layers)]
+            vps = [cache.pool("v%d" % i) for i in range(adapter.num_layers)]
         return adapter.forward(tokens, lengths, tables, kps, vps)
 
-    def _commit(self, adapter, cache, slot, row, new_k, new_v, count):
-        """Append `count` chunk positions of one row into the cache."""
-        for c in range(count):
-            for i in range(adapter.num_layers):
-                cache.append("k%d" % i, slot, new_k[i][row, c])
-                cache.append("v%d" % i, slot, new_v[i][row, c])
-            cache.advance(slot)
+    def _commit(self, adapter, cache, slots, new_k, new_v, count):
+        """Append the first `count` chunk positions of every row (row r
+        belongs to ``slots[r]``) into the cache."""
+        with _tr.span("kv.commit"):
+            for row, slot in enumerate(slots):
+                for c in range(count):
+                    for i in range(adapter.num_layers):
+                        cache.append("k%d" % i, slot, new_k[i][row, c])
+                        cache.append("v%d" % i, slot, new_v[i][row, c])
+                    cache.advance(slot)
 
     def _step(self, adapter, cache, slots, tokens, commit=True):
         """Feed one token per slot ((S, 1)); commit K/V; return the
         (S, V) next-token logits."""
         logits, nk, nv = self._forward(adapter, cache, slots, tokens)
         if commit:
-            for row, slot in enumerate(slots):
-                self._commit(adapter, cache, slot, row, nk, nv, 1)
+            self._commit(adapter, cache, slots, nk, nv, 1)
         return logits[:, -1]
 
     def _prefill(self, adapter, cache, slot, tokens_1d):
@@ -166,7 +187,7 @@ class GenerateEngine:
             padded = np.zeros((1, chunk), np.int32)
             padded[0, :valid] = piece
             _logits, nk, nv = self._forward(adapter, cache, [slot], padded)
-            self._commit(adapter, cache, slot, 0, nk, nv, valid)
+            self._commit(adapter, cache, [slot], nk, nv, valid)
 
     def _sample(self, logits_row):
         if self.temperature <= 0:
@@ -212,13 +233,15 @@ class GenerateEngine:
                              "out": [], "done": False})
 
             # prefill: commit ctx[:-1]; the last prompt token is fed by
-            # the first decode step (its logits choose token 1)
-            t0 = time.monotonic()
+            # the first decode step (its logits choose token 1). Each
+            # region (a prompt's prefill, a decode step or round) is
+            # timed once: its span, its histogram and last_stats hold
+            # that one reading.
             for s in seqs:
-                t_seq = time.monotonic()
                 with _tr.span("gen.prefill", model=self.name,
                               slot=s["slot"],
-                              tokens=max(len(s["ctx"]) - 1, 0)):
+                              tokens=max(len(s["ctx"]) - 1, 0)) as sp:
+                    t0 = time.monotonic()
                     if len(s["ctx"]) > 1:
                         self._prefill(self.model, self.cache, s["slot"],
                                       s["ctx"][:-1])
@@ -226,18 +249,17 @@ class GenerateEngine:
                             self._prefill(self.draft, self.draft_cache,
                                           s["dslot"], s["ctx"][:-1])
                         stats["prefill_tokens"] += len(s["ctx"]) - 1
-                _cat.gen_prefill_seconds.observe(
-                    time.monotonic() - t_seq, model=self.name)
-            stats["prefill_seconds"] = time.monotonic() - t0
+                    dt = time.monotonic() - t0
+                    sp.set_duration(dt)
+                stats["prefill_seconds"] += dt
+                _cat.gen_prefill_seconds.observe(dt, model=self.name)
 
-            t1 = time.monotonic()
             if self.draft is not None and self.spec_k > 0:
                 for s in seqs:
                     self._speculative_loop(s, max_new_tokens, eos_id,
                                            stats)
             else:
                 self._plain_loop(seqs, max_new_tokens, eos_id, stats)
-            stats["decode_seconds"] = time.monotonic() - t1
             _cat.gen_tokens_committed.inc(
                 stats["prefill_tokens"], model=self.name, phase="prefill")
             _cat.gen_tokens_committed.inc(
@@ -260,9 +282,9 @@ class GenerateEngine:
             live = [s for s in seqs if not s["done"]]
             if not live:
                 return
-            t0 = time.monotonic()
             with _tr.span("gen.decode_step", model=self.name,
                           rows=len(live)) as sp:
+                t0 = time.monotonic()
                 tokens = np.asarray([[s["ctx"][-1]] for s in live],
                                     np.int32)
                 logits = self._step(self.model, self.cache,
@@ -277,8 +299,10 @@ class GenerateEngine:
                     if tok == eos_id or len(s["out"]) >= max_new_tokens:
                         s["done"] = True
                 sp.set_attr("tokens_committed", committed)
-            _cat.gen_decode_seconds.observe(time.monotonic() - t0,
-                                            model=self.name)
+                dt = time.monotonic() - t0
+                sp.set_duration(dt)
+            stats["decode_seconds"] += dt
+            _cat.gen_decode_seconds.observe(dt, model=self.name)
 
     # ------------------------------------------------ speculative decode
     def _speculative_loop(self, s, max_new_tokens, eos_id, stats):
@@ -328,7 +352,7 @@ class GenerateEngine:
             verify = np.asarray([[ctx[-1]] + drafts], np.int32)
             logits, nk, nv = self._forward(self.model, self.cache,
                                            [slot], verify)
-            self._commit(self.model, self.cache, slot, 0, nk, nv, k + 1)
+            self._commit(self.model, self.cache, [slot], nk, nv, k + 1)
             target = [int(np.argmax(logits[0, j])) for j in range(k + 1)]
             # 4) longest accepted prefix + the target's own token
             a = 0
@@ -351,6 +375,7 @@ class GenerateEngine:
             self.cache.truncate(slot, len(ctx) - 1)
             self.draft_cache.truncate(dslot, len(ctx) - 1)
             dt = time.monotonic() - t0
+            stats["decode_seconds"] += dt
             _cat.gen_decode_seconds.observe(dt, model=self.name)
             cur = _tr.current()
             if cur is not None:

@@ -144,6 +144,7 @@ def _fwd_call(q, k, v, bias, scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
 
 
@@ -282,6 +283,7 @@ def _bwd_call(q, k, v, out, lse, g, bias, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, T, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*args)
 
     kv_specs = [
@@ -333,6 +335,7 @@ def _bwd_call(q, k, v, out, lse, g, bias, scale, causal, block_q, block_k,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*args)
     if has_dbias:
         dk, dv, dbias = outs

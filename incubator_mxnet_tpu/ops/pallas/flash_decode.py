@@ -170,6 +170,7 @@ def _kernel_call(q, k_pool, v_pool, block_tables, lengths, scale,
             jax.ShapeDtypeStruct((S, 8, H), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(block_tables, lengths, q, k_pool, v_pool)
     return o, m[:, 0, :], l[:, 0, :]
 

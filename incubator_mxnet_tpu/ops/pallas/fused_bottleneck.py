@@ -93,6 +93,7 @@ def fused_bottleneck(x, w1, s1, b1, w2, s2, b2, w3, s3, b3,
         out_specs=pl.BlockSpec((1, H, W, C), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, W, C), x.dtype),
         interpret=interpret,
+        name="fused_bottleneck",
     )(x, w1, s1.reshape(1, M), b1.reshape(1, M),
       w2, s2.reshape(1, M), b2.reshape(1, M),
       w3, s3.reshape(1, C), b3.reshape(1, C))

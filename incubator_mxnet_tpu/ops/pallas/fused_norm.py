@@ -67,6 +67,7 @@ def _ln_call(x2d, gamma, beta, eps, block_r, interpret=False):
         out_specs=pl.BlockSpec((block_r, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), x2d.dtype),
         interpret=interpret,
+        name="fused_layernorm",
     )(x2d, gamma.reshape(1, C), beta.reshape(1, C))
 
 
@@ -137,6 +138,7 @@ def _softmax_core(x2d, interpret):
         out_specs=pl.BlockSpec((block_r, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, C), x2d.dtype),
         interpret=interpret,
+        name="fused_softmax",
     )(x2d)
 
 

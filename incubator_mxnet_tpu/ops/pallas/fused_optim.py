@@ -60,17 +60,20 @@ def flatten_group(arrs):
     """Concat ravelled same-dtype ``arrs`` -> (flat_1d, metas) where metas
     reverses the packing via :func:`split_group`."""
     metas = [(a.shape, int(a.size)) for a in arrs]
-    if len(arrs) == 1:
-        return arrs[0].reshape(-1), metas
-    return jnp.concatenate([a.reshape(-1) for a in arrs]), metas
+    with jax.named_scope("pack"):
+        if len(arrs) == 1:
+            return arrs[0].reshape(-1), metas
+        return jnp.concatenate([a.reshape(-1) for a in arrs]), metas
 
 
 def split_group(flat, metas):
     """Inverse of :func:`flatten_group`."""
     out, off = [], 0
-    for shape, size in metas:
-        out.append(jax.lax.slice(flat, (off,), (off + size,)).reshape(shape))
-        off += size
+    with jax.named_scope("unpack"):
+        for shape, size in metas:
+            out.append(
+                jax.lax.slice(flat, (off,), (off + size,)).reshape(shape))
+            off += size
     return out
 
 
@@ -154,10 +157,12 @@ def _adamw_kernel(s_ref, w_ref, m_ref, v_ref, g_ref,
 # launch plumbing
 # ---------------------------------------------------------------------------
 
-def _launch(kernel, scalars, bufs, n_out, interpret):
+def _launch(kernel, scalars, bufs, n_out, interpret, name):
     """One pallas_call over the packed (R, 128) buffers. ``bufs[:n_out]``
     are aliased to the outputs (in-place update in HBM) on the real-TPU
-    path; weight/state buffers must therefore come first."""
+    path; weight/state buffers must therefore come first. `name` is the
+    launch's name on the device: the compiled instruction and its events
+    in a trace are called after it."""
     tiles = [_to_tiles(b) for b in bufs]
     R = tiles[0].shape[0]
     block_r = _row_block(R)
@@ -178,6 +183,7 @@ def _launch(kernel, scalars, bufs, n_out, interpret):
                         for _ in range(n_out)),
         input_output_aliases=aliases,
         interpret=interpret,
+        name=name,
     )(scalars, *tiles)
     n = bufs[0].shape[0]
     return tuple(o.reshape(-1)[:n] for o in outs)
@@ -201,7 +207,8 @@ def fused_sgd_mom_flat(w, g, mom, lr, wd, momentum, rescale, clip,
                        interpret=False):
     """One-launch SGD-momentum over packed 1-D buffers -> (w, mom)."""
     s = _scalars(lr, wd, momentum, 0.0, 0.0, rescale, clip, 0.0)
-    return _launch(_sgd_mom_kernel, s, [w, mom, g], 2, interpret)
+    return _launch(_sgd_mom_kernel, s, [w, mom, g], 2, interpret,
+                   "fused_sgd_mom")
 
 
 def fused_adam_flat(w, g, m, v, lr, wd, b1, b2, eps, t, rescale, clip,
@@ -210,7 +217,8 @@ def fused_adam_flat(w, g, m, v, lr, wd, b1, b2, eps, t, rescale, clip,
     c1, c2 = _bias_corrections(b1, b2, t)
     coef = jnp.asarray(lr, jnp.float32) * jnp.sqrt(c2) / c1
     s = _scalars(lr, wd, b1, b2, eps, rescale, clip, coef)
-    return _launch(_adam_kernel, s, [w, m, v, g], 3, interpret)
+    return _launch(_adam_kernel, s, [w, m, v, g], 3, interpret,
+                   "fused_adam")
 
 
 def fused_adamw_flat(w, g, m, v, lr, wd, eta, b1, b2, eps, t, rescale, clip,
@@ -218,7 +226,8 @@ def fused_adamw_flat(w, g, m, v, lr, wd, eta, b1, b2, eps, t, rescale, clip,
     """One-launch AdamW over packed 1-D buffers -> (w, m, v)."""
     c1, c2 = _bias_corrections(b1, b2, t)
     s = _scalars(lr, wd, b1, b2, eps, rescale, clip, eta, c1, c2)
-    return _launch(_adamw_kernel, s, [w, m, v, g], 3, interpret)
+    return _launch(_adamw_kernel, s, [w, m, v, g], 3, interpret,
+                   "fused_adamw")
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +270,7 @@ def multi_trainer_sgd_mom(ws, gs, moms, lr, wd, momentum, interpret=False):
         # (both prologue ops are bitwise no-ops at those values)
         s = _scalars(lr, wd, momentum, 0.0, 0.0, 1.0, -1.0, 0.0)
         nw, nm = _launch(_sgd_mom_kernel, s, [wflat, mflat, gflat], 2,
-                         interpret)
+                         interpret, "fused_sgd_mom")
     else:
         nm = momentum * mflat - lr * (gflat + wd * wflat)
         nw = wflat + nm
@@ -282,7 +291,8 @@ def multi_trainer_adam(ws, gs, ms, vs, lr, wd, b1, b2, eps, t, adamw=False,
         s = _scalars(lr, wd, b1, b2, eps, lr * wd, c1, c2)
         kern = functools.partial(_trainer_adam_kernel, adamw=adamw)
         nw, nm, nv = _launch(kern, s, [wflat, mflat, vflat, gflat], 3,
-                             interpret)
+                             interpret,
+                             "fused_adamw" if adamw else "fused_adam")
     else:
         g = gflat if adamw else gflat + wd * wflat
         nm = b1 * mflat + (1 - b1) * g

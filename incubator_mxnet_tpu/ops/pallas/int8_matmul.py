@@ -45,4 +45,5 @@ def int8_matmul(a, b, block_m=512, block_n=512, interpret=False):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         interpret=interpret,
+        name="int8_matmul",
     )(a, b)

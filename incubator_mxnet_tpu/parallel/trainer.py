@@ -25,8 +25,15 @@ from ..ndarray import NDArray
 from ..telemetry import catalog as _cat
 from ..telemetry import costs as _costs
 from ..telemetry import metrics as _met
+from ..telemetry import tracing as _tr
 
 __all__ = ["ShardedTrainer", "sharding_rules"]
+
+#: the ``jax.named_scope`` around the optimizer update of every step
+#: variant (fused launch, per-parameter, ZeRO): in the compiled step's HLO
+#: the whole optimizer path reads ``op_name=".../optim/..."``, with
+#: ``pack`` / ``unpack`` below it from ``ops/pallas/fused_optim.py``.
+OPTIM_SCOPE = "optim"
 
 
 def _gput(arr, sharding):
@@ -40,6 +47,16 @@ def _gput(arr, sharding):
     if isinstance(arr, jax.Array) and not sharding.is_fully_addressable:
         arr = _np.asarray(arr)
     return jax.device_put(arr, sharding)
+
+
+def _rows(datas, scan_over_batch=False):
+    """Rows of one step's batch, 0 where the first data array has no
+    shape. With a batch per step of a ``step_scan`` call the leading axis
+    is the scan axis and the rows come second."""
+    shape = getattr(datas[0], "shape", None) if datas else None
+    if not shape:
+        return 0
+    return int(shape[1] if scan_over_batch and len(shape) > 1 else shape[0])
 
 
 def _stochastic_round(x32, dtype, key):
@@ -655,8 +672,9 @@ class ShardedTrainer:
             # decorrelated key stream for stochastic-rounding write-back
             upd_key = (jax.random.fold_in(key, 0x51A57)
                        if self._param_dtype is not None else None)
-            new_params, new_opt = self._apply_all(param_vals, grads,
-                                                  opt_state, t, upd_key)
+            with jax.named_scope(OPTIM_SCOPE):
+                new_params, new_opt = self._apply_all(param_vals, grads,
+                                                      opt_state, t, upd_key)
             return new_params, new_aux, new_opt, loss
 
         return step_fn
@@ -685,8 +703,9 @@ class ShardedTrainer:
             ok = jnp.isfinite(loss) & jnp.isfinite(gnorm)
             upd_key = (jax.random.fold_in(key, 0x51A57)
                        if self._param_dtype is not None else None)
-            new_params, new_opt = self._apply_all(param_vals, grads,
-                                                  opt_state, t, upd_key)
+            with jax.named_scope(OPTIM_SCOPE):
+                new_params, new_opt = self._apply_all(param_vals, grads,
+                                                      opt_state, t, upd_key)
 
             # skip-step: elementwise select old vs new (both sides already
             # computed). where, not cond: a NaN in the rejected branch
@@ -741,33 +760,35 @@ class ShardedTrainer:
                            if jnp.issubdtype(v.dtype, jnp.inexact) else v)
                        for n, v in new_aux.items()}
             new_params, new_opt = {}, {}
-            for i, n in enumerate(diff_names):
-                k_n = (jax.random.fold_in(upd_key, i)
-                       if upd_key is not None else None)
-                st = opt_state.get(n, ())
-                p, g = param_vals[n], grads[n]
-                ax = zero_axes[n]
-                if ax is None:
-                    # no dp-divisible dim: plain all-reduce + full update
-                    g = jax.lax.pmean(g, dp)
-                    newp, new_st = self._apply_opt(p, g, st, t, key=k_n)
-                else:
-                    # grad mean arrives SHARDED (reduce-scatter), each rank
-                    # updates only its 1/dp slice of param + opt state,
-                    # fresh weights are all-gathered
-                    g = jax.lax.psum_scatter(
-                        g, dp, scatter_dimension=ax, tiled=True) / dp_size
-                    size = p.shape[ax] // dp_size
-                    start = jax.lax.axis_index(dp) * size
-                    p_sh = jax.lax.dynamic_slice_in_dim(p, start, size,
-                                                        axis=ax)
-                    newp_sh, new_st = self._apply_opt(p_sh, g, st, t,
-                                                      key=k_n)
-                    newp = jax.lax.all_gather(newp_sh, dp, axis=ax,
-                                              tiled=True)
-                new_params[n] = newp
-                if new_st:
-                    new_opt[n] = new_st
+            with jax.named_scope(OPTIM_SCOPE):
+                for i, n in enumerate(diff_names):
+                    k_n = (jax.random.fold_in(upd_key, i)
+                           if upd_key is not None else None)
+                    st = opt_state.get(n, ())
+                    p, g = param_vals[n], grads[n]
+                    ax = zero_axes[n]
+                    if ax is None:
+                        # no dp-divisible dim: plain all-reduce, full update
+                        g = jax.lax.pmean(g, dp)
+                        newp, new_st = self._apply_opt(p, g, st, t, key=k_n)
+                    else:
+                        # grad mean arrives SHARDED (reduce-scatter), each
+                        # rank updates only its 1/dp slice of param + opt
+                        # state, fresh weights are all-gathered
+                        g = jax.lax.psum_scatter(
+                            g, dp, scatter_dimension=ax,
+                            tiled=True) / dp_size
+                        size = p.shape[ax] // dp_size
+                        start = jax.lax.axis_index(dp) * size
+                        p_sh = jax.lax.dynamic_slice_in_dim(p, start, size,
+                                                            axis=ax)
+                        newp_sh, new_st = self._apply_opt(p_sh, g, st, t,
+                                                          key=k_n)
+                        newp = jax.lax.all_gather(newp_sh, dp, axis=ax,
+                                                  tiled=True)
+                    new_params[n] = newp
+                    if new_st:
+                        new_opt[n] = new_st
             return new_params, new_aux, new_opt, loss
 
         rep = P()
@@ -839,6 +860,21 @@ class ShardedTrainer:
         `n_steps`; pass the flag explicitly in that case). Returns the
         per-step loss array (device-resident).
         """
+        with _tr.span("trainer.step") as sp:
+            with _tr.span("trainer.prep_batch"):
+                datas, labels, scan_over_batch = self._prep_scan_batch(
+                    data, label, n_steps, per_step_batches)
+            sp.set_attr("rows", _rows(datas, scan_over_batch))
+            with _tr.span("trainer.dispatch"):
+                new_params, new_aux, new_opt, losses = self._dispatch_scan(
+                    datas, labels, n_steps, scan_over_batch, key)
+            self._param_vals = {**new_params, **new_aux}
+            self._opt_state = new_opt if new_opt else self._opt_state
+        return losses
+
+    def _prep_scan_batch(self, data, label, n_steps, per_step_batches):
+        """Place the batch of a ``step_scan`` call.
+        -> (datas, labels, scan_over_batch)"""
         datas = list(data) if isinstance(data, (list, tuple)) else [data]
         labels = list(label) if isinstance(label, (list, tuple)) else [label]
         datas = [d._data if isinstance(d, NDArray) else jnp.asarray(d)
@@ -869,6 +905,12 @@ class ShardedTrainer:
                      for d in datas]
         labels = [_gput(l, _shard(self._label_sharding))
                   for l in labels]
+        return datas, labels, scan_over_batch
+
+    def _dispatch_scan(self, datas, labels, n_steps, scan_over_batch, key):
+        """The host's part of a ``step_scan`` call after the batch is
+        placed: key and step scalars, the program (built once for a
+        shape), the call, the retry. -> the program's outputs"""
         cache_key = (len(datas), n_steps, scan_over_batch)
         if getattr(self, "_scan_cache", None) is None:
             self._scan_cache = {}
@@ -881,13 +923,6 @@ class ShardedTrainer:
         scan_args = (pv, aux_vals, self._opt_state, t, key,
                      *(datas + labels))
 
-        def _scan_samples():
-            shp = datas[0].shape if datas else None
-            if not shp:
-                return None
-            batch = shp[1] if scan_over_batch and len(shp) > 1 else shp[0]
-            return int(batch) * n_steps
-
         def _build_scan_program():
             jit_fn = self._build_scan(len(datas), n_steps, scan_over_batch)
             if not self._aot_wanted():
@@ -899,7 +934,8 @@ class ShardedTrainer:
                                           int(scan_over_batch))
             return self._compile_program(
                 exe_name, jit_fn, scan_args, cost_name="trainer.step_scan",
-                samples_per_exec=_scan_samples()), True
+                samples_per_exec=(_rows(datas, scan_over_batch)
+                                  * n_steps) or None), True
         if cache_key not in self._scan_cache:
             self._scan_cache[cache_key] = _build_scan_program()
         t0 = time.perf_counter() if _met.enabled() else None
@@ -914,20 +950,16 @@ class ShardedTrainer:
             self._scan_cache[cache_key] = _build_scan_program()
             new_params, new_aux, new_opt, losses = \
                 self._scan_cache[cache_key][0](*scan_args)
-        self._param_vals = {**new_params, **new_aux}
-        self._opt_state = new_opt if new_opt else self._opt_state
         if t0 is not None:
             lbl = self._telemetry_labels
             _cat.trainer_steps.inc(n_steps, **lbl)
             if getattr(self, "_fused_launches", 0):
                 _cat.optim_fused_launches.inc(self._fused_launches * n_steps)
-            if datas and getattr(datas[0], "shape", None):
-                shp = datas[0].shape
-                # per-step-batch mode: leading axis is the scan axis
-                batch = shp[1] if scan_over_batch and len(shp) > 1 else shp[0]
-                _cat.trainer_samples.inc(int(batch) * n_steps)
+            rows = _rows(datas, scan_over_batch)
+            if rows:
+                _cat.trainer_samples.inc(rows * n_steps)
             _costs.observe("trainer.step_scan", time.perf_counter() - t0)
-        return losses
+        return new_params, new_aux, new_opt, losses
 
     def _prep_batch(self, data, label):
         datas = list(data) if isinstance(data, (list, tuple)) else [data]
@@ -977,10 +1009,9 @@ class ShardedTrainer:
                             depth=depth, transfer=_transfer,
                             retry_window=retry_window)
 
-    def step(self, data, label, key=None):
-        """Run one sharded train step; returns the (device) scalar loss."""
-        t0 = time.perf_counter() if _met.enabled() else None
-        datas, labels = self._prep_batch(data, label)
+    def _dispatch_step(self, datas, labels, key):
+        """The host's part of one step after the batch is placed: the key
+        and step scalars, the jitted call, the retrace retry."""
         if key is None:
             key = jax.random.PRNGKey(self._step_count)
         self._ensure_step_program(datas, labels, key)
@@ -989,7 +1020,7 @@ class ShardedTrainer:
         self._param_vals_diff = {n: self._param_vals[n] for n in self._diff_names}
         aux_vals = {n: self._param_vals[n] for n in self._aux_names}
         try:
-            new_params, new_aux, new_opt, loss = self._jit_step(
+            return self._jit_step(
                 self._param_vals_diff, aux_vals, self._opt_state, t, key,
                 *datas, *labels)
         except TypeError:
@@ -1000,11 +1031,23 @@ class ShardedTrainer:
             # re-lowers through the cache and retries once
             self._jit_step = None
             self._ensure_step_program(datas, labels, key)
-            new_params, new_aux, new_opt, loss = self._jit_step(
+            return self._jit_step(
                 self._param_vals_diff, aux_vals, self._opt_state, t, key,
                 *datas, *labels)
-        self._param_vals = {**new_params, **new_aux}
-        self._opt_state = new_opt if new_opt else self._opt_state
+
+    def step(self, data, label, key=None):
+        """Run one sharded train step; returns the (device) scalar loss."""
+        t0 = time.perf_counter() if _met.enabled() else None
+        with _tr.span("trainer.step") as sp:
+            with _tr.span("trainer.prep_batch"):
+                datas, labels = self._prep_batch(data, label)
+            rows = _rows(datas)
+            sp.set_attr("rows", rows)
+            with _tr.span("trainer.dispatch"):
+                new_params, new_aux, new_opt, loss = self._dispatch_step(
+                    datas, labels, key)
+            self._param_vals = {**new_params, **new_aux}
+            self._opt_state = new_opt if new_opt else self._opt_state
         if t0 is not None:
             dt = time.perf_counter() - t0
             lbl = self._telemetry_labels
@@ -1012,8 +1055,8 @@ class ShardedTrainer:
             _cat.trainer_steps.inc(**lbl)
             if getattr(self, "_fused_launches", 0):
                 _cat.optim_fused_launches.inc(self._fused_launches)
-            if datas and hasattr(datas[0], "shape") and datas[0].shape:
-                _cat.trainer_samples.inc(int(datas[0].shape[0]))
+            if rows:
+                _cat.trainer_samples.inc(rows)
             _costs.observe("trainer.step", dt)
         return loss
 
@@ -1035,30 +1078,36 @@ class ShardedTrainer:
         fused 3-float device->host read vs step().
         """
         t0 = time.perf_counter() if _met.enabled() else None
-        datas, labels = self._prep_batch(data, label)
-        if self._jit_step_guarded is None:
-            self._jit_step_guarded = jax.jit(
-                self._build_raw_guarded(len(datas)),
-                donate_argnums=(0, 1, 2))
-        if key is None:
-            key = jax.random.PRNGKey(self._step_count)
-        self._step_count += 1
-        t = jnp.float32(self._step_count)
-        pv = {n: self._param_vals[n] for n in self._diff_names}
-        aux_vals = {n: self._param_vals[n] for n in self._aux_names}
-        new_params, new_aux, new_opt, loss, stats = self._jit_step_guarded(
-            pv, aux_vals, self._opt_state, t, key,
-            jnp.float32(loss_scale), *datas, *labels)
-        self._param_vals = {**new_params, **new_aux}
-        self._opt_state = new_opt if new_opt else self._opt_state
-        stats = jax.device_get(stats)   # the ONE host sync of the step
+        with _tr.span("trainer.step") as sp:
+            with _tr.span("trainer.prep_batch"):
+                datas, labels = self._prep_batch(data, label)
+            rows = _rows(datas)
+            sp.set_attr("rows", rows)
+            with _tr.span("trainer.dispatch"):
+                if self._jit_step_guarded is None:
+                    self._jit_step_guarded = jax.jit(
+                        self._build_raw_guarded(len(datas)),
+                        donate_argnums=(0, 1, 2))
+                if key is None:
+                    key = jax.random.PRNGKey(self._step_count)
+                self._step_count += 1
+                t = jnp.float32(self._step_count)
+                pv = {n: self._param_vals[n] for n in self._diff_names}
+                aux_vals = {n: self._param_vals[n] for n in self._aux_names}
+                new_params, new_aux, new_opt, loss, stats = \
+                    self._jit_step_guarded(
+                        pv, aux_vals, self._opt_state, t, key,
+                        jnp.float32(loss_scale), *datas, *labels)
+            self._param_vals = {**new_params, **new_aux}
+            self._opt_state = new_opt if new_opt else self._opt_state
+            stats = jax.device_get(stats)   # the ONE host sync of the step
         if t0 is not None:
             lbl = self._telemetry_labels
             _cat.trainer_step_seconds.observe(time.perf_counter() - t0,
                                               **lbl)
             _cat.trainer_steps.inc(**lbl)
-            if datas and hasattr(datas[0], "shape") and datas[0].shape:
-                _cat.trainer_samples.inc(int(datas[0].shape[0]))
+            if rows:
+                _cat.trainer_samples.inc(rows)
         return loss, bool(stats[0] > 0.5), float(stats[1])
 
     def _inspection_step(self, data, label, key=None):

@@ -24,10 +24,18 @@ it leaves the queue), and ``build_timeline()`` stitches one trace id's
 spans — local + fetched from remote processes — into a parent/child
 tree tolerant of orphan parents and duplicate ids.
 
+One clock with the device: while a ``jax.profiler`` session is running
+(the benchmark's, an operator's ``start_trace``), every real span also
+enters a ``jax.profiler.TraceAnnotation`` of its name for its lifetime,
+so the session's trace holds the framework's phases as host events on
+the profiler's own clock, beside the device operations. Nothing to
+switch on: ``TraceAnnotation.is_enabled()`` says whether a session runs.
+
 Cheap when off: ``span()`` returns a shared no-op object unless
-telemetry metrics are enabled, the profiler is running, or a parent
-span is already active (needed so propagated contexts keep linking);
-``request_span()`` with sampling off is one dict lookup + compare.
+telemetry metrics are enabled, the profiler (this package's or jax's)
+is running, or a parent span is already active (needed so propagated
+contexts keep linking); ``request_span()`` with sampling off is one
+dict lookup + compare.
 """
 
 import json
@@ -37,6 +45,8 @@ import threading
 import time
 import uuid
 from collections import deque
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .. import profiler
 from . import metrics as _metrics
@@ -165,7 +175,7 @@ class Span:
     """A timed region; use as a context manager."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "sampled", "_t0")
+                 "sampled", "_t0", "_dur", "_annotation")
 
     def __init__(self, name, trace_id=None, parent_id=None, attrs=None,
                  sampled=False):
@@ -175,17 +185,33 @@ class Span:
         self.parent_id = parent_id
         self.attrs = attrs or {}
         self.sampled = sampled
-        self._t0 = None
+        self._t0 = self._dur = self._annotation = None
 
     def set_attr(self, key, value):
+        """Attributes set before the span is entered also ride its
+        profiler annotation; later ones reach the span's record only."""
         self.attrs[key] = value
+
+    def set_duration(self, seconds):
+        """Record `seconds` (the caller's own reading of the region,
+        taken inside the span on a monotonic clock) as the span's
+        duration, from the start the span took itself: a caller that
+        times the region for its statistics anyway hands the reading
+        over, and span, histogram and statistics hold one number."""
+        self._dur = seconds * 1e6
 
     def __enter__(self):
         self._t0 = time.time() * 1e6
         _stack().append(self)
+        if _TraceAnnotation.is_enabled():
+            self._annotation = _TraceAnnotation(self.name, **self.attrs)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -197,7 +223,8 @@ class Span:
         if exc_type is not None:
             args["error"] = exc_type.__name__
         args.update(self.attrs)
-        dur = time.time() * 1e6 - self._t0
+        dur = (time.time() * 1e6 - self._t0 if self._dur is None
+               else self._dur)
         profiler._record("span", self.name, ts=self._t0,
                          dur=dur, args=args)
         rec = {"name": self.name, "ts_us": self._t0, "dur_us": dur}
@@ -219,6 +246,9 @@ class _NullSpan:
     def set_attr(self, key, value):
         pass
 
+    def set_duration(self, seconds):
+        pass
+
     def __enter__(self):
         return self
 
@@ -230,7 +260,8 @@ NULL_SPAN = _NullSpan()
 
 
 def _active():
-    if _metrics._state["enabled"] or profiler._state["running"]:
+    if (_metrics._state["enabled"] or profiler._state["running"]
+            or _TraceAnnotation.is_enabled()):     # a jax.profiler session
         return True
     st = getattr(_tls, "stack", None)
     return bool(st)
@@ -239,8 +270,10 @@ def _active():
 def span(name, **attrs):
     """Open a child span of the current thread context (or a new trace).
 
-    Returns NULL_SPAN when telemetry is fully idle, so instrumented
-    code pays one call + two dict lookups when off.
+    Returns NULL_SPAN when telemetry is fully idle (metrics off, no
+    profiler session of this package's or of jax's, no parent span), so
+    instrumented code pays one call, two dict lookups and one
+    ``is_enabled()`` when off.
     """
     if not _active():
         return NULL_SPAN

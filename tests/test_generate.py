@@ -404,6 +404,52 @@ def test_engine_prefill_chunk_invariance(target_lm):
     assert outs[0] == outs[1] == outs[2]
 
 
+def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
+    """One generate call under a parent span: every forward leaves
+    kv.gather, lm.dispatch, lm.fetch and kv.commit as children of its
+    gen.prefill / gen.decode_step, at most 5 records a forward, and
+    lm.dispatch counts the bytes that cross to the device where they
+    cross: the pools' bytes plus tokens, lengths and tables."""
+    from incubator_mxnet_tpu.telemetry import tracing
+    cache = target_lm.make_cache(2, max_len=64)
+    eng = GenerateEngine(target_lm, cache, prefill_chunk=4)
+    prompts = [[3, 5, 7, 2, 11, 1], [9, 8, 4]]
+    tracing.clear_spans()
+    with tracing.Span("test.call") as call:
+        out = eng.generate(prompts, max_new_tokens=3)
+    assert [len(o) for o in out] == [3, 3]
+    recs = tracing.recent_spans()
+    by_id = {r["span_id"]: r for r in recs}
+    phases = ("kv.gather", "lm.dispatch", "lm.fetch", "kv.commit")
+    forwards = [r for r in recs if r["name"] == "lm.dispatch"]
+    # prefill: 5 tokens in chunks of 4 is two forwards, 2 tokens one;
+    # decode: three steps of both rows
+    assert len(forwards) == 3 + 3
+    assert len(recs) == 1 + 2 + 3 + 4 * len(forwards) <= 1 + 5 * len(forwards)
+    for r in recs:
+        if r["name"] in phases:
+            assert by_id[r["parent_id"]]["name"] in ("gen.prefill",
+                                                     "gen.decode_step")
+        elif r["name"] != "test.call":
+            assert r["parent_id"] == call.span_id
+    pools = sum(cache.pool("%s%d" % (kind, i)).nbytes
+                for i in range(target_lm.num_layers) for kind in "kv")
+    steps = [r for r in recs if r["name"] == "gen.decode_step"]
+    for step in steps:
+        kids = {r["name"]: r for r in recs
+                if r.get("parent_id") == step["span_id"]}
+        assert tuple(kids) == phases            # in the order they ran
+        # tokens (2, 1), lengths (2,) and tables (2, 64 / 16), all int32
+        assert kids["lm.dispatch"]["h2d_bytes"] == pools + 8 + 8 + 32
+        assert sum(k["dur_us"] for k in kids.values()) <= step["dur_us"]
+    # one reading a region: the spans' durations ARE last_stats' seconds
+    assert sum(s["dur_us"] for s in steps) / 1e6 == pytest.approx(
+        eng.last_stats["decode_seconds"], rel=1e-6)
+    assert sum(r["dur_us"] for r in recs if r["name"] == "gen.prefill") \
+        / 1e6 == pytest.approx(eng.last_stats["prefill_seconds"], rel=1e-6)
+    assert cat.gen_decode_seconds.count(model="gpt") == 3
+
+
 def test_engine_speculative_bit_identical_to_plain_greedy(target_lm,
                                                           draft_lm):
     """THE speculation pin: same tokens as plain greedy, token for

@@ -405,3 +405,49 @@ def test_int8_matmul_kernel_numerics():
                       block_n=32, interpret=True)
     want = a.astype(np.int32) @ b.astype(np.int32)
     np.testing.assert_array_equal(np.asarray(out), want)
+
+
+# ------------------------------------------------------- names on the device
+_KERNEL_FILES = ["flash_attention.py", "flash_decode.py",
+                 "fused_bottleneck.py", "fused_norm.py", "fused_optim.py",
+                 "int8_matmul.py"]
+
+
+def _pallas_calls(tree):
+    import ast
+    return [node for node in ast.walk(tree) if isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == "pallas_call"]
+
+
+@pytest.mark.parametrize("filename", _KERNEL_FILES)
+def test_every_pallas_call_names_its_kernel(filename):
+    """A launch's instruction in the compiled program, and its events in a
+    device trace, are called after ``name=``; without it they take the
+    name of whatever scope the call sits in (``step_fn.1``)."""
+    import ast
+    import os
+
+    from incubator_mxnet_tpu.ops import pallas
+    path = os.path.join(os.path.dirname(pallas.__file__), filename)
+    with open(path) as f:
+        calls = _pallas_calls(ast.parse(f.read()))
+    assert calls, "no pallas_call in %s: drop it from the list" % filename
+    for call in calls:
+        assert "name" in {kw.arg for kw in call.keywords}, \
+            "%s:%d pallas_call without name=" % (filename, call.lineno)
+
+
+def test_the_list_of_kernel_files_is_whole():
+    import ast
+    import glob
+    import os
+
+    from incubator_mxnet_tpu.ops import pallas
+    having = []
+    for path in glob.glob(os.path.join(os.path.dirname(pallas.__file__),
+                                       "*.py")):
+        with open(path) as f:
+            if _pallas_calls(ast.parse(f.read())):
+                having.append(os.path.basename(path))
+    assert sorted(having) == _KERNEL_FILES

@@ -221,6 +221,42 @@ def test_step_scan_matches_step():
                                    rtol=2e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("call", ["step", "step_guarded", "step_scan"])
+def test_a_step_under_a_parent_span_leaves_its_phases(call):
+    """trainer.step with the batch placement and the dispatch below it,
+    each with its parent's id; with nothing listening, no record at all."""
+    from incubator_mxnet_tpu.telemetry import tracing
+    np.random.seed(0)
+    X = np.random.rand(16, 8).astype(np.float32)
+    y = np.random.randint(0, 4, (16,)).astype(np.int32)
+    tr = ShardedTrainer(_make_mlp(0), _loss_fn,
+                        make_mesh({"dp": 1}, devices=jax.devices()[:1]),
+                        optimizer="sgd",
+                        optimizer_params={"learning_rate": 0.1})
+
+    def run():
+        if call == "step_scan":
+            return tr.step_scan(nd.array(X), nd.array(y), 2,
+                                per_step_batches=False)
+        return getattr(tr, call)(nd.array(X), nd.array(y))
+
+    run()                               # compiles; nothing listens
+    tracing.clear_spans()
+    run()
+    assert tracing.recent_spans() == []
+    with tracing.Span("test.parent") as parent:
+        run()
+    recs = {r["name"]: r for r in tracing.recent_spans()}
+    assert list(recs) == ["trainer.prep_batch", "trainer.dispatch",
+                          "trainer.step", "test.parent"]
+    step = recs["trainer.step"]
+    assert step["parent_id"] == parent.span_id and step["rows"] == 16
+    for name in ("trainer.prep_batch", "trainer.dispatch"):
+        assert recs[name]["parent_id"] == step["span_id"]
+    assert recs["trainer.prep_batch"]["dur_us"] \
+        + recs["trainer.dispatch"]["dur_us"] <= step["dur_us"]
+
+
 def test_step_scan_per_step_batches():
     """A leading steps-axis on data/label feeds a fresh batch per step."""
     np.random.seed(0)

@@ -48,6 +48,50 @@ def test_idle_span_is_shared_noop():
     assert _per_call(lambda: telemetry.span("x")) < MAX_SECONDS_PER_CALL
 
 
+def test_span_is_real_inside_a_jax_profiler_session(tmp_path):
+    """No switch of ours: with metrics off, the package's profiler off and
+    no parent span, span() is the shared null object until a jax.profiler
+    session starts; inside one it is real, its name is a host event of the
+    session's trace with the attributes it was opened with, and its record
+    still reaches the ring. After the session it is null again."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    telemetry.disable()
+    assert not profiler._state["running"]
+    assert tracing.current() is None
+    assert tracing.span("x") is tracing.NULL_SPAN
+    tracing.clear_spans()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        outer = tracing.span("overhead.outer", rows=3)
+        assert outer is not tracing.NULL_SPAN
+        with outer:
+            with tracing.span("overhead.inner") as inner:
+                inner.set_attr("late", 1)       # the record's alone
+    finally:
+        jax.profiler.stop_trace()
+    assert tracing.span("x") is tracing.NULL_SPAN
+    recs = {r["name"]: r for r in tracing.recent_spans()}
+    assert recs["overhead.inner"]["parent_id"] == \
+        recs["overhead.outer"]["span_id"]
+    assert recs["overhead.outer"]["rows"] == 3
+    assert recs["overhead.inner"]["late"] == 1
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    host = {ev.name: ev for plane in ProfileData.from_file(path).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("overhead.")}
+    assert set(host) == {"overhead.outer", "overhead.inner"}
+    assert dict(host["overhead.outer"].stats)["rows"] == 3
+    # the child lies inside its parent on the profiler's clock too
+    assert host["overhead.outer"].start_ns <= host["overhead.inner"].start_ns
+    assert host["overhead.inner"].end_ns <= host["overhead.outer"].end_ns
+
+
 def test_sampling_off_request_span_is_cheap_shared_noop():
     """Head sampling off (MXTPU_TRACE_SAMPLE=0): request_span() is one
     rate lookup + compare returning the shared null span — no id

@@ -11,8 +11,10 @@ only one process may load libtpu, and every xdist worker imports every
 test file. A compile that passes is not a chip run.
 """
 
+import collections
 import functools
 import importlib
+import re
 
 import pytest
 
@@ -71,7 +73,8 @@ def _trainer_adam(w, g, m, v, t):
     kern = functools.partial(fused_optim._trainer_adam_kernel, adamw=False)
     c1, c2 = fused_optim._bias_corrections(0.9, 0.999, t)
     s = fused_optim._scalars(1e-4, 0.01, 0.9, 0.999, 1e-8, 1e-6, c1, c2)
-    return fused_optim._launch(kern, s, [w, m, v, g], 3, False)
+    return fused_optim._launch(kern, s, [w, m, v, g], 3, False,
+                               "fused_adam")
 
 
 @pytest.mark.parametrize("update", [_adamw, _adam, _sgd_mom, _trainer_adam],
@@ -163,6 +166,18 @@ def test_bert_base_train_step_compiles_for_v5e(one_chip, monkeypatch):
     # AdamW launch; nothing gave way to a lax reference
     assert text.count("tpu_custom_call") >= 2 * 12 + 3
     assert tr._fused_launches == 1
+    # names on the device: the launches are called after their kernels, not
+    # after the scope they happen to sit in, and the optimizer path's ops
+    # carry the trainer's scope with pack / unpack below it
+    # (a kernel under autodiff is prefixed by its transform: %jvp_fused_...)
+    kernels = collections.Counter(
+        re.sub(r"\.\d+$", "", name) for name in re.findall(
+            r"^\s*%([\w.]+) = .*custom_call_target=\"tpu_custom_call\"",
+            text, re.M))
+    assert kernels == {"fused_adamw": 1, "jvp_fused_layernorm_": 26,
+                       "jvp_fused_softmax_": 12}
+    for scope in ("/optim/pack/", "/optim/unpack/", "/optim/fused_adamw"):
+        assert scope in text, scope
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert need < 16e9, "does not fit one v5e chip: %d bytes" % need
