@@ -1711,9 +1711,8 @@ def _mfu_ab_fused_arm(enabled, steps, width, depth):
     deep narrow MLP — many small params, so the per-param path pays one
     jitted dispatch per parameter per step while the fused path folds
     each dtype-homogeneous group into a single packed launch. (The
-    traced ShardedTrainer only engages the fused launch on TPU, where
-    it is really one Pallas launch — the eager path is where the fold
-    pays on every backend.)"""
+    compiled ShardedTrainer step takes no packed launch: its update is
+    already inside one program.)"""
     from incubator_mxnet_tpu import autograd, gluon, nd
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu.telemetry import catalog as cat

@@ -15,9 +15,11 @@ Phase `train` — the BERT-base pretrain step exactly as bench.py's
 ``bench_bert`` builds it (12 layers, 768 units, 12 heads, FFN 3072,
 vocab 30522, tied decoder, gather-first MLM head + NSP; AdamW, bf16
 compute, bf16-stored moments) through ``parallel.ShardedTrainer`` on a
-one-device mesh, at B=64,T=128 (fused LayerNorm + fused AdamW kernels)
-and at B=16,T=512 (+ the flash-attention kernel, whose step-0 loss is
-compared with the dense-attention path on the same parameters).
+one-device mesh, at B=64,T=128 (38 kernels: 26 fused LayerNorm + 12 fused
+softmax) and at B=16,T=512 (62: the flash-attention kernels, forward, dq
+and dkv, in the softmax's place; the step-0 loss is compared with the
+dense-attention path on the same parameters). The optimizer is applied
+leaf by leaf by XLA: no packed launch.
 
 Phase `generate` — GPT-2-small widths through ``GenerateEngine.generate``
 over ``GPTPagedLM``, in-process; each prompt's first generated token is
@@ -186,9 +188,10 @@ def phase_train(shapes=((64, 128, 6), (16, 512, 8)), num_layers=12):
         label = [mx.nd.array(a) for a in host_label]
         tr = bert_trainer(mesh, num_layers=num_layers)
         n_kernels = kernel_calls(tr, data, label)
-        # fused LayerNorm twice a layer + embedding + MLM head, the fused
-        # AdamW launch, and from T=512 flash attention fwd + bwd
-        check(n_kernels >= 2 * num_layers + 3,
+        # fused LayerNorm twice a layer + embedding + MLM head, and a layer's
+        # attention: one fused softmax, from T=512 flash attention fwd + bwd
+        # (38 kernels at T=128, 62 at T=512)
+        check(n_kernels >= 3 * num_layers + 2,
               "%s: only %d tpu_custom_call in the step program — a kernel "
               "gave way to its reference" % (what, n_kernels))
         dense_loss = None
@@ -204,8 +207,6 @@ def phase_train(shapes=((64, 128, 6), (16, 512, 8)), num_layers=12):
                   % (what, n_kernels, n_dense))
         losses, secs = run_steps(tr, data, label, n_steps)
         check_training(losses, what)
-        check(getattr(tr, "_fused_launches", 0) == 1,
-              "%s: the fused optimizer launch is not in the step" % what)
         report = step_report(losses, secs, batch, seqlen)
         if dense_loss is not None:
             check(abs(losses[0] - dense_loss) <= LOSS_RTOL * abs(dense_loss),
@@ -321,7 +322,6 @@ def phase_mesh(batch=64, seqlen=128, n_steps=6, num_layers=12):
     say("mesh", smoke_numbers_not_results=True, mesh={"dp": 2, "tp": 2},
         batch=batch, seqlen=seqlen, layers=num_layers,
         tpu_custom_calls=n_kernels,
-        fused_optimizer_launches=getattr(tr, "_fused_launches", 0),
         one_device=step_report(one_losses, one_secs, batch, seqlen),
         **step_report(losses, secs, batch, seqlen),
         bytes_in_use_per_device=in_use,

@@ -1,8 +1,9 @@
-"""Pallas TPU kernels: fused multi-tensor optimizer updates.
+"""Pallas TPU kernels: fused multi-tensor optimizer updates for the EAGER
+``gluon.Trainer`` / ``Updater``.
 
 Reference parity: the fused update kernels of src/operator/optimizer_op.cc
-apply one parameter per launch; a ResNet-50 step therefore pays ~160 tiny
-kernel dispatches just to apply SGD. Here the caller flattens every
+apply one parameter per launch; an eager ResNet-50 step therefore pays ~160
+tiny kernel dispatches just to apply SGD. Here the caller flattens every
 (weight, grad, state...) tree of one dtype into a single 1-D buffer and the
 whole update runs as ONE Pallas launch: each program owns a (block_r, 128)
 tile held in VMEM, the hyper-parameters ride SMEM, and weight/state inputs
@@ -19,12 +20,16 @@ kernel body passes interpret mode and is refused by the chip's compiler.
 The lazy/sparse update kernels stay on the per-parameter path.
 
 Dispatch lives behind the ``_optim_kernels`` seam (``_multi_*`` wrappers):
-real Pallas on TPU, interpret mode for CPU tier-1 tests, and a lax fallback
-(the per-parameter kernel applied once to the packed flat buffer) anywhere
-else. ``MXTPU_FUSED_OPTIM=0`` disables the fused path entirely.
+real Pallas on TPU, interpret mode where a test asks for it, and a lax
+fallback (the per-parameter kernel applied once to the packed flat buffer)
+anywhere else. ``MXTPU_FUSED_OPTIM=0`` disables the fold entirely.
+
+The compiled ``parallel.ShardedTrainer`` step does NOT come through here:
+its update is already inside one XLA program, where the per-leaf form fuses
+and runs in place and the packed form only adds copies of every buffer
+(measured on the chip, PERF.md section 6, PR 28).
 """
 
-import functools
 import os
 
 import jax
@@ -228,80 +233,3 @@ def fused_adamw_flat(w, g, m, v, lr, wd, eta, b1, b2, eps, t, rescale, clip,
     s = _scalars(lr, wd, b1, b2, eps, rescale, clip, eta, c1, c2)
     return _launch(_adamw_kernel, s, [w, m, v, g], 3, interpret,
                    "fused_adamw")
-
-
-# ---------------------------------------------------------------------------
-# ShardedTrainer flavor — parallel/trainer.py's _apply_opt_fp math (no
-# rescale/clip prologue; Adam in the mhat/vhat formulation; AdamW couples
-# the decay as `upd + lr*wd*w`). The scalar slot `lrwd` carries lr*wd
-# precomputed in python (f64) so the single f64->f32 rounding matches the
-# per-param `lr * wd * p` evaluation order.
-# ---------------------------------------------------------------------------
-
-def _trainer_adam_kernel(s_ref, w_ref, m_ref, v_ref, g_ref,
-                         ow_ref, om_ref, ov_ref, *, adamw):
-    dt = w_ref.dtype
-    lr, wd, b1, b2 = s_ref[0, 0], s_ref[0, 1], s_ref[0, 2], s_ref[0, 3]
-    eps, lrwd, c1, c2 = s_ref[0, 4], s_ref[0, 5], s_ref[0, 6], s_ref[0, 7]
-    one = jnp.float32(1)
-    w, g, m, v = w_ref[...], g_ref[...], m_ref[...], v_ref[...]
-    if not adamw:
-        g = g + wd.astype(dt) * w
-    m = b1.astype(dt) * m + (one - b1).astype(dt) * g
-    v = b2.astype(dt) * v + (one - b2).astype(dt) * g * g
-    mhat = m / c1.astype(dt)
-    vhat = v / c2.astype(dt)
-    upd = lr.astype(dt) * mhat / (jnp.sqrt(vhat) + eps.astype(dt))
-    if adamw:
-        upd = upd + lrwd.astype(dt) * w
-    ow_ref[...] = w - upd
-    om_ref[...] = m
-    ov_ref[...] = v
-
-
-def multi_trainer_sgd_mom(ws, gs, moms, lr, wd, momentum, interpret=False):
-    """Fused multi-tensor SGD-momentum in the trainer's _apply_opt_fp
-    formulation; python-float hyperparams. Returns (new_ws, new_moms)."""
-    wflat, metas = flatten_group(ws)
-    gflat, _ = flatten_group(gs)
-    mflat, _ = flatten_group(moms)
-    if interpret or fused_optim_available():
-        # the per-param math is the kernel's with rescale=1, clip off
-        # (both prologue ops are bitwise no-ops at those values)
-        s = _scalars(lr, wd, momentum, 0.0, 0.0, 1.0, -1.0, 0.0)
-        nw, nm = _launch(_sgd_mom_kernel, s, [wflat, mflat, gflat], 2,
-                         interpret, "fused_sgd_mom")
-    else:
-        nm = momentum * mflat - lr * (gflat + wd * wflat)
-        nw = wflat + nm
-    return split_group(nw, metas), split_group(nm, metas)
-
-
-def multi_trainer_adam(ws, gs, ms, vs, lr, wd, b1, b2, eps, t, adamw=False,
-                       interpret=False):
-    """Fused multi-tensor Adam/AdamW in the trainer's _apply_opt_fp
-    formulation; python-float hyperparams, traced scalar t. Returns
-    (new_ws, new_ms, new_vs)."""
-    wflat, metas = flatten_group(ws)
-    gflat, _ = flatten_group(gs)
-    mflat, _ = flatten_group(ms)
-    vflat, _ = flatten_group(vs)
-    if interpret or fused_optim_available():
-        c1, c2 = _bias_corrections(b1, b2, t)
-        s = _scalars(lr, wd, b1, b2, eps, lr * wd, c1, c2)
-        kern = functools.partial(_trainer_adam_kernel, adamw=adamw)
-        nw, nm, nv = _launch(kern, s, [wflat, mflat, vflat, gflat], 3,
-                             interpret,
-                             "fused_adamw" if adamw else "fused_adam")
-    else:
-        g = gflat if adamw else gflat + wd * wflat
-        nm = b1 * mflat + (1 - b1) * g
-        nv = b2 * vflat + (1 - b2) * g * g
-        mhat = nm / (1 - b1 ** t)
-        vhat = nv / (1 - b2 ** t)
-        upd = lr * mhat / (jnp.sqrt(vhat) + eps)
-        if adamw:
-            upd = upd + lr * wd * wflat
-        nw = wflat - upd
-    return split_group(nw, metas), split_group(nm, metas), split_group(
-        nv, metas)

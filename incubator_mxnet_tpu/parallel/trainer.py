@@ -8,7 +8,6 @@ activation PartitionSpecs make XLA insert the dp gradient psum and tp/sp
 collectives over ICI automatically (GSPMD).
 """
 
-import os
 import re
 import time
 
@@ -30,9 +29,8 @@ from ..telemetry import tracing as _tr
 __all__ = ["ShardedTrainer", "sharding_rules"]
 
 #: the ``jax.named_scope`` around the optimizer update of every step
-#: variant (fused launch, per-parameter, ZeRO): in the compiled step's HLO
-#: the whole optimizer path reads ``op_name=".../optim/..."``, with
-#: ``pack`` / ``unpack`` below it from ``ops/pallas/fused_optim.py``.
+#: variant (plain, guarded, scan, ZeRO): in the compiled step's HLO the
+#: whole optimizer path reads ``op_name=".../optim/..."``.
 OPTIM_SCOPE = "optim"
 
 
@@ -550,74 +548,6 @@ class ShardedTrainer:
 
         return grads_of
 
-    def _fused_update_names(self):
-        """Param names taking the fused multi-tensor optimizer launch
-        (ops/pallas/fused_optim.py), or None when the fused path is off
-        for this trainer. Trace-time only: the compiled step either
-        contains the one fused launch or the per-param loop, so a
-        disabled path costs nothing at runtime. ZeRO-1 (dp-sharded
-        state), stochastically-rounded bf16 params, and non-fp32 params
-        keep the per-param path — their layouts/key streams are
-        per-param by construction."""
-        from ..ops.pallas import fused_optim as _fo
-        if not _fo.fused_optim_enabled():
-            return None
-        # the whole update already lives inside ONE compiled step program
-        # here, so the fused form only pays where it really is one Pallas
-        # launch (real TPU) or where interpret is explicitly forced (CPU
-        # tier-1 drills). On other backends the lax fallback would just
-        # add pack/unpack copies of every buffer to a program XLA already
-        # fuses — measured 5x slower on the CPU bench box. The EAGER
-        # gluon path keeps the fold everywhere: there it replaces one
-        # jitted dispatch PER PARAM with one per group.
-        # One device only: jit refuses to partition a Mosaic kernel over
-        # a mesh, replicated operands or not, so until the packed launch
-        # is wrapped in a shard_map a step over several devices keeps the
-        # per-param update.
-        if not ((_fo.fused_optim_available() and self._mesh.size == 1)
-                or os.environ.get("MXTPU_FUSED_OPTIM_INTERPRET",
-                                  "0") == "1"):
-            return None
-        if self._zero1_mode is not None or self._param_dtype is not None:
-            return None
-        if self._opt not in ("sgd", "adam", "adamw") or \
-                (self._opt == "sgd" and self._momentum == 0.0):
-            return None
-        names = [n for n in self._diff_names
-                 if self._param_vals[n].dtype == jnp.float32]
-        return names or None
-
-    def _apply_fused(self, param_vals, grads, opt_state, t, names,
-                     new_params, new_opt):
-        """Apply the optimizer to `names` as ONE fused launch. Same math
-        as _apply_opt_fp on the packed buffer: low-precision stored opt
-        state is lifted to fp32 for the update and rounded back on the
-        way out."""
-        from ..ops.pallas import fused_optim as _fo
-        interp = os.environ.get("MXTPU_FUSED_OPTIM_INTERPRET", "0") == "1"
-        ws = [param_vals[n] for n in names]
-        gs = [grads[n] for n in names]
-        if self._opt == "sgd":
-            sdts = [opt_state[n][0].dtype for n in names]
-            ms = [opt_state[n][0].astype(jnp.float32) for n in names]
-            nws, nms = _fo.multi_trainer_sgd_mom(
-                ws, gs, ms, self._lr, self._wd, self._momentum,
-                interpret=interp)
-            for n, nw, nm, sdt in zip(names, nws, nms, sdts):
-                new_params[n] = nw
-                new_opt[n] = (nm.astype(sdt),)
-        else:
-            sdts = [opt_state[n][0].dtype for n in names]
-            ms = [opt_state[n][0].astype(jnp.float32) for n in names]
-            vs = [opt_state[n][1].astype(jnp.float32) for n in names]
-            nws, nms, nvs = _fo.multi_trainer_adam(
-                ws, gs, ms, vs, self._lr, self._wd, self._beta1,
-                self._beta2, self._eps, t, adamw=(self._opt == "adamw"),
-                interpret=interp)
-            for n, nw, nm, nv, sdt in zip(names, nws, nms, nvs, sdts):
-                new_params[n] = nw
-                new_opt[n] = (nm.astype(sdt), nv.astype(sdt))
-
     def _apply_all(self, param_vals, grads, opt_state, t, upd_key):
         """Apply the optimizer to every differentiable param — the shared
         update stage of the plain and guarded step builders. Handles the
@@ -625,15 +555,7 @@ class ShardedTrainer:
         stochastic-rounding key base (None for fp32-stored params)."""
         auto_zero = self._zero1_mode == "auto"
         new_params, new_opt = {}, {}
-        fused = self._fused_update_names()
-        self._fused_launches = 1 if fused else 0
-        fused_set = frozenset(fused or ())
-        if fused:
-            self._apply_fused(param_vals, grads, opt_state, t, fused,
-                              new_params, new_opt)
         for i, n in enumerate(self._diff_names):
-            if n in fused_set:
-                continue
             k_n = (jax.random.fold_in(upd_key, i)
                    if upd_key is not None else None)
             st = opt_state.get(n, ())
@@ -953,8 +875,6 @@ class ShardedTrainer:
         if t0 is not None:
             lbl = self._telemetry_labels
             _cat.trainer_steps.inc(n_steps, **lbl)
-            if getattr(self, "_fused_launches", 0):
-                _cat.optim_fused_launches.inc(self._fused_launches * n_steps)
             rows = _rows(datas, scan_over_batch)
             if rows:
                 _cat.trainer_samples.inc(rows * n_steps)
@@ -1053,8 +973,6 @@ class ShardedTrainer:
             lbl = self._telemetry_labels
             _cat.trainer_step_seconds.observe(dt, **lbl)
             _cat.trainer_steps.inc(**lbl)
-            if getattr(self, "_fused_launches", 0):
-                _cat.optim_fused_launches.inc(self._fused_launches)
             if rows:
                 _cat.trainer_samples.inc(rows)
             _costs.observe("trainer.step", dt)

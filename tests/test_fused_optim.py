@@ -1,17 +1,17 @@
 """Fused multi-tensor optimizer (ops/pallas/fused_optim.py) — bit-parity
-pins against the per-param kernels at the _optim_kernels seam, the
-ShardedTrainer / gluon.Trainer integration, and the stay-per-param
-carve-outs (sparse grads, momentum=0).
+pins against the per-param kernels at the _optim_kernels seam, the eager
+gluon.Trainer integration, the stay-per-param carve-outs (sparse grads,
+momentum=0), and the ShardedTrainer's own per-leaf update, which takes no
+packed launch.
 
 Parity tiers (FMA contraction moves once shapes/fusion change):
 - seam level (_multi_* vs per-param _*_update, same jit boundary):
   BITWISE, f32 and bf16;
-- whole trainer on-vs-off: allclose rtol=1e-5/atol=1e-8 (different
-  program partitioning around the update);
+- compiled ShardedTrainer step vs the per-param kernels on a gradient
+  written out by hand: allclose rtol=1e-5/atol=1e-6; vs its own
+  _apply_opt_fp leaf by leaf, and under MXTPU_FUSED_OPTIM* : BITWISE;
 - interpret-vs-fallback arms of the same seam call: rtol=1e-4/atol=1e-6.
 """
-
-import os
 
 import numpy as np
 import pytest
@@ -143,47 +143,127 @@ _OPTS = [("sgd", {"learning_rate": 0.1, "momentum": 0.9}),
 _COUNTER = [0]
 
 
-def _sharded_run(opt, params, env, monkeypatch):
+def _batch():
+    np.random.seed(1)
+    X = np.random.rand(16, 8).astype(np.float32)
+    y = np.random.randint(0, 4, (16,)).astype(np.int32)
+    return X, y
+
+
+def _mlp_loss(pv, X, y):
+    """_make_mlp's forward and _loss_fn written out, so that a reference
+    shares no code with the trainer's gradient stage."""
+    h = jax.nn.relu(X @ pv["dense0_weight"].T + pv["dense0_bias"])
+    return _loss_fn(h @ pv["dense1_weight"].T + pv["dense1_bias"], y)
+
+
+def _bare(tree):
+    """Host copies under the names without the net's prefix."""
+    return {k.split("_", 1)[1]: jax.tree_util.tree_map(np.array, v)
+            for k, v in tree.items()}
+
+
+def _sharded_run(opt, params, env, monkeypatch, **trainer_kw):
+    """Three steps of a one-device ShardedTrainer under `env` -> (trainer,
+    its first parameters, its last parameters)."""
     for k, v in env.items():
         monkeypatch.setenv(k, v)
     net = _make_mlp("fo%d_" % _COUNTER[0])
     _COUNTER[0] += 1
-    np.random.seed(1)
-    X = np.random.rand(16, 8).astype(np.float32)
-    y = np.random.randint(0, 4, (16,)).astype(np.int32)
+    X, y = _batch()
     mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
     tr = ShardedTrainer(net, _loss_fn, mesh, optimizer=opt,
-                        optimizer_params=params)
-    losses = [float(jax.device_get(tr.step(nd.array(X), nd.array(y))))
-              for _ in range(3)]
-    pv = {k.split("_", 1)[1]: np.asarray(jax.device_get(v))
-          for k, v in tr.param_values.items()}
+                        optimizer_params=params, **trainer_kw)
+    first = _bare(tr.param_values)      # copies: the step donates them
+    for _ in range(3):
+        tr.step(nd.array(X), nd.array(y))
     for k in env:
         monkeypatch.delenv(k, raising=False)
-    return losses, pv, getattr(tr, "_fused_launches", None)
+    return tr, first, _bare(tr.param_values)
+
+
+def _per_param_kernel(opt, params, w, g, st, t):
+    """One leaf through the per-parameter kernel of ops/_optim_kernels.py
+    (rescale 1, clip off) -> (new weight, new state)."""
+    lr, wd = params["learning_rate"], params.get("wd", 0.0)
+    if opt == "sgd":
+        nw, nm = K._sgd_mom_update(w, g, st[0], lr, wd, params["momentum"],
+                                   1.0, -1.0)
+        return nw, (nm,)
+    if opt == "adam":
+        # the kernel adds epsilon to sqrt(v) before the bias correction,
+        # the trainer to sqrt(vhat) after it
+        nw, nm, nv = K._adam_update(w, g, *st, lr, wd, 0.9, 0.999,
+                                    1e-8 * (1 - 0.999 ** t) ** 0.5, t,
+                                    1.0, -1.0)
+    else:
+        # the kernel decays by eta * wd * w, the trainer by lr * wd * w
+        nw, nm, nv = K._adamw_update(w, g, *st, lr, lr * wd, 1.0, 0.9,
+                                     0.999, 1e-8, t, 1.0, -1.0)
+    return nw, (nm, nv)
+
+
+def _reference_steps(first, state, update):
+    """Three steps from the parameters `first` on _mlp_loss's gradient,
+    `update(w, g, leaf state, t)` leaf by leaf -> (parameters, state)."""
+    X, y = _batch()
+    pv = {k: jnp.asarray(v) for k, v in first.items()}
+    for t in (1, 2, 3):
+        grads = jax.grad(_mlp_loss)(pv, X, y)
+        for k in pv:
+            pv[k], state[k] = update(pv[k], grads[k], state[k], t)
+    return pv, state
 
 
 @pytest.mark.parametrize("opt,params", _OPTS,
                          ids=[o for o, _ in _OPTS])
-def test_sharded_trainer_fused_on_off_interpret(opt, params, monkeypatch):
-    l_off, p_off, fl_off = _sharded_run(
-        opt, params, {"MXTPU_FUSED_OPTIM": "0"}, monkeypatch)
-    l_on, p_on, fl_on = _sharded_run(opt, params, {}, monkeypatch)
-    l_in, p_in, fl_in = _sharded_run(
-        opt, params, {"MXTPU_FUSED_OPTIM_INTERPRET": "1"}, monkeypatch)
-    # the traced trainer only engages the fused launch where it really is
-    # one launch (TPU) or when interpret is forced; on CPU the default-on
-    # arm stays per-param by design (lax-packed form would only add
-    # pack/unpack copies to the already-fused step program)
-    expect_on = 1 if jax.default_backend() == "tpu" else 0
-    assert fl_off == 0 and fl_on == expect_on and fl_in == 1, (
-        fl_off, fl_on, fl_in)
-    for k in p_off:
-        np.testing.assert_allclose(p_off[k], p_on[k], rtol=1e-5,
-                                   atol=1e-8, err_msg="%s %s" % (opt, k))
-        np.testing.assert_allclose(p_on[k], p_in[k], rtol=1e-4,
+def test_sharded_trainer_matches_per_param_kernels(opt, params, monkeypatch):
+    """The compiled step applies the optimizer leaf by leaf: three steps
+    land on the per-parameter kernels' parameters, and the variables that
+    once chose a packed launch change no bit of them."""
+    _, first, last = _sharded_run(opt, params, {}, monkeypatch)
+    for env in ({"MXTPU_FUSED_OPTIM": "0"},
+                {"MXTPU_FUSED_OPTIM_INTERPRET": "1"}):
+        _, first_env, last_env = _sharded_run(opt, params, env, monkeypatch)
+        for k in last:
+            np.testing.assert_array_equal(first[k], first_env[k])
+            np.testing.assert_array_equal(last[k], last_env[k],
+                                          err_msg="%s %s %s" % (opt, k, env))
+    n_slots = 1 if opt == "sgd" else 2
+    pv, _ = _reference_steps(
+        first, {k: (jnp.zeros_like(v),) * n_slots for k, v in first.items()},
+        lambda w, g, st, t: _per_param_kernel(opt, params, w, g, st, t))
+    for k in pv:
+        assert np.abs(last[k] - first[k]).max() > 0, k
+        # atol: a few float32 roundings of weights of size 1, which is what
+        # an element near zero is held to after three +-lr steps of Adam
+        np.testing.assert_allclose(last[k], np.asarray(pv[k]), rtol=1e-5,
                                    atol=1e-6, err_msg="%s %s" % (opt, k))
-    np.testing.assert_allclose(l_off, l_on, rtol=1e-6, atol=1e-9)
+
+
+@pytest.mark.parametrize("opt,params", _OPTS[1:],
+                         ids=[o for o, _ in _OPTS[1:]])
+def test_sharded_trainer_bf16_moments_per_leaf(opt, params, monkeypatch):
+    """opt_state_dtype="bfloat16": the moments are STORED bfloat16 beside
+    float32 weights, lifted to float32 for the arithmetic and rounded on
+    the way out — _apply_opt_fp, leaf by leaf, as a mesh runs it."""
+    tr, first, last = _sharded_run(opt, params, {}, monkeypatch,
+                                   opt_state_dtype="bfloat16")
+    moments = _bare(tr._opt_state)
+    assert sorted(moments) == sorted(last)
+    for k in last:
+        assert last[k].dtype == np.float32, k
+        assert [s.dtype for s in moments[k]] == [jnp.bfloat16] * 2, k
+    leaf_update = jax.jit(tr._apply_opt_fp)
+    pv, state = _reference_steps(
+        first, {k: (jnp.zeros(v.shape, jnp.bfloat16),) * 2
+                for k, v in first.items()},
+        lambda w, g, st, t: leaf_update(w, g, st, jnp.float32(t)))
+    for k in pv:
+        np.testing.assert_array_equal(last[k], np.asarray(pv[k]), err_msg=k)
+        for mine, theirs in zip(moments[k], state[k]):
+            np.testing.assert_array_equal(mine, np.asarray(theirs),
+                                          err_msg=k)
 
 
 @pytest.mark.parametrize("opt,params", _OPTS,

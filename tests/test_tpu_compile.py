@@ -12,7 +12,6 @@ test file. A compile that passes is not a chip run.
 """
 
 import collections
-import functools
 import importlib
 import re
 
@@ -68,17 +67,8 @@ def _sgd_mom(w, g, m, v, t):
     return fused_optim.fused_sgd_mom_flat(w, g, m, 0.1, 1e-4, 0.9, 1.0, -1.0)
 
 
-def _trainer_adam(w, g, m, v, t):
-    # the ShardedTrainer flavor; adamw=True rides the whole-step case
-    kern = functools.partial(fused_optim._trainer_adam_kernel, adamw=False)
-    c1, c2 = fused_optim._bias_corrections(0.9, 0.999, t)
-    s = fused_optim._scalars(1e-4, 0.01, 0.9, 0.999, 1e-8, 1e-6, c1, c2)
-    return fused_optim._launch(kern, s, [w, m, v, g], 3, False,
-                               "fused_adam")
-
-
-@pytest.mark.parametrize("update", [_adamw, _adam, _sgd_mom, _trainer_adam],
-                         ids=["adamw", "adam", "sgd_mom", "trainer_adam"])
+@pytest.mark.parametrize("update", [_adamw, _adam, _sgd_mom],
+                         ids=["adamw", "adam", "sgd_mom"])
 def test_fused_optimizer_compiles_for_v5e(one_chip, update):
     buf = jax.ShapeDtypeStruct((BERT_BASE_PARAMS,), jnp.float32,
                                sharding=one_chip)
@@ -132,18 +122,15 @@ def _answer_tpu(monkeypatch):
     def on_tpu():
         return True
 
-    for name in ("fused_norm_available", "flash_attention_available",
-                 "fused_optim_available"):
+    for name in ("fused_norm_available", "flash_attention_available"):
         monkeypatch.setattr(pallas, name, on_tpu)
     monkeypatch.setattr(fused_norm, "fused_norm_available", on_tpu)
     monkeypatch.setattr(flash_mod, "flash_attention_available", on_tpu)
-    monkeypatch.setattr(fused_optim, "fused_optim_available", on_tpu)
 
 
 def test_bert_base_train_step_compiles_for_v5e(one_chip, monkeypatch):
-    """chip_smoke.py's shape A: the default (fused-optimizer-on) BERT-base
-    AdamW step at B=64,T=128, built on the CPU mesh and lowered for the
-    described chip."""
+    """chip_smoke.py's shape A: the BERT-base AdamW step at B=64,T=128,
+    built on the CPU mesh and lowered for the described chip."""
     import chip_smoke
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu.parallel import make_mesh
@@ -162,22 +149,23 @@ def test_bert_base_train_step_compiles_for_v5e(one_chip, monkeypatch):
     _answer_tpu(monkeypatch)
     compiled = fn.lower(*shapes).compile()
     text = compiled.as_text()
-    # fused LayerNorm (2 a layer + embedding + MLM head) and the one fused
-    # AdamW launch; nothing gave way to a lax reference
-    assert text.count("tpu_custom_call") >= 2 * 12 + 3
-    assert tr._fused_launches == 1
     # names on the device: the launches are called after their kernels, not
-    # after the scope they happen to sit in, and the optimizer path's ops
-    # carry the trainer's scope with pack / unpack below it
+    # after the scope they happen to sit in
     # (a kernel under autodiff is prefixed by its transform: %jvp_fused_...)
     kernels = collections.Counter(
         re.sub(r"\.\d+$", "", name) for name in re.findall(
             r"^\s*%([\w.]+) = .*custom_call_target=\"tpu_custom_call\"",
             text, re.M))
-    assert kernels == {"fused_adamw": 1, "jvp_fused_layernorm_": 26,
-                       "jvp_fused_softmax_": 12}
-    for scope in ("/optim/pack/", "/optim/unpack/", "/optim/fused_adamw"):
-        assert scope in text, scope
+    # fused LayerNorm (2 a layer + embedding + MLM head) and softmax (1 a
+    # layer); nothing gave way to a lax reference
+    assert kernels == {"jvp_fused_layernorm_": 26, "jvp_fused_softmax_": 12}
+    # the optimizer is applied leaf by leaf under the trainer's scope: no
+    # packed launch, no copy of the parameters into or out of one buffer
+    assert "/optim/" in text
+    for gone in ("fused_adamw", "/optim/pack/", "/optim/unpack/"):
+        assert gone not in text, gone
+    assert not re.search(r"^\s*%concatenate.*op_name=\"[^\"]*/optim/", text,
+                         re.M)
     mem = compiled.memory_analysis()
     need = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert need < 16e9, "does not fit one v5e chip: %d bytes" % need
@@ -187,8 +175,8 @@ def test_bert_train_step_compiles_for_v5e_mesh(topo, monkeypatch):
     """chip_smoke.py --chips 4: the dp2 x tp2 step (depth cut to 2 layers
     here; every layer shards alike). jit refuses a Mosaic kernel in a
     program for several devices, so over a mesh the step must take the XLA
-    forms of LayerNorm and the per-param optimizer, decided from its mesh —
-    with every gate answering "tpu" it still lowers, and holds no kernel."""
+    form of LayerNorm, decided from its mesh — with every gate answering
+    "tpu" it still lowers, and holds no kernel."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import chip_smoke
@@ -222,7 +210,6 @@ def test_bert_train_step_compiles_for_v5e_mesh(topo, monkeypatch):
     compiled = jax.jit(tr._build_raw(3)).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
-    assert tr._fused_launches == 0
     assert "all-reduce" in text          # dp grads, tp activations
     # a tp-ruled weight is held in halves
     name = next(n for n in shapes[0] if n.endswith("ffn1_weight"))
