@@ -5,8 +5,9 @@ through the continuous-batching plane, with
 
 - :mod:`.paged_kv` — block-table + free-list KV allocator that drops in
   behind the ``serving/kv_cache.py`` alloc/free/append surface,
-- :mod:`.engine` — chunked prefill, greedy/temperature sampling, and
-  draft-model speculative decoding (Leviathan et al., ICML 2023),
+- :mod:`.engine` — chunked prefill, greedy/temperature sampling,
+  draft-model speculative decoding (Leviathan et al., ICML 2023), and
+  block-diffusion decoding for a model that declares a block length,
 - :mod:`.family` — the ``gpt_decoder`` ``@serving_family`` wiring the
   engine's forward into ModelServer's slot grid with AOT programs.
 
@@ -14,7 +15,7 @@ Importing this package registers the serving family.
 """
 
 from .paged_kv import PagedKVCache
-from .engine import GenerateEngine, GPTPagedLM
+from .engine import GenerateEngine, GPTPagedLM, SDARPagedLM
 from . import family  # noqa: F401  (registers the gpt_decoder family)
 from .family import export_gpt_for_serving, gpt_cache_spec
 
@@ -22,6 +23,7 @@ __all__ = [
     "PagedKVCache",
     "GenerateEngine",
     "GPTPagedLM",
+    "SDARPagedLM",
     "export_gpt_for_serving",
     "gpt_cache_spec",
 ]

@@ -22,6 +22,18 @@ agrees often.
 All cache mutation happens here (append committed K/V, advance,
 truncate rejected suffixes); the model adapter is a pure shape-cached
 forward. ``GPTPagedLM`` adapts ``models/gpt.py`` to that contract.
+
+A model adapter that declares a ``block_length`` (``SDARPagedLM`` over
+``models/sdar_moe.py``) is a block-diffusion decoder, and the engine
+drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
+
+    block:  the row's next B positions, the prompt's tail fixed, the
+            rest MASK
+            up to `denoise_steps` forwards over the block that STORE
+            NOTHING; after each, the ceil(masked / steps left) most
+            confident masked positions take their argmax token
+            once none is masked, one forward over the final tokens
+            whose K and V are committed: B positions a row at once
 """
 
 import os
@@ -33,7 +45,7 @@ from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
 from .paged_kv import PagedKVCache
 
-__all__ = ["GenerateEngine", "GPTPagedLM"]
+__all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM"]
 
 
 def _env_int(name, default):
@@ -54,6 +66,16 @@ def _host_bytes(args):
         elif isinstance(a, np.ndarray):
             total += a.nbytes
     return total
+
+
+def _dispatch(fn, params, args):
+    """The jitted call of an adapter's forward under its ``lm.dispatch``
+    span; a real span also counts the host bytes the call ships."""
+    sp = _tr.span("lm.dispatch")
+    if sp is not _tr.NULL_SPAN:     # counted only for a real span
+        sp.set_attr("h2d_bytes", _host_bytes(args))
+    with sp:
+        return fn(params, *args)
 
 
 class GPTPagedLM:
@@ -96,15 +118,99 @@ class GPTPagedLM:
                             max_len=max_len or self.config["max_len"], **kw)
 
     def forward(self, tokens, lengths, tables, k_pools, v_pools):
-        args = (tokens, lengths, tables, k_pools, v_pools)
-        sp = _tr.span("lm.dispatch")
-        if sp is not _tr.NULL_SPAN:     # counted only for a real span
-            sp.set_attr("h2d_bytes", _host_bytes(args))
-        with sp:
-            logits, nk, nv = self._fn(self.params, *args)
+        logits, nk, nv = _dispatch(
+            self._fn, self.params,
+            (tokens, lengths, tables, k_pools, v_pools))
         with _tr.span("lm.fetch"):
             return (np.asarray(logits), [np.asarray(a) for a in nk],
                     [np.asarray(a) for a in nv])
+
+
+class SDARPagedLM:
+    """Shape-cached jit adapter over ``sdar_forward_paged``: the
+    block-diffusion decoder of ``models/sdar_moe.py``, served in
+    `dtype` (bfloat16: weights, activations and the K/V pools).
+
+    What it declares to the engine: ``block_length`` (B; the engine then
+    runs its block loop) and ``mask_id`` (the token a position holds
+    until a denoising forward fixes it). Three programs a shape, one a
+    kind of forward:
+
+    - ``forward`` — the ``GPTPagedLM`` contract, ``(logits (S, C, V),
+      new_k, new_v)``;
+    - ``forward_choice`` — a denoising forward: ``((x0, confidence),
+      None, None)``, each (S, C), the argmax token and its softmax
+      probability computed on the device; nothing of K and V comes back,
+      as nothing is stored;
+    - ``forward_kv`` — prefill and a block's store pass: ``(None, new_k,
+      new_v)``, no final norm, no head.
+
+    new_k / new_v index as ``[layer][row, c]`` (one stacked array a
+    forward, one copy back). After every forward ``last_expert_loads``
+    holds the (layers, experts) routes each expert got.
+    """
+
+    def __init__(self, params, config, dtype="bfloat16"):
+        import jax
+        import jax.numpy as jnp
+        from ..models.sdar_moe import sdar_config, sdar_forward_paged
+        self.config = sdar_config(config)
+        self.dtype = jnp.dtype(dtype)
+        self.params = {n: jnp.asarray(v, self.dtype)
+                       for n, v in params.items()}
+        self.num_layers = self.config["num_layers"]
+        self.block_length = int(self.config["block_length"])
+        self.mask_id = int(self.config["mask_id"])
+        self.last_expert_loads = None
+
+        def program(head):
+            def pure(params, tokens, lengths, tables, kps, vps):
+                out, nk, nv, loads = sdar_forward_paged(
+                    params, self.config, tokens, lengths, tables, kps, vps,
+                    head=head)
+                if head == "choice":        # (x0, confidence): no K, V
+                    return out + (loads,)
+                kv = jnp.stack([jnp.stack(nk), jnp.stack(nv)])
+                return (kv, loads) if out is None else (out, kv, loads)
+            return jax.jit(pure)
+        self._fns = {head: program(head)
+                     for head in ("logits", "choice", "none")}
+
+    def cache_spec(self):
+        shape = (self.config["num_kv_heads"], self.config["head_dim"])
+        spec = {}
+        for i in range(self.num_layers):
+            spec["k%d" % i] = ("kv", shape, self.dtype)
+            spec["v%d" % i] = ("kv", shape, self.dtype)
+        return spec
+
+    def make_cache(self, slots, max_len=None, **kw):
+        return PagedKVCache(slots, self.cache_spec(),
+                            max_len=max_len or self.config["max_len"], **kw)
+
+    def _call(self, head, *args):
+        """One forward: the program's outputs as host arrays, without the
+        expert loads, which are kept for the engine to count."""
+        outs = _dispatch(self._fns[head], self.params, args)
+        with _tr.span("lm.fetch"):
+            outs = [np.asarray(a) for a in outs]
+        self.last_expert_loads = outs.pop()
+        return outs
+
+    def forward(self, tokens, lengths, tables, k_pools, v_pools):
+        logits, kv = self._call("logits", tokens, lengths, tables,
+                                k_pools, v_pools)
+        return logits, kv[0], kv[1]
+
+    def forward_choice(self, tokens, lengths, tables, k_pools, v_pools):
+        x0, confidence = self._call("choice", tokens, lengths, tables,
+                                    k_pools, v_pools)
+        return (x0, confidence), None, None
+
+    def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
+        (kv,) = self._call("none", tokens, lengths, tables, k_pools,
+                           v_pools)
+        return None, kv[0], kv[1]
 
 
 class GenerateEngine:
@@ -115,11 +221,17 @@ class GenerateEngine:
     speculative decoding (greedy only — temperature sampling with a
     draft raises, the acceptance rule here is the deterministic
     argmax-match variant).
+
+    A model that declares ``block_length`` is decoded by blocks
+    (``_block_loop``): ``denoise_steps`` forwards a block at most
+    (default: the block length, one position a step), greedy, no draft;
+    ``prefill_chunk`` is then a multiple of the block length, as a chunk
+    must not split a block.
     """
 
     def __init__(self, model, cache, draft=None, draft_cache=None,
                  spec_k=None, prefill_chunk=None, temperature=0.0,
-                 seed=0, name="gpt", use_kernel=False):
+                 seed=0, name="gpt", use_kernel=False, denoise_steps=None):
         if (draft is None) != (draft_cache is None):
             raise ValueError("draft model and draft cache come together")
         self.model = model
@@ -140,19 +252,54 @@ class GenerateEngine:
                 "speculative decoding is greedy-only: the accept rule "
                 "compares draft tokens to target argmax; run with "
                 "temperature=0 or drop the draft model")
+        self.block_length = int(getattr(model, "block_length", 0) or 0)
+        self.denoise_steps = int(denoise_steps or self.block_length)
+        if self.block_length:
+            if self.draft is not None or self.temperature > 0:
+                raise ValueError(
+                    "block decoding is greedy and takes no draft model: "
+                    "a denoising forward fixes argmax tokens by their "
+                    "confidence")
+            if self.prefill_chunk % self.block_length:
+                raise ValueError(
+                    "prefill_chunk (%d) must be a multiple of the model's "
+                    "block_length (%d): a chunk must not split a block"
+                    % (self.prefill_chunk, self.block_length))
+            if self.denoise_steps < 1:
+                raise ValueError("denoise_steps must be >= 1")
         self.last_stats = {}
+        self._routing = None    # a call's expert-routing tally, if any
 
     # ---------------------------------------------------------- plumbing
-    def _forward(self, adapter, cache, slots, tokens):
+    def _forward(self, adapter, cache, slots, tokens, call=None):
         """One adapter forward for `slots` (list) feeding `tokens`
-        (S, C); returns (logits, new_k, new_v) WITHOUT committing."""
+        (S, C); returns (logits, new_k, new_v) WITHOUT committing.
+        `call`: another forward of the adapter's with the same
+        arguments (the block loop's ``forward_choice`` / ``forward_kv``)."""
         with _tr.span("kv.gather"):
             lengths = np.asarray([int(cache.lengths[s]) for s in slots],
                                  np.int32)
             tables = cache.tables_array(slots)
             kps = [cache.pool("k%d" % i) for i in range(adapter.num_layers)]
             vps = [cache.pool("v%d" % i) for i in range(adapter.num_layers)]
-        return adapter.forward(tokens, lengths, tables, kps, vps)
+        out = (call or adapter.forward)(tokens, lengths, tables, kps, vps)
+        if self._routing is not None and adapter is self.model:
+            self._note_routing(adapter.last_expert_loads)
+        return out
+
+    def _note_routing(self, loads):
+        """`loads` (layers, experts): the routes each expert got in the
+        forward just made, into this call's ``last_stats["moe"]``."""
+        moe = self._routing
+        routes, hit = int(loads.sum()), int((loads > 0).sum())
+        uneven = float(np.mean(loads.max(axis=1) / loads.mean(axis=1)))
+        moe["forwards"] += 1
+        moe["routes"] += routes
+        moe["experts_hit"] += hit
+        moe["load_max_over_mean"].append(uneven)
+        _cat.moe_routes.inc(routes, model=self.name)
+        _cat.moe_experts_hit.inc(hit, model=self.name)
+        _cat.moe_load_max_over_mean.observe(uneven, model=self.name)
 
     def _commit(self, adapter, cache, slots, new_k, new_v, count):
         """Append the first `count` chunk positions of every row (row r
@@ -173,12 +320,13 @@ class GenerateEngine:
             self._commit(adapter, cache, slots, nk, nv, 1)
         return logits[:, -1]
 
-    def _prefill(self, adapter, cache, slot, tokens_1d):
+    def _prefill(self, adapter, cache, slot, tokens_1d, call=None):
         """Chunked prompt ingestion: commit K/V for every prompt token
         in fixed ``prefill_chunk``-wide forwards (last chunk padded;
         pad positions sit AFTER the valid ones, so causality keeps them
         out of every valid position's attention window and they are
-        simply not committed)."""
+        simply not committed; under a block mask the valid tokens are
+        whole blocks, so the pads begin a later block)."""
         n = len(tokens_1d)
         chunk = self.prefill_chunk
         for start in range(0, n, chunk):
@@ -186,7 +334,8 @@ class GenerateEngine:
             valid = len(piece)
             padded = np.zeros((1, chunk), np.int32)
             padded[0, :valid] = piece
-            _logits, nk, nv = self._forward(adapter, cache, [slot], padded)
+            _logits, nk, nv = self._forward(adapter, cache, [slot], padded,
+                                            call)
             self._commit(adapter, cache, [slot], nk, nv, valid)
 
     def _sample(self, logits_row):
@@ -209,7 +358,10 @@ class GenerateEngine:
         for p in prompts:
             if not p:
                 raise ValueError("empty prompt")
-            if len(p) + max_new_tokens > self.cache.max_len:
+            need = len(p) + max_new_tokens
+            if self.block_length:       # the last block is stored whole
+                need = -(-need // self.block_length) * self.block_length
+            if need > self.cache.max_len:
                 raise ValueError(
                     "prompt (%d) + max_new_tokens (%d) exceeds cache "
                     "max_len (%d)" % (len(p), max_new_tokens,
@@ -217,6 +369,10 @@ class GenerateEngine:
         stats = {"prefill_seconds": 0.0, "decode_seconds": 0.0,
                  "prefill_tokens": 0, "decode_tokens": 0,
                  "proposed": 0, "accepted": 0}
+        if hasattr(self.model, "last_expert_loads"):    # an expert layer
+            self._routing = stats["moe"] = {
+                "forwards": 0, "routes": 0, "experts_hit": 0,
+                "load_max_over_mean": []}
         seqs = []      # per sequence: dict(ctx, slot, dslot, out, done)
         try:
             for p in prompts:
@@ -237,24 +393,31 @@ class GenerateEngine:
             # region (a prompt's prefill, a decode step or round) is
             # timed once: its span, its histogram and last_stats hold
             # that one reading.
+            # A block model prefills the prompt's WHOLE blocks, with the
+            # forward that skips the head; the tail opens the first
+            # generated block.
+            B = self.block_length
+            kv_only = self.model.forward_kv if B else None
             for s in seqs:
+                n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
                 with _tr.span("gen.prefill", model=self.name,
-                              slot=s["slot"],
-                              tokens=max(len(s["ctx"]) - 1, 0)) as sp:
+                              slot=s["slot"], tokens=max(n, 0)) as sp:
                     t0 = time.monotonic()
-                    if len(s["ctx"]) > 1:
+                    if n > 0:
                         self._prefill(self.model, self.cache, s["slot"],
-                                      s["ctx"][:-1])
+                                      s["ctx"][:n], kv_only)
                         if self.draft is not None:
                             self._prefill(self.draft, self.draft_cache,
-                                          s["dslot"], s["ctx"][:-1])
-                        stats["prefill_tokens"] += len(s["ctx"]) - 1
+                                          s["dslot"], s["ctx"][:n])
+                        stats["prefill_tokens"] += n
                     dt = time.monotonic() - t0
                     sp.set_duration(dt)
                 stats["prefill_seconds"] += dt
                 _cat.gen_prefill_seconds.observe(dt, model=self.name)
 
-            if self.draft is not None and self.spec_k > 0:
+            if B:
+                self._block_loop(seqs, max_new_tokens, eos_id, stats)
+            elif self.draft is not None and self.spec_k > 0:
                 for s in seqs:
                     self._speculative_loop(s, max_new_tokens, eos_id,
                                            stats)
@@ -301,6 +464,115 @@ class GenerateEngine:
                 sp.set_attr("tokens_committed", committed)
                 dt = time.monotonic() - t0
                 sp.set_duration(dt)
+            stats["decode_seconds"] += dt
+            _cat.gen_decode_seconds.observe(dt, model=self.name)
+
+    # ------------------------------------------------------ block decode
+    @staticmethod
+    def _fix_most_confident(masked, confidence, steps_left):
+        """The static low-confidence schedule: of a row's still-masked
+        positions the ``ceil(masked / steps_left)`` most confident are
+        fixed by this forward (ties: the leftmost). masked (R, B) bool,
+        confidence (R, B) -> fixed (R, B) bool."""
+        count = -(-masked.sum(axis=1) // steps_left)
+        order = np.argsort(np.where(masked, -confidence, np.inf), axis=1,
+                           kind="stable")
+        rank = np.argsort(order, axis=1, kind="stable")
+        return masked & (rank < count[:, None])
+
+    def _block_loop(self, seqs, max_new_tokens, eos_id, stats):
+        """Block-diffusion decode: a step commits a block, not a token.
+
+        All live rows advance by one block of B positions a round, each
+        at its own absolute positions (``cache.lengths``, a multiple of
+        B). A row's first block opens with its prompt's tail as fixed
+        tokens. ``denoise_steps`` forwards at most fix the masked
+        positions (a row with none left rides along) and store nothing;
+        one forward over the final tokens commits the block's K and V.
+        A row returns exactly ``max_new_tokens`` tokens (fewer after
+        `eos_id`): the last block is denoised and stored whole and cut
+        on the way out.
+
+        ``stats`` gains ``block_forwards`` by phase, ``block_row_forwards``
+        (rows summed over forwards), ``block_positions_committed`` and
+        ``blocks``: a record a round of what every forward was given,
+        chose and fixed (docs/GENERATE.md).
+        """
+        B, mask_id = self.block_length, self.model.mask_id
+        stats.update(block_forwards={"denoise": 0, "store": 0},
+                     block_row_forwards=0, block_positions_committed=0,
+                     blocks=[])
+        for index, s in enumerate(seqs):
+            s["index"] = index
+            s["open"] = s["ctx"][len(s["ctx"]) // B * B:]   # prompt's tail
+        while True:
+            live = [s for s in seqs if not s["done"]]
+            if not live:
+                return
+            slots = [s["slot"] for s in live]
+            rows = len(live)
+            with _tr.span("gen.block", model=self.name, rows=rows) as bsp:
+                t0 = time.monotonic()
+                tokens = np.full((rows, B), mask_id, np.int32)
+                masked = np.ones((rows, B), bool)
+                for r, s in enumerate(live):
+                    tokens[r, :len(s["open"])] = s["open"]
+                    masked[r, :len(s["open"])] = False
+                record = {"rows": [s["index"] for s in live],
+                          "starts": [int(self.cache.lengths[slot])
+                                     for slot in slots],
+                          "steps": []}
+                for step in range(self.denoise_steps):
+                    if not masked.any():
+                        break
+                    with _tr.span("gen.denoise_step", model=self.name,
+                                  rows=rows) as sp:
+                        t1 = time.monotonic()
+                        (x0, confidence), _nk, _nv = self._forward(
+                            self.model, self.cache, slots, tokens,
+                            self.model.forward_choice)
+                        fixed = self._fix_most_confident(
+                            masked, confidence, self.denoise_steps - step)
+                        record["steps"].append(
+                            {"tokens": tokens, "masked": masked,
+                             "fixed": fixed, "x0": x0,
+                             "confidence": confidence})
+                        tokens = np.where(fixed, x0, tokens)
+                        masked = masked & ~fixed
+                        sp.set_attr("fixed", int(fixed.sum()))
+                        sp.set_duration(time.monotonic() - t1)
+                    stats["block_forwards"]["denoise"] += 1
+                    stats["block_row_forwards"] += rows
+                    _cat.gen_block_forwards.inc(model=self.name,
+                                                phase="denoise")
+                with _tr.span("gen.block_store", model=self.name,
+                              rows=rows) as sp:
+                    t1 = time.monotonic()
+                    _out, nk, nv = self._forward(
+                        self.model, self.cache, slots, tokens,
+                        self.model.forward_kv)
+                    self._commit(self.model, self.cache, slots, nk, nv, B)
+                    sp.set_duration(time.monotonic() - t1)
+                stats["block_forwards"]["store"] += 1
+                stats["block_row_forwards"] += rows
+                stats["block_positions_committed"] += rows * B
+                _cat.gen_block_forwards.inc(model=self.name, phase="store")
+                _cat.gen_block_positions_committed.inc(rows * B,
+                                                       model=self.name)
+                record["final"] = tokens
+                stats["blocks"].append(record)
+                for r, s in enumerate(live):
+                    for tok in tokens[r, len(s["open"]):].tolist():
+                        s["ctx"].append(tok)
+                        s["out"].append(tok)
+                        stats["decode_tokens"] += 1
+                        if tok == eos_id or len(s["out"]) >= max_new_tokens:
+                            s["done"] = True
+                            break
+                    s["open"] = []
+                bsp.set_attr("tokens_committed", rows * B)
+                dt = time.monotonic() - t0
+                bsp.set_duration(dt)
             stats["decode_seconds"] += dt
             _cat.gen_decode_seconds.observe(dt, model=self.name)
 

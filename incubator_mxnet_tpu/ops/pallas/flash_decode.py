@@ -207,19 +207,77 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, lengths,
 
 def paged_causal_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
                            lengths, scale=None, use_kernel=None,
-                           interpret=False):
-    """Full decode-step attention: paged past + causal in-chunk self.
+                           interpret=False, mask_block=None):
+    """Full decode-step attention: paged past + in-chunk self.
 
     q/k_new/v_new (S, C, H, D) — the chunk being fed this step, whose
     k/v are NOT yet in the pool; position ``c`` attends every past
     position plus in-chunk positions ``<= c``. Returns (S, C, H, D).
 
+    Fewer key/value heads than query heads (grouped-query attention):
+    ``k_new``/``v_new`` and the pools may carry ``Hkv = H // G`` heads;
+    query head ``h`` reads key/value head ``h // G``. The G query heads of
+    a key/value head are folded into the chunk axis, so that both terms
+    run the one-``H`` code below on ``(S, C * G, Hkv, D)`` queries.
+
+    ``mask_block`` = B replaces the causal in-chunk term by the block
+    mask of block-diffusion decoders: with absolute positions
+    ``lengths + c``, position i sees j iff ``j // B <= i // B`` — causal
+    between blocks of B, bidirectional inside one. (Every past position
+    is in an earlier or the same block, so the past term is unchanged.)
+
     The past term comes from :func:`paged_flash_decode` (kernel when
-    available); the in-chunk term is a small C x C causal softmax in
+    available); the in-chunk term is a small C x C masked softmax in
     lax; the two are merged with the standard two-way online-softmax
     combine. The diagonal guarantees every row has at least one live
     score, so the merge never divides by zero even with empty past.
     """
+    S, C, H, D = q.shape
+    if k_new.shape[2] != H:
+        return _grouped_query(q, k_new, v_new, k_pool, v_pool, block_tables,
+                              lengths, scale, use_kernel, interpret,
+                              mask_block)
+    return _paged_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
+                            lengths, scale, use_kernel, interpret,
+                            _in_chunk_mask(C, lengths, mask_block))
+
+
+def _in_chunk_mask(C, lengths, mask_block, fold=1):
+    """Which in-chunk key t a query row sees, broadcastable to
+    (S, H, C * fold, T): causal, or the block mask at absolute
+    positions. `fold` query rows share a chunk position (grouped-query
+    heads folded into the chunk axis, position-major)."""
+    row = jnp.arange(C * fold) // fold
+    if mask_block is None:
+        return (row[:, None] >= jnp.arange(C)[None, :])[None, None]
+    pos = jnp.asarray(lengths, jnp.int32)[:, None] + jnp.arange(C)[None]
+    blk = pos // mask_block                                  # (S, C)
+    return (blk[:, :, None] >= blk[:, None, :])[:, None, row, :]
+
+
+def _grouped_query(q, k_new, v_new, k_pool, v_pool, block_tables, lengths,
+                   scale, use_kernel, interpret, mask_block):
+    S, C, H, D = q.shape
+    Hkv = k_new.shape[2]
+    if H % Hkv:
+        raise ValueError("query heads (%d) must be a multiple of key/value "
+                         "heads (%d)" % (H, Hkv))
+    G = H // Hkv
+    # (S, C, Hkv, G, D) -> rows (c, g) of key/value head kv
+    folded = q.reshape(S, C, Hkv, G, D).transpose(0, 1, 3, 2, 4).reshape(
+        S, C * G, Hkv, D)
+    out = _paged_attention(folded, k_new, v_new, k_pool, v_pool,
+                           block_tables, lengths, scale, use_kernel,
+                           interpret,
+                           _in_chunk_mask(C, lengths, mask_block, fold=G))
+    return out.reshape(S, C, G, Hkv, D).transpose(0, 1, 3, 2, 4).reshape(
+        S, C, H, D)
+
+
+def _paged_attention(q, k_new, v_new, k_pool, v_pool, block_tables, lengths,
+                     scale, use_kernel, interpret, causal):
+    """`causal`: the in-chunk mask, broadcastable to (S, H, Cq, T) where
+    q holds Cq rows a sequence and k_new/v_new T in-chunk positions."""
     S, C, H, D = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -229,12 +287,10 @@ def paged_causal_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
 
     s_new = jnp.einsum("schd,sthd->shct", q.astype(jnp.float32),
                        k_new.astype(jnp.float32)) * scale  # (S, H, C, T)
-    causal = (jnp.arange(C)[:, None]
-              >= jnp.arange(C)[None, :])                   # (C, T)
-    s_new = jnp.where(causal[None, None], s_new, _NEG_INF)
+    s_new = jnp.where(causal, s_new, _NEG_INF)
     m_s = jnp.max(s_new, axis=-1)                          # (S, H, C)
     p = jnp.exp(s_new - m_s[..., None])
-    p = jnp.where(causal[None, None], p, 0.0)
+    p = jnp.where(causal, p, 0.0)
     l_s = jnp.sum(p, axis=-1)                              # (S, H, C)
     o_s = jnp.einsum("shct,sthd->schd", p,
                      v_new.astype(jnp.float32))            # unnormalized

@@ -1,4 +1,12 @@
-"""Expert parallelism: Switch-style mixture-of-experts over an `ep` axis.
+"""Mixture-of-experts layers: a capacity layer over an `ep` axis, and a
+dropless one.
+
+Two layers with two contracts. ``moe_apply`` (below, first) is the
+Switch/GShard capacity layer; ``moe_dropless`` (further down) routes every
+token to its top-k experts whatever the load, sorts the routes by expert
+and runs one grouped matrix product over the experts held.
+
+The capacity layer.
 
 TPU-native formulation (Mesh-TensorFlow / Switch Transformer lineage):
 token->expert routing is expressed as DENSE dispatch/combine einsums over
@@ -16,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..gluon.block import HybridBlock
 
-__all__ = ["moe_apply", "moe_ffn", "MoEBlock"]
+__all__ = ["moe_apply", "moe_ffn", "MoEBlock", "moe_dropless"]
 
 
 def moe_apply(x, gate_w, w1, b1, w2, b2, capacity_factor=1.25,
@@ -128,6 +136,64 @@ def moe_ffn(x, params, prefix, top_k=2, capacity_factor=1.25,
         params[prefix + "expert_b1"], params[prefix + "expert_w2"],
         params[prefix + "expert_b2"], capacity_factor,
         ep_sharding=ep_sharding, top_k=top_k)
+    return out
+
+
+# ------------------------------------------------------------- dropless
+def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
+                 interpret=False, return_stats=False):
+    """Dropless top-k mixture of gated-SiLU experts, no biases.
+
+    x : (N, d) tokens
+    router_w : (d, E); gate_w, up_w : (E, d, f); down_w : (E, f, d)
+
+    ``p = softmax(x router_w)`` over ALL experts in float32, the top-k of
+    it renormalised to sum to one (``norm_topk_prob``), and every one of a
+    token's k routes is computed, whatever the load on its expert:
+
+        out = sum_{e in top-k} w_e * (silu(x gate_w[e]) * (x up_w[e])) down_w[e]
+
+    The N x k routes are sorted by expert (a stable sort, so a token's
+    routes keep their order inside a group) and each of the three expert
+    products is ONE grouped product over the sorted rows
+    (``ops.pallas.grouped_matmul``; `use_kernel` / `interpret` are its).
+    Its cost follows the routes and the experts hit: an expert that gets
+    no route costs nothing, one that gets them all gets them all. There
+    is no capacity and no other path: this layer never becomes
+    ``moe_apply``. Products accumulate in float32; the gated activation is
+    rounded to x's dtype before the down product, the result after the
+    weighted sum.
+
+    -> out (N, d), and with `return_stats` also ``{"expert_load": (E,)
+    int32 routes an expert}``.
+    """
+    from ..ops.pallas.grouped_matmul import grouped_matmul
+    n, d = x.shape
+    experts = router_w.shape[1]
+    k = int(top_k)
+    if not 1 <= k <= experts:
+        raise ValueError("top_k must be in [1, %d], got %d" % (experts, k))
+
+    def grouped(rows, w, sizes):
+        return grouped_matmul(rows, w, sizes, use_kernel=use_kernel,
+                              interpret=interpret)
+    logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)                  # (N, k)
+    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    expert_of_route = top_e.reshape(n * k)
+    order = jnp.argsort(expert_of_route, stable=True)       # sorted -> route
+    load = jnp.zeros((experts,), jnp.int32).at[expert_of_route].add(1)
+    rows = x[order // k]                                    # (N k, d)
+    hidden = (jax.nn.silu(grouped(rows, gate_w, load))
+              * grouped(rows, up_w, load)).astype(x.dtype)
+    y = grouped(hidden, down_w, load)                       # (N k, d) f32
+    back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32))                 # route -> sorted
+    out = jnp.sum(y[back].reshape(n, k, d) * weights[:, :, None],
+                  axis=1).astype(x.dtype)
+    if return_stats:
+        return out, {"expert_load": load}
     return out
 
 

@@ -292,6 +292,32 @@ gen_spec_accepted = _m.counter(
     "mxtpu_gen_spec_accepted_total",
     "Draft tokens accepted by target verification (accept-rate "
     "numerator; denominator is gen_spec_proposed)")
+gen_block_forwards = _m.counter(
+    "mxtpu_gen_block_forwards_total",
+    "Forwards of the block-diffusion loop by model and phase (denoise = "
+    "a forward that stores nothing | store = the one that commits a "
+    "block's K and V)")
+gen_block_positions_committed = _m.counter(
+    "mxtpu_gen_block_positions_committed_total",
+    "Cache positions committed by block store passes, by model (block "
+    "length a row a block; over the block forwards a row it is the "
+    "tokens a forward yields)")
+moe_routes = _m.counter(
+    "mxtpu_moe_routes_total",
+    "Token-expert routes computed by the dropless expert layer, summed "
+    "over layers, by model — tokens x experts a token x layers a forward: "
+    "none is dropped")
+moe_experts_hit = _m.counter(
+    "mxtpu_moe_experts_hit_total",
+    "Distinct experts that got at least one route, summed over layers "
+    "and forwards, by model (over layers x forwards: the experts whose "
+    "weights a forward reads)")
+moe_load_max_over_mean = _m.histogram(
+    "mxtpu_moe_load_max_over_mean",
+    "The fullest expert's routes over the mean expert's, one "
+    "observation a forward (mean over layers), by model — 1 is even "
+    "routing",
+    buckets=(1, 1.5, 2, 3, 4, 6, 8, 12, 16, 32, 64, 128))
 gen_kv_blocks_in_use = _m.gauge(
     "mxtpu_gen_kv_blocks_in_use",
     "Paged-KV pool blocks currently mapped into live slot block tables")

@@ -1,0 +1,469 @@
+"""The ``sdar_moe`` family at a small size on the CPU: 2 layers, hidden 64,
+4 query and 2 key/value heads of 16, 8 experts top-2 of width 32,
+vocabulary 256, block length 4, seeded weights.
+
+- the dropless expert layer against a per-token loop (also every route on
+  one expert, and an expert that gets none), on the lax path and through
+  the Pallas launch in interpret mode;
+- the grouped product's launch against ``jax.lax.ragged_dot``;
+- ``paged_causal_attention`` with 2 key/value heads under both in-chunk
+  masks against a dense mask, and bit-identical to the function as it was
+  for GPT-2's call;
+- prefill then block decoding through ``PagedKVCache`` against the plain
+  reference's full forward;
+- the block loop's invariants, and the plain loop left as it was.
+"""
+
+import math
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.reference import sdar_moe as reference
+from incubator_mxnet_tpu.generate import (GenerateEngine, GPTPagedLM,
+                                          SDARPagedLM)
+from incubator_mxnet_tpu.models.gpt import gpt_config, gpt_param_shapes
+from incubator_mxnet_tpu.models.sdar_moe import sdar_logits
+from incubator_mxnet_tpu.ops.pallas import flash_decode
+from incubator_mxnet_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                           tile_plan)
+from incubator_mxnet_tpu.parallel.moe import moe_dropless
+from incubator_mxnet_tpu.telemetry import catalog as cat
+from incubator_mxnet_tpu.telemetry import metrics as _met
+from incubator_mxnet_tpu import telemetry
+
+MASK = 255
+CFG = {"hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+       "head_dim": 16, "num_experts": 8, "num_experts_per_tok": 2,
+       "moe_intermediate_size": 32, "vocab_size": 256, "rope_theta": 1000000,
+       "rms_norm_eps": 1e-6, "num_hidden_layers": 2, "dtype": "float32",
+       "seed_weight_range": 0.3,
+       "assumed": {"block_length": {"value": 4},
+                   "mask_token_id": {"value": MASK}}}
+PROGRAM = {"vocab_size": 256, "units": 64, "num_layers": 2, "num_heads": 4,
+           "num_kv_heads": 2, "head_dim": 16, "num_experts": 8,
+           "experts_per_token": 2, "expert_hidden": 32, "block_length": 4,
+           "mask_id": MASK, "max_len": 64}
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return reference.init_weights(CFG, 3)
+
+
+@pytest.fixture(scope="module")
+def model(weights):
+    return SDARPagedLM(weights, PROGRAM, dtype="float32")
+
+
+def _engine(model, slots=4, max_len=64, **kw):
+    kw.setdefault("prefill_chunk", 8)
+    kw.setdefault("denoise_steps", 2)
+    return GenerateEngine(model, model.make_cache(slots, max_len=max_len,
+                                                  block_size=8), **kw)
+
+
+# ------------------------------------------------------------ expert layer
+def _expert_weights(rng, experts=8, d=16, f=12):
+    return [jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+            for s in ((d, experts), (experts, d, f), (experts, d, f),
+                      (experts, f, d))]
+
+
+def _per_token_loop(x, router_w, gate_w, up_w, down_w, k):
+    """Every token's experts one at a time, in float64."""
+    x, router_w, gate_w, up_w, down_w = [
+        np.asarray(a, np.float64) for a in (x, router_w, gate_w, up_w,
+                                            down_w)]
+    out = np.zeros_like(x)
+    for t, row in enumerate(x):
+        z = row @ router_w
+        p = np.exp(z - z.max())
+        p /= p.sum()
+        chosen = np.argsort(-p, kind="stable")[:k]
+        for e in chosen:
+            gate = row @ gate_w[e]
+            hidden = gate / (1 + np.exp(-gate)) * (row @ up_w[e])
+            out[t] += p[e] / p[chosen].sum() * (hidden @ down_w[e])
+    return out
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["lax", "interpret"])
+@pytest.mark.parametrize("routing", ["even", "one_expert", "one_unused"])
+def test_the_dropless_layer_agrees_with_a_per_token_loop(routing, kernel):
+    rng = np.random.default_rng(7)
+    router_w, gate_w, up_w, down_w = _expert_weights(rng)
+    x = jnp.asarray(rng.normal(size=(21, 16)), jnp.float32)
+    if routing == "one_expert":
+        # expert 5's column so large that it is every token's first choice
+        # (the second still varies): 21 of the 42 routes on one expert
+        router_w = router_w.at[:, 5].set(0.0)
+        x = x.at[:, 0].set(4.0)
+        router_w = router_w.at[0, 5].set(25.0)
+    if routing == "one_unused":
+        router_w = router_w.at[:, 2].set(0.0).at[0, 2].set(-25.0)
+        x = x.at[:, 0].set(4.0)
+    out, stats = moe_dropless(x, router_w, gate_w, up_w, down_w, 2,
+                              use_kernel=kernel, interpret=kernel,
+                              return_stats=True)
+    load = np.asarray(stats["expert_load"])
+    assert load.sum() == 21 * 2             # no token dropped
+    if routing == "one_expert":
+        assert load[5] == 21
+    if routing == "one_unused":
+        assert load[2] == 0
+    # float32 products against a float64 loop: sums of 16 and 12 terms
+    np.testing.assert_allclose(
+        np.asarray(out), _per_token_loop(x, router_w, gate_w, up_w, down_w,
+                                         2), atol=2e-5)
+
+
+def test_all_routes_on_one_expert_top_1():
+    """k = 1 with a router that sends everything to expert 3: the grouped
+    product gets one group of all rows and seven empty ones."""
+    rng = np.random.default_rng(8)
+    router_w, gate_w, up_w, down_w = _expert_weights(rng)
+    router_w = jnp.zeros_like(router_w).at[0, 3].set(9.0)
+    x = jnp.asarray(rng.normal(size=(33, 16)), jnp.float32).at[:, 0].set(1.0)
+    for kernel in (False, True):
+        out, stats = moe_dropless(x, router_w, gate_w, up_w, down_w, 1,
+                                  use_kernel=kernel, interpret=kernel,
+                                  return_stats=True)
+        assert np.asarray(stats["expert_load"]).tolist() == [
+            0, 0, 0, 33, 0, 0, 0, 0]
+        np.testing.assert_allclose(
+            np.asarray(out),
+            _per_token_loop(x, router_w, gate_w, up_w, down_w, 1), atol=2e-5)
+
+
+@pytest.mark.parametrize("sizes", [[3, 0, 17, 1, 0, 11], [32, 0, 0, 0, 0, 0],
+                                   [0, 0, 0, 0, 0, 5], [16, 16, 16, 0, 1, 0]])
+def test_the_grouped_launch_is_ragged_dot(sizes):
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(sum(sizes), 64)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(6, 64, 48)), jnp.float32)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    launch = grouped_matmul(x, w, group_sizes, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(launch),
+        np.asarray(jax.lax.ragged_dot(x, w, group_sizes)))
+    dest, tile_group, used = tile_plan(group_sizes, sum(sizes))
+    tiles = [-(-n // 16) for n in sizes]
+    assert int(used[0]) == sum(tiles)
+    # a tile belongs to one group; the tiles past the used ones repeat the
+    # last used group, so that they fetch nothing
+    owners = [g for g, n in enumerate(tiles) for _ in range(n)]
+    assert np.asarray(tile_group)[:len(owners)].tolist() == owners
+    assert set(np.asarray(tile_group)[len(owners):].tolist()) <= {owners[-1]}
+    assert len(set(np.asarray(dest).tolist())) == sum(sizes)
+
+
+# --------------------------------------------------------------- attention
+def _pool(rng, lengths, bs, mb, H, D):
+    S = len(lengths)
+    kp = rng.normal(size=(S * mb, bs, H, D)).astype(np.float32)
+    vp = rng.normal(size=(S * mb, bs, H, D)).astype(np.float32)
+    tables = rng.permutation(S * mb).reshape(S, mb).astype(np.int32)
+    return kp, vp, tables
+
+
+@pytest.mark.parametrize("mask_block,C,past", [
+    (None, 4, 8), (None, 8, 4), (None, 4, 0), (None, 1, 5),
+    (4, 4, 8), (4, 8, 4), (4, 4, 0), (4, 12, 16)])
+def test_grouped_query_attention_matches_a_dense_mask(mask_block, C, past):
+    """A chunk under the block mask holds whole blocks."""
+    S, H, Hkv, D, bs, mb = 2, 4, 2, 8, 4, 4
+    rng = np.random.default_rng(C * 31 + past)
+    lengths = np.asarray([past, max(past - 4, 0)], np.int32)
+    q = rng.normal(size=(S, C, H, D)).astype(np.float32)
+    k_new = rng.normal(size=(S, C, Hkv, D)).astype(np.float32)
+    v_new = rng.normal(size=(S, C, Hkv, D)).astype(np.float32)
+    kp, vp, tables = _pool(rng, lengths, bs, mb, Hkv, D)
+    out = np.asarray(flash_decode.paged_causal_attention(
+        jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
+        jnp.asarray(kp), jnp.asarray(vp), tables, lengths,
+        use_kernel=False, mask_block=mask_block))
+    for s in range(S):
+        n = int(lengths[s])
+        k_all = np.concatenate([kp[tables[s]].reshape(-1, Hkv, D)[:n],
+                                k_new[s]])
+        v_all = np.concatenate([vp[tables[s]].reshape(-1, Hkv, D)[:n],
+                                v_new[s]])
+        pos = np.arange(n + C)
+        sees = (pos[None, :] // mask_block <= pos[:, None] // mask_block
+                if mask_block else pos[None, :] <= pos[:, None])
+        for h in range(H):
+            sc = q[s, :, h] @ k_all[:, h // 2].T / math.sqrt(D)
+            sc = np.where(sees[n:], sc, -np.inf)
+            w = np.exp(sc - sc.max(-1, keepdims=True))
+            ref = (w / w.sum(-1, keepdims=True)) @ v_all[:, h // 2]
+            np.testing.assert_allclose(out[s, :, h], ref, atol=1e-5)
+
+
+def _attention_as_it_was(q, k_new, v_new, k_pool, v_pool, block_tables,
+                         lengths):
+    """``paged_causal_attention`` of the commit before grouped-query heads
+    and the block mask, lax path, copied whole."""
+    S, C, H, D = q.shape
+    scale = 1.0 / math.sqrt(D)
+    o_p, m_p, l_p = flash_decode.paged_flash_decode(
+        q, k_pool, v_pool, block_tables, lengths, scale=scale,
+        use_kernel=False)
+    s_new = jnp.einsum("schd,sthd->shct", q.astype(jnp.float32),
+                       k_new.astype(jnp.float32)) * scale
+    causal = (jnp.arange(C)[:, None] >= jnp.arange(C)[None, :])
+    s_new = jnp.where(causal[None, None], s_new, -1e30)
+    m_s = jnp.max(s_new, axis=-1)
+    p = jnp.exp(s_new - m_s[..., None])
+    p = jnp.where(causal[None, None], p, 0.0)
+    l_s = jnp.sum(p, axis=-1)
+    o_s = jnp.einsum("shct,sthd->schd", p, v_new.astype(jnp.float32))
+    m_s = m_s.transpose(0, 2, 1)
+    l_s = l_s.transpose(0, 2, 1)
+    m = jnp.maximum(m_p, m_s)
+    w_p = l_p * jnp.exp(m_p - m)
+    w_s = jnp.exp(m_s - m)
+    num = o_p.astype(jnp.float32) * w_p[..., None] + o_s * w_s[..., None]
+    den = w_p + l_s * w_s
+    return (num / den[..., None]).astype(q.dtype)
+
+
+@pytest.mark.parametrize("C,past", [(1, 9), (5, 0), (8, 3)])
+def test_gpt2s_attention_call_is_bit_identical_to_what_it_was(C, past):
+    S, H, D, bs, mb = 3, 2, 8, 4, 4
+    rng = np.random.default_rng(C)
+    lengths = np.full(S, past, np.int32)
+    q, k_new, v_new = (jnp.asarray(rng.normal(size=(S, C, H, D)),
+                                   jnp.float32) for _ in range(3))
+    kp, vp, tables = _pool(rng, lengths, bs, mb, H, D)
+    args = (q, k_new, v_new, jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(tables), jnp.asarray(lengths))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda *a: flash_decode.paged_causal_attention(
+            *a, use_kernel=False))(*args)),
+        np.asarray(jax.jit(_attention_as_it_was)(*args)))
+
+
+# ------------------------------------------------- model against reference
+def test_the_full_forward_agrees_with_the_plain_reference(weights):
+    tokens = np.random.default_rng(0).integers(0, 250, (3, 24)).astype(
+        np.int32)
+    ours = np.asarray(sdar_logits(weights, PROGRAM, jnp.asarray(tokens)))
+    theirs = reference.logits(weights, CFG, tokens)
+    assert np.abs(theirs).max() > 5        # the logits are not all alike
+    # float32 both, the reference at `highest`; on the CPU both are exact
+    # float32 products and differ by the order of summation alone
+    np.testing.assert_allclose(ours, theirs, atol=2e-4)
+
+
+@pytest.mark.parametrize("prompt_len", [8, 9, 10, 11])
+def test_prefill_then_a_block_through_the_cache_is_the_full_forward(
+        model, weights, prompt_len):
+    """The prompt's whole blocks are prefilled into a PagedKVCache, the tail
+    opens the block; the block's logits through the cache, with every
+    masked position holding MASK, are the reference's over the whole
+    sequence under the dense block mask."""
+    rng = np.random.default_rng(prompt_len)
+    prompt = rng.integers(0, 250, prompt_len).tolist()
+    eng = _engine(model)
+    slot = eng.cache.alloc()
+    whole = prompt_len // 4 * 4
+    eng._prefill(model, eng.cache, slot, prompt[:whole], model.forward_kv)
+    assert eng.cache.lengths[slot] == whole
+    block = (prompt[whole:] + [MASK] * 4)[:4]
+    tokens = np.asarray([block], np.int32)
+    logits, nk, nv = eng._forward(model, eng.cache, [slot], tokens)
+    theirs = reference.logits(weights, CFG, np.asarray([prompt[:whole]
+                                                        + block], np.int32),
+                              at=np.arange(whole, whole + 4)[None])
+    # float32 on both sides; the paged path sums past and chunk apart
+    np.testing.assert_allclose(logits[0], theirs[0], atol=2e-4)
+    # the device's choice is the logits' argmax and its softmax share
+    (x0, confidence), _, _ = eng._forward(model, eng.cache, [slot], tokens,
+                                          model.forward_choice)
+    logits = np.array(logits)
+    logits[..., MASK] = -np.inf             # a position never takes MASK
+    assert x0[0].tolist() == logits[0].argmax(-1).tolist()
+    z = logits[0] - logits[0].max(-1, keepdims=True)
+    np.testing.assert_allclose(confidence[0], 1 / np.exp(z).sum(-1),
+                               rtol=1e-5)
+    # a second block after the first is stored: the cache now holds the
+    # block's final tokens' K and V
+    final = np.where(tokens == MASK, x0, tokens)
+    _none, nk, nv = eng._forward(model, eng.cache, [slot], final,
+                                 model.forward_kv)
+    eng._commit(model, eng.cache, [slot], nk, nv, 4)
+    nxt = np.full((1, 4), MASK, np.int32)
+    logits2, _, _ = eng._forward(model, eng.cache, [slot], nxt)
+    theirs2 = reference.logits(
+        weights, CFG, np.asarray([prompt[:whole] + final[0].tolist()
+                                  + [MASK] * 4], np.int32),
+        at=np.arange(whole + 4, whole + 8)[None])
+    np.testing.assert_allclose(logits2[0], theirs2[0], atol=2e-4)
+    eng.cache.free(slot)
+
+
+def test_bfloat16_serving_stays_near_the_reference(weights):
+    """Served as the cell serves it (bfloat16 weights, activations and
+    pools): bfloat16 keeps 8 bits, a relative error of 2**-9 a rounding;
+    through 2 layers of some 20 roundings each the logits stay within 5 %
+    of their largest magnitude, and an 8-bit reference does not."""
+    low = reference.init_weights(dict(CFG, dtype="bfloat16"), 3)
+    tokens = np.random.default_rng(1).integers(0, 250, (2, 16)).astype(
+        np.int32)
+    theirs = reference.logits(low, CFG, tokens)
+    ours = np.asarray(sdar_logits(low, PROGRAM, jnp.asarray(tokens)))
+    bound = 0.05 * np.abs(theirs).max()
+    assert np.abs(ours - theirs).max() < bound
+    eight = reference.logits(low, CFG, tokens, precision="float8_e4m3")
+    assert np.abs(eight - theirs).max() > bound
+
+
+# --------------------------------------------------------------- block loop
+@pytest.fixture()
+def _metrics():
+    telemetry.enable()
+    _met.reset()
+    yield
+    _met.reset()
+    telemetry.disable()
+
+
+def test_the_block_loop_keeps_its_invariants(model, _metrics, monkeypatch):
+    """Prompts with tails of 0, 1, 2 and 3 tokens, 10 new tokens each (a
+    last block cut by max_new_tokens): exactly 10 a row, none MASK, the
+    cache holding prompt + committed whole blocks when the row is freed,
+    every forward counted."""
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 250, n).tolist() for n in (8, 9, 10, 11)]
+    eng = _engine(model)
+    at_free = {}
+    free = eng.cache.free
+    monkeypatch.setattr(eng.cache, "free", lambda slot: (
+        at_free.update({slot: int(eng.cache.lengths[slot])}), free(slot)))
+    out = eng.generate(prompts, max_new_tokens=10)
+    assert [len(o) for o in out] == [10] * 4
+    assert all(MASK not in o and all(0 <= t < 256 for t in o) for o in out)
+    # 8: blocks at 8, 12, 16 (4 + 4 + 2 of 4); 9: 3 + 4 + 3; 10: 2 + 4 + 4;
+    # 11: 1 + 4 + 4 + 1 of a fourth block that the row takes alone
+    assert sorted(at_free.values()) == [20, 20, 20, 24]
+    assert eng.cache.in_use == 0
+    st = eng.last_stats
+    assert st["block_forwards"] == {"denoise": 8, "store": 4}
+    assert st["block_row_forwards"] == 3 * (4 + 4 + 4 + 1)
+    assert st["block_positions_committed"] == 4 * (4 + 4 + 4 + 1)
+    assert st["decode_tokens"] == 40 and st["prefill_tokens"] == 32
+    assert [b["rows"] for b in st["blocks"]] == [[0, 1, 2, 3]] * 3 + [[3]]
+    assert st["blocks"][0]["starts"] == [8, 8, 8, 8]
+    first = st["blocks"][0]["steps"]
+    # the static schedule: ceil(masked / steps left) a forward
+    assert [s["masked"].sum(1).tolist() for s in first] == [[4, 3, 2, 1],
+                                                            [2, 1, 1, 0]]
+    assert [s["fixed"].sum(1).tolist() for s in first] == [[2, 2, 1, 1],
+                                                           [2, 1, 1, 0]]
+    for step in first:      # the most confident of the masked are fixed
+        for r in range(4):
+            conf = np.where(step["masked"][r], step["confidence"][r], -1)
+            worst_fixed = conf[step["fixed"][r]].min(initial=np.inf)
+            assert (conf[step["masked"][r] & ~step["fixed"][r]]
+                    <= worst_fixed).all()
+    # no token is dropped: every forward routes tokens x 2 in every layer
+    moe = st["moe"]
+    fed = 4 * 8 + 3 * (4 + 4 + 4 + 1) * 4          # prefill chunks; blocks
+    assert moe["forwards"] == 4 + 12 and moe["routes"] == fed * 2 * 2
+    assert len(moe["load_max_over_mean"]) == 16
+    assert cat.moe_routes.value(model="gpt") == moe["routes"]
+    assert cat.gen_block_forwards.value(model="gpt", phase="denoise") == 8
+    assert cat.gen_block_forwards.value(model="gpt", phase="store") == 4
+    assert cat.gen_block_positions_committed.value(model="gpt") == 52
+    # the same call again gives the same tokens: nothing is left behind
+    assert eng.generate(prompts, max_new_tokens=10) == out
+
+
+def test_the_block_loop_follows_the_reference_forward_by_forward(model,
+                                                                 weights):
+    """Every denoising forward's choice, from the recorded schedule: the
+    reference over prompt + served tokens up to the block + the block as
+    the forward saw it picks the same tokens with the same confidence."""
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, 250, n).tolist() for n in (9, 12)]
+    eng = _engine(model)
+    out = eng.generate(prompts, max_new_tokens=7)
+    checked = 0
+    for block in eng.last_stats["blocks"]:
+        for step in block["steps"]:
+            for r, (row, start) in enumerate(zip(block["rows"],
+                                                 block["starts"])):
+                masked = step["masked"][r]
+                if not masked.any():
+                    continue
+                sequence = (prompts[row] + out[row])[:start] \
+                    + step["tokens"][r].tolist()
+                logits = reference.logits(
+                    weights, CFG, np.asarray([sequence], np.int32),
+                    at=np.arange(start, start + 4)[None], block_rows=1)[0]
+                logits[:, MASK] = -np.inf   # a position never takes MASK
+                assert (logits.argmax(-1) == step["x0"][r])[masked].all()
+                z = logits - logits.max(-1, keepdims=True)
+                np.testing.assert_allclose(
+                    step["confidence"][r][masked],
+                    (1 / np.exp(z).sum(-1))[masked], rtol=1e-4)
+                checked += 1
+    assert checked >= 6
+
+
+def test_block_decoding_takes_no_draft_no_temperature_no_split_chunk(model):
+    cache = model.make_cache(2, max_len=32)
+    with pytest.raises(ValueError, match="greedy"):
+        GenerateEngine(model, cache, temperature=0.7, prefill_chunk=8)
+    with pytest.raises(ValueError, match="multiple of the model's"):
+        GenerateEngine(model, cache, prefill_chunk=6)
+    eng = GenerateEngine(model, cache, prefill_chunk=8)
+    assert eng.denoise_steps == 4           # default: a position a step
+
+
+def test_the_last_block_must_fit_the_cache_whole(model):
+    eng = GenerateEngine(model, model.make_cache(1, max_len=30),
+                         prefill_chunk=8)
+    with pytest.raises(ValueError, match="exceeds cache"):
+        eng.generate([[1] * 26], max_new_tokens=3)   # 29 -> stored to 32
+
+
+def test_eos_ends_a_row_inside_a_block(model):
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, 250, 8).tolist()]
+    eng = _engine(model)
+    out = eng.generate(prompts, max_new_tokens=12)[0]
+    eos = out[5]
+    cut = eng.generate(prompts, max_new_tokens=12, eos_id=eos)[0]
+    assert cut == out[:out.index(eos) + 1]
+
+
+def test_a_gpt_model_still_takes_the_plain_loop_token_for_token():
+    """A model that declares no block length is decoded as before: the
+    engine's tokens are those of a hand-rolled loop of one-token steps."""
+    cfg = gpt_config({"vocab_size": 29, "units": 24, "num_layers": 2,
+                      "num_heads": 2, "max_len": 64})
+    rng = np.random.RandomState(0)
+    lm = GPTPagedLM({n: (rng.randn(*s) * 0.05).astype(np.float32)
+                     for n, s in gpt_param_shapes(cfg).items()}, cfg)
+    prompts = [[3, 5, 7, 2, 11, 1, 4], [9, 8]]
+    eng = GenerateEngine(lm, lm.make_cache(2, max_len=32))
+    assert eng.block_length == 0
+    out = eng.generate(prompts, max_new_tokens=6)
+    assert "block_forwards" not in eng.last_stats
+    assert "moe" not in eng.last_stats
+    for prompt, served in zip(prompts, out):
+        cache = lm.make_cache(1, max_len=32)
+        hand = GenerateEngine(lm, cache)
+        slot = cache.alloc()
+        hand._prefill(lm, cache, slot, prompt[:-1])
+        token, tokens = prompt[-1], []
+        for _ in range(6):
+            token = int(np.argmax(hand._step(
+                lm, cache, [slot], np.asarray([[token]], np.int32))[0]))
+            tokens.append(token)
+        assert tokens == served

@@ -115,6 +115,50 @@ def test_flash_attention_compiles_for_v5e(one_chip, direction):
     _compile(fwd if direction == "fwd" else bwd, qkv, qkv, qkv, mask)
 
 
+# ------------------------------------------------------------ expert layer
+@pytest.mark.parametrize("rows,k,n", [(512, 2048, 768), (512, 768, 2048),
+                                      (768, 2048, 768)],
+                         ids=["up_block", "down_block", "up_prefill"])
+def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n):
+    """The dropless layer's products at the SDAR-30B-A3B cell's sizes: 128
+    experts of 2048 x 768, 64 or 96 tokens x 8 routes."""
+    from incubator_mxnet_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((128, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((128,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda x, w, s: grouped_matmul(x, w, s, use_kernel=True),
+                    x, w, sizes)
+    assert "moe_grouped_matmul" in text
+
+
+def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch):
+    """One layer of the cell's denoising forward (16 rows x 4 positions
+    over 16-block tables of bfloat16 pools, published widths, the whole
+    vocabulary) with the grouped launch in it."""
+    from incubator_mxnet_tpu.models import sdar_moe
+    from incubator_mxnet_tpu.ops.pallas import grouped_matmul as gm
+    monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
+    cfg = sdar_moe.sdar_config({
+        "vocab_size": 151936, "units": 2048, "num_layers": 1,
+        "num_heads": 32, "num_kv_heads": 4, "head_dim": 128,
+        "num_experts": 128, "experts_per_token": 8, "expert_hidden": 768,
+        "block_length": 4, "mask_id": 151669})
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    params = {n: shape(s) for n, s in sdar_moe.sdar_param_shapes(cfg).items()}
+    pools = [shape((256, 16, 4, 128))]
+
+    def forward(params, tokens, lengths, tables, kps, vps):
+        (x0, conf), _nk, _nv, loads = sdar_moe.sdar_forward_paged(
+            params, cfg, tokens, lengths, tables, kps, vps, head="choice")
+        return x0, conf, loads
+    text = _compile(forward, params, shape((16, 4), jnp.int32),
+                    shape((16,), jnp.int32), shape((16, 16), jnp.int32),
+                    pools, pools)
+    assert text.count("moe_grouped_matmul") >= 3
+
+
 # ------------------------------------------------- one whole BERT-base step
 def _answer_tpu(monkeypatch):
     """The kernel gates ask the backend, which is the CPU here, so the test
