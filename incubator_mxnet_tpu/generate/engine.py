@@ -19,9 +19,13 @@ A round commits between 1 (a=0, the target's own token) and k+1 tokens
 for ~1 target forward, which is the decode speedup when the draft
 agrees often.
 
-All cache mutation happens here (append committed K/V, advance,
-truncate rejected suffixes); the model adapter is a pure shape-cached
-forward. ``GPTPagedLM`` adapts ``models/gpt.py`` to that contract.
+All cache mutation happens here (commit a forward's K/V, truncate
+rejected suffixes); the model adapter is a pure shape-cached forward.
+``GPTPagedLM`` adapts ``models/gpt.py`` to that contract. The K/V pools
+live on the device (``paged_kv``): a forward is given them where it runs,
+returns the chunk's K and V as device arrays, and ``cache.commit`` stores
+those there; the host fetches only what it reads (the logits of a decode
+step; ``x0`` and its confidence; the expert loads).
 
 A model adapter that declares a ``block_length`` (``SDARPagedLM`` over
 ``models/sdar_moe.py``) is a block-diffusion decoder, and the engine
@@ -39,6 +43,7 @@ drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
 import os
 import time
 
+import jax
 import numpy as np
 
 from ..telemetry import catalog as _cat
@@ -78,19 +83,34 @@ def _dispatch(fn, params, args):
         return fn(params, *args)
 
 
+def _fetch(arrays):
+    """`arrays` (what the caller reads of a forward's outputs) as host
+    arrays, under the forward's ``lm.fetch`` span: the wait for the
+    device, then the copies, all started before the first is waited for;
+    the span counts the bytes copied."""
+    with _tr.span("lm.fetch") as sp:
+        out = jax.device_get(list(arrays))
+        sp.set_attr("d2h_bytes", sum(a.nbytes for a in out))
+    return out
+
+
 class GPTPagedLM:
     """Shape-cached jit adapter over ``gpt_forward_paged``.
 
-    ``forward(tokens, lengths, tables, k_pools, v_pools)`` takes numpy
-    arrays, returns numpy ``(logits (S, C, V), new_k, new_v)``. One
-    XLA program per (S, C) shape — the engine keeps shapes fixed
-    (padded prefill chunks, fixed spec width), so steady state is two
-    programs: prefill (S, chunk) and decode (S, 1) plus (1, k+1) for
-    speculative verify.
+    ``forward(tokens, lengths, tables, k_pools, v_pools)`` takes host
+    tokens, lengths and tables and the cache's pools (device arrays: not
+    shipped), and returns ``(logits (S, C, V), new_k, new_v)``: the logits
+    a host array, new_k / new_v DEVICE arrays (layers, S, C, H, D) for
+    ``cache.commit``, one array each: on the chip's host a launch costs
+    some 50 us an output buffer, so the 2 x 48 per-layer arrays are
+    stacked in the program. ``forward_kv`` is the same program for a caller
+    that reads no logits (prefill) and fetches nothing. One XLA program
+    per (S, C) shape — the engine keeps shapes fixed (padded prefill
+    chunks, fixed spec width), so steady state is two programs: prefill
+    (S, chunk) and decode (S, 1) plus (1, k+1) for speculative verify.
     """
 
     def __init__(self, params, config, use_kernel=False, interpret=False):
-        import jax
         import jax.numpy as jnp
         from ..models.gpt import gpt_config, gpt_forward_paged
         self.config = gpt_config(config)
@@ -98,10 +118,10 @@ class GPTPagedLM:
         self.num_layers = self.config["num_layers"]
 
         def pure(params, tokens, lengths, tables, kps, vps):
-            return gpt_forward_paged(params, self.config, tokens, lengths,
-                                     tables, kps, vps,
-                                     use_kernel=use_kernel,
-                                     interpret=interpret)
+            logits, nk, nv = gpt_forward_paged(
+                params, self.config, tokens, lengths, tables, kps, vps,
+                use_kernel=use_kernel, interpret=interpret)
+            return logits, jnp.stack(nk), jnp.stack(nv)
         self._fn = jax.jit(pure)
 
     def cache_spec(self):
@@ -121,9 +141,15 @@ class GPTPagedLM:
         logits, nk, nv = _dispatch(
             self._fn, self.params,
             (tokens, lengths, tables, k_pools, v_pools))
-        with _tr.span("lm.fetch"):
-            return (np.asarray(logits), [np.asarray(a) for a in nk],
-                    [np.asarray(a) for a in nv])
+        (logits,) = _fetch([logits])
+        return logits, nk, nv
+
+    def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
+        _logits, nk, nv = _dispatch(
+            self._fn, self.params,
+            (tokens, lengths, tables, k_pools, v_pools))
+        _fetch([])
+        return None, nk, nv
 
 
 class SDARPagedLM:
@@ -140,18 +166,19 @@ class SDARPagedLM:
       new_k, new_v)``;
     - ``forward_choice`` — a denoising forward: ``((x0, confidence),
       None, None)``, each (S, C), the argmax token and its softmax
-      probability computed on the device; nothing of K and V comes back,
-      as nothing is stored;
+      probability computed on the device; the program returns no K and
+      V, as nothing is stored;
     - ``forward_kv`` — prefill and a block's store pass: ``(None, new_k,
       new_v)``, no final norm, no head.
 
-    new_k / new_v index as ``[layer][row, c]`` (one stacked array a
-    forward, one copy back). After every forward ``last_expert_loads``
-    holds the (layers, experts) routes each expert got.
+    Tokens, lengths and tables are host arrays, the pools the cache's
+    device arrays; logits, ``x0`` and confidence come back as host
+    arrays, new_k / new_v stay on the device, (layers, S, C, Hkv, D) each,
+    for ``cache.commit``. After every forward ``last_expert_loads`` holds
+    the (layers, experts) routes each expert got, on the host.
     """
 
     def __init__(self, params, config, dtype="bfloat16"):
-        import jax
         import jax.numpy as jnp
         from ..models.sdar_moe import sdar_config, sdar_forward_paged
         self.config = sdar_config(config)
@@ -168,10 +195,11 @@ class SDARPagedLM:
                 out, nk, nv, loads = sdar_forward_paged(
                     params, self.config, tokens, lengths, tables, kps, vps,
                     head=head)
+                # -> (what the host reads, what stays on the device)
                 if head == "choice":        # (x0, confidence): no K, V
-                    return out + (loads,)
-                kv = jnp.stack([jnp.stack(nk), jnp.stack(nv)])
-                return (kv, loads) if out is None else (out, kv, loads)
+                    return out + (loads,), None
+                kv = (jnp.stack(nk), jnp.stack(nv))
+                return ((loads,) if out is None else (out, loads)), kv
             return jax.jit(pure)
         self._fns = {head: program(head)
                      for head in ("logits", "choice", "none")}
@@ -189,28 +217,27 @@ class SDARPagedLM:
                             max_len=max_len or self.config["max_len"], **kw)
 
     def _call(self, head, *args):
-        """One forward: the program's outputs as host arrays, without the
-        expert loads, which are kept for the engine to count."""
-        outs = _dispatch(self._fns[head], self.params, args)
-        with _tr.span("lm.fetch"):
-            outs = [np.asarray(a) for a in outs]
-        self.last_expert_loads = outs.pop()
-        return outs
+        """One forward -> (the head's outputs as host arrays, (new_k,
+        new_v) on the device or None); the expert loads are fetched with
+        the former and kept for the engine to count."""
+        read, kv = _dispatch(self._fns[head], self.params, args)
+        *read, self.last_expert_loads = _fetch(read)
+        return read, kv
 
     def forward(self, tokens, lengths, tables, k_pools, v_pools):
-        logits, kv = self._call("logits", tokens, lengths, tables,
-                                k_pools, v_pools)
-        return logits, kv[0], kv[1]
+        (logits,), (nk, nv) = self._call("logits", tokens, lengths, tables,
+                                         k_pools, v_pools)
+        return logits, nk, nv
 
     def forward_choice(self, tokens, lengths, tables, k_pools, v_pools):
-        x0, confidence = self._call("choice", tokens, lengths, tables,
-                                    k_pools, v_pools)
-        return (x0, confidence), None, None
+        choice, _none = self._call("choice", tokens, lengths, tables,
+                                   k_pools, v_pools)
+        return tuple(choice), None, None
 
     def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
-        (kv,) = self._call("none", tokens, lengths, tables, k_pools,
-                           v_pools)
-        return None, kv[0], kv[1]
+        _read, (nk, nv) = self._call("none", tokens, lengths, tables,
+                                     k_pools, v_pools)
+        return None, nk, nv
 
 
 class GenerateEngine:
@@ -301,34 +328,32 @@ class GenerateEngine:
         _cat.moe_experts_hit.inc(hit, model=self.name)
         _cat.moe_load_max_over_mean.observe(uneven, model=self.name)
 
-    def _commit(self, adapter, cache, slots, new_k, new_v, count):
-        """Append the first `count` chunk positions of every row (row r
-        belongs to ``slots[r]``) into the cache."""
-        with _tr.span("kv.commit"):
-            for row, slot in enumerate(slots):
-                for c in range(count):
-                    for i in range(adapter.num_layers):
-                        cache.append("k%d" % i, slot, new_k[i][row, c])
-                        cache.append("v%d" % i, slot, new_v[i][row, c])
-                    cache.advance(slot)
+    def _commit(self, cache, slots, new_k, new_v, count):
+        """Store the first `count` chunk positions of every row (row r
+        belongs to ``slots[r]``) in the cache; the span counts the pool
+        rows written an entry."""
+        with _tr.span("kv.commit", rows=len(slots) * count):
+            cache.commit(slots, new_k, new_v, count)
 
-    def _step(self, adapter, cache, slots, tokens, commit=True):
+    def _step(self, adapter, cache, slots, tokens):
         """Feed one token per slot ((S, 1)); commit K/V; return the
         (S, V) next-token logits."""
         logits, nk, nv = self._forward(adapter, cache, slots, tokens)
-        if commit:
-            self._commit(adapter, cache, slots, nk, nv, 1)
+        self._commit(cache, slots, nk, nv, 1)
         return logits[:, -1]
 
-    def _prefill(self, adapter, cache, slot, tokens_1d, call=None):
+    def _prefill(self, adapter, cache, slot, tokens_1d):
         """Chunked prompt ingestion: commit K/V for every prompt token
         in fixed ``prefill_chunk``-wide forwards (last chunk padded;
         pad positions sit AFTER the valid ones, so causality keeps them
         out of every valid position's attention window and they are
         simply not committed; under a block mask the valid tokens are
-        whole blocks, so the pads begin a later block)."""
+        whole blocks, so the pads begin a later block). No logits are
+        read: the adapter's ``forward_kv``, where it has one, fetches
+        none."""
         n = len(tokens_1d)
         chunk = self.prefill_chunk
+        call = getattr(adapter, "forward_kv", None)
         for start in range(0, n, chunk):
             piece = tokens_1d[start:start + chunk]
             valid = len(piece)
@@ -336,7 +361,7 @@ class GenerateEngine:
             padded[0, :valid] = piece
             _logits, nk, nv = self._forward(adapter, cache, [slot], padded,
                                             call)
-            self._commit(adapter, cache, [slot], nk, nv, valid)
+            self._commit(cache, [slot], nk, nv, valid)
 
     def _sample(self, logits_row):
         if self.temperature <= 0:
@@ -397,7 +422,6 @@ class GenerateEngine:
             # forward that skips the head; the tail opens the first
             # generated block.
             B = self.block_length
-            kv_only = self.model.forward_kv if B else None
             for s in seqs:
                 n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
                 with _tr.span("gen.prefill", model=self.name,
@@ -405,7 +429,7 @@ class GenerateEngine:
                     t0 = time.monotonic()
                     if n > 0:
                         self._prefill(self.model, self.cache, s["slot"],
-                                      s["ctx"][:n], kv_only)
+                                      s["ctx"][:n])
                         if self.draft is not None:
                             self._prefill(self.draft, self.draft_cache,
                                           s["dslot"], s["ctx"][:n])
@@ -551,7 +575,7 @@ class GenerateEngine:
                     _out, nk, nv = self._forward(
                         self.model, self.cache, slots, tokens,
                         self.model.forward_kv)
-                    self._commit(self.model, self.cache, slots, nk, nv, B)
+                    self._commit(self.cache, slots, nk, nv, B)
                     sp.set_duration(time.monotonic() - t1)
                 stats["block_forwards"]["store"] += 1
                 stats["block_row_forwards"] += rows
@@ -624,7 +648,7 @@ class GenerateEngine:
             verify = np.asarray([[ctx[-1]] + drafts], np.int32)
             logits, nk, nv = self._forward(self.model, self.cache,
                                            [slot], verify)
-            self._commit(self.model, self.cache, [slot], nk, nv, k + 1)
+            self._commit(self.cache, [slot], nk, nv, k + 1)
             target = [int(np.argmax(logits[0, j])) for j in range(k + 1)]
             # 4) longest accepted prefix + the target's own token
             a = 0

@@ -8,13 +8,17 @@ fixed slot grid) plus the family-owned extras this decoder adds:
   the DecodeLoop commits a joining prompt in ``ceil(P/chunk)`` wide
   forwards instead of P one-token steps;
 - AOT programs for the decode step (``gptdecode/s%d``), the prefill
-  chunk (``gptprefill/s%dxc%d``) and — when the checkpoint carries a
-  draft model — the draft's decode step (``gptdraft/s%d``), all built
-  through the persistent compile cache and exported/bound via the
-  checkpoint ``executables`` section like every other family;
+  chunk (``gptprefill/s%dxc%d``), the cache's commit of each
+  (``gptcommit/s%d/r%dxc%d``: the pools live on the device and one
+  donated program stores a forward's K and V there) and — when the
+  checkpoint carries a draft model — the draft's decode step
+  (``gptdraft/s%d``), all built through the persistent compile cache
+  and exported/bound via the checkpoint ``executables`` section like
+  every other family;
 - ``extra_warmup(slots)`` — called by the warmup driver to pre-build
-  the full program grid (target decode × prefill × draft decode), so a
-  warm replica's first generative request compiles nothing.
+  the full program grid (target decode × prefill × their commits ×
+  draft decode), so a warm replica's first generative request compiles
+  nothing.
 
 The programs are pure functions over the flat param dict (sorted-name
 ``BlockProgram`` convention), NOT gluon traces — the paged forward
@@ -34,7 +38,7 @@ from ..models.gpt import gpt_config, gpt_forward_paged, gpt_param_shapes
 from ..serving.loader import (GenerationMismatchError, ServedModel,
                               serving_family)
 from ..utils.checkpoint import CheckpointManager
-from .paged_kv import PagedKVCache
+from .paged_kv import PagedKVCache, device_order, store_program
 
 __all__ = ["export_gpt_for_serving", "gpt_cache_spec"]
 
@@ -128,6 +132,24 @@ class _PagedProgramSet:
         return _aot.BlockProgram(compiled, self.pvals, self.n_inputs,
                                  name, blob=blob)
 
+    def build_commit(self, name, rows, chunk, slots, max_len):
+        """The cache's ``store_program`` for what a (rows, chunk) forward
+        of this set returns, held as a BlockProgram for its name and
+        blob; it takes no params and is called as ``.compiled``."""
+        import jax.numpy as jnp
+        L = self.num_layers
+        pools = self.example_inputs(rows, chunk, slots, max_len)[3:]
+        new = [jnp.zeros((rows, chunk) + pools[0].shape[2:], jnp.float32)
+               for _ in range(L)]
+        lowered = store_program.lower(
+            pools[:L], pools[L:], new, new,
+            jnp.zeros((rows, chunk), jnp.int32),
+            tuple(device_order(p) for p in pools))
+        compiled, blob = _aot.cached_compile(
+            lowered, name=name, where="serving", donation=(0, 1),
+            want_blob=True)
+        return _aot.BlockProgram(compiled, [], 0, name, blob=blob)
+
     def eager(self, tokens, lengths, tables, kps, vps):
         """jit fallback (compiles on first use — the non-warm path)."""
         if self._jit is None:
@@ -204,16 +226,19 @@ def _build_gpt_decoder(config, params, quantize):
         return PagedKVCache(slots, gpt_cache_spec(cfg), max_len=max_len,
                             name="gpt")
 
+    def commit_name(slots, rows, chunk):
+        return "gptcommit/s%d/r%dxc%d" % (int(slots), rows, chunk)
+
     def _geometry(slots):
         return (int(slots),
                 geom["max_len"] or _env_int("MXTPU_SERVE_CACHE_LEN", 512))
 
-    def _program(pset, name, rows, chunk, slots):
+    def _program(build, name, rows, chunk, slots):
         if name not in decode_programs:
             slots_n, max_len = _geometry(slots)
             try:
-                decode_programs[name] = pset.build(name, rows, chunk,
-                                                   slots_n, max_len)
+                decode_programs[name] = build(name, rows, chunk, slots_n,
+                                              max_len)
             except Exception as e:  # noqa: BLE001 — an AOT build
                 # failure falls back to the jit path
                 log.warning("serving: cannot build %r (%s: %s); this "
@@ -223,17 +248,22 @@ def _build_gpt_decoder(config, params, quantize):
         return decode_programs[name]
 
     def decode_program_for(slots):
-        return _program(target, "gptdecode/s%d" % int(slots),
+        return _program(target.build, "gptdecode/s%d" % int(slots),
                         int(slots), 1, int(slots))
 
     def prefill_program_for(slots):
         name = "gptprefill/s%dxc%d" % (int(slots), prefill_chunk)
-        return _program(target, name, 1, prefill_chunk, int(slots))
+        return _program(target.build, name, 1, prefill_chunk, int(slots))
+
+    def commit_program_for(slots, rows, chunk):
+        return _program(target.build_commit,
+                        commit_name(slots, rows, chunk), rows, chunk,
+                        int(slots))
 
     def draft_program_for(slots):
         if draft is None:
             return None
-        return _program(draft, "gptdraft/s%d" % int(slots),
+        return _program(draft.build, "gptdraft/s%d" % int(slots),
                         int(slots), 1, int(slots))
 
     def bind(name, blob):
@@ -242,6 +272,10 @@ def _build_gpt_decoder(config, params, quantize):
             return True
         if name.startswith("gptdraft/s") and draft is not None:
             decode_programs[name] = draft.bind(name, blob)
+            return True
+        if name.startswith("gptcommit/s"):
+            decode_programs[name] = _aot.BlockProgram(
+                _aot.deserialize_compiled(blob), [], 0, name, blob=blob)
             return True
         return False
 
@@ -256,7 +290,8 @@ def _build_gpt_decoder(config, params, quantize):
     def _run(pset, prog_name, prog_factory, slots_arg, tokens, lengths,
              tables, kps, vps):
         """One paged forward: AOT program when available/gated, jit
-        fallback otherwise. Returns the flat [logits, k..., v...]."""
+        fallback otherwise. Returns the flat [logits, k..., v...], device
+        arrays (the pools are the cache's device arrays: not shipped)."""
         if _ccstore.enabled() or decode_programs:
             prog = prog_factory(slots_arg)
             if prog is not None:
@@ -266,13 +301,17 @@ def _build_gpt_decoder(config, params, quantize):
                     decode_programs[prog_name] = None
         return pset.eager(tokens, lengths, tables, kps, vps)
 
-    def _commit(cache, slot, row, flat, count):
-        nk, nv = flat[1:1 + L], flat[1 + L:]
-        for c in range(count):
-            for i in range(L):
-                cache.append("k%d" % i, slot, np.asarray(nk[i])[row, c])
-                cache.append("v%d" % i, slot, np.asarray(nv[i])[row, c])
-            cache.advance(slot)
+    def _commit(cache, slots_arg, slots, flat, count):
+        """Store a forward's K and V (``flat[1:]``) in the cache, through
+        the AOT commit program of the forward's shape when available/
+        gated (the cache keeps it; jit inside the cache otherwise)."""
+        shape = tuple(flat[1].shape[:2])
+        if shape not in cache.programs and (_ccstore.enabled()
+                                            or decode_programs):
+            prog = commit_program_for(slots_arg, *shape)
+            if prog is not None:
+                cache.programs[shape] = prog.compiled
+        cache.commit(slots, list(flat[1:1 + L]), list(flat[1 + L:]), count)
 
     def step(tokens, cache, active):
         """DecodeLoop contract: tokens (slots,) int32 over the FULL
@@ -282,8 +321,7 @@ def _build_gpt_decoder(config, params, quantize):
         flat = _run(target, "gptdecode/s%d" % s, decode_program_for, s,
                     np.asarray(tokens, np.int32).reshape(s, 1), lengths,
                     tables, kps, vps)
-        for slot in np.flatnonzero(np.asarray(active)):
-            _commit(cache, int(slot), int(slot), flat, 1)
+        _commit(cache, s, range(s), flat, np.asarray(active, np.int32))
         return np.asarray(flat[0])[:, 0]
 
     def prefill(slot, tokens, cache):
@@ -301,7 +339,7 @@ def _build_gpt_decoder(config, params, quantize):
             lengths, tables, kps, vps = _gather(cache, [slot])
             flat = _run(target, name, prefill_program_for, n_slots,
                         padded, lengths, tables, kps, vps)
-            _commit(cache, slot, 0, flat, len(piece))
+            _commit(cache, n_slots, [slot], flat, len(piece))
 
     def extra_warmup(slots):
         """Pre-build the generative program grid for a slot count:
@@ -311,6 +349,10 @@ def _build_gpt_decoder(config, params, quantize):
         jobs = [("gptdecode/s%d" % slots, decode_program_for),
                 ("gptprefill/s%dxc%d" % (slots, prefill_chunk),
                  prefill_program_for)]
+        for shape in ((slots, 1), (1, prefill_chunk)):
+            jobs.append((commit_name(slots, *shape),
+                         lambda s, shape=shape: commit_program_for(
+                             s, *shape)))
         if draft is not None:
             jobs.append(("gptdraft/s%d" % slots, draft_program_for))
         for name, factory in jobs:
@@ -335,8 +377,8 @@ def _build_gpt_decoder(config, params, quantize):
         for pset, vals in staged:
             pset.apply_swap(vals)
         for name, prog in decode_programs.items():
-            if prog is None:
-                continue
+            if prog is None or name.startswith("gptcommit/"):
+                continue        # a commit program reads no weight
             pset = draft if name.startswith("gptdraft/") else target
             prog.param_vals[:] = pset.pvals
 

@@ -10,11 +10,24 @@ blocks are immediately reusable by other slots.
 
 The public surface is a strict superset of ``serving.kv_cache.KVCache``
 (alloc/free/append/advance/prefix/set_state/state, same error messages),
-so the continuous-batching ``DecodeLoop`` runs unchanged on top. The
-paged extras feed the flash-decode kernel:
+so the continuous-batching ``DecodeLoop`` runs unchanged on top.
+
+**Where the data lives.** The book-keeping (lengths, block tables, the
+free list, the gauges) is host state. The pool of every kv entry is a
+DEVICE array in the entry's dtype: a forward reads it where it runs, so a
+jitted call ships the block tables and lengths, never a pool, and what
+the forward produced is stored by ``commit``: one donated program over
+all pools that scatters the new rows and hands the pools back in place.
+``append`` / ``prefix`` are the ``KVCache`` contract's slow surface, for
+tests and third-party ``step_fn``s: a launch a row, a gathered copy
+fetched to the host. ``cache.data[name]`` of a kv entry is that device
+array (immutable: write through ``commit`` or ``append``).
+
+The paged extras feed the flash-decode kernel:
 
 - ``pool(name)`` — the ``(num_blocks, block_size) + per_step_shape``
-  backing array of a kv entry,
+  backing device array of a kv entry,
+- ``commit(slots, new_k, new_v, count)`` — store a forward's K and V,
 - ``tables_array(slots)`` — an ``(S, max_blocks_per_slot)`` int32 block
   table, padded with block 0 (padded fetches are masked by ``lengths``
   so any valid pool row is safe),
@@ -22,14 +35,17 @@ paged extras feed the flash-decode kernel:
   decode rejects draft tokens by truncating the drafted suffix),
 - ``fragmentation()`` — unused fraction of mapped block capacity.
 
-State-kind entries stay dense ``(slots,) + shape`` (they are replaced,
-not appended — paging buys nothing). All kv entries share one block
-table per slot: the spec's kv entries advance in lockstep (the KVCache
+State-kind entries stay dense host ``(slots,) + shape`` arrays (they are
+replaced, not appended — paging buys nothing). All kv entries share one
+block table per slot: the spec's kv entries advance in lockstep (the KVCache
 contract), so their block layouts are identical by construction.
 """
 
+import functools
 import math
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry import catalog as _cat
@@ -69,6 +85,78 @@ def _env_int(name, default):
         return int(os.environ.get(name, "") or default)
     except ValueError:
         return default
+
+
+# a fresh device buffer a call, one trace a (shape, dtype): `jnp.zeros`
+# itself costs the host 1.5 ms a pool, and a cache has 2 a layer
+_device_zeros = jax.jit(jnp.zeros, static_argnums=(0, 1))
+
+
+def device_order(pool):
+    """A pool's dims major to minor as its device holds them. A TPU keeps
+    a (blocks, 16, 25, 64) float32 pool as (blocks, 25, 16, 64): its tiles
+    cover the two minor dims, and 25 heads would pad to 32."""
+    return tuple(pool.format.layout.major_to_minor)
+
+
+def _cells_at(shape, rows, order):
+    """For the positions `rows` (n,), flat over (block, offset), of a
+    `shape` pool: the rows of its (size, shape[minor]) cells, the pool as
+    the device holds it (`order`) with all but its minor dim flattened,
+    that hold them, position by position; `size`, past the cells, for a
+    position past the pool."""
+    minor = order[-1]
+    stride, size = {}, 1
+    for d in reversed(order[:-1]):
+        stride[d], size = size, size * shape[d]
+    at = (rows // shape[1]) * stride[0] + (rows % shape[1]) * stride[1]
+    for d in range(2, len(shape)):
+        if d != minor:
+            at = at[..., None] + jnp.arange(shape[d],
+                                            dtype=jnp.int32) * stride[d]
+    stored = rows.reshape((-1,) + (1,) * (at.ndim - 1)) < shape[0] * shape[1]
+    return jnp.where(stored, at, size).reshape(-1)
+
+
+def _put(pool, rows, new, order, memo):
+    """`pool` with its positions `rows` (n,), flat over (block, offset),
+    set to `new` (n, ...); a position past the pool is dropped. The
+    scatter runs over the pool as the device holds it (`order`), row by
+    row of its minor dim: a scatter over the logical (block, offset) rows
+    would copy a pool whose device order differs into that order and
+    back, in every commit. `memo`: the cell rows of `rows`, shared by the
+    pools of one shape and order (every layer's, as a rule)."""
+    shape, minor = pool.shape, order[-1]
+    if minor < 2:       # one number a position: give it a minor dim
+        return _put(pool[..., None], rows, new[..., None],
+                    order + (pool.ndim,), memo)[..., 0]
+    if (shape, order) not in memo:
+        memo[shape, order] = _cells_at(shape, rows, order)
+    cells = pool.transpose(order).reshape(-1, shape[minor])
+    new = jnp.moveaxis(new.reshape((-1,) + shape[2:]), minor - 1, -1)
+    cells = cells.at[memo[shape, order]].set(
+        new.reshape(-1, shape[minor]).astype(pool.dtype), mode="drop")
+    return cells.reshape([shape[d] for d in order]).transpose(
+        np.argsort(order))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1), static_argnums=5)
+def store_program(k_pools, v_pools, new_k, new_v, rows, orders):
+    """Every layer's pools with ``new_k[i]`` / ``new_v[i]`` (S, C, ...)
+    scattered to the flat pool positions `rows` (S, C). The pools are
+    donated: the scatter is in place. `orders`: the `device_order` of
+    each k pool, then of each v pool. One compiled program a (S, C)."""
+    rows, memo = rows.reshape(-1), {}
+    k_orders, v_orders = orders[:len(k_pools)], orders[len(k_pools):]
+    return ([_put(p, rows, new_k[i], k_orders[i], memo)
+             for i, p in enumerate(k_pools)],
+            [_put(p, rows, new_v[i], v_orders[i], memo)
+             for i, p in enumerate(v_pools)])
+
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnums=3)
+def _store_one(pool, row, value, order):
+    return _put(pool, row.reshape(1), value, order, {})
 
 
 class PagedKVCache:
@@ -115,7 +203,20 @@ class PagedKVCache:
             full = ((self.slots,) + shape if kind == "state"
                     else (self.num_blocks, self.block_size) + shape)
             self.spec[ent_name] = (kind, shape, dtype)
-            self.data[ent_name] = np.zeros(full, dtype)
+            self.data[ent_name] = (np.zeros(full, dtype) if kind == "state"
+                                   else _device_zeros(full, dtype))
+        # `commit` takes a forward's K and V by layer: entries k<i>, v<i>
+        kv = [n for n, ent in self.spec.items() if ent[0] == "kv"]
+        self._orders = {n: device_order(self.data[n]) for n in kv}
+        self._layer_names = tuple(
+            ["%s%d" % (kind, i) for i in range(len(kv) // 2)]
+            for kind in "kv")
+        if set(kv) != set(sum(self._layer_names, [])):
+            self._layer_names = None
+        # (S, C) -> a compiled `store_program` put there by an owner that
+        # ships executables (the serving family's warm grid); `commit`
+        # takes the jitted one where there is none
+        self.programs = {}
         self.lengths = np.zeros(self.slots, np.int64)
         self._free = list(range(self.slots - 1, -1, -1))
         self._live = set()
@@ -133,8 +234,8 @@ class PagedKVCache:
 
     def alloc(self):
         """Claim a zeroed slot; None when the grid is full. Blocks are
-        mapped lazily by `append`, so alloc itself never exhausts the
-        pool."""
+        mapped lazily by `commit` / `append`, so alloc itself never
+        exhausts the pool."""
         if not self._free:
             return None
         slot = self._free.pop()
@@ -172,16 +273,11 @@ class PagedKVCache:
         self._check(slot)
         return self.data[name][slot]
 
-    def append(self, name, slot, value):
-        """Write `value` at this slot's current position (all kv entries
-        share the position counter; call `advance` once per step after
-        every entry is written). Maps a fresh pool block when the
-        position crosses a block boundary."""
-        kind, shape, _ = self.spec[name]
-        if kind != "kv":
-            raise ValueError("%r is a %r entry, not kv" % (name, kind))
-        self._check(slot)
-        pos = int(self.lengths[slot])
+    def _row_at(self, slot, pos):
+        """The flat pool row of position `pos`, `slot`'s next, in every kv
+        entry; maps a fresh pool block when the position opens one. A
+        reused block keeps its last owner's rows: every read of a pool is
+        cut at ``lengths``, so a stale tail is never seen."""
         if pos >= self.max_len:
             raise ValueError("slot %d is full (max_len=%d)"
                              % (slot, self.max_len))
@@ -198,15 +294,74 @@ class PagedKVCache:
                     name=self.name, slot=slot, block=bi,
                     num_blocks=self.num_blocks,
                     block_size=self.block_size)
-            block = self._free_blocks.pop()
-            # zero the reused block across ALL kv entries so a partial
-            # fill never exposes a previous sequence's tail
-            for n, (k, _s, _d) in self.spec.items():
-                if k == "kv":
-                    self.data[n][block] = 0
-            table.append(block)
+            table.append(self._free_blocks.pop())
             self._note_blocks()
-        self.data[name][table[bi], off] = np.asarray(value).reshape(shape)
+        return table[bi] * self.block_size + off
+
+    def append(self, name, slot, value):
+        """Write `value` at this slot's current position (all kv entries
+        share the position counter; call `advance` once per step after
+        every entry is written). Maps a fresh pool block when the
+        position crosses a block boundary. The slow surface: one launch
+        a call; a forward's K and V go through `commit`."""
+        kind, shape, _ = self.spec[name]
+        if kind != "kv":
+            raise ValueError("%r is a %r entry, not kv" % (name, kind))
+        self._check(slot)
+        row = self._row_at(slot, int(self.lengths[slot]))
+        self.data[name] = _store_one(
+            self.data[name], np.int32(row), np.asarray(value).reshape(shape),
+            self._orders[name])
+
+    def commit(self, slots, new_k, new_v, count):
+        """Store what a forward produced: the first `count` (one number,
+        or one a row) chunk positions of row r of ``new_k[i]`` /
+        ``new_v[i]`` ((S, C) + shape device arrays, layer i's, as the
+        forward returned them: a sequence, or one array stacked over
+        layers) at the next positions of ``slots[r]`` in the entries
+        ``"k<i>"`` / ``"v<i>"``, and advance the slots.
+
+        The host maps the blocks those positions need, row by row as a
+        loop of `append`s would (so the pool runs out at the same
+        position and leaves the same lengths and tables), and ONE donated
+        program scatters every layer's rows; the positions not stored (a
+        prefill chunk's pads, a row whose count is 0) point past the pool
+        and are dropped. What was mapped before an error is stored."""
+        if self._layer_names is None:
+            raise ValueError("commit stores a forward's layers in kv entries "
+                             "named k<i> and v<i>; this cache's are not")
+        slots = list(slots)
+        k_names, v_names = self._layer_names
+        # per layer: a sequence of (S, C, ...) arrays, or one stacked
+        chunk = (new_k.shape[2] if hasattr(new_k, "shape")
+                 else new_k[0].shape[1])
+        counts = np.broadcast_to(np.asarray(count), (len(slots),))
+        rows = np.full((len(slots), chunk),
+                       self.num_blocks * self.block_size, np.int32)
+        try:
+            for r, slot in enumerate(slots):
+                if counts[r]:
+                    self._check(slot)
+                for c in range(int(counts[r])):
+                    rows[r, c] = self._row_at(slot, int(self.lengths[slot]))
+                    self.lengths[slot] += 1
+        finally:
+            k_pools, v_pools = self._store(rows, new_k, new_v)
+            self.data.update(zip(k_names + v_names, k_pools + v_pools))
+            self._note_blocks()
+
+    def _store(self, rows, new_k, new_v):
+        k_names, v_names = self._layer_names
+        args = ([self.data[n] for n in k_names],
+                [self.data[n] for n in v_names], new_k, new_v, rows)
+        program = self.programs.get(rows.shape)
+        if program is not None:
+            try:
+                return program(*args)
+            except TypeError:   # bound for other pools: retire it
+                del self.programs[rows.shape]
+        return store_program(
+            *args, tuple(self._orders[n] for n in k_names + v_names))
 
     def advance(self, slot):
         self._check(slot)
@@ -214,8 +369,9 @@ class PagedKVCache:
         self._note_blocks()
 
     def prefix(self, name, slot):
-        """The filled (length, ...) view of a kv entry for one slot
-        (gathered copy — pool rows are not contiguous)."""
+        """The filled (length, ...) rows of a kv entry for one slot: a
+        gathered copy (pool rows are not contiguous), fetched to the
+        host."""
         kind = self.spec[name][0]
         if kind != "kv":
             raise ValueError("%r is a %r entry, not kv" % (name, kind))
@@ -224,14 +380,15 @@ class PagedKVCache:
         if length == 0:
             _kind, shape, dtype = self.spec[name]
             return np.zeros((0,) + shape, dtype)
-        table = self._tables[slot]
         nb = math.ceil(length / self.block_size)
-        rows = self.data[name][table[:nb]]          # (nb, bs) + shape
+        rows = np.asarray(
+            self.data[name][np.asarray(self._tables[slot][:nb])])
         return rows.reshape((nb * self.block_size,) + rows.shape[2:])[:length]
 
     # ------------------------------------------------- paged extensions
     def pool(self, name):
-        """The (num_blocks, block_size, ...) backing array of a kv entry."""
+        """The (num_blocks, block_size, ...) backing device array of a kv
+        entry."""
         kind = self.spec[name][0]
         if kind != "kv":
             raise ValueError("%r is a %r entry, not kv" % (name, kind))

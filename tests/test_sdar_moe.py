@@ -271,7 +271,7 @@ def test_prefill_then_a_block_through_the_cache_is_the_full_forward(
     eng = _engine(model)
     slot = eng.cache.alloc()
     whole = prompt_len // 4 * 4
-    eng._prefill(model, eng.cache, slot, prompt[:whole], model.forward_kv)
+    eng._prefill(model, eng.cache, slot, prompt[:whole])
     assert eng.cache.lengths[slot] == whole
     block = (prompt[whole:] + [MASK] * 4)[:4]
     tokens = np.asarray([block], np.int32)
@@ -295,7 +295,7 @@ def test_prefill_then_a_block_through_the_cache_is_the_full_forward(
     final = np.where(tokens == MASK, x0, tokens)
     _none, nk, nv = eng._forward(model, eng.cache, [slot], final,
                                  model.forward_kv)
-    eng._commit(model, eng.cache, [slot], nk, nv, 4)
+    eng._commit(eng.cache, [slot], nk, nv, 4)
     nxt = np.full((1, 4), MASK, np.int32)
     logits2, _, _ = eng._forward(model, eng.cache, [slot], nxt)
     theirs2 = reference.logits(
@@ -392,6 +392,9 @@ def test_the_block_loop_follows_the_reference_forward_by_forward(model,
     prompts = [rng.integers(0, 250, n).tolist() for n in (9, 12)]
     eng = _engine(model)
     out = eng.generate(prompts, max_new_tokens=7)
+    # the tokens of the tree before ISSUE 30 (host pools, appends)
+    assert out == [[141, 224, 224, 115, 94, 15, 224],
+                   [23, 23, 216, 216, 67, 162, 56]]
     checked = 0
     for block in eng.last_stats["blocks"]:
         for step in block["steps"]:

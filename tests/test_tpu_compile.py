@@ -172,6 +172,40 @@ def _answer_tpu(monkeypatch):
     monkeypatch.setattr(flash_mod, "flash_attention_available", on_tpu)
 
 
+# ----------------------------------------------------- the paged KV commit
+@pytest.mark.parametrize("layers,pool,dtype,chunk,held_as", [
+    # gpt2_xl: a decode step, a prefill chunk; sdar_30b_a3b: a block
+    (48, (24, 16, 25, 64), jnp.float32, (4, 1), (0, 2, 1, 3)),
+    (48, (24, 16, 25, 64), jnp.float32, (1, 32), (0, 2, 1, 3)),
+    (6, (256, 16, 4, 128), jnp.bfloat16, (16, 4), (0, 1, 2, 3)),
+], ids=["gpt2_xl_step", "gpt2_xl_chunk", "sdar_block"])
+def test_the_kv_commit_copies_no_pool_on_v5e(one_chip, layers, pool, dtype,
+                                             chunk, held_as):
+    """``PagedKVCache.commit``'s program at the benchmark's sizes: every
+    pool aliases its output (the donation takes) and none is copied into
+    another layout for the scatter and back. The chip keeps a pool whose
+    head count its tiles do not divide in another dim order (25 heads:
+    blocks, heads, positions, width), which `device_order` reads off the
+    array and is read here off a compiled identity."""
+    from incubator_mxnet_tpu.generate.paged_kv import store_program
+    kv = jax.ShapeDtypeStruct(pool, dtype, sharding=one_chip)
+    # K and V as the adapters return them: one array stacked over layers
+    new = jax.ShapeDtypeStruct((layers,) + chunk + pool[2:], dtype,
+                               sharding=one_chip)
+    rows = jax.ShapeDtypeStruct(chunk, jnp.int32, sharding=one_chip)
+    held = jax.jit(lambda p: p).lower(kv).compile().input_formats[0][0]
+    order = tuple(held.layout.major_to_minor)
+    assert order == held_as
+    compiled = store_program.lower([kv] * layers, [kv] * layers, new, new,
+                                   rows, (order,) * 2 * layers).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * layers * kv.size * kv.dtype.itemsize
+    assert memory.temp_size_in_bytes < 1 << 20
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
+    assert not [dims for dims in copies      # a pool, in whatever order
+                if sorted(map(int, dims.split(","))) == sorted(pool)]
+
+
 def test_bert_base_train_step_compiles_for_v5e(one_chip, monkeypatch):
     """chip_smoke.py's shape A: the BERT-base AdamW step at B=64,T=128,
     built on the CPU mesh and lowered for the described chip."""
