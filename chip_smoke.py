@@ -11,9 +11,8 @@ raises ends the run. The figures printed on the way are SMOKE NUMBERS — a
 few steps of one repeated batch — not benchmark results. The last line of
 standard output is the contract's JSON object and nothing more.
 
-Phase `train` — the BERT-base pretrain step exactly as bench.py's
-``bench_bert`` builds it (12 layers, 768 units, 12 heads, FFN 3072,
-vocab 30522, tied decoder, gather-first MLM head + NSP; AdamW, bf16
+Phase `train` — the BERT-base pretrain step (12 layers, 768 units, 12
+heads, FFN 3072, vocab 30522, tied decoder, gather-first MLM head + NSP; AdamW, bf16
 compute, bf16-stored moments) through ``parallel.ShardedTrainer`` on a
 one-device mesh, at B=64,T=128 (38 kernels: 26 fused LayerNorm + 12 fused
 softmax) and at B=16,T=512 (62: the flash-attention kernels, forward, dq
@@ -57,8 +56,8 @@ def check(ok, what):
 
 # --------------------------------------------------------------- phase train
 def bert_batch(batch, seqlen):
-    """bench_bert's synthetic pretraining batch from a fixed seed:
-    (data, label) lists of host arrays."""
+    """A synthetic pretraining batch from a fixed seed: (data, label)
+    lists of host arrays."""
     rng = np.random.RandomState(0)
     n_mask = max(1, int(seqlen * MASK_FRAC))
     ids = rng.randint(0, VOCAB, (batch, seqlen)).astype(np.int32)
@@ -73,7 +72,7 @@ def bert_batch(batch, seqlen):
 
 
 def bert_trainer(mesh, rules=None, data_spec=None, num_layers=12):
-    """BERT-base pretraining under ShardedTrainer, as bench_bert wires it.
+    """BERT-base pretraining under ShardedTrainer.
     Parameters come from a fixed seed, so two calls give equal weights."""
     import jax
     import jax.numpy as jnp

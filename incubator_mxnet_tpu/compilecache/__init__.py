@@ -16,8 +16,8 @@ With no cache dir configured the subsystem costs one env lookup per
 query and touches no files.
 
 ``use_jax_cache`` is separate from all of that: it places JAX's OWN
-persistent compilation cache for the entry-point scripts (``bench.py``,
-``chip_smoke.py``).
+persistent compilation cache for the entry-point scripts
+(``benchmarks/run.py``, ``chip_smoke.py``).
 """
 
 import os

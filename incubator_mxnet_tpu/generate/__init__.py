@@ -5,11 +5,12 @@ through the continuous-batching plane, with
 
 - :mod:`.paged_kv` — block-table + free-list KV allocator that drops in
   behind the ``serving/kv_cache.py`` alloc/free/append surface,
-- :mod:`.engine` — chunked prefill, greedy/temperature sampling,
-  draft-model speculative decoding (Leviathan et al., ICML 2023), and
-  block-diffusion decoding for a model that declares a block length,
-- :mod:`.family` — the ``gpt_decoder`` ``@serving_family`` wiring the
-  engine's forward into ModelServer's slot grid with AOT programs.
+- :mod:`.engine` — the paged step (gather, forward, commit) and over it
+  chunked prefill, greedy/temperature sampling, draft-model speculative
+  decoding (Leviathan et al., ICML 2023), and block-diffusion decoding
+  for a model that declares a block length,
+- :mod:`.family` — the ``gpt_decoder`` ``@serving_family`` putting the
+  engine's step under ModelServer's slot grid, with AOT programs.
 
 Importing this package registers the serving family.
 """
@@ -17,7 +18,7 @@ Importing this package registers the serving family.
 from .paged_kv import PagedKVCache
 from .engine import GenerateEngine, GPTPagedLM, SDARPagedLM
 from . import family  # noqa: F401  (registers the gpt_decoder family)
-from .family import export_gpt_for_serving, gpt_cache_spec
+from .family import export_gpt_for_serving
 
 __all__ = [
     "PagedKVCache",
@@ -25,5 +26,4 @@ __all__ = [
     "GPTPagedLM",
     "SDARPagedLM",
     "export_gpt_for_serving",
-    "gpt_cache_spec",
 ]

@@ -27,6 +27,13 @@ returns the chunk's K and V as device arrays, and ``cache.commit`` stores
 those there; the host fetches only what it reads (the logits of a decode
 step; ``x0`` and its confidence; the expert loads).
 
+The step itself is four functions over (adapter, cache, slots), the one
+implementation under this engine's loops and under ``serving.DecodeLoop``
+(``generate/family.py`` calls them): ``forward_slots`` asks the cache for
+a forward's inputs and calls the adapter, ``commit_slots`` stores what it
+returned, ``step_slots`` is both for one token a slot, ``prefill_slot``
+both for a prompt in padded chunks.
+
 A model adapter that declares a ``block_length`` (``SDARPagedLM`` over
 ``models/sdar_moe.py``) is a block-diffusion decoder, and the engine
 drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
@@ -40,7 +47,6 @@ drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
             whose K and V are committed: B positions a row at once
 """
 
-import os
 import time
 
 import jax
@@ -48,16 +54,15 @@ import numpy as np
 
 from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
-from .paged_kv import PagedKVCache
+from .paged_kv import PagedKVCache, _env_int
 
 __all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM"]
 
 
-def _env_int(name, default):
-    try:
-        return int(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
+def default_prefill_chunk():
+    """``MXTPU_GEN_PREFILL_CHUNK`` (32): the chunk width of the engine and
+    of the serving family, read here alone."""
+    return _env_int("MXTPU_GEN_PREFILL_CHUNK", 32)
 
 
 def _host_bytes(args):
@@ -73,13 +78,22 @@ def _host_bytes(args):
     return total
 
 
-def _dispatch(fn, params, args):
-    """The jitted call of an adapter's forward under its ``lm.dispatch``
-    span; a real span also counts the host bytes the call ships."""
+def _dispatch(fn, params, args, programs=None):
+    """The call of an adapter's forward under its ``lm.dispatch`` span; a
+    real span also counts the host bytes the call ships. `programs`: the
+    adapter's (S, C) -> shipped executable of `fn`; one is preferred to
+    the jitted `fn`, and retired when it refuses its arguments (compiled
+    for other pools or weights, or an export of another signature)."""
     sp = _tr.span("lm.dispatch")
     if sp is not _tr.NULL_SPAN:     # counted only for a real span
         sp.set_attr("h2d_bytes", _host_bytes(args))
     with sp:
+        program = programs.get(args[0].shape) if programs else None
+        if program is not None:
+            try:
+                return program(params, *args)
+            except TypeError:
+                del programs[args[0].shape]
         return fn(params, *args)
 
 
@@ -94,7 +108,72 @@ def _fetch(arrays):
     return out
 
 
-class GPTPagedLM:
+def forward_slots(adapter, cache, slots, tokens, call=None, routing=None):
+    """One adapter forward for `slots` (list) feeding `tokens` (S, C);
+    returns (logits, new_k, new_v) WITHOUT committing. `call`: another
+    forward of the adapter's with the same arguments (the block loop's
+    ``forward_choice`` / ``forward_kv``). `routing`: called with the
+    forward's expert loads (the engine's tally of an expert layer)."""
+    with _tr.span("kv.gather"):
+        inputs = cache.forward_inputs(slots)
+    out = (call or adapter.forward)(tokens, *inputs)
+    if routing is not None:
+        routing(adapter.last_expert_loads)
+    return out
+
+
+def commit_slots(cache, slots, new_k, new_v, count):
+    """Store the first `count` (one number, or one a row) chunk positions
+    of every row (row r belongs to ``slots[r]``) in the cache; the span
+    counts the pool rows written an entry."""
+    rows = (len(slots) * count if isinstance(count, int)
+            else int(np.sum(count)))
+    with _tr.span("kv.commit", rows=rows):
+        cache.commit(slots, new_k, new_v, count)
+
+
+def step_slots(adapter, cache, slots, tokens, count=1, routing=None):
+    """Feed one token per slot ((S, 1)); commit K/V (of the rows whose
+    `count` is 1: a serving grid's active ones); return the (S, V)
+    next-token logits."""
+    logits, nk, nv = forward_slots(adapter, cache, slots, tokens,
+                                   routing=routing)
+    commit_slots(cache, slots, nk, nv, count)
+    return logits[:, -1]
+
+
+def prefill_slot(adapter, cache, slot, tokens_1d, chunk, routing=None):
+    """Chunked prompt ingestion: commit K/V for every prompt token in
+    fixed `chunk`-wide forwards (last chunk padded; pad positions sit
+    AFTER the valid ones, so causality keeps them out of every valid
+    position's attention window and they are simply not committed; under
+    a block mask the valid tokens are whole blocks, so the pads begin a
+    later block). No logits are read: the adapter's ``forward_kv``, where
+    it has one, fetches none."""
+    call = getattr(adapter, "forward_kv", None)
+    for start in range(0, len(tokens_1d), chunk):
+        piece = tokens_1d[start:start + chunk]
+        padded = np.zeros((1, chunk), np.int32)
+        padded[0, :len(piece)] = piece
+        _logits, nk, nv = forward_slots(adapter, cache, [slot], padded,
+                                        call, routing)
+        commit_slots(cache, [slot], nk, nv, len(piece))
+
+
+class _PagedLM:
+    """What the adapters share: the cache of their layers. An adapter
+    sets ``config``, ``num_layers`` and ``kv_entry``, the (shape, dtype)
+    of a position in every K and V pool."""
+
+    def cache_spec(self):
+        return PagedKVCache.layer_spec(self.num_layers, *self.kv_entry)
+
+    def make_cache(self, slots, max_len=None, **kw):
+        return PagedKVCache(slots, self.cache_spec(),
+                            max_len=max_len or self.config["max_len"], **kw)
+
+
+class GPTPagedLM(_PagedLM):
     """Shape-cached jit adapter over ``gpt_forward_paged``.
 
     ``forward(tokens, lengths, tables, k_pools, v_pools)`` takes host
@@ -108,6 +187,13 @@ class GPTPagedLM:
     per (S, C) shape — the engine keeps shapes fixed (padded prefill
     chunks, fixed spec width), so steady state is two programs: prefill
     (S, chunk) and decode (S, 1) plus (1, k+1) for speculative verify.
+
+    ``programs``: (S, C) -> a shipped executable of the same program
+    (``lower(...)`` compiled), put there by an owner that ships them (the
+    serving family's bind / warm grid) as ``cache.programs`` holds the
+    commit's; a call prefers it to the jit and retires it when it refuses
+    its arguments. ``params`` is passed a call, so replacing the dict (of
+    the same shapes and dtypes) swaps the weights under every program.
     """
 
     def __init__(self, params, config, use_kernel=False, interpret=False):
@@ -116,6 +202,9 @@ class GPTPagedLM:
         self.config = gpt_config(config)
         self.params = {n: jnp.asarray(v) for n, v in params.items()}
         self.num_layers = self.config["num_layers"]
+        H = self.config["num_heads"]
+        self.kv_entry = ((H, self.config["units"] // H), jnp.float32)
+        self.programs = {}
 
         def pure(params, tokens, lengths, tables, kps, vps):
             logits, nk, nv = gpt_forward_paged(
@@ -124,35 +213,28 @@ class GPTPagedLM:
             return logits, jnp.stack(nk), jnp.stack(nv)
         self._fn = jax.jit(pure)
 
-    def cache_spec(self):
-        H = self.config["num_heads"]
-        D = self.config["units"] // H
-        spec = {}
-        for i in range(self.num_layers):
-            spec["k%d" % i] = ("kv", (H, D))
-            spec["v%d" % i] = ("kv", (H, D))
-        return spec
-
-    def make_cache(self, slots, max_len=None, **kw):
-        return PagedKVCache(slots, self.cache_spec(),
-                            max_len=max_len or self.config["max_len"], **kw)
+    def lower(self, tokens, lengths, tables, k_pools, v_pools):
+        """The one program lowered for arguments of these shapes: what an
+        owner compiles into ``programs``."""
+        return self._fn.lower(self.params, tokens, lengths, tables, k_pools,
+                              v_pools)
 
     def forward(self, tokens, lengths, tables, k_pools, v_pools):
         logits, nk, nv = _dispatch(
             self._fn, self.params,
-            (tokens, lengths, tables, k_pools, v_pools))
+            (tokens, lengths, tables, k_pools, v_pools), self.programs)
         (logits,) = _fetch([logits])
         return logits, nk, nv
 
     def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
         _logits, nk, nv = _dispatch(
             self._fn, self.params,
-            (tokens, lengths, tables, k_pools, v_pools))
+            (tokens, lengths, tables, k_pools, v_pools), self.programs)
         _fetch([])
         return None, nk, nv
 
 
-class SDARPagedLM:
+class SDARPagedLM(_PagedLM):
     """Shape-cached jit adapter over ``sdar_forward_paged``: the
     block-diffusion decoder of ``models/sdar_moe.py``, served in
     `dtype` (bfloat16: weights, activations and the K/V pools).
@@ -186,6 +268,8 @@ class SDARPagedLM:
         self.params = {n: jnp.asarray(v, self.dtype)
                        for n, v in params.items()}
         self.num_layers = self.config["num_layers"]
+        self.kv_entry = ((self.config["num_kv_heads"],
+                          self.config["head_dim"]), self.dtype)
         self.block_length = int(self.config["block_length"])
         self.mask_id = int(self.config["mask_id"])
         self.last_expert_loads = None
@@ -203,18 +287,6 @@ class SDARPagedLM:
             return jax.jit(pure)
         self._fns = {head: program(head)
                      for head in ("logits", "choice", "none")}
-
-    def cache_spec(self):
-        shape = (self.config["num_kv_heads"], self.config["head_dim"])
-        spec = {}
-        for i in range(self.num_layers):
-            spec["k%d" % i] = ("kv", shape, self.dtype)
-            spec["v%d" % i] = ("kv", shape, self.dtype)
-        return spec
-
-    def make_cache(self, slots, max_len=None, **kw):
-        return PagedKVCache(slots, self.cache_spec(),
-                            max_len=max_len or self.config["max_len"], **kw)
 
     def _call(self, head, *args):
         """One forward -> (the head's outputs as host arrays, (new_k,
@@ -258,7 +330,7 @@ class GenerateEngine:
 
     def __init__(self, model, cache, draft=None, draft_cache=None,
                  spec_k=None, prefill_chunk=None, temperature=0.0,
-                 seed=0, name="gpt", use_kernel=False, denoise_steps=None):
+                 seed=0, name="gpt", denoise_steps=None):
         if (draft is None) != (draft_cache is None):
             raise ValueError("draft model and draft cache come together")
         self.model = model
@@ -268,7 +340,7 @@ class GenerateEngine:
         self.spec_k = (spec_k if spec_k is not None
                        else _env_int("MXTPU_GEN_SPEC_K", 4))
         self.prefill_chunk = (prefill_chunk if prefill_chunk is not None
-                              else _env_int("MXTPU_GEN_PREFILL_CHUNK", 32))
+                              else default_prefill_chunk())
         if self.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         self.temperature = float(temperature)
@@ -295,25 +367,13 @@ class GenerateEngine:
             if self.denoise_steps < 1:
                 raise ValueError("denoise_steps must be >= 1")
         self.last_stats = {}
-        self._routing = None    # a call's expert-routing tally, if any
+        # a model with an expert layer: every forward of its is tallied
+        # into the call's ``last_stats["moe"]`` (``_routing``)
+        self._routing = None
+        self._note = (self._note_routing
+                      if hasattr(model, "last_expert_loads") else None)
 
     # ---------------------------------------------------------- plumbing
-    def _forward(self, adapter, cache, slots, tokens, call=None):
-        """One adapter forward for `slots` (list) feeding `tokens`
-        (S, C); returns (logits, new_k, new_v) WITHOUT committing.
-        `call`: another forward of the adapter's with the same
-        arguments (the block loop's ``forward_choice`` / ``forward_kv``)."""
-        with _tr.span("kv.gather"):
-            lengths = np.asarray([int(cache.lengths[s]) for s in slots],
-                                 np.int32)
-            tables = cache.tables_array(slots)
-            kps = [cache.pool("k%d" % i) for i in range(adapter.num_layers)]
-            vps = [cache.pool("v%d" % i) for i in range(adapter.num_layers)]
-        out = (call or adapter.forward)(tokens, lengths, tables, kps, vps)
-        if self._routing is not None and adapter is self.model:
-            self._note_routing(adapter.last_expert_loads)
-        return out
-
     def _note_routing(self, loads):
         """`loads` (layers, experts): the routes each expert got in the
         forward just made, into this call's ``last_stats["moe"]``."""
@@ -327,41 +387,6 @@ class GenerateEngine:
         _cat.moe_routes.inc(routes, model=self.name)
         _cat.moe_experts_hit.inc(hit, model=self.name)
         _cat.moe_load_max_over_mean.observe(uneven, model=self.name)
-
-    def _commit(self, cache, slots, new_k, new_v, count):
-        """Store the first `count` chunk positions of every row (row r
-        belongs to ``slots[r]``) in the cache; the span counts the pool
-        rows written an entry."""
-        with _tr.span("kv.commit", rows=len(slots) * count):
-            cache.commit(slots, new_k, new_v, count)
-
-    def _step(self, adapter, cache, slots, tokens):
-        """Feed one token per slot ((S, 1)); commit K/V; return the
-        (S, V) next-token logits."""
-        logits, nk, nv = self._forward(adapter, cache, slots, tokens)
-        self._commit(cache, slots, nk, nv, 1)
-        return logits[:, -1]
-
-    def _prefill(self, adapter, cache, slot, tokens_1d):
-        """Chunked prompt ingestion: commit K/V for every prompt token
-        in fixed ``prefill_chunk``-wide forwards (last chunk padded;
-        pad positions sit AFTER the valid ones, so causality keeps them
-        out of every valid position's attention window and they are
-        simply not committed; under a block mask the valid tokens are
-        whole blocks, so the pads begin a later block). No logits are
-        read: the adapter's ``forward_kv``, where it has one, fetches
-        none."""
-        n = len(tokens_1d)
-        chunk = self.prefill_chunk
-        call = getattr(adapter, "forward_kv", None)
-        for start in range(0, n, chunk):
-            piece = tokens_1d[start:start + chunk]
-            valid = len(piece)
-            padded = np.zeros((1, chunk), np.int32)
-            padded[0, :valid] = piece
-            _logits, nk, nv = self._forward(adapter, cache, [slot], padded,
-                                            call)
-            self._commit(cache, [slot], nk, nv, valid)
 
     def _sample(self, logits_row):
         if self.temperature <= 0:
@@ -394,7 +419,7 @@ class GenerateEngine:
         stats = {"prefill_seconds": 0.0, "decode_seconds": 0.0,
                  "prefill_tokens": 0, "decode_tokens": 0,
                  "proposed": 0, "accepted": 0}
-        if hasattr(self.model, "last_expert_loads"):    # an expert layer
+        if self._note is not None:      # an expert layer
             self._routing = stats["moe"] = {
                 "forwards": 0, "routes": 0, "experts_hit": 0,
                 "load_max_over_mean": []}
@@ -428,11 +453,13 @@ class GenerateEngine:
                               slot=s["slot"], tokens=max(n, 0)) as sp:
                     t0 = time.monotonic()
                     if n > 0:
-                        self._prefill(self.model, self.cache, s["slot"],
-                                      s["ctx"][:n])
+                        prefill_slot(self.model, self.cache, s["slot"],
+                                     s["ctx"][:n], self.prefill_chunk,
+                                     self._note)
                         if self.draft is not None:
-                            self._prefill(self.draft, self.draft_cache,
-                                          s["dslot"], s["ctx"][:n])
+                            prefill_slot(self.draft, self.draft_cache,
+                                         s["dslot"], s["ctx"][:n],
+                                         self.prefill_chunk)
                         stats["prefill_tokens"] += n
                     dt = time.monotonic() - t0
                     sp.set_duration(dt)
@@ -474,8 +501,9 @@ class GenerateEngine:
                 t0 = time.monotonic()
                 tokens = np.asarray([[s["ctx"][-1]] for s in live],
                                     np.int32)
-                logits = self._step(self.model, self.cache,
-                                    [s["slot"] for s in live], tokens)
+                logits = step_slots(self.model, self.cache,
+                                    [s["slot"] for s in live], tokens,
+                                    routing=self._note)
                 committed = 0
                 for row, s in enumerate(live):
                     tok = self._sample(logits[row])
@@ -552,9 +580,9 @@ class GenerateEngine:
                     with _tr.span("gen.denoise_step", model=self.name,
                                   rows=rows) as sp:
                         t1 = time.monotonic()
-                        (x0, confidence), _nk, _nv = self._forward(
+                        (x0, confidence), _nk, _nv = forward_slots(
                             self.model, self.cache, slots, tokens,
-                            self.model.forward_choice)
+                            self.model.forward_choice, self._note)
                         fixed = self._fix_most_confident(
                             masked, confidence, self.denoise_steps - step)
                         record["steps"].append(
@@ -572,10 +600,10 @@ class GenerateEngine:
                 with _tr.span("gen.block_store", model=self.name,
                               rows=rows) as sp:
                     t1 = time.monotonic()
-                    _out, nk, nv = self._forward(
+                    _out, nk, nv = forward_slots(
                         self.model, self.cache, slots, tokens,
-                        self.model.forward_kv)
-                    self._commit(self.cache, slots, nk, nv, B)
+                        self.model.forward_kv, self._note)
+                    commit_slots(self.cache, slots, nk, nv, B)
                     sp.set_duration(time.monotonic() - t1)
                 stats["block_forwards"]["store"] += 1
                 stats["block_row_forwards"] += rows
@@ -631,14 +659,14 @@ class GenerateEngine:
                 m = int(self.draft_cache.lengths[dslot])
                 d_logits = None
                 while m < n:
-                    d_logits = self._step(
+                    d_logits = step_slots(
                         self.draft, self.draft_cache, [dslot],
                         np.asarray([[ctx[m]]], np.int32))[0]
                     m += 1
                 # 2) propose d_2..d_k autoregressively
                 drafts.append(int(np.argmax(d_logits)))
                 for _ in range(k - 1):
-                    d_logits = self._step(
+                    d_logits = step_slots(
                         self.draft, self.draft_cache, [dslot],
                         np.asarray([[drafts[-1]]], np.int32))[0]
                     drafts.append(int(np.argmax(d_logits)))
@@ -646,9 +674,9 @@ class GenerateEngine:
             #    is the target's next-token distribution after
             #    ctx + drafts[:j]
             verify = np.asarray([[ctx[-1]] + drafts], np.int32)
-            logits, nk, nv = self._forward(self.model, self.cache,
-                                           [slot], verify)
-            self._commit(self.cache, [slot], nk, nv, k + 1)
+            logits, nk, nv = forward_slots(self.model, self.cache, [slot],
+                                           verify, routing=self._note)
+            commit_slots(self.cache, [slot], nk, nv, k + 1)
             target = [int(np.argmax(logits[0, j])) for j in range(k + 1)]
             # 4) longest accepted prefix + the target's own token
             a = 0

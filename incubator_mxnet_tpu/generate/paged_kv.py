@@ -27,7 +27,13 @@ The paged extras feed the flash-decode kernel:
 
 - ``pool(name)`` — the ``(num_blocks, block_size) + per_step_shape``
   backing device array of a kv entry,
-- ``commit(slots, new_k, new_v, count)`` — store a forward's K and V,
+- ``layer_spec(num_layers, shape, dtype)`` — the spec of a decoder's
+  cache, a K and a V entry a layer: the entry names are this module's,
+- ``forward_inputs(slots)`` — what a forward over `slots` reads of the
+  cache: lengths, block tables, K pools, V pools,
+- ``commit(slots, new_k, new_v, count)`` — store a forward's K and V
+  (``lower_commit``: that program lowered, for an owner that ships
+  executables),
 - ``tables_array(slots)`` — an ``(S, max_blocks_per_slot)`` int32 block
   table, padded with block 0 (padded fetches are masked by ``lengths``
   so any valid pool row is safe),
@@ -171,6 +177,14 @@ class PagedKVCache:
     oversubscribe (appends raise when the pool is exhausted).
     """
 
+    @staticmethod
+    def layer_spec(num_layers, shape, dtype=np.float32):
+        """The spec of a decoder's cache: for each of `num_layers` a K and
+        a V entry holding `shape` of `dtype` a position, under the names
+        ``forward_inputs`` and ``commit`` find them by."""
+        return {"%s%d" % (kind, i): ("kv", tuple(shape), dtype)
+                for i in range(num_layers) for kind in "kv"}
+
     def __init__(self, slots, spec, max_len=512, block_size=None,
                  num_blocks=None, name="default"):
         if slots < 1:
@@ -205,7 +219,8 @@ class PagedKVCache:
             self.spec[ent_name] = (kind, shape, dtype)
             self.data[ent_name] = (np.zeros(full, dtype) if kind == "state"
                                    else _device_zeros(full, dtype))
-        # `commit` takes a forward's K and V by layer: entries k<i>, v<i>
+        # a forward reads and `commit` stores K and V by layer: entries
+        # k<i>, v<i>
         kv = [n for n, ent in self.spec.items() if ent[0] == "kv"]
         self._orders = {n: device_order(self.data[n]) for n in kv}
         self._layer_names = tuple(
@@ -327,11 +342,8 @@ class PagedKVCache:
         program scatters every layer's rows; the positions not stored (a
         prefill chunk's pads, a row whose count is 0) point past the pool
         and are dropped. What was mapped before an error is stored."""
-        if self._layer_names is None:
-            raise ValueError("commit stores a forward's layers in kv entries "
-                             "named k<i> and v<i>; this cache's are not")
         slots = list(slots)
-        k_names, v_names = self._layer_names
+        k_names, v_names = self._layers()
         # per layer: a sequence of (S, C, ...) arrays, or one stacked
         chunk = (new_k.shape[2] if hasattr(new_k, "shape")
                  else new_k[0].shape[1])
@@ -350,18 +362,41 @@ class PagedKVCache:
             self.data.update(zip(k_names + v_names, k_pools + v_pools))
             self._note_blocks()
 
+    def _layers(self):
+        if self._layer_names is None:
+            raise ValueError("commit stores a forward's layers in kv entries "
+                             "named k<i> and v<i>; this cache's are not")
+        return self._layer_names
+
+    def _layer_pools(self):
+        """(every layer's K pool, every layer's V pool, their device orders
+        K first): what a forward reads and `store_program` takes."""
+        k_names, v_names = self._layers()
+        return ([self.data[n] for n in k_names],
+                [self.data[n] for n in v_names],
+                tuple(self._orders[n] for n in k_names + v_names))
+
     def _store(self, rows, new_k, new_v):
-        k_names, v_names = self._layer_names
-        args = ([self.data[n] for n in k_names],
-                [self.data[n] for n in v_names], new_k, new_v, rows)
+        k_pools, v_pools, orders = self._layer_pools()
+        args = (k_pools, v_pools, new_k, new_v, rows)
         program = self.programs.get(rows.shape)
         if program is not None:
             try:
                 return program(*args)
             except TypeError:   # bound for other pools: retire it
                 del self.programs[rows.shape]
-        return store_program(
-            *args, tuple(self._orders[n] for n in k_names + v_names))
+        return store_program(*args, orders)
+
+    def lower_commit(self, new_k, new_v):
+        """`commit`'s program lowered for what a forward returns (`new_k`
+        / `new_v`: arrays or their shapes and dtypes, stacked over layers)
+        against this cache's pools, for an owner that ships executables:
+        compiled with the pools donated (arguments 0 and 1) it is what
+        ``programs[(S, C)]`` holds."""
+        k_pools, v_pools, orders = self._layer_pools()
+        return store_program.lower(
+            k_pools, v_pools, new_k, new_v,
+            jax.ShapeDtypeStruct(new_k.shape[1:3], np.int32), orders)
 
     def advance(self, slot):
         self._check(slot)
@@ -397,6 +432,16 @@ class PagedKVCache:
     def table(self, slot):
         self._check(slot)
         return list(self._tables[slot])
+
+    def forward_inputs(self, slots):
+        """What a forward over `slots` reads of the cache, in the order a
+        paged forward takes them: the committed lengths (S,) and the block
+        tables (S, max_blocks_per_slot), int32 host arrays it ships, and
+        every layer's K pool and V pool, the device arrays (not shipped)."""
+        slots = list(slots)
+        k_pools, v_pools, _orders = self._layer_pools()
+        return (self.lengths[slots].astype(np.int32),
+                self.tables_array(slots), k_pools, v_pools)
 
     def tables_array(self, slots=None):
         """Block tables as an (S, max_blocks_per_slot) int32 array for

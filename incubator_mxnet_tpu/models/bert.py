@@ -278,7 +278,7 @@ class BERTForPretrain(HybridBlock):
         # keyword-only: the pre-r4 positional contract (ids, types, mask)
         # keeps working; a mask can never silently land in the positions
         # slot (call sites that pipeline positional data through a trainer
-        # wrap the model — see bench.py's _BertPretrainStep)
+        # wrap the model — see chip_smoke.py's _BertPretrainStep)
         seq, pooled = self.bert(token_ids, token_types, valid_mask)
         if mlm_positions is not None:
             B = token_ids.shape[0]

@@ -22,7 +22,7 @@ VMEM copy.
 
 Reference counterpart: src/operator/fusion/fused_op.cu (the reference
 fuses elementwise chains into generated CUDA; conv fusion is what its
-cuDNN backend provides). measured A/B: bench.py BENCH_MODEL=fused_block.
+cuDNN backend provides). No caller outside the tests (ROADMAP C3).
 """
 
 import functools

@@ -6,7 +6,8 @@ the silicon directly: a Mosaic matmul fed int8 operands with an s32
 accumulator. If the MXU's int8 mode is reachable through this stack it
 should clear the bf16 calibration (~150-166 TF/s on this part);
 if Mosaic also upcasts, the probe confirms the ceiling is the stack,
-not the benchmark. A/B lives in bench.py BENCH_MODEL=int8_matmul.
+not the benchmark. No caller outside the tests and ``serving/quant.py``
+(ROADMAP C3).
 
 Reference counterpart: src/operator/quantization/ (the reference's int8
 wins come from backend int8 kernels, mkldnn/cuDNN).

@@ -117,9 +117,9 @@ class DecodeLoop:
         self._step_fn = step_fn
         self._cache = cache
         self._prefill_fn = prefill_fn
-        self._prefill_chunk = max(1, int(
-            prefill_chunk if prefill_chunk is not None
-            else os.environ.get("MXTPU_GEN_PREFILL_CHUNK", "32") or 32))
+        # the width of `prefill_fn`'s forwards, as its family says it
+        # (``ServedModel.prefill_chunk``): the join estimate counts them
+        self._prefill_chunk = max(1, int(prefill_chunk or 32))
         self._pad = int(pad_token)
         self._cap = int(max_new_tokens_cap if max_new_tokens_cap is not None
                         else os.environ.get("MXTPU_SERVE_MAX_NEW_TOKENS",

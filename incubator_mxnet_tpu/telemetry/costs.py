@@ -12,8 +12,7 @@ gated behind ``MXTPU_COSTS=1`` because capture lowers and compiles a
 second, non-donating executable purely for accounting.  ``observe()``
 is one predicate check when telemetry is off and a dict miss when
 nothing was captured, so it rides inside the existing hot-path
-telemetry blocks.  bench.py uses the same helpers to put an ``mfu``
-field in its JSON line.
+telemetry blocks.
 
 Roofline defaults are TPU v5e bf16: 197 TFLOP/s, 819 GB/s — override
 with ``MXTPU_PEAK_TFLOPS`` / ``MXTPU_PEAK_GBS`` per accelerator.

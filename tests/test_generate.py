@@ -24,6 +24,7 @@ from incubator_mxnet_tpu import nd, serving, telemetry
 from incubator_mxnet_tpu.generate import (GenerateEngine, GPTPagedLM,
                                           PagedKVCache,
                                           export_gpt_for_serving)
+from incubator_mxnet_tpu.generate.engine import prefill_slot, step_slots
 from incubator_mxnet_tpu.generate.paged_kv import KVPoolExhausted
 from incubator_mxnet_tpu.models.gpt import (GPTDecoder, gpt_config,
                                             gpt_logits, gpt_param_shapes)
@@ -189,11 +190,10 @@ def test_paged_kv_eviction_reuse_never_shows_a_stale_tail():
 def _prefill_and_step(lm, cache, prompt, feed):
     """-> (slot, the (len(feed), 1, V) logits of feeding `feed` token by
     token after `prompt` is prefilled)."""
-    eng = GenerateEngine(lm, cache, prefill_chunk=4)
     slot = cache.alloc()
-    eng._prefill(lm, cache, slot, prompt)
-    return slot, np.stack([eng._step(lm, cache, [slot],
-                                     np.asarray([[t]], np.int32))
+    prefill_slot(lm, cache, slot, prompt, 4)
+    return slot, np.stack([step_slots(lm, cache, [slot],
+                                      np.asarray([[t]], np.int32))
                            for t in feed])
 
 
@@ -567,12 +567,11 @@ def test_gpt_full_forward_matches_incremental_paged_decode(target_lm):
     full = np.asarray(gpt_logits(target_lm.params, _TCFG,
                                  jnp.asarray([tokens], jnp.int32)))[0]
     cache = target_lm.make_cache(1, max_len=32)
-    eng = GenerateEngine(target_lm, cache)
     slot = cache.alloc()
     inc = []
     for t in tokens:
-        logits = eng._step(target_lm, cache, [slot],
-                           np.asarray([[t]], np.int32))
+        logits = step_slots(target_lm, cache, [slot],
+                            np.asarray([[t]], np.int32))
         inc.append(logits[0])
     np.testing.assert_allclose(np.asarray(inc), full, atol=1e-4)
     cache.free(slot)
@@ -927,6 +926,217 @@ def test_gpt_family_serves_and_matches_engine_greedy(tmp_path):
         client.close()
     finally:
         srv.stop()
+
+
+def _served_gpt(tmp_path, tiny=None, name="seam", executables=None):
+    """-> the ServedModel of a checkpoint of `tiny` (model, cfg; default: a
+    new ``_tiny_gpt``) under `tmp_path`/`name`, with what `executables`
+    (cfg, model) exports attached."""
+    from incubator_mxnet_tpu.serving import loader as L
+    model, cfg = tiny or _tiny_gpt(prefix=name + "_")
+    ckpt = str(tmp_path / name)
+    export_gpt_for_serving(ckpt, cfg, model)
+    if executables is not None:
+        L.attach_executables(ckpt, executables(cfg, model))
+    return L.load_served_model(ckpt, quantize=False)
+
+
+def _serve_by_hand(prefill, step, cache, prompt, feed):
+    """What a DecodeLoop does for one request on a grid of two slots:
+    -> the (len(feed), V) logits its slot's row got, step by step."""
+    slot = cache.alloc()
+    prefill(slot, np.asarray(prompt, np.int32), cache)
+    tokens = np.zeros(2, np.int32)
+    active = np.arange(2) == slot
+    out = []
+    for t in feed:
+        tokens[slot] = t
+        out.append(np.asarray(step(tokens, cache, active))[slot])
+    return np.stack(out)
+
+
+def test_the_served_step_is_the_engines_step_to_the_bit(tmp_path):
+    """One prompt on one cache geometry through ``step_fn`` / ``prefill_fn``
+    and through the engine's ``prefill_slot`` / ``step_slots`` over the
+    same grid: every logit of every step is equal to the bit, and so are
+    the lengths and the pools the two leave behind."""
+    model, cfg = tiny = _tiny_gpt(prefix="seam_")
+    served = _served_gpt(tmp_path, tiny)
+    lm = GPTPagedLM({k: np.asarray(v.data()._data) for k, v
+                     in model._collect_params_with_prefix().items()}, cfg)
+    prompt, feed = [3, 5, 7, 2, 11, 1], [4, 9, 30, 2]
+    ours = served.make_cache(2, 64)
+    got = _serve_by_hand(served.prefill_fn, served.step_fn, ours, prompt,
+                         feed)
+    theirs = lm.make_cache(2, max_len=64)
+    want = _serve_by_hand(
+        lambda slot, tokens, cache: prefill_slot(
+            lm, cache, slot, tokens, served.prefill_chunk),
+        lambda tokens, cache, active: step_slots(
+            lm, cache, range(2), tokens.reshape(2, 1),
+            active.astype(np.int32)),
+        theirs, prompt, feed)
+    assert got.shape == (4, 37) and np.abs(got).max() > 0
+    np.testing.assert_array_equal(got, want)
+    _same_cache(ours, theirs)
+    assert ours.lengths.tolist() == [len(prompt) + len(feed), 0]
+
+
+def test_a_decode_loop_request_leaves_the_steps_spans(tmp_path):
+    """The served path runs the engine's step, so a DecodeLoop request
+    sent under a parent span leaves what a generate call leaves: kv.gather,
+    lm.dispatch, lm.fetch and kv.commit for the prefill chunk and for each
+    of the three grid steps, counting what crossed."""
+    from incubator_mxnet_tpu.telemetry import tracing
+    served = _served_gpt(tmp_path)
+    cache = served.make_cache(2, 64)
+    loop = DecodeLoop("gpt", served.step_fn, cache,
+                      prefill_fn=served.prefill_fn,
+                      prefill_chunk=served.prefill_chunk).start()
+    tracing.clear_spans()
+    try:
+        with tracing.Span("test.call"):
+            req = loop.submit(DecodeRequest("gpt", [3, 5, 7, 2, 11, 1],
+                                            max_new_tokens=3))
+            assert req.wait(60.0)["tokens"].shape == (3,)
+    finally:
+        loop.stop()
+    recs = tracing.recent_spans()
+    by_name = {name: [r for r in recs if r["name"] == name]
+               for name in ("kv.gather", "lm.dispatch", "lm.fetch",
+                            "kv.commit")}
+    assert [len(v) for v in by_name.values()] == [4, 4, 4, 4]
+    assert all(r["dur_us"] > 0 for v in by_name.values() for r in v)
+    # the chunk's 5 positions, then one row of the grid's two a step
+    assert [r["rows"] for r in by_name["kv.commit"]] == [5, 1, 1, 1]
+    # nothing of the prefill chunk, then the grid's logits (2, 1, 37)
+    assert [r["d2h_bytes"] for r in by_name["lm.fetch"]] \
+        == [0] + [2 * 37 * 4] * 3
+    # tokens, lengths and tables, and no pool: (1, 32), (1,), (1, 4), then
+    # (2, 1), (2,), (2, 4)
+    assert [r["h2d_bytes"] for r in by_name["lm.dispatch"]] \
+        == [128 + 4 + 16] + [8 + 8 + 32] * 3
+
+
+def test_loading_a_gpt_checkpoint_walks_no_array_by_elements(tmp_path,
+                                                            monkeypatch):
+    """A restore hands back NDArrays, and ``jnp.asarray`` of one iterates
+    it row by row, scalar by scalar: minutes for a real embedding (found
+    on the chip at GPT-2-small sizes, ISSUE 31). Load and weight swap
+    unwrap them whole: with iteration forbidden both still work."""
+    from incubator_mxnet_tpu.ndarray.ndarray import NDArray
+    from incubator_mxnet_tpu.serving import loader as L
+    tiny = _tiny_gpt(prefix="walk_")
+    ckpt = str(tmp_path / "walk")
+    export_gpt_for_serving(ckpt, tiny[1], tiny[0])
+
+    def refuse(self):
+        raise AssertionError("an NDArray was walked element by element")
+    monkeypatch.setattr(NDArray, "__iter__", refuse)
+    served = L.load_served_model(ckpt, quantize=False)
+    params, _meta = L.load_generation_params(ckpt, 0)
+    assert any(isinstance(v, NDArray) for v in params.values())
+    served.swap_params(params, 1)
+    got = _serve_by_hand(served.prefill_fn, served.step_fn,
+                         served.make_cache(2, 64), [3, 5, 7], [4])
+    assert got.shape == (1, 37)
+
+
+def _old_export(cfg, model):
+    """``gptdecode/s2`` as the tree before ISSUE 31 exported it: flat
+    inputs, the params a list, 1 + 2L outputs."""
+    from incubator_mxnet_tpu.compilecache import aot
+    from incubator_mxnet_tpu.models.gpt import gpt_forward_paged
+    params = {k: jnp.asarray(v.data()._data)
+              for k, v in model._collect_params_with_prefix().items()}
+    names = sorted(params)
+
+    def pure(input_vals, param_vals):
+        tokens, lengths, tables, k_pool, v_pool = input_vals
+        logits, nk, nv = gpt_forward_paged(
+            dict(zip(names, param_vals)), cfg, tokens, lengths, tables,
+            [k_pool], [v_pool])
+        return [logits] + nk + nv
+    pool = jnp.zeros((8, 16, 2, 8), jnp.float32)
+    ins = [jnp.zeros((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32),
+           jnp.zeros((2, 4), jnp.int32), pool, pool]
+    compiled = jax.jit(pure).lower(ins, [params[n] for n in names]).compile()
+    return {"gptdecode/s2": aot.serialize_compiled(compiled)}
+
+
+def _export_for_a_shorter_cache(cfg, model):
+    """Today's whole grid, for caches of 32 positions (two blocks a
+    slot) where the request's has 64."""
+    from incubator_mxnet_tpu.serving import loader as L
+    import tempfile
+    with tempfile.TemporaryDirectory() as scratch:
+        export_gpt_for_serving(scratch, cfg, model)
+        served = L.load_served_model(scratch, quantize=False)
+        served.make_cache(2, 32)
+        assert not served.extra_warmup(2)["failed"]
+        return served.export_executables()
+
+
+_GRID = ["gptcommit/s2/r1xc32", "gptcommit/s2/r2xc1", "gptdecode/s2",
+         "gptprefill/s2xc32"]
+
+
+@pytest.mark.parametrize("executables,bound,rebuilt", [
+    (_old_export, [], _GRID), (_export_for_a_shorter_cache, _GRID, [])],
+    ids=["another-signature", "another-geometry"])
+def test_a_shipped_program_that_does_not_fit_is_retired_not_raised(
+        tmp_path, executables, bound, rebuilt):
+    """A checkpoint whose executables were exported before ISSUE 31 (the
+    old calling convention: refused at bind, so a later warm-up builds the
+    program anew) or for another cache geometry (bound, refused by its
+    first call and retired: the warm-up reports it failed and the next
+    export ships none): the request is served through jit, with the logits
+    of a checkpoint that shipped nothing."""
+    tiny = _tiny_gpt(prefix="ship_")
+    served = _served_gpt(tmp_path, tiny, "shipped", executables)
+    assert sorted(served.decode_programs) == bound
+    plain = _served_gpt(tmp_path, tiny, "plain")
+    prompt, feed = [3, 5, 7, 2, 11, 1], [4, 9]
+    got = _serve_by_hand(served.prefill_fn, served.step_fn,
+                         served.make_cache(2, 64), prompt, feed)
+    want = _serve_by_hand(plain.prefill_fn, plain.step_fn,
+                          plain.make_cache(2, 64), prompt, feed)
+    np.testing.assert_array_equal(got, want)
+    assert not plain.decode_programs                # nothing shipped: jit
+    assert sorted(served.extra_warmup(2)["built"]) == rebuilt
+    assert sorted(served.export_executables()) == rebuilt
+
+
+def test_the_grid_is_compiled_for_the_pools_make_cache_builds(
+        tmp_path, monkeypatch):
+    """The family's AOT example inputs are a cache's own: with
+    MXTPU_GEN_BLOCK_SIZE=8 when ``make_cache`` ran (and unset when the
+    programs are built, so that a formula over the environment would say
+    16) every pool the decode program and its commit were compiled for
+    has the shape, dtype and device order of the cache's, and a step runs
+    them: none is retired."""
+    from incubator_mxnet_tpu.generate.paged_kv import device_order
+    served = _served_gpt(tmp_path)
+    monkeypatch.setenv("MXTPU_GEN_BLOCK_SIZE", "8")
+    cache = served.make_cache(2, 64)
+    monkeypatch.delenv("MXTPU_GEN_BLOCK_SIZE")
+    assert cache.pool("k0").shape == (2 * 8, 8, 2, 8)
+    assert not served.extra_warmup(2)["failed"]
+    decode = served.decode_programs["gptdecode/s2"].compiled
+    commit = served.decode_programs["gptcommit/s2/r2xc1"].compiled
+    pools = [cache.pool("k0")], [cache.pool("v0")]
+
+    def facts(arrays, formats):
+        return [[(a.shape, a.dtype, tuple(f.layout.major_to_minor))
+                 for a, f in zip(*pair)] for pair in zip(arrays, formats)]
+    want = [[(p.shape, p.dtype, device_order(p)) for p in ps] for ps in pools]
+    (_p, _tokens, _lengths, tables, *kv), _kw = decode.in_avals
+    assert tables.shape == (2, 8)
+    assert facts(kv, decode.input_formats[0][4:]) == want
+    assert facts(commit.in_avals[0][:2], commit.input_formats[0][:2]) == want
+    _serve_by_hand(served.prefill_fn, served.step_fn, cache, [3, 5, 7], [4])
+    assert served.decode_program_for(2).compiled is decode
+    assert cache.programs[(2, 1)] is commit
 
 
 def test_export_gpt_requires_draft_config(tmp_path):

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from benchmarks.reference import sdar_moe as reference
 from incubator_mxnet_tpu.generate import (GenerateEngine, GPTPagedLM,
                                           SDARPagedLM)
+from incubator_mxnet_tpu.generate.engine import (commit_slots, forward_slots,
+                                                 prefill_slot, step_slots)
 from incubator_mxnet_tpu.models.gpt import gpt_config, gpt_param_shapes
 from incubator_mxnet_tpu.models.sdar_moe import sdar_logits
 from incubator_mxnet_tpu.ops.pallas import flash_decode
@@ -271,19 +273,19 @@ def test_prefill_then_a_block_through_the_cache_is_the_full_forward(
     eng = _engine(model)
     slot = eng.cache.alloc()
     whole = prompt_len // 4 * 4
-    eng._prefill(model, eng.cache, slot, prompt[:whole])
+    prefill_slot(model, eng.cache, slot, prompt[:whole], eng.prefill_chunk)
     assert eng.cache.lengths[slot] == whole
     block = (prompt[whole:] + [MASK] * 4)[:4]
     tokens = np.asarray([block], np.int32)
-    logits, nk, nv = eng._forward(model, eng.cache, [slot], tokens)
+    logits, nk, nv = forward_slots(model, eng.cache, [slot], tokens)
     theirs = reference.logits(weights, CFG, np.asarray([prompt[:whole]
                                                         + block], np.int32),
                               at=np.arange(whole, whole + 4)[None])
     # float32 on both sides; the paged path sums past and chunk apart
     np.testing.assert_allclose(logits[0], theirs[0], atol=2e-4)
     # the device's choice is the logits' argmax and its softmax share
-    (x0, confidence), _, _ = eng._forward(model, eng.cache, [slot], tokens,
-                                          model.forward_choice)
+    (x0, confidence), _, _ = forward_slots(model, eng.cache, [slot], tokens,
+                                           model.forward_choice)
     logits = np.array(logits)
     logits[..., MASK] = -np.inf             # a position never takes MASK
     assert x0[0].tolist() == logits[0].argmax(-1).tolist()
@@ -293,11 +295,11 @@ def test_prefill_then_a_block_through_the_cache_is_the_full_forward(
     # a second block after the first is stored: the cache now holds the
     # block's final tokens' K and V
     final = np.where(tokens == MASK, x0, tokens)
-    _none, nk, nv = eng._forward(model, eng.cache, [slot], final,
-                                 model.forward_kv)
-    eng._commit(eng.cache, [slot], nk, nv, 4)
+    _none, nk, nv = forward_slots(model, eng.cache, [slot], final,
+                                  model.forward_kv)
+    commit_slots(eng.cache, [slot], nk, nv, 4)
     nxt = np.full((1, 4), MASK, np.int32)
-    logits2, _, _ = eng._forward(model, eng.cache, [slot], nxt)
+    logits2, _, _ = forward_slots(model, eng.cache, [slot], nxt)
     theirs2 = reference.logits(
         weights, CFG, np.asarray([prompt[:whole] + final[0].tolist()
                                   + [MASK] * 4], np.int32),
@@ -461,12 +463,11 @@ def test_a_gpt_model_still_takes_the_plain_loop_token_for_token():
     assert "moe" not in eng.last_stats
     for prompt, served in zip(prompts, out):
         cache = lm.make_cache(1, max_len=32)
-        hand = GenerateEngine(lm, cache)
         slot = cache.alloc()
-        hand._prefill(lm, cache, slot, prompt[:-1])
+        prefill_slot(lm, cache, slot, prompt[:-1], eng.prefill_chunk)
         token, tokens = prompt[-1], []
         for _ in range(6):
-            token = int(np.argmax(hand._step(
+            token = int(np.argmax(step_slots(
                 lm, cache, [slot], np.asarray([[token]], np.int32))[0]))
             tokens.append(token)
         assert tokens == served

@@ -482,9 +482,9 @@ def main():
     except Exception as e:  # noqa: BLE001 — diagnostics must not crash
         print("thread dump failed:", e)
 
-    section("Environment Variables (MXTPU_*/BENCH_*)")
+    section("Environment Variables (MXTPU_*/MXNET_*)")
     hits = {k: v for k, v in sorted(os.environ.items())
-            if k.startswith(("MXTPU_", "BENCH_", "MXNET_"))}
+            if k.startswith(("MXTPU_", "MXNET_"))}
     for k, v in hits.items():
         print("%-28s = %s" % (k, v))
     if not hits:

@@ -29,9 +29,6 @@ wait and sheds, never as a silently stretched schedule.
 
     python tools/loadstorm.py --serving host:port [--serving host:port]
         --model gpt --duration 20 --rps 30 --seed 7 --sample 0.2
-
-``bench.py`` wires this module in as ``BENCH_MODEL=load_storm`` so the
-goodput and p99 lines gate in bench_diff like every other north-star.
 """
 
 import argparse
@@ -378,15 +375,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--serving", action="append", required=True,
                     help="model-server host:port (repeat per replica)")
-    ap.add_argument("--duration", type=float,
-                    default=float(os.environ.get("BENCH_STORM_SECONDS",
-                                                 "20")))
-    ap.add_argument("--rps", type=float,
-                    default=float(os.environ.get("BENCH_STORM_RPS", "20")))
-    ap.add_argument("--clients", type=int,
-                    default=int(os.environ.get("BENCH_STORM_CLIENTS", "8")))
-    ap.add_argument("--seed", type=int,
-                    default=int(os.environ.get("BENCH_STORM_SEED", "7")))
+    ap.add_argument("--duration", type=float, default=20.0)
+    ap.add_argument("--rps", type=float, default=20.0)
+    ap.add_argument("--clients", type=int, default=8)
+    ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--slo-ms", type=float, default=2000.0)
     ap.add_argument("--spec", help="JSON spec file (overrides the flags)")
     ap.add_argument("--gpt-model", default="gpt",
