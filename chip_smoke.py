@@ -15,10 +15,10 @@ Phase `train` — the BERT-base pretrain step (12 layers, 768 units, 12
 heads, FFN 3072, vocab 30522, tied decoder, gather-first MLM head + NSP; AdamW, bf16
 compute, bf16-stored moments) through ``parallel.ShardedTrainer`` on a
 one-device mesh, at B=64,T=128 (38 kernels: 26 fused LayerNorm + 12 fused
-softmax) and at B=16,T=512 (62: the flash-attention kernels, forward, dq
-and dkv, in the softmax's place; the step-0 loss is compared with the
-dense-attention path on the same parameters). The optimizer is applied
-leaf by leaf by XLA: no packed launch.
+softmax) and at B=16,T=512 (50: the one-tile flash-attention launches,
+one forward and one backward a layer, in the softmax's place; the step-0
+loss is compared with the dense-attention path on the same parameters).
+The optimizer is applied leaf by leaf by XLA: no packed launch.
 
 Phase `generate` — GPT-2-small widths through ``GenerateEngine.generate``
 over ``GPTPagedLM``, in-process; each prompt's first generated token is
@@ -189,7 +189,7 @@ def phase_train(shapes=((64, 128, 6), (16, 512, 8)), num_layers=12):
         n_kernels = kernel_calls(tr, data, label)
         # fused LayerNorm twice a layer + embedding + MLM head, and a layer's
         # attention: one fused softmax, from T=512 flash attention fwd + bwd
-        # (38 kernels at T=128, 62 at T=512)
+        # (38 kernels at T=128, 50 at T=512)
         check(n_kernels >= 3 * num_layers + 2,
               "%s: only %d tpu_custom_call in the step program — a kernel "
               "gave way to its reference" % (what, n_kernels))

@@ -34,20 +34,24 @@ class MultiHeadAttention(HybridBlock):
     def hybrid_forward(self, F, x, mask=None):
         B = x.shape[0]
         T = x.shape[1]
-        H = self._num_heads
-        D = self._units // H
-        q = F.reshape(self.query(x), shape=(B, T, H, D))
-        k = F.reshape(self.key(x), shape=(B, T, H, D))
-        v = F.reshape(self.value(x), shape=(B, T, H, D))
-        q = F.transpose(q, axes=(0, 2, 1, 3))   # (B,H,T,D)
-        k = F.transpose(k, axes=(0, 2, 1, 3))
-        v = F.transpose(v, axes=(0, 2, 1, 3))
-        out = self._attend(F, q, k, v, mask, B, T, D)
-        out = F.transpose(out, axes=(0, 2, 1, 3))
-        out = F.reshape(out, shape=(B, T, self._units))
+        D = self._units // self._num_heads
+        out = self._attend(F, self.query(x), self.key(x), self.value(x),
+                           mask, B, T, D)
         return self.proj(out)
 
+    def _split_heads(self, F, x, B, T, D):
+        x = F.reshape(x, shape=(B, T, self._num_heads, D))
+        return F.transpose(x, axes=(0, 2, 1, 3))            # (B,H,T,D)
+
+    def _merge_heads(self, F, x, B, T):
+        x = F.transpose(x, axes=(0, 2, 1, 3))
+        return F.reshape(x, shape=(B, T, self._units))
+
     def _attend(self, F, q, k, v, mask, B, T, D):
+        """q, k, v: (B, T, H*D) as the projections leave them; so is the
+        result. The path is chosen BEFORE any reshape: only the ring and
+        einsum paths (and the flash kernel's tiled form, inside
+        ``flash_attention_bthd``) carry heads outermost."""
         # Sequence-parallel fast path (VERDICT r4 #3): when tracing under a
         # ShardedTrainer whose mesh carries sp>1, attention runs as RING
         # attention over the sp axis — flash per KV shard with online-
@@ -62,22 +66,24 @@ class MultiHeadAttention(HybridBlock):
                 and mask is None and self.dropout._rate == 0
                 and _os.environ.get("MXTPU_DISABLE_RING", "0") != "1"
                 and T % dict(mesh.shape)["sp"] == 0):
-            return self._ring_attend(q, k, v, mesh, T, D)
-        # Pallas flash-attention fast path (O(T) memory on the MXU) when on
-        # TPU inside a trace with no attention-dropout; einsum otherwise.
-        # Valid-length masks ride the kernel's kv-mask path (r2).
-        from ..ops.pallas import flash_attention, flash_attention_available
+            q, k, v = (self._split_heads(F, a, B, T, D) for a in (q, k, v))
+            return self._merge_heads(
+                F, self._ring_attend(q, k, v, mesh, T, D), B, T)
+        # Pallas flash-attention fast path when on TPU inside a trace with
+        # no attention-dropout; einsum otherwise. Valid-length masks ride
+        # the kernel's kv-mask path (r2). In the T=512 step (B=32, 12 heads
+        # of 64) the one-tile launches take 0.39 ms forward and 0.71 ms
+        # backward a layer, where the tiled ones took 0.92 and 1.25 with
+        # 1.0 ms of transposes and row statistics around them (PERF.md
+        # section 5, cell 3). Under 512 the einsum path with the fused
+        # softmax runs (section 5, cell 1); where the two cross is not
+        # measured, so the threshold stays env-tunable (MXTPU_FLASH_MIN_T,
+        # default 512; ROADMAP A3 settles it); the T % 128 tiling contract
+        # is NOT tunable. MXTPU_DISABLE_FLASH=1 forces the einsum path
+        # (A/B benchmarking).
+        from ..ops.pallas import (flash_attention_bthd,
+                                  flash_attention_available)
         in_trace = ctx is not None
-        # Crossover re-measured on v5e after the r2 kernel tuning (bf16 MXU
-        # feeds + 1024-blocks): flash fwd+bwd beats XLA dense attention from
-        # T=2048 up (6.3 vs 20.5 ms at 2048; 9.1 vs 252 ms at 8192, bf16
-        # B=1 H=8 D=64). Below that the O(T) memory saving still lets the
-        # step avoid the T^2 scores materialization, and the MFU round's
-        # kernel keeps parity from T=512 up — so the threshold is
-        # env-tunable (MXTPU_FLASH_MIN_T, default 512) rather than pinned
-        # at the pure-latency crossover; the T % 128 tiling contract is
-        # NOT tunable. MXTPU_DISABLE_FLASH=1 forces the einsum path (A/B
-        # benchmarking).
         try:
             min_t = int(_os.environ.get("MXTPU_FLASH_MIN_T", "512"))
         except ValueError:
@@ -86,15 +92,17 @@ class MultiHeadAttention(HybridBlock):
                 and _os.environ.get("MXTPU_DISABLE_FLASH", "0") != "1"
                 and T >= min_t and T % 128 == 0
                 and flash_attention_available() and trace_on_one_device()):
-            return flash_attention(q, k, v, scale=1.0 / math.sqrt(D),
-                                   kv_mask=mask)
+            return flash_attention_bthd(q, k, v, self._num_heads,
+                                        scale=1.0 / math.sqrt(D),
+                                        kv_mask=mask)
+        q, k, v = (self._split_heads(F, a, B, T, D) for a in (q, k, v))
         scores = F.batch_dot(q, k, transpose_b=True) * (1.0 / math.sqrt(D))
         if mask is not None:
             neg = (1.0 - F.reshape(mask, shape=(B, 1, 1, T))) * -1e30
             scores = scores + neg
         attn = F.softmax(scores, axis=-1)
         attn = self.dropout(attn)
-        return F.batch_dot(attn, v)             # (B,H,T,D)
+        return self._merge_heads(F, F.batch_dot(attn, v), B, T)
 
     def _ring_attend(self, q, k, v, mesh, T, D):
         """shard_map(axis_names={'sp'}) ring attention: sp is bound MANUAL

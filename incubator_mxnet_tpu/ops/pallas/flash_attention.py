@@ -15,12 +15,21 @@ output has its own accumulation order — dQ over KV blocks, dK/dV over Q
 blocks — each streaming one tile pair at a time (O(T) memory, no T x T
 materialization). delta = rowsum(dO * O) is a cheap fused jnp elementwise.
 
+A sequence that is ONE tile (``flash_attention_bthd``, T <= _TILE_MAX_T):
+the same algorithm with a tile count of 1, on the projections' own
+(B, T, H*D) arrays. One program instance a batch row and 128-lane group of
+heads (grid (B, H*D/128), blocks (1, T, 128)); the forward has no running
+statistics (one max, one exp, one sum), the backward is ONE launch that
+writes dQ, dK and dV from scores computed once. Launches
+``flash_attention_tile_fwd`` / ``flash_attention_tile_bwd``.
+
 Falls back transparently on CPU (no Mosaic) — callers check
 ``flash_attention_available()``; tests run the same kernels with
 ``interpret=True``.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -348,8 +357,9 @@ import os as _os
 
 
 def _default_blocks(T):
-    """Block sizes: tunable via MXTPU_FLASH_BLOCK_Q/K; defaults from the
-    on-chip sweep in BENCHMARKS.md (v5e)."""
+    """Block sizes of the TILED kernels: MXTPU_FLASH_BLOCK_Q/K, else the
+    whole sequence up to 1024 (the one-tile path below reads neither; what
+    the tiled launches cost at T=512 is in PERF.md section 5, cell 3)."""
     bq = int(_os.environ.get("MXTPU_FLASH_BLOCK_Q", "0")) or min(T, 1024)
     bk = int(_os.environ.get("MXTPU_FLASH_BLOCK_K", "0")) or min(T, 1024)
     while T % bq:
@@ -446,3 +456,234 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_mask=None,
                       int(bq), int(bk), bool(interpret),
                       kv_bias is not None)
     return out.reshape(B, H, T, D)
+
+
+# ----------------------------------------------------------------------
+# one tile: the whole sequence of a batch row in one program instance
+# ----------------------------------------------------------------------
+# T up to which the (T, T) float32 temporaries of a head fit the scoped
+# VMEM: 1024 compiles for v5e with a mask, in the backward, at either head
+# width (tests/test_tpu_compile.py); 2048 is 16 MB a temporary. Measured on
+# the chip at 128 to 1024 against the tiled launches (PERF.md section 6,
+# PR 32).
+_TILE_MAX_T = 1024
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _one_tile(T, num_heads, d, causal, kv_bias):
+    """What the call itself says: the sequence is one tile, the heads fill
+    whole 128-lane groups, and nothing needs a position or a bias
+    gradient. (num_heads <= 128: a head's row statistic has a lane.)"""
+    return (not causal and kv_bias is None and T % 128 == 0
+            and T <= _TILE_MAX_T and d in (64, 128)
+            and (num_heads * d) % 128 == 0 and num_heads <= 128)
+
+
+def _head_lanes(d):
+    """Lane masks (1, 128) of the heads of one 128-lane group; a head is
+    taken by zeroing the other's lanes of ONE operand and contracting over
+    all 128 (what a 64-deep product costs on a 128-deep MXU anyway), and
+    its 64 output lanes are selected from an N=128 product."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    if d == 128:
+        return lane, [None]
+    return lane, [(lane >= h * d) & (lane < (h + 1) * d)
+                  for h in range(128 // d)]
+
+
+def _only(sel, x):
+    return x if sel is None else jnp.where(sel, x, jnp.zeros_like(x))
+
+
+def _folds(scale):
+    """A power of two scales q exactly (1/8 at d=64), so it is applied to
+    q's (T, 128) elements; any other scale stays on the float32 scores."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _tile_fwd_kernel(q_ref, k_ref, v_ref, *rest, d, scale, has_bias):
+    bias_ref = rest[0] if has_bias else None
+    o_ref, lse_ref = rest[-2:]
+    j = pl.program_id(1)
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]              # (T, 128)
+    fold = _folds(scale)
+    if fold:
+        q = q * jnp.asarray(scale, q.dtype)
+    lane, heads = _head_lanes(d)
+    o = lse_all = None
+    for h, sel in enumerate(heads):
+        s = _dot(_only(sel, q), k, _NT)                 # (T, T) f32
+        if not fold:
+            s = s * scale
+        if has_bias:
+            s = s + bias_ref[0]                         # (1, T) over rows
+        # every statistic stays a (T, 1) column: no column becomes a row
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)          # >= 1
+        oh = _dot(p.astype(v.dtype), v, _NN) / l        # (T, 128)
+        lse = m + jnp.log(l)
+        if has_bias:
+            # all keys masked: exact zeros, and lse = 0 so that the
+            # backward's exp(s - lse) underflows to 0 (as the tiled kernel)
+            dead = m <= _NEG_INF * 0.5
+            oh = jnp.where(dead, 0.0, oh)
+            lse = jnp.where(dead, 0.0, lse)
+        o = oh if o is None else jnp.where(sel, oh, o)
+        # head g's lse lives in lane g of one (T, 128) block a batch row
+        mine = jnp.where(lane == j * len(heads) + h, lse, 0.0)
+        lse_all = mine if lse_all is None else lse_all + mine
+    o_ref[0] = o.astype(o_ref.dtype)
+
+    # the block is resident over the head-group axis (its index is b alone)
+    @pl.when(j == 0)
+    def _first():
+        lse_ref[0] = lse_all
+
+    @pl.when(j > 0)
+    def _more():
+        lse_ref[0] += lse_all
+
+
+def _tile_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest, d,
+                     scale, has_bias):
+    bias_ref = rest[0] if has_bias else None
+    dq_ref, dk_ref, dv_ref = rest[-3:]
+    j = pl.program_id(1)
+    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+    fold = _folds(scale)
+    if fold:
+        q = q * jnp.asarray(scale, q.dtype)
+    lane, heads = _head_lanes(d)
+    lse_all = lse_ref[0]
+    do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    dq = dk = dv = None
+    for h, sel in enumerate(heads):
+        doh = _only(sel, do)
+        delta = jnp.sum(_only(sel, do_o), axis=-1, keepdims=True)
+        lse = jnp.sum(jnp.where(lane == j * len(heads) + h, lse_all, 0.0),
+                      axis=-1, keepdims=True)
+        s = _dot(_only(sel, q), k, _NT)
+        if not fold:
+            s = s * scale
+        if has_bias:
+            s = s + bias_ref[0]
+        p = jnp.exp(s - lse)
+        ds = p * (_dot(doh, v, _NT) - delta)            # dL/ds, f32
+        if not fold:
+            ds = ds * scale
+        p = p.astype(v.dtype)
+        ds = ds.astype(v.dtype)
+        dqh = _dot(ds, k, _NN)
+        if fold:
+            dqh = dqh * scale
+        dkh = _dot(ds, q, _TN)          # q carries the folded scale
+        dvh = _dot(p, do, _TN)
+        if dq is None:
+            dq, dk, dv = dqh, dkh, dvh
+        else:
+            dq = jnp.where(sel, dqh, dq)
+            dk = jnp.where(sel, dkh, dk)
+            dv = jnp.where(sel, dvh, dv)
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _tile_call(kernel, name, per_head, per_row, bias, num_heads, scale,
+               out_shape, interpret):
+    """One launch over grid (B, H*D/128). `per_head`: (B, T, H*D) operands,
+    q first, in (1, T, 128) blocks a group of heads; `per_row`: the packed
+    lse (B, T, 128), one block a batch row, as is the key bias (B, 1, T).
+    Results take the same two forms, told by their width (where H*D is
+    128 the two are one)."""
+    B, T, HD = per_head[0].shape
+    heads = pl.BlockSpec((1, T, 128), lambda b, j: (b, 0, j))
+    row = pl.BlockSpec((1, T, 128), lambda b, j: (b, 0, 0))
+    args = per_head + per_row
+    in_specs = [heads] * len(per_head) + [row] * len(per_row)
+    if bias is not None:
+        args.append(bias)
+        in_specs.append(pl.BlockSpec((1, 1, T), lambda b, j: (b, 0, 0)))
+    return pl.pallas_call(
+        functools.partial(kernel, d=HD // num_heads, scale=scale,
+                          has_bias=bias is not None),
+        grid=(B, HD // 128),
+        in_specs=in_specs,
+        out_specs=[heads if o.shape[-1] == HD else row for o in out_shape],
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(*args)
+
+
+def _tile_fwd(q, k, v, bias, num_heads, scale, interpret):
+    B, T, _ = q.shape
+    return _tile_call(
+        _tile_fwd_kernel, "flash_attention_tile_fwd", [q, k, v], [], bias,
+        num_heads, scale,
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((B, T, 128), jnp.float32)], interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _tile_core(q, k, v, bias, num_heads, scale, interpret):
+    return _tile_fwd(q, k, v, bias, num_heads, scale, interpret)[0]
+
+
+def _tile_core_fwd(q, k, v, bias, num_heads, scale, interpret):
+    out, lse = _tile_fwd(q, k, v, bias, num_heads, scale, interpret)
+    return out, (q, k, v, bias, out, lse)
+
+
+def _tile_core_bwd(num_heads, scale, interpret, res, g):
+    q, k, v, bias, out, lse = res
+    dq, dk, dv = _tile_call(
+        _tile_bwd_kernel, "flash_attention_tile_bwd",
+        [q, k, v, g, out], [lse], bias, num_heads, scale,
+        [jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3, interpret)
+    # the bias is a mask here (a learned kv_bias stays on the tiled path)
+    return dq, dk, dv, None if bias is None else jnp.zeros_like(bias)
+
+
+_tile_core.defvjp(_tile_core_fwd, _tile_core_bwd)
+
+
+def flash_attention_bthd(q, k, v, num_heads, scale=None, causal=False,
+                         kv_mask=None, kv_bias=None, interpret=False):
+    """q/k/v: (B, T, H*D) as three dense layers leave them. Returns
+    (B, T, H*D), ready for the output projection.
+
+    A sequence that is one tile (see ``_one_tile``: read off this call's
+    shapes and arguments, nothing else) runs the one-tile launches on these
+    arrays as they are. Anything else — longer, causal, a learned
+    ``kv_bias`` — is carried into (B, H, T, D) and runs ``flash_attention``
+    with its contract (seq_len % 128 == 0, ...)."""
+    B, T, HD = q.shape
+    D = HD // num_heads
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
+    if _one_tile(T, num_heads, D, causal, kv_bias):
+        bias = None
+        if kv_mask is not None:
+            live = jnp.asarray(kv_mask).reshape(B, 1, T) != 0
+            bias = jnp.where(live, 0.0, _NEG_INF).astype(jnp.float32)
+        return _tile_core(q, k, v, bias, int(num_heads), float(scale),
+                          bool(interpret))
+
+    def split(x):
+        return x.reshape(B, T, num_heads, D).transpose(0, 2, 1, 3)
+
+    out = flash_attention(split(q), split(k), split(v), scale=scale,
+                          causal=causal, kv_mask=kv_mask, kv_bias=kv_bias,
+                          interpret=interpret)
+    return out.transpose(0, 2, 1, 3).reshape(B, T, HD)

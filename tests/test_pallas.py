@@ -336,6 +336,129 @@ def test_flash_parity_at_default_min_t():
         assert rel < 1e-4, rel
 
 
+# ---------------------------------------------------------------------------
+# flash attention for a sequence that is one tile (interpret mode on CPU)
+# ---------------------------------------------------------------------------
+
+def _dense_bthd(q, k, v, H, mask):
+    """Dense float32 attention on (B, T, H*D); rows whose keys are all
+    masked give exact zeros."""
+    B, T, HD = q.shape
+    D = HD // H
+
+    def split(x):
+        return x.astype(jnp.float32).reshape(B, T, H, D).transpose(0, 2, 1, 3)
+
+    s = jnp.einsum("bhqd,bhkd->bhqk", split(q), split(k)) / np.sqrt(D)
+    if mask is not None:
+        s = s + jnp.where(mask != 0, 0.0, -1e30)[:, None, None, :]
+    p = jax.nn.softmax(s, axis=-1)
+    if mask is not None:
+        p = jnp.where(jnp.max(s, -1, keepdims=True) <= -5e29, 0.0, p)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, split(v))
+    return out.transpose(0, 2, 1, 3).reshape(B, T, HD)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kv_mask"])
+@pytest.mark.parametrize("H,D", [(2, 64), (12, 64), (2, 128)])
+@pytest.mark.parametrize("T", [128, 256, 512, 1024])
+def test_flash_one_tile_matches_dense(T, H, D, masked):
+    """The one-tile launches against dense float32 attention: forward and
+    all three gradients, with a valid-length mask, and with a row whose
+    keys are ALL masked (exact zeros, zero gradients)."""
+    from incubator_mxnet_tpu.ops.pallas import flash_attention_bthd
+    from incubator_mxnet_tpu.ops.pallas.flash_attention import _one_tile
+    B = 3 if masked else 1
+    assert _one_tile(T, H, D, False, None)
+    rng = np.random.RandomState(T + H + D)
+    q, k, v, g = (jnp.asarray(rng.randn(B, T, H * D).astype(np.float32))
+                  .astype(jnp.bfloat16) for _ in range(4))
+    mask = None
+    if masked:
+        lens = np.array([T // 2, 0, T])          # row 1: no live key
+        mask = jnp.asarray((np.arange(T)[None] < lens[:, None])
+                           .astype(np.int32))
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention_bthd(
+        q, k, v, H, kv_mask=mask, interpret=True), q, k, v)
+    want, want_vjp = jax.vjp(lambda q, k, v: _dense_bthd(q, k, v, H, mask),
+                             q, k, v)
+    assert out.shape == (B, T, H * D) and out.dtype == jnp.bfloat16
+    # bfloat16 results of float32 mathematics: half an ulp of the largest
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), atol=8e-3)
+    grads, want_grads = vjp(g), want_vjp(g.astype(jnp.float32))
+    for got, ref in zip(grads, want_grads):
+        ref = np.asarray(ref, np.float32)
+        np.testing.assert_allclose(np.asarray(got, np.float32), ref,
+                                   atol=2e-2 * max(1.0, np.abs(ref).max()))
+    if masked:
+        np.testing.assert_array_equal(np.asarray(out[1], np.float32), 0.0)
+        for got in grads:
+            np.testing.assert_array_equal(np.asarray(got[1], np.float32),
+                                          0.0)
+        # no attention mass on padded keys: dK and dV vanish there
+        for got in grads[1:]:
+            np.testing.assert_array_equal(
+                np.asarray(got[0, T // 2:], np.float32), 0.0)
+
+
+def _launches(fn, *args):
+    """The Pallas launches of fn's forward and backward, by name, as the
+    program lowered for the TPU holds them (nothing compiles or runs)."""
+    import re
+    loss = lambda *a: fn(*a).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return sorted(re.findall(r"kernel_name = \"(\w+)\"", text))
+
+
+def test_flash_one_tile_is_chosen_from_the_call_alone(monkeypatch):
+    """No knob: the shape and arguments of the call pick the launches, and
+    the block-size variables of the tiled kernels mean nothing to the
+    one-tile path."""
+    from incubator_mxnet_tpu.ops.pallas import flash_attention_bthd
+    monkeypatch.setenv("MXTPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("MXTPU_FLASH_BLOCK_K", "128")
+    tile = ["flash_attention_tile_bwd", "flash_attention_tile_fwd"]
+    tiled = ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+             "flash_attention_fwd"]
+
+    def x(T):
+        return jax.ShapeDtypeStruct((2, T, 768), jnp.bfloat16)
+
+    plain = lambda q, k, v: flash_attention_bthd(q, k, v, 12)
+    assert _launches(plain, x(512), x(512), x(512)) == tile
+    mask = jnp.ones((2, 512), jnp.int32)
+    assert _launches(lambda q, k, v: flash_attention_bthd(
+        q, k, v, 12, kv_mask=mask), x(512), x(512), x(512)) == tile
+    assert _launches(plain, x(2048), x(2048), x(2048)) == tiled
+    assert _launches(lambda q, k, v: flash_attention_bthd(
+        q, k, v, 12, causal=True), x(512), x(512), x(512)) == tiled
+    bias = jnp.zeros((2, 512), jnp.float32)
+    assert _launches(lambda q, k, v: flash_attention_bthd(
+        q, k, v, 12, kv_bias=bias), x(512), x(512), x(512)) == tiled
+    # heads that do not fill 128-lane groups: 6 heads of 32
+    assert _launches(lambda q, k, v: flash_attention_bthd(q, k, v, 6),
+                     jax.ShapeDtypeStruct((2, 512, 192), jnp.bfloat16),
+                     jax.ShapeDtypeStruct((2, 512, 192), jnp.bfloat16),
+                     jax.ShapeDtypeStruct((2, 512, 192), jnp.bfloat16)
+                     ) == tiled
+
+
+def test_flash_bthd_tiled_fallback_matches_dense():
+    """Beyond one tile the (B, T, H*D) entry carries heads outermost and
+    runs the tiled kernels: same results as the dense reference."""
+    from incubator_mxnet_tpu.ops.pallas import flash_attention_bthd
+    rng = np.random.RandomState(3)
+    B, T, H, D = 1, 256, 2, 32           # D=32: not a one-tile shape
+    q, k, v = (jnp.asarray(rng.randn(B, T, H * D).astype(np.float32))
+               for _ in range(3))
+    out = flash_attention_bthd(q, k, v, H, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_dense_bthd(q, k, v, H, None)),
+                               rtol=2e-5, atol=2e-5)
+
+
 _GATE_N = [0]
 
 
@@ -350,7 +473,7 @@ def _flash_gate_fired(T, monkeypatch, min_t=None):
 
     called = []
 
-    def _sentinel(q, k, v, scale=None, kv_mask=None, **kw):
+    def _sentinel(q, k, v, num_heads, scale=None, kv_mask=None, **kw):
         called.append(T)
         return q
 
@@ -358,14 +481,16 @@ def _flash_gate_fired(T, monkeypatch, min_t=None):
     # module-attr patching reaches the gate without a TPU attached
     monkeypatch.setattr(pallas_mod, "flash_attention_available",
                         lambda: True)
-    monkeypatch.setattr(pallas_mod, "flash_attention", _sentinel)
+    monkeypatch.setattr(pallas_mod, "flash_attention_bthd", _sentinel)
     if min_t is None:
         monkeypatch.delenv("MXTPU_FLASH_MIN_T", raising=False)
     else:
         monkeypatch.setenv("MXTPU_FLASH_MIN_T", min_t)
     B, H, D = 1, 1, 8
+    # (B, T, H*D), as the projections leave it: the gate sits before any
+    # reshape into heads
     q = jnp.asarray(np.random.RandomState(0)
-                    .randn(B, H, T, D).astype(np.float32))
+                    .randn(B, T, H * D).astype(np.float32))
     mha = MultiHeadAttention(H * D, H, prefix="flashgate%d_" % _GATE_N[0])
     _GATE_N[0] += 1
     prev = getattr(_trace_state, "ctx", None)
@@ -374,7 +499,7 @@ def _flash_gate_fired(T, monkeypatch, min_t=None):
         out = mha._attend(_trace_state.ctx.F, q, q, q, None, B, T, D)
     finally:
         _trace_state.ctx = prev
-    assert out.shape == (B, H, T, D)
+    assert out.shape == (B, T, H * D)
     return bool(called)
 
 
