@@ -16,7 +16,7 @@ Importing this package registers the serving family.
 """
 
 from .paged_kv import PagedKVCache
-from .engine import GenerateEngine, GPTPagedLM, SDARPagedLM
+from .engine import GenerateEngine, GPTPagedLM, MLAPagedLM, SDARPagedLM
 from . import family  # noqa: F401  (registers the gpt_decoder family)
 from .family import export_gpt_for_serving
 
@@ -25,5 +25,6 @@ __all__ = [
     "GenerateEngine",
     "GPTPagedLM",
     "SDARPagedLM",
+    "MLAPagedLM",
     "export_gpt_for_serving",
 ]

@@ -52,11 +52,12 @@ import time
 import jax
 import numpy as np
 
+from ..ops.pallas.paged_latent import cache_row_width, latent_path
 from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
 from .paged_kv import PagedKVCache, _env_int
 
-__all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM"]
+__all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM", "MLAPagedLM"]
 
 
 def default_prefill_chunk():
@@ -78,15 +79,18 @@ def _host_bytes(args):
     return total
 
 
-def _dispatch(fn, params, args, programs=None):
+def _dispatch(fn, params, args, programs=None, **attrs):
     """The call of an adapter's forward under its ``lm.dispatch`` span; a
-    real span also counts the host bytes the call ships. `programs`: the
-    adapter's (S, C) -> shipped executable of `fn`; one is preferred to
-    the jitted `fn`, and retired when it refuses its arguments (compiled
-    for other pools or weights, or an export of another signature)."""
+    real span also counts the host bytes the call ships and carries
+    `attrs`. `programs`: the adapter's (S, C) -> shipped executable of
+    `fn`; one is preferred to the jitted `fn`, and retired when it
+    refuses its arguments (compiled for other pools or weights, or an
+    export of another signature)."""
     sp = _tr.span("lm.dispatch")
     if sp is not _tr.NULL_SPAN:     # counted only for a real span
         sp.set_attr("h2d_bytes", _host_bytes(args))
+        for key, value in attrs.items():
+            sp.set_attr(key, value)
     with sp:
         program = programs.get(args[0].shape) if programs else None
         if program is not None:
@@ -108,41 +112,45 @@ def _fetch(arrays):
     return out
 
 
-def forward_slots(adapter, cache, slots, tokens, call=None, routing=None):
+def forward_slots(adapter, cache, slots, tokens, call=None, note=None):
     """One adapter forward for `slots` (list) feeding `tokens` (S, C);
-    returns (logits, new_k, new_v) WITHOUT committing. `call`: another
-    forward of the adapter's with the same arguments (the block loop's
-    ``forward_choice`` / ``forward_kv``). `routing`: called with the
-    forward's expert loads (the engine's tally of an expert layer)."""
+    returns ``(logits, *new)``, `new` what the chunk adds to each cache
+    entry (new_k, new_v), WITHOUT committing. `call`: another forward of
+    the adapter's with the same arguments (the block loop's
+    ``forward_choice`` / ``forward_kv``). `note`: called with the adapter
+    after the forward (the engine's tallies of what it last did: an
+    expert layer's loads, a latent cache's attention path)."""
     with _tr.span("kv.gather"):
         inputs = cache.forward_inputs(slots)
     out = (call or adapter.forward)(tokens, *inputs)
-    if routing is not None:
-        routing(adapter.last_expert_loads)
+    if note is not None:
+        note(adapter)
     return out
 
 
-def commit_slots(cache, slots, new_k, new_v, count):
-    """Store the first `count` (one number, or one a row) chunk positions
-    of every row (row r belongs to ``slots[r]``) in the cache; the span
-    counts the pool rows written an entry."""
+def commit_slots(cache, slots, *new_and_count):
+    """``commit_slots(cache, slots, *new, count)``: store the first
+    `count` (one number, or one a row) chunk positions of every row (row
+    r belongs to ``slots[r]``) of `new` (a forward's additions, entry by
+    entry: new_k, new_v) in the cache; the span counts the pool rows
+    written an entry."""
+    count = new_and_count[-1]
     rows = (len(slots) * count if isinstance(count, int)
             else int(np.sum(count)))
     with _tr.span("kv.commit", rows=rows):
-        cache.commit(slots, new_k, new_v, count)
+        cache.commit(slots, *new_and_count)
 
 
-def step_slots(adapter, cache, slots, tokens, count=1, routing=None):
-    """Feed one token per slot ((S, 1)); commit K/V (of the rows whose
-    `count` is 1: a serving grid's active ones); return the (S, V)
-    next-token logits."""
-    logits, nk, nv = forward_slots(adapter, cache, slots, tokens,
-                                   routing=routing)
-    commit_slots(cache, slots, nk, nv, count)
+def step_slots(adapter, cache, slots, tokens, count=1, note=None):
+    """Feed one token per slot ((S, 1)); commit what it adds to the cache
+    (of the rows whose `count` is 1: a serving grid's active ones);
+    return the (S, V) next-token logits."""
+    logits, *new = forward_slots(adapter, cache, slots, tokens, note=note)
+    commit_slots(cache, slots, *new, count)
     return logits[:, -1]
 
 
-def prefill_slot(adapter, cache, slot, tokens_1d, chunk, routing=None):
+def prefill_slot(adapter, cache, slot, tokens_1d, chunk, note=None):
     """Chunked prompt ingestion: commit K/V for every prompt token in
     fixed `chunk`-wide forwards (last chunk padded; pad positions sit
     AFTER the valid ones, so causality keeps them out of every valid
@@ -155,18 +163,20 @@ def prefill_slot(adapter, cache, slot, tokens_1d, chunk, routing=None):
         piece = tokens_1d[start:start + chunk]
         padded = np.zeros((1, chunk), np.int32)
         padded[0, :len(piece)] = piece
-        _logits, nk, nv = forward_slots(adapter, cache, [slot], padded,
-                                        call, routing)
-        commit_slots(cache, [slot], nk, nv, len(piece))
+        _logits, *new = forward_slots(adapter, cache, [slot], padded, call,
+                                      note)
+        commit_slots(cache, [slot], *new, len(piece))
 
 
 class _PagedLM:
     """What the adapters share: the cache of their layers. An adapter
-    sets ``config``, ``num_layers`` and ``kv_entry``, the (shape, dtype)
-    of a position in every K and V pool."""
+    sets ``config``, ``num_layers`` and ``kv_entries``: what a layer
+    caches, entry name -> the (shape, dtype) of a position (``k`` and
+    ``v`` of one per-head shape; one latent row ``c``), in the order its
+    forward takes the pools and returns the chunk's additions."""
 
     def cache_spec(self):
-        return PagedKVCache.layer_spec(self.num_layers, *self.kv_entry)
+        return PagedKVCache.layer_spec(self.num_layers, self.kv_entries)
 
     def make_cache(self, slots, max_len=None, **kw):
         return PagedKVCache(slots, self.cache_spec(),
@@ -203,7 +213,8 @@ class GPTPagedLM(_PagedLM):
         self.params = {n: jnp.asarray(v) for n, v in params.items()}
         self.num_layers = self.config["num_layers"]
         H = self.config["num_heads"]
-        self.kv_entry = ((H, self.config["units"] // H), jnp.float32)
+        self.kv_entries = dict.fromkeys(
+            "kv", ((H, self.config["units"] // H), jnp.float32))
         self.programs = {}
 
         def pure(params, tokens, lengths, tables, kps, vps):
@@ -268,8 +279,9 @@ class SDARPagedLM(_PagedLM):
         self.params = {n: jnp.asarray(v, self.dtype)
                        for n, v in params.items()}
         self.num_layers = self.config["num_layers"]
-        self.kv_entry = ((self.config["num_kv_heads"],
-                          self.config["head_dim"]), self.dtype)
+        self.kv_entries = dict.fromkeys(
+            "kv", ((self.config["num_kv_heads"], self.config["head_dim"]),
+                   self.dtype))
         self.block_length = int(self.config["block_length"])
         self.mask_id = int(self.config["mask_id"])
         self.last_expert_loads = None
@@ -310,6 +322,82 @@ class SDARPagedLM(_PagedLM):
         _read, (nk, nv) = self._call("none", tokens, lengths, tables,
                                      k_pools, v_pools)
         return None, nk, nv
+
+
+class MLAPagedLM(_PagedLM):
+    """Shape-cached jit adapter over ``mla_forward_paged``: the
+    latent-attention decoder of ``models/mla_moe.py``, served in `dtype`
+    (bfloat16: weights, activations and the cache).
+
+    Its cache holds ONE entry a layer, ``c``: a row of ``kv_rank +
+    rope_dim`` values a position (the normalised latent and the rotated
+    key part; zeros up to whole lanes, ``paged_latent.cache_row_width``:
+    576 values in a row of 640), shared by all heads, and no per-head K or
+    V. It declares no ``block_length``: the engine decodes it a token a
+    step.
+
+    - ``forward`` — ``(logits (S, C, V), new_rows)``, the logits a host
+      array;
+    - ``forward_kv`` — prefill: ``(None, new_rows)``, no final norm, no
+      head (a 2048-position chunk's logits over a 131,072-wide vocabulary
+      would be 1 GB).
+
+    Tokens, lengths and tables are host arrays, the pools the cache's
+    device arrays; new_rows stays on the device, (layers, S, C,
+    cache_row_width), for ``cache.commit``. After every forward
+    ``last_expert_loads`` holds the (expert layers, experts) routes each
+    routed expert got, on the host, and ``last_latent_path`` which
+    attention path the chunk's width chose and the cached rows it
+    expanded again: ``("absorbed", 0)`` for a decode step, ``("expanded",
+    the sequences' committed lengths summed)`` for any wider chunk.
+    """
+
+    def __init__(self, params, config, dtype="bfloat16"):
+        import jax.numpy as jnp
+        from ..models.mla_moe import mla_config, mla_forward_paged
+        self.config = mla_config(config)
+        self.dtype = jnp.dtype(dtype)
+        self.params = {n: jnp.asarray(v, self.dtype)
+                       for n, v in params.items()}
+        self.num_layers = self.config["num_layers"]
+        self.kv_entries = {"c": ((cache_row_width(
+            self.config["kv_rank"], self.config["rope_dim"]),), self.dtype)}
+        self.last_expert_loads = self.last_latent_path = None
+
+        def program(head):
+            def pure(params, tokens, lengths, tables, pools):
+                out, rows, loads = mla_forward_paged(
+                    params, self.config, tokens, lengths, tables, pools,
+                    head=head)
+                # -> (what the host reads, what stays on the device)
+                return ((loads,) if out is None else (out, loads),
+                        jnp.stack(rows))
+            return jax.jit(pure)
+        self._fns = {head: program(head) for head in ("logits", "none")}
+
+    def lower(self, tokens, lengths, tables, pools, head="logits"):
+        """A forward's program lowered for arguments of these shapes."""
+        return self._fns[head].lower(self.params, tokens, lengths, tables,
+                                     pools)
+
+    def _call(self, head, tokens, lengths, tables, pools):
+        path = latent_path(tokens.shape[1])
+        read, rows = _dispatch(self._fns[head], self.params,
+                               (tokens, lengths, tables, pools),
+                               mla_path=path)
+        *read, self.last_expert_loads = _fetch(read)
+        self.last_latent_path = (
+            path, int(np.sum(lengths)) if path == "expanded" else 0)
+        return read, rows
+
+    def forward(self, tokens, lengths, tables, pools):
+        (logits,), rows = self._call("logits", tokens, lengths, tables,
+                                     pools)
+        return logits, rows
+
+    def forward_kv(self, tokens, lengths, tables, pools):
+        _read, rows = self._call("none", tokens, lengths, tables, pools)
+        return None, rows
 
 
 class GenerateEngine:
@@ -367,17 +455,32 @@ class GenerateEngine:
             if self.denoise_steps < 1:
                 raise ValueError("denoise_steps must be >= 1")
         self.last_stats = {}
-        # a model with an expert layer: every forward of its is tallied
-        # into the call's ``last_stats["moe"]`` (``_routing``)
-        self._routing = None
-        self._note = (self._note_routing
-                      if hasattr(model, "last_expert_loads") else None)
+        # a model with an expert layer or a latent cache: every forward of
+        # its is tallied into the call's ``last_stats["moe"]`` / ``["mla"]``
+        self._tallies = {}
+        self._note = (self._note_forward
+                      if hasattr(model, "last_expert_loads")
+                      or hasattr(model, "last_latent_path") else None)
 
     # ---------------------------------------------------------- plumbing
-    def _note_routing(self, loads):
-        """`loads` (layers, experts): the routes each expert got in the
-        forward just made, into this call's ``last_stats["moe"]``."""
-        moe = self._routing
+    def _note_forward(self, model):
+        """What `model`'s forward just made says of itself, into this
+        call's ``last_stats``: ``last_latent_path`` (the attention path
+        over a latent cache and the cached rows it expanded) into
+        ``"mla"``, ``last_expert_loads`` (layers, experts: the routes each
+        expert got) into ``"moe"``."""
+        path = getattr(model, "last_latent_path", None)
+        if path is not None:
+            mla = self._tallies["mla"]
+            mla[path[0] + "_forwards"] += 1
+            mla["expanded_rows"] += path[1]
+            (_cat.mla_absorbed_forwards if path[0] == "absorbed"
+             else _cat.mla_expanded_forwards).inc(model=self.name)
+            _cat.mla_expanded_rows.inc(path[1], model=self.name)
+        loads = getattr(model, "last_expert_loads", None)
+        if loads is None:
+            return
+        moe = self._tallies["moe"]
         routes, hit = int(loads.sum()), int((loads > 0).sum())
         uneven = float(np.mean(loads.max(axis=1) / loads.mean(axis=1)))
         moe["forwards"] += 1
@@ -419,10 +522,13 @@ class GenerateEngine:
         stats = {"prefill_seconds": 0.0, "decode_seconds": 0.0,
                  "prefill_tokens": 0, "decode_tokens": 0,
                  "proposed": 0, "accepted": 0}
-        if self._note is not None:      # an expert layer
-            self._routing = stats["moe"] = {
-                "forwards": 0, "routes": 0, "experts_hit": 0,
-                "load_max_over_mean": []}
+        if hasattr(self.model, "last_expert_loads"):
+            stats["moe"] = {"forwards": 0, "routes": 0, "experts_hit": 0,
+                            "load_max_over_mean": []}
+        if hasattr(self.model, "last_latent_path"):
+            stats["mla"] = {"absorbed_forwards": 0, "expanded_forwards": 0,
+                            "expanded_rows": 0}
+        self._tallies = stats
         seqs = []      # per sequence: dict(ctx, slot, dslot, out, done)
         try:
             for p in prompts:
@@ -503,7 +609,7 @@ class GenerateEngine:
                                     np.int32)
                 logits = step_slots(self.model, self.cache,
                                     [s["slot"] for s in live], tokens,
-                                    routing=self._note)
+                                    note=self._note)
                 committed = 0
                 for row, s in enumerate(live):
                     tok = self._sample(logits[row])
@@ -600,10 +706,10 @@ class GenerateEngine:
                 with _tr.span("gen.block_store", model=self.name,
                               rows=rows) as sp:
                     t1 = time.monotonic()
-                    _out, nk, nv = forward_slots(
+                    _out, *new = forward_slots(
                         self.model, self.cache, slots, tokens,
                         self.model.forward_kv, self._note)
-                    commit_slots(self.cache, slots, nk, nv, B)
+                    commit_slots(self.cache, slots, *new, B)
                     sp.set_duration(time.monotonic() - t1)
                 stats["block_forwards"]["store"] += 1
                 stats["block_row_forwards"] += rows
@@ -674,9 +780,9 @@ class GenerateEngine:
             #    is the target's next-token distribution after
             #    ctx + drafts[:j]
             verify = np.asarray([[ctx[-1]] + drafts], np.int32)
-            logits, nk, nv = forward_slots(self.model, self.cache, [slot],
-                                           verify, routing=self._note)
-            commit_slots(self.cache, [slot], nk, nv, k + 1)
+            logits, *new = forward_slots(self.model, self.cache, [slot],
+                                         verify, note=self._note)
+            commit_slots(self.cache, [slot], *new, k + 1)
             target = [int(np.argmax(logits[0, j])) for j in range(k + 1)]
             # 4) longest accepted prefix + the target's own token
             a = 0

@@ -170,9 +170,10 @@ def _build_gpt_decoder(config, params, quantize):
         lowered = adapter.lower(np.zeros(shape, np.int32),
                                 *cache.forward_inputs(range(shape[0])))
         donation = ()
-        if commit:
-            _logits, new_k, new_v = lowered.out_info
-            lowered, donation = cache.lower_commit(new_k, new_v), (0, 1)
+        if commit:      # of what the forward adds, entry by entry
+            _logits, *new = lowered.out_info
+            lowered = cache.lower_commit(*new)
+            donation = tuple(range(len(new)))
         return _aot.cached_compile(lowered, name=name, where="serving",
                                    donation=donation, want_blob=True)
 
@@ -219,9 +220,9 @@ def _build_gpt_decoder(config, params, quantize):
         if not entry:
             return False
         (_name, table, shape, adapter, commit), = entry
-        layers = [0] * adapter.num_layers
-        args = ((layers, layers, 0, 0, 0) if commit else
-                (adapter.params, 0, 0, 0, layers, layers))
+        pools = ([0] * adapter.num_layers,) * len(adapter.kv_entries)
+        args = (pools + (0,) * len(pools) + (0,) if commit else
+                (adapter.params, 0, 0, 0) + pools)
         compiled = _aot.deserialize_compiled(blob)
         if compiled.in_tree != jax.tree_util.tree_structure((args, {})):
             log.info("serving: executable %r was exported under another "

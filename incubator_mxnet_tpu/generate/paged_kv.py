@@ -27,12 +27,16 @@ The paged extras feed the flash-decode kernel:
 
 - ``pool(name)`` — the ``(num_blocks, block_size) + per_step_shape``
   backing device array of a kv entry,
-- ``layer_spec(num_layers, shape, dtype)`` — the spec of a decoder's
-  cache, a K and a V entry a layer: the entry names are this module's,
+- ``layer_spec(num_layers, entries)`` — the spec of a decoder's cache:
+  every layer holds the named `entries` (per-head keys and values ``k``
+  and ``v``; one latent row ``c`` shared by all heads), ``<entry><i>``
+  for layer i,
 - ``forward_inputs(slots)`` — what a forward over `slots` reads of the
-  cache: lengths, block tables, K pools, V pools,
-- ``commit(slots, new_k, new_v, count)`` — store a forward's K and V
-  (``lower_commit``: that program lowered, for an owner that ships
+  cache: lengths, block tables, then every layer's pool entry by entry
+  (K pools, V pools),
+- ``commit(slots, *new, count)`` — store what a forward produced, entry
+  by entry in the same order (``commit(slots, new_k, new_v, count)``;
+  ``lower_commit``: that program lowered, for an owner that ships
   executables),
 - ``tables_array(slots)`` — an ``(S, max_blocks_per_slot)`` int32 block
   table, padded with block 0 (padded fetches are masked by ``lengths``
@@ -49,6 +53,7 @@ contract), so their block layouts are identical by construction.
 
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -146,23 +151,52 @@ def _put(pool, rows, new, order, memo):
         np.argsort(order))
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1), static_argnums=5)
-def store_program(k_pools, v_pools, new_k, new_v, rows, orders):
-    """Every layer's pools with ``new_k[i]`` / ``new_v[i]`` (S, C, ...)
-    scattered to the flat pool positions `rows` (S, C). The pools are
-    donated: the scatter is in place. `orders`: the `device_order` of
-    each k pool, then of each v pool. One compiled program a (S, C)."""
-    rows, memo = rows.reshape(-1), {}
-    k_orders, v_orders = orders[:len(k_pools)], orders[len(k_pools):]
-    return ([_put(p, rows, new_k[i], k_orders[i], memo)
-             for i, p in enumerate(k_pools)],
-            [_put(p, rows, new_v[i], v_orders[i], memo)
-             for i, p in enumerate(v_pools)])
+@functools.lru_cache(maxsize=None)
+def store_program_for(entries):
+    """The commit program of a cache whose layers hold `entries` entries:
+    ``(*pools, *new, rows, orders)``, the pools entry by entry, each
+    every layer's, then what a forward produced in the same order.
+
+    Every pool comes back with ``new[e][i]`` (S, C, ...) scattered to the
+    flat pool positions `rows` (S, C). The pools are donated: the scatter
+    is in place. `orders`: the `device_order` of each pool, entry by
+    entry. One compiled program a (S, C)."""
+    def store_program(*args):
+        pools, new = args[:entries], args[entries:2 * entries]
+        rows, orders = args[2 * entries:]
+        rows, memo, orders = rows.reshape(-1), {}, iter(orders)
+        return tuple([_put(p, rows, of_entry[i], next(orders), memo)
+                      for i, p in enumerate(entry_pools)]
+                     for entry_pools, of_entry in zip(pools, new))
+    return jax.jit(store_program, donate_argnums=tuple(range(entries)),
+                   static_argnums=2 * entries + 1)
+
+
+#: per-head keys and values: ``(k_pools, v_pools, new_k, new_v, rows,
+#: orders)``, the `device_order` of each k pool, then of each v pool
+store_program = store_program_for(2)
 
 
 @functools.partial(jax.jit, donate_argnums=0, static_argnums=3)
 def _store_one(pool, row, value, order):
     return _put(pool, row.reshape(1), value, order, {})
+
+
+def _by_layer(names):
+    """Entry names ``<entry><layer>`` grouped by entry in the order they
+    come, each in layer order: ``(["k0", "k1"], ["v0", "v1"])``. None
+    unless every entry has every layer 0..L-1."""
+    layers = {}
+    for name in names:
+        m = re.fullmatch(r"(.*?)(\d+)", name)
+        if m is None:
+            return None
+        layers.setdefault(m.group(1), set()).add(int(m.group(2)))
+    if not layers or any(found != set(range(len(names) // len(layers)))
+                         for found in layers.values()):
+        return None
+    return tuple(["%s%d" % (entry, i) for i in range(len(found))]
+                 for entry, found in layers.items())
 
 
 class PagedKVCache:
@@ -178,12 +212,15 @@ class PagedKVCache:
     """
 
     @staticmethod
-    def layer_spec(num_layers, shape, dtype=np.float32):
-        """The spec of a decoder's cache: for each of `num_layers` a K and
-        a V entry holding `shape` of `dtype` a position, under the names
-        ``forward_inputs`` and ``commit`` find them by."""
-        return {"%s%d" % (kind, i): ("kv", tuple(shape), dtype)
-                for i in range(num_layers) for kind in "kv"}
+    def layer_spec(num_layers, entries):
+        """The spec of a decoder's cache: each of `num_layers` holds the
+        `entries`, name -> (shape, dtype) of a position (``{"k": ..., "v":
+        ...}`` of one per-head shape; one latent row ``{"c": ((640,),
+        bfloat16)}``), under the names ``<entry><layer>`` that
+        ``forward_inputs`` and ``commit`` find them by, in this order."""
+        return {"%s%d" % (name, i): ("kv", tuple(shape), dtype)
+                for i in range(num_layers)
+                for name, (shape, dtype) in entries.items()}
 
     def __init__(self, slots, spec, max_len=512, block_size=None,
                  num_blocks=None, name="default"):
@@ -219,15 +256,11 @@ class PagedKVCache:
             self.spec[ent_name] = (kind, shape, dtype)
             self.data[ent_name] = (np.zeros(full, dtype) if kind == "state"
                                    else _device_zeros(full, dtype))
-        # a forward reads and `commit` stores K and V by layer: entries
-        # k<i>, v<i>
+        # a forward reads and `commit` stores the entries by layer:
+        # <entry><i>, as `layer_spec` names them
         kv = [n for n, ent in self.spec.items() if ent[0] == "kv"]
         self._orders = {n: device_order(self.data[n]) for n in kv}
-        self._layer_names = tuple(
-            ["%s%d" % (kind, i) for i in range(len(kv) // 2)]
-            for kind in "kv")
-        if set(kv) != set(sum(self._layer_names, [])):
-            self._layer_names = None
+        self._layer_names = _by_layer(kv)
         # (S, C) -> a compiled `store_program` put there by an owner that
         # ships executables (the serving family's warm grid); `commit`
         # takes the jitted one where there is none
@@ -328,13 +361,15 @@ class PagedKVCache:
             self.data[name], np.int32(row), np.asarray(value).reshape(shape),
             self._orders[name])
 
-    def commit(self, slots, new_k, new_v, count):
-        """Store what a forward produced: the first `count` (one number,
-        or one a row) chunk positions of row r of ``new_k[i]`` /
-        ``new_v[i]`` ((S, C) + shape device arrays, layer i's, as the
-        forward returned them: a sequence, or one array stacked over
-        layers) at the next positions of ``slots[r]`` in the entries
-        ``"k<i>"`` / ``"v<i>"``, and advance the slots.
+    def commit(self, slots, *new_and_count):
+        """``commit(slots, *new, count)``: store what a forward produced,
+        the first `count` (one number, or one a row) chunk positions of
+        row r of ``new[e][i]`` ((S, C) + shape device arrays, entry e of
+        layer i, as the forward returned them: a sequence a layer, or one
+        array stacked over layers) at the next positions of ``slots[r]``
+        in the entries ``"<entry><i>"``, and advance the slots. `new` is
+        in the spec's order of entries: ``commit(slots, new_k, new_v,
+        count)`` for a cache of ``"k<i>"`` / ``"v<i>"``.
 
         The host maps the blocks those positions need, row by row as a
         loop of `append`s would (so the pool runs out at the same
@@ -343,10 +378,11 @@ class PagedKVCache:
         prefill chunk's pads, a row whose count is 0) point past the pool
         and are dropped. What was mapped before an error is stored."""
         slots = list(slots)
-        k_names, v_names = self._layers()
+        *new, count = new_and_count
+        names = self._layers()
         # per layer: a sequence of (S, C, ...) arrays, or one stacked
-        chunk = (new_k.shape[2] if hasattr(new_k, "shape")
-                 else new_k[0].shape[1])
+        chunk = (new[0].shape[2] if hasattr(new[0], "shape")
+                 else new[0][0].shape[1])
         counts = np.broadcast_to(np.asarray(count), (len(slots),))
         rows = np.full((len(slots), chunk),
                        self.num_blocks * self.block_size, np.int32)
@@ -358,45 +394,46 @@ class PagedKVCache:
                     rows[r, c] = self._row_at(slot, int(self.lengths[slot]))
                     self.lengths[slot] += 1
         finally:
-            k_pools, v_pools = self._store(rows, new_k, new_v)
-            self.data.update(zip(k_names + v_names, k_pools + v_pools))
+            for entry_names, pools in zip(names, self._store(rows, new)):
+                self.data.update(zip(entry_names, pools))
             self._note_blocks()
 
     def _layers(self):
         if self._layer_names is None:
             raise ValueError("commit stores a forward's layers in kv entries "
-                             "named k<i> and v<i>; this cache's are not")
+                             "named <entry><i> (k<i> and v<i>, say); this "
+                             "cache's are not")
         return self._layer_names
 
     def _layer_pools(self):
-        """(every layer's K pool, every layer's V pool, their device orders
-        K first): what a forward reads and `store_program` takes."""
-        k_names, v_names = self._layers()
-        return ([self.data[n] for n in k_names],
-                [self.data[n] for n in v_names],
-                tuple(self._orders[n] for n in k_names + v_names))
+        """(every layer's pool, entry by entry: K pools, V pools; their
+        device orders alike, flat): what a forward reads and the commit
+        program takes."""
+        names = self._layers()
+        return (tuple([self.data[n] for n in entry] for entry in names),
+                tuple(self._orders[n] for entry in names for n in entry))
 
-    def _store(self, rows, new_k, new_v):
-        k_pools, v_pools, orders = self._layer_pools()
-        args = (k_pools, v_pools, new_k, new_v, rows)
+    def _store(self, rows, new):
+        pools, orders = self._layer_pools()
+        args = pools + tuple(new) + (rows,)
         program = self.programs.get(rows.shape)
         if program is not None:
             try:
                 return program(*args)
             except TypeError:   # bound for other pools: retire it
                 del self.programs[rows.shape]
-        return store_program(*args, orders)
+        return store_program_for(len(pools))(*args, orders)
 
-    def lower_commit(self, new_k, new_v):
-        """`commit`'s program lowered for what a forward returns (`new_k`
-        / `new_v`: arrays or their shapes and dtypes, stacked over layers)
-        against this cache's pools, for an owner that ships executables:
-        compiled with the pools donated (arguments 0 and 1) it is what
-        ``programs[(S, C)]`` holds."""
-        k_pools, v_pools, orders = self._layer_pools()
-        return store_program.lower(
-            k_pools, v_pools, new_k, new_v,
-            jax.ShapeDtypeStruct(new_k.shape[1:3], np.int32), orders)
+    def lower_commit(self, *new):
+        """`commit`'s program lowered for what a forward returns (`new`:
+        arrays or their shapes and dtypes, entry by entry, each stacked
+        over layers) against this cache's pools, for an owner that ships
+        executables: compiled with the pools donated (the first
+        ``len(new)`` arguments) it is what ``programs[(S, C)]`` holds."""
+        pools, orders = self._layer_pools()
+        return store_program_for(len(pools)).lower(
+            *pools, *new, jax.ShapeDtypeStruct(new[0].shape[1:3], np.int32),
+            orders)
 
     def advance(self, slot):
         self._check(slot)
@@ -437,11 +474,12 @@ class PagedKVCache:
         """What a forward over `slots` reads of the cache, in the order a
         paged forward takes them: the committed lengths (S,) and the block
         tables (S, max_blocks_per_slot), int32 host arrays it ships, and
-        every layer's K pool and V pool, the device arrays (not shipped)."""
+        every layer's pool entry by entry (the K pools, the V pools), the
+        device arrays (not shipped)."""
         slots = list(slots)
-        k_pools, v_pools, _orders = self._layer_pools()
+        pools, _orders = self._layer_pools()
         return (self.lengths[slots].astype(np.int32),
-                self.tables_array(slots), k_pools, v_pools)
+                self.tables_array(slots)) + pools
 
     def tables_array(self, slots=None):
         """Block tables as an (S, max_blocks_per_slot) int32 array for

@@ -17,7 +17,9 @@ worst case, every group ending in a nearly empty tile); the tiles past the
 last used one point at the last used group (no fetch) and write zeros.
 
 Decode-sized batches (a few rows an expert, 3 MB of weights a group) are
-bound by the weight reads; the short tiles keep the MXU work under them.
+bound by the weight reads; the short tiles keep the MXU work under them. A
+batch that fills its groups (a prefill chunk of 2,048 tokens x 4 routes over
+64 experts: 128 rows a group) takes tiles of 128 rows (``tile_rows_for``).
 Measured on a v5e at 512 routes over 128 experts of 2048 x 768 bfloat16
 (PERF.md, PR 29): 0.62 ms a product, 77 % of the HBM roofline of the experts
 hit, where the launch XLA makes of ``ragged_dot`` takes 1.56-1.82 ms.
@@ -34,19 +36,31 @@ __all__ = ["grouped_matmul", "tile_plan", "grouped_matmul_tiles",
 
 #: rows a tile; 16 is one packed bfloat16 sublane group
 TILE_ROWS = 16
+#: rows a tile where the groups are full: a prefill chunk's routes
+FULL_TILE_ROWS = 128
+
+
+def tile_rows_for(rows, groups):
+    """Rows a tile for `rows` rows over `groups` groups: short tiles where
+    a group gets a few rows (a decode step: the weight reads bound it and
+    the padding of 128-row tiles would be most of the work), tiles as tall
+    as the MXU where the mean group would fill half of one (a prefill
+    chunk: a 16-row tile leaves the MXU loading weights seven cycles of
+    eight)."""
+    return (FULL_TILE_ROWS if 2 * rows >= groups * FULL_TILE_ROWS
+            else TILE_ROWS)
 
 
 def grouped_matmul_available():
     return jax.default_backend() == "tpu"
 
 
-def tile_plan(group_sizes, rows):
+def tile_plan(group_sizes, rows, tile_rows=TILE_ROWS):
     """Where sorted row ``r`` sits once every group is padded to whole
     tiles. -> ``(dest (rows,), tile_group (T,), used (1,))``: the padded
     position of each row, the group of each tile (tiles past the used ones
     repeat the last used group) and the number of tiles used. ``T`` is the
-    static worst case, ``(rows + G * (TILE_ROWS - 1)) // TILE_ROWS``."""
-    tile_rows = TILE_ROWS
+    static worst case, ``(rows + G * (tile_rows - 1)) // tile_rows``."""
     groups = group_sizes.shape[0]
     tiles = (rows + groups * (tile_rows - 1)) // tile_rows
     sizes = group_sizes.astype(jnp.int32)
@@ -82,9 +96,9 @@ def _kernel(tile_group_ref, used_ref, x_ref, w_ref, o_ref):
 
 def grouped_matmul_tiles(x_tiles, w, tile_group, used, interpret=False):
     """The launch itself, on rows already laid out in tiles.
-    x_tiles (T * TILE_ROWS, K); w (G, K, N) -> (T * TILE_ROWS, N) float32."""
-    tile_rows = TILE_ROWS
+    x_tiles (T * tile_rows, K); w (G, K, N) -> (T * tile_rows, N) float32."""
     rows, k = x_tiles.shape
+    tile_rows = rows // tile_group.shape[0]
     n = w.shape[2]
     # one weight block is (K, block_n): whole when it is 4 MB at most, so
     # that two of them (the pipeline's) stay far under the scoped VMEM
@@ -123,8 +137,9 @@ def grouped_matmul(x, w, group_sizes, use_kernel=None, interpret=False):
         return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
                                   preferred_element_type=jnp.float32)
     rows = x.shape[0]
-    dest, tile_group, used = tile_plan(group_sizes, rows)
+    tile_rows = tile_rows_for(rows, w.shape[0])
+    dest, tile_group, used = tile_plan(group_sizes, rows, tile_rows)
     # padding rows read row 0: what they produce is never gathered back
-    src = jnp.zeros((tile_group.shape[0] * TILE_ROWS,), jnp.int32
+    src = jnp.zeros((tile_group.shape[0] * tile_rows,), jnp.int32
                     ).at[dest].set(jnp.arange(rows, dtype=jnp.int32))
     return grouped_matmul_tiles(x[src], w, tile_group, used, interpret)[dest]

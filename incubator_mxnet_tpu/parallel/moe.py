@@ -140,8 +140,32 @@ def moe_ffn(x, params, prefix, top_k=2, capacity_factor=1.25,
 
 
 # ------------------------------------------------------------- dropless
+def _routes(logits, k, scoring, choice_bias, route_scale):
+    """The router's decision from its float32 `logits` (N, E): -> (the k
+    experts a token (N, k), their weights (N, k)). The choice is by score
+    (+ `choice_bias`); the weights are the chosen experts' unbiased scores,
+    renormalised, times `route_scale`."""
+    if scoring == "softmax":
+        probs, total_floor = jax.nn.softmax(logits, axis=-1), None
+    elif scoring == "sigmoid":
+        probs, total_floor = jax.nn.sigmoid(logits), 1e-20
+    else:
+        raise ValueError("no such scoring: %r" % (scoring,))
+    if choice_bias is None:
+        top_p, top_e = jax.lax.top_k(probs, k)              # (N, k)
+    else:
+        _, top_e = jax.lax.top_k(probs + choice_bias.astype(jnp.float32), k)
+        top_p = jnp.take_along_axis(probs, top_e, axis=-1)
+    total = jnp.sum(top_p, axis=-1, keepdims=True)
+    weights = top_p / (total if total_floor is None else total + total_floor)
+    if route_scale != 1.0:
+        weights = weights * route_scale
+    return top_e, weights
+
+
 def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
-                 interpret=False, return_stats=False):
+                 interpret=False, return_stats=False, scoring="softmax",
+                 choice_bias=None, route_scale=1.0, shared=None):
     """Dropless top-k mixture of gated-SiLU experts, no biases.
 
     x : (N, d) tokens
@@ -152,6 +176,14 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     token's k routes is computed, whatever the load on its expert:
 
         out = sum_{e in top-k} w_e * (silu(x gate_w[e]) * (x up_w[e])) down_w[e]
+
+    `scoring` ``"sigmoid"`` scores each expert by itself, ``p =
+    sigmoid(x router_w)``. `choice_bias` (E,) is added to the scores for
+    the CHOICE of the top-k alone; the weights are the unbiased scores of
+    the chosen, renormalised, times `route_scale`. `shared`: the ``(gate_w
+    (d, fs), up_w, down_w (fs, d))`` of a shared expert that every token
+    visits: dense, so computed by the plain products, added once a token
+    and not a route (``expert_load`` counts routed experts only).
 
     The N x k routes are sorted by expert (a stable sort, so a token's
     routes keep their order inside a group) and each of the three expert
@@ -177,21 +209,28 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     def grouped(rows, w, sizes):
         return grouped_matmul(rows, w, sizes, use_kernel=use_kernel,
                               interpret=interpret)
-    logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)                  # (N, k)
-    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+    def gated(gate, up):
+        return (jax.nn.silu(gate) * up).astype(x.dtype)
+    top_e, weights = _routes(
+        jnp.dot(x, router_w, preferred_element_type=jnp.float32), k, scoring,
+        choice_bias, route_scale)
     expert_of_route = top_e.reshape(n * k)
     order = jnp.argsort(expert_of_route, stable=True)       # sorted -> route
     load = jnp.zeros((experts,), jnp.int32).at[expert_of_route].add(1)
     rows = x[order // k]                                    # (N k, d)
-    hidden = (jax.nn.silu(grouped(rows, gate_w, load))
-              * grouped(rows, up_w, load)).astype(x.dtype)
+    hidden = gated(grouped(rows, gate_w, load), grouped(rows, up_w, load))
     y = grouped(hidden, down_w, load)                       # (N k, d) f32
     back = jnp.zeros((n * k,), jnp.int32).at[order].set(
         jnp.arange(n * k, dtype=jnp.int32))                 # route -> sorted
-    out = jnp.sum(y[back].reshape(n, k, d) * weights[:, :, None],
-                  axis=1).astype(x.dtype)
+    out = jnp.sum(y[back].reshape(n, k, d) * weights[:, :, None], axis=1)
+    if shared is not None:
+        s_gate, s_up, s_down = shared
+        out = out + jnp.dot(
+            gated(jnp.dot(x, s_gate, preferred_element_type=jnp.float32),
+                  jnp.dot(x, s_up, preferred_element_type=jnp.float32)),
+            s_down, preferred_element_type=jnp.float32)
+    out = out.astype(x.dtype)
     if return_stats:
         return out, {"expert_load": load}
     return out

@@ -318,6 +318,22 @@ moe_load_max_over_mean = _m.histogram(
     "observation a forward (mean over layers), by model — 1 is even "
     "routing",
     buckets=(1, 1.5, 2, 3, 4, 6, 8, 12, 16, 32, 64, 128))
+mla_absorbed_forwards = _m.counter(
+    "mxtpu_mla_absorbed_forwards_total",
+    "Forwards over a latent cache that ran the absorbed attention path "
+    "(a chunk of one position: a decode step reads the cached rows "
+    "alone), by model")
+mla_expanded_forwards = _m.counter(
+    "mxtpu_mla_expanded_forwards_total",
+    "Forwards over a latent cache that ran the expanded attention path "
+    "(a wider chunk: prefill up-projects rows to per-head keys and "
+    "values), by model")
+mla_expanded_rows = _m.counter(
+    "mxtpu_mla_expanded_rows_total",
+    "Cached latent rows that expanded forwards up-projected AGAIN (the "
+    "committed lengths of their sequences, summed): the price of "
+    "prefilling in chunks, some L^2 / 2c a prompt of L in chunks of c, "
+    "by model")
 gen_kv_blocks_in_use = _m.gauge(
     "mxtpu_gen_kv_blocks_in_use",
     "Paged-KV pool blocks currently mapped into live slot block tables")

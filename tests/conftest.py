@@ -36,6 +36,18 @@ POOLS_CROSS_IN_EVERY_STEP = (
     "test_a_traced_generation_run_reads_every_phase_of_a_decode_step")
 
 
+# One more (closed to a model_config PR) asserts the exact {name: reduced}
+# of THREE configurations; ISSUE 33 lists a fourth. All else it asserted,
+# with the lists as they now stand, is asserted by tests/benchmark/
+# test_benchmark_latent.py::test_the_real_benchmark_as_it_stands_with_the_
+# long_prompt_cell. Strict: once a benchmark PR makes it ask only for the
+# configurations it names, it passes, this mark fails the run, and the
+# mark goes.
+THREE_CONFIGURATIONS = (
+    "tests/benchmark/test_benchmark_blocks.py::"
+    "test_the_real_benchmark_as_it_stands_with_the_block_cell")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid.endswith(POOLS_CROSS_IN_EVERY_STEP):
@@ -43,6 +55,12 @@ def pytest_collection_modifyitems(items):
                 strict=True, raises=AssertionError,
                 reason="asserts that the pools cross to the device in every "
                        "step (ISSUE 30); PERF.md section 7 item 8"))
+        if item.nodeid.endswith(THREE_CONFIGURATIONS):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="asserts the reduced lists of exactly three "
+                       "configurations (ISSUE 33 lists a fourth); PERF.md "
+                       "section 7"))
 
 
 @pytest.fixture(autouse=True)

@@ -1,0 +1,449 @@
+"""The ``xing4_0`` family at a small size on the CPU, float32: 3 layers (one
+dense, two expert layers), hidden 32, 4 heads over a latent of 16 + 8, 8
+experts top-2 of width 16 with a shared one, 4 streams, vocabulary 128,
+seeded weights.
+
+- prefill in chunks then decoding through ``PagedKVCache`` against the
+  plain reference's full forward;
+- the absorbed and the expanded attention path on the same rows, and the
+  same rows committed whatever the chunk width;
+- the cache: one latent row a position and layer, no per-head K or V;
+- the hyper-connections' invariants, YaRN's numbers;
+- the sigmoid router (choice by score + bias, weight by score), the shared
+  expert counted once, ``moe_dropless``'s defaults left as they were;
+- which path ran, in ``last_stats["mla"]``, the counters and the span.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.families import xing4 as family
+from benchmarks.reference import xing4 as reference
+from incubator_mxnet_tpu import telemetry
+from incubator_mxnet_tpu.generate import GenerateEngine, MLAPagedLM
+from incubator_mxnet_tpu.generate.engine import (forward_slots, prefill_slot,
+                                                 step_slots)
+from incubator_mxnet_tpu.generate.paged_kv import PagedKVCache
+from incubator_mxnet_tpu.models import mla_moe
+from incubator_mxnet_tpu.ops.pallas.paged_latent import (
+    cache_row_width, latent_path, paged_latent_attention)
+from incubator_mxnet_tpu.parallel.moe import moe_dropless
+from incubator_mxnet_tpu.telemetry import catalog as cat
+from incubator_mxnet_tpu.telemetry import tracing
+
+YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
+        "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+        "type": "yarn"}
+CFG = {"hidden_size": 32, "num_attention_heads": 4, "q_lora_rank": 16,
+       "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+       "v_head_dim": 8, "intermediate_size": 64, "n_routed_experts": 8,
+       "num_experts_per_tok": 2, "moe_intermediate_size": 16,
+       "n_shared_experts": 1, "first_k_dense_replace": 1,
+       "num_hidden_layers": 3, "vocab_size": 128, "hc_mult": 4,
+       "hc_sinkhorn_iters": 20, "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
+       "mhc_h_res_clamp_max": 30, "rms_norm_eps": 1e-6, "rope_theta": 10000,
+       "routed_scaling_factor": 2, "max_position_embeddings": 64,
+       "rope_scaling": YARN, "dtype": "float32", "seed_weight_range": 0.2,
+       "assumed": {"initializer_range": {"value": 0.02},
+                   "hc_phi_range": {"value": 0.1},
+                   "hc_bias_range": {"value": 1.0},
+                   "router_bias_range": {"value": 0.1},
+                   "prefill_chunk": {"value": 8}}}
+PUBLISHED_YARN = dict(CFG, qk_rope_head_dim=64, qk_nope_head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return reference.init_weights(CFG, 3)
+
+
+@pytest.fixture(scope="module")
+def model(weights):
+    return MLAPagedLM(weights, family.program_config(CFG), dtype="float32")
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(1, 128, n).tolist()
+
+
+def _reference_at(weights, tokens, positions):
+    return reference.logits(weights, CFG, [tokens], [positions],
+                            block_rows=8)[0]
+
+
+# ---------------------------------------------- through the cache, end to end
+@pytest.mark.parametrize("length,chunk", [(21, 8), (9, 8), (17, 4), (6, 8)],
+                         ids=["chunks_and_tail", "one_over", "whole_chunks",
+                              "under_a_chunk"])
+def test_prefill_then_decode_through_the_cache_is_the_full_forward(
+        weights, model, length, chunk):
+    """Chunks run the expanded path, the steps after them the absorbed
+    one; the reference expands everywhere and has no cache."""
+    prompt = _prompt(length + 3, seed=length)
+    cache = model.make_cache(2, max_len=32, block_size=4)
+    slot = cache.alloc()
+    prefill_slot(model, cache, slot, prompt[:length], chunk)
+    theirs = _reference_at(weights, prompt, np.arange(length, length + 3))
+    for i in range(3):
+        logits = step_slots(model, cache, [slot],
+                            np.asarray([[prompt[length + i]]], np.int32))
+        np.testing.assert_allclose(logits[0], theirs[i], atol=1e-4)
+    assert int(cache.lengths[slot]) == length + 3
+
+
+def test_a_chunks_logits_are_the_full_forwards(weights, model):
+    """A (1, k + 1) verify forward of speculative decoding is a chunk too:
+    the expanded path with the head."""
+    prompt = _prompt(14)
+    cache = model.make_cache(1, max_len=32, block_size=4)
+    slot = cache.alloc()
+    prefill_slot(model, cache, slot, prompt[:9], 4)
+    logits, _rows = forward_slots(model, cache, [slot],
+                                  np.asarray([prompt[9:]], np.int32))
+    np.testing.assert_allclose(
+        logits[0], _reference_at(weights, prompt, np.arange(9, 14)),
+        atol=1e-4)
+
+
+def test_generate_serves_the_references_choice(weights, model):
+    engine = GenerateEngine(model, model.make_cache(2, max_len=32,
+                                                    block_size=4),
+                            prefill_chunk=8, name="mla_test")
+    prompts = [_prompt(11, 1), _prompt(5, 2)]
+    served = engine.generate(prompts, max_new_tokens=4)
+    for prompt, out in zip(prompts, served):
+        tokens = prompt + out
+        theirs = _reference_at(weights, tokens,
+                               np.arange(len(prompt) - 1, len(tokens) - 1))
+        top2 = np.sort(theirs, axis=1)[:, -2:]
+        clear = top2[:, 1] - top2[:, 0] > 1e-3      # no close call
+        assert np.array_equal(theirs.argmax(1)[clear], np.asarray(out)[clear])
+    assert engine.last_stats["decode_tokens"] == 8
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 8])
+def test_any_chunk_width_commits_the_same_rows(weights, model, chunk):
+    """Width 1 prefills by the absorbed path, 3 and 8 by the expanded one
+    (8 in two chunks, the last padded)."""
+    prompt = _prompt(13, seed=5)
+
+    def committed(width):
+        cache = model.make_cache(1, max_len=16, block_size=4)
+        slot = cache.alloc()
+        prefill_slot(model, cache, slot, prompt, width)
+        return [cache.prefix(name, slot) for name in cache.spec]
+    for ours, whole in zip(committed(chunk), committed(13)):
+        assert ours.shape == (13, 128)      # 16 + 8 values, then zeros
+        assert np.abs(ours[:, :24]).min() > 0 and not ours[:, 24:].any()
+        np.testing.assert_allclose(ours, whole, atol=1e-5)
+
+
+# ------------------------------------------------------------- the two paths
+def test_absorbed_and_expanded_attention_agree_on_the_same_rows():
+    rng = np.random.default_rng(0)
+    S, C, H, r, d_n, d_r, d_v, bs = 2, 3, 4, 16, 8, 8, 8, 4
+    f = jnp.float32
+    q_nope = jnp.asarray(rng.normal(size=(S, C, H, d_n)), f)
+    q_rope = jnp.asarray(rng.normal(size=(S, C, H, d_r)), f)
+    # rows of 24 values in 32 lanes: what lies past them is never read by
+    # the expanded path and meets the query's zeros in the absorbed one
+    new_rows = jnp.asarray(rng.normal(size=(S, C, 32)), f)
+    kv_b = jnp.asarray(rng.normal(size=(r, H, d_n + d_v)) * 0.3, f)
+    pool = np.asarray(rng.normal(size=(6, bs, 32)), np.float32)
+    tables = np.asarray([[4, 1, 0], [2, 5, 3]], np.int32)
+    lengths = np.asarray([6, 3], np.int32)
+    assert latent_path(C) == "expanded" and latent_path(1) == "absorbed"
+    expanded = paged_latent_attention(q_nope, q_rope, new_rows, kv_b,
+                                      jnp.asarray(pool), tables, lengths,
+                                      0.25, key_tile=8)
+    for c in range(C):
+        # the chunk's earlier rows stored where the cache would put them
+        stored, at = pool.copy(), lengths.copy()
+        for s in range(S):
+            for j in range(c):
+                block, off = divmod(int(lengths[s]) + j, bs)
+                stored[tables[s, block], off] = np.asarray(new_rows[s, j])
+            at[s] += c
+        absorbed = paged_latent_attention(
+            q_nope[:, c:c + 1], q_rope[:, c:c + 1], new_rows[:, c:c + 1],
+            kv_b, jnp.asarray(stored), tables, at, 0.25, key_tile=4)
+        np.testing.assert_allclose(absorbed[:, 0], expanded[:, c], atol=1e-5)
+
+
+def test_the_tiles_walked_follow_the_longest_sequence():
+    """A sequence with no past, beside one with a past of three tiles: the
+    rows past a length are masked, whatever block the table names."""
+    rng = np.random.default_rng(1)
+    f = jnp.float32
+    q_nope = jnp.asarray(rng.normal(size=(2, 1, 2, 4)), f)
+    q_rope = jnp.asarray(rng.normal(size=(2, 1, 2, 2)), f)
+    new_rows = jnp.asarray(rng.normal(size=(2, 1, 6)), f)
+    kv_b = jnp.asarray(rng.normal(size=(4, 2, 7)), f)
+    pool = jnp.asarray(rng.normal(size=(8, 2, 6)), f)
+    tables = np.asarray([[0, 0, 0, 0, 0], [7, 6, 5, 4, 3]], np.int32)
+    out = paged_latent_attention(q_nope, q_rope, new_rows, kv_b, pool,
+                                 tables, np.asarray([0, 9], np.int32), 0.5,
+                                 key_tile=4)
+    # no past: the softmax is over the row itself, so the output is its value
+    alone = np.einsum("r,rhv->hv", np.asarray(new_rows[0, 0, :4]),
+                      np.asarray(kv_b[..., 4:]))
+    np.testing.assert_allclose(out[0, 0], alone, atol=1e-5)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+# ------------------------------------------------------------------ the cache
+def test_the_cache_holds_one_latent_row_a_position_and_no_k_or_v(model):
+    # 16 + 8 values in a row of whole lanes (576 in 640 as published)
+    assert cache_row_width(16, 8) == 128 and cache_row_width(512, 64) == 640
+    assert model.kv_entries == {"c": ((128,), jnp.float32)}
+    cache = model.make_cache(3, max_len=32, block_size=4)
+    assert list(cache.spec) == ["c0", "c1", "c2"]
+    assert all(cache.pool(n).shape == (24, 4, 128) for n in cache.spec)
+    lengths, tables, pools = cache.forward_inputs([0, 1])
+    assert len(pools) == 3 and tables.shape == (2, 8)
+    # the commit program takes one entry: (pools, new rows, positions)
+    lowered = cache.lower_commit(jax.ShapeDtypeStruct((3, 2, 1, 128),
+                                                      jnp.float32))
+    (entry,), rows = lowered.in_avals[0][:1], lowered.in_avals[0][-1]
+    assert [a.shape for a in entry] == [(24, 4, 128)] * 3
+    assert rows.shape == (2, 1)
+
+
+def test_layer_spec_names_the_entries_a_layer_declares():
+    spec = PagedKVCache.layer_spec(2, {"k": ((2, 4), np.float32),
+                                       "v": ((2, 4), np.float32)})
+    assert list(spec) == ["k0", "v0", "k1", "v1"]
+    cache = PagedKVCache(1, spec, max_len=8, block_size=4)
+    _lengths, _tables, k_pools, v_pools = cache.forward_inputs([0])
+    assert len(k_pools) == len(v_pools) == 2
+    mixed = PagedKVCache(1, {"c0": ("kv", (6,)), "c2": ("kv", (6,))},
+                         max_len=8)
+    with pytest.raises(ValueError, match="<entry><i>"):
+        mixed.forward_inputs([0])
+
+
+# ---------------------------------------------------------- hyper-connections
+def test_sinkhorn_leaves_rows_and_columns_that_sum_to_one(weights):
+    rng = np.random.default_rng(2)
+    X = jnp.asarray(rng.normal(size=(4, 7, 32)), jnp.float32)
+    cfg = mla_moe.mla_config(family.program_config(CFG))
+    pre, post, res = mla_moe.hyper_connection_maps(weights, "l1_ffn_", cfg, X)
+    assert pre.shape == post.shape == (4, 7) and res.shape == (4, 4, 7)
+    np.testing.assert_allclose(res.sum(axis=0), 1.0, atol=1e-3)
+    np.testing.assert_allclose(res.sum(axis=1), 1.0, atol=1e-3)
+    assert float(pre.min()) > 0 and float(pre.max()) < 1
+    assert float(post.min()) > 0 and float(post.max()) < 2
+    # far from the identity and from uniform, and not one map for all tokens
+    assert float(jnp.std(res, axis=2).mean()) > 0.02
+    # the reference's maps, written token-major, are the same numbers
+    theirs = reference.mappings(
+        X.transpose(1, 0, 2), weights["l1_ffn_hc_phi"],
+        weights["l1_ffn_hc_alpha"], weights["l1_ffn_hc_b"], CFG)
+    np.testing.assert_allclose(pre.T, theirs[0], atol=1e-5)
+    np.testing.assert_allclose(post.T, theirs[1], atol=1e-5)
+    np.testing.assert_allclose(res.transpose(2, 0, 1), theirs[2], atol=1e-5)
+
+
+def test_the_streams_sum_moves_by_the_post_weights_times_the_output(weights):
+    rng = np.random.default_rng(3)
+    X = jnp.asarray(rng.normal(size=(4, 5, 32)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(5, 32)), jnp.float32)
+    cfg = mla_moe.mla_config(family.program_config(CFG))
+    _pre, post, _res = mla_moe.hyper_connection_maps(weights, "l0_attn_",
+                                                     cfg, X)
+    after = mla_moe._hyper(weights, "l0_attn_", cfg, X,
+                           weights["l0_attn_norm"], lambda h: y)
+    np.testing.assert_allclose(
+        after.sum(axis=0), X.sum(axis=0) + post.sum(axis=0)[:, None] * y,
+        atol=2e-3)
+
+
+def test_yarn_low_high_and_scale_are_the_published_numbers():
+    freq, low, high = reference.yarn_frequencies(PUBLISHED_YARN)
+    assert (low, high) == (10, 23)
+    assert reference.softmax_scale(PUBLISHED_YARN) == pytest.approx(
+        0.144679, abs=1e-6)
+    ours = mla_moe.yarn_inv_freq(64, 10000.0, YARN)
+    np.testing.assert_allclose(ours, freq, rtol=1e-12)
+    plain = 10000.0 ** (-np.arange(32) / 32)
+    np.testing.assert_allclose(ours[:11], plain[:11])       # kept
+    np.testing.assert_allclose(ours[23:], plain[23:] / 64)  # divided
+    assert mla_moe.attention_scale(
+        {"nope_dim": 128, "rope_dim": 64, "yarn": YARN}) == pytest.approx(
+            0.144679, abs=1e-6)
+    assert mla_moe.attention_scale(
+        {"nope_dim": 128, "rope_dim": 64, "yarn": None}) == 192 ** -0.5
+
+
+# ------------------------------------------------------------ the expert layer
+def _experts(rng, experts=8, d=16, f=12):
+    return [jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+            for s in ((d, experts), (experts, d, f), (experts, d, f),
+                      (experts, f, d))]
+
+
+def _sigmoid_loop(x, router_w, gate_w, up_w, down_w, k, bias, scale,
+                  shared=None):
+    """Every token's experts one at a time, in float64: chosen by score +
+    bias, weighed by score."""
+    x, router_w, gate_w, up_w, down_w, bias = [
+        np.asarray(a, np.float64) for a in (x, router_w, gate_w, up_w,
+                                            down_w, bias)]
+
+    def expert(row, gate, up, down):
+        g = row @ gate
+        return (g / (1 + np.exp(-g)) * (row @ up)) @ down
+    out, chosen_all = np.zeros_like(x), []
+    for t, row in enumerate(x):
+        s = 1 / (1 + np.exp(-(row @ router_w)))
+        chosen = np.argsort(-(s + bias), kind="stable")[:k]
+        chosen_all.append(sorted(chosen))
+        for e in chosen:
+            out[t] += scale * s[e] / (s[chosen].sum() + 1e-20) * expert(
+                row, gate_w[e], up_w[e], down_w[e])
+        if shared is not None:
+            out[t] += expert(row, *[np.asarray(a, np.float64)
+                                    for a in shared])
+    return out, chosen_all
+
+
+def test_the_router_chooses_by_score_plus_bias_and_weighs_by_score():
+    rng = np.random.default_rng(7)
+    router_w, gate_w, up_w, down_w = _experts(rng)
+    x = jnp.asarray(rng.normal(size=(21, 16)), jnp.float32)
+    none = jnp.zeros((8,), jnp.float32)
+    planted = none.at[3].set(5.0)           # expert 3: every token's choice
+
+    def layer(bias):
+        return moe_dropless(x, router_w, gate_w, up_w, down_w, 2,
+                            return_stats=True, scoring="sigmoid",
+                            choice_bias=bias, route_scale=2.0)
+    for bias in (none, planted):
+        out, stats = layer(bias)
+        want, chosen = _sigmoid_loop(x, router_w, gate_w, up_w, down_w, 2,
+                                     bias, 2.0)
+        np.testing.assert_allclose(out, want, atol=2e-5)
+        assert np.asarray(stats["expert_load"]).tolist() == [
+            sum(e in c for c in chosen) for e in range(8)]
+    assert int(layer(planted)[1]["expert_load"][3]) == 21
+    assert int(layer(none)[1]["expert_load"][3]) < 21       # a choice flipped
+    # ... and no weight: a bias that leaves every choice as it was (the
+    # same for all experts) leaves the output as it was
+    np.testing.assert_array_equal(layer(none + 0.25)[0], layer(none)[0])
+
+
+def test_the_shared_expert_is_added_once_and_is_not_a_route():
+    rng = np.random.default_rng(8)
+    router_w, gate_w, up_w, down_w = _experts(rng)
+    shared = [jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+              for s in ((16, 12), (16, 12), (12, 16))]
+    x = jnp.asarray(rng.normal(size=(9, 16)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=8) * 0.1, jnp.float32)
+    kw = dict(scoring="sigmoid", choice_bias=bias, route_scale=2.0,
+              return_stats=True)
+    with_shared, stats = moe_dropless(x, router_w, gate_w, up_w, down_w, 2,
+                                      shared=shared, **kw)
+    routed, routed_stats = moe_dropless(x, router_w, gate_w, up_w, down_w, 2,
+                                        **kw)
+    want, _ = _sigmoid_loop(x, router_w, gate_w, up_w, down_w, 2, bias, 2.0,
+                            shared)
+    np.testing.assert_allclose(with_shared, want, atol=2e-5)
+    gate = x @ shared[0]
+    np.testing.assert_allclose(
+        with_shared - routed,
+        (jax.nn.silu(gate) * (x @ shared[1])) @ shared[2], atol=2e-5)
+    assert np.array_equal(stats["expert_load"], routed_stats["expert_load"])
+    assert int(stats["expert_load"].sum()) == 9 * 2
+
+
+def test_the_dropless_layers_defaults_are_the_softmax_layer_as_it_was():
+    rng = np.random.default_rng(9)
+    router_w, gate_w, up_w, down_w = _experts(rng)
+    x = jnp.asarray(rng.normal(size=(11, 16)), jnp.float32)
+    plain = moe_dropless(x, router_w, gate_w, up_w, down_w, 2)
+    spelled = moe_dropless(x, router_w, gate_w, up_w, down_w, 2,
+                           scoring="softmax", choice_bias=None,
+                           route_scale=1.0, shared=None)
+    np.testing.assert_array_equal(plain, spelled)
+    probs = jax.nn.softmax(x @ router_w, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, 2)
+    want = np.zeros((11, 16), np.float32)
+    for t in range(11):
+        for p, e in zip(top_p[t] / top_p[t].sum(), top_e[t]):
+            want[t] += p * ((jax.nn.silu(x[t] @ gate_w[e])
+                             * (x[t] @ up_w[e])) @ down_w[e])
+    np.testing.assert_allclose(plain, want, atol=2e-5)
+    with pytest.raises(ValueError, match="no such scoring"):
+        moe_dropless(x, router_w, gate_w, up_w, down_w, 2, scoring="tanh")
+
+
+# -------------------------------------------------------------------- tallies
+def test_a_traced_call_says_which_path_every_forward_ran(model):
+    """Prefill runs the expanded path and counts the cached rows it
+    expands again; a decode step runs the absorbed one."""
+    telemetry.enable()
+    engine = GenerateEngine(model, model.make_cache(2, max_len=32,
+                                                    block_size=4),
+                            prefill_chunk=4, name="mla_tally")
+    before = {c: c.value(model="mla_tally") for c in (
+        cat.mla_absorbed_forwards, cat.mla_expanded_forwards,
+        cat.mla_expanded_rows)}
+    tracing.clear_spans()
+    with tracing.Span("test.call"):
+        engine.generate([_prompt(10), _prompt(6)], max_new_tokens=3)
+    mla = engine.last_stats["mla"]
+    # 9 tokens in chunks of 4 at lengths 0, 4, 8; 5 tokens at 0, 4
+    assert mla == {"absorbed_forwards": 3, "expanded_forwards": 5,
+                   "expanded_rows": 4 + 8 + 4}
+    moe = engine.last_stats["moe"]
+    assert moe["forwards"] == 8
+    # two expert layers: a step of 2 rows makes 2 x 2 routes in each
+    assert moe["routes"] == 2 * (5 * 4 * 2 + 3 * 2 * 2)
+    assert cat.mla_absorbed_forwards.value(model="mla_tally") \
+        - before[cat.mla_absorbed_forwards] == 3
+    assert cat.mla_expanded_forwards.value(model="mla_tally") \
+        - before[cat.mla_expanded_forwards] == 5
+    assert cat.mla_expanded_rows.value(model="mla_tally") \
+        - before[cat.mla_expanded_rows] == 16
+    paths = [s["mla_path"] for s in tracing.recent_spans()
+             if s["name"] == "lm.dispatch"]
+    assert paths == ["expanded"] * 5 + ["absorbed"] * 3
+
+
+def test_the_configuration_maps_every_published_width(model):
+    cfg = model.config
+    assert (cfg["q_rank"], cfg["kv_rank"], cfg["nope_dim"], cfg["rope_dim"],
+            cfg["v_dim"]) == (16, 16, 8, 8, 8)
+    shapes = mla_moe.mla_param_shapes(cfg)
+    assert set(shapes) == set(model.params)
+    assert all(tuple(model.params[n].shape) == s for n, s in shapes.items())
+    assert shapes["l0_gate_w"] == (32, 64)              # the dense layer
+    assert shapes["l1_gate_w"] == (8, 32, 16)           # an expert layer
+    assert shapes["l1_attn_hc_phi"] == (4 * 32, 24)
+    with pytest.raises(ValueError, match="missing 'kv_rank'"):
+        mla_moe.mla_config({k: v for k, v in cfg.items() if k != "kv_rank"})
+
+
+@pytest.mark.parametrize("rows,tile", [(40, 16), (256, 128), (300, 128)],
+                         ids=["decode_sized", "half_full", "ragged"])
+def test_the_grouped_product_takes_tall_tiles_where_the_groups_are_full(
+        rows, tile):
+    """A prefill chunk's routes fill their experts: tiles of 128 rows, the
+    MXU's height; a decode step's keep the 16-row tiles. Either way the
+    launch (interpret mode) is ``ragged_dot``."""
+    from incubator_mxnet_tpu.ops.pallas.grouped_matmul import (
+        grouped_matmul, tile_rows_for)
+    groups = 4
+    assert tile_rows_for(rows, groups) == tile
+    assert tile_rows_for(768, 128) == tile_rows_for(512, 128) == 16   # SDAR's
+    assert tile_rows_for(8192, 64) == 128 and tile_rows_for(64, 64) == 16
+    rng = np.random.default_rng(rows)
+    sizes = rng.multinomial(rows, [0.5, 0.0, 0.3, 0.2]).astype(np.int32)
+    x = jnp.asarray(rng.normal(size=(rows, 24)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(groups, 24, 128)), jnp.float32)
+    got = grouped_matmul(x, w, jnp.asarray(sizes), interpret=True)
+    want = jax.lax.ragged_dot(x, w, jnp.asarray(sizes))
+    np.testing.assert_allclose(got, want, atol=1e-4)

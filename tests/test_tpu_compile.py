@@ -181,6 +181,22 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n):
     assert "moe_grouped_matmul" in text
 
 
+@pytest.mark.parametrize("rows,k,n", [(8192, 3584, 1024), (8192, 1024, 3584),
+                                      (64, 3584, 1024)],
+                         ids=["up_prefill", "down_prefill", "up_step"])
+def test_grouped_matmul_of_full_groups_compiles_for_v5e(one_chip, rows, k, n):
+    """The latent-cache cell's products, 64 experts of 3584 x 1024: a
+    prefill chunk's 2,048 tokens x 4 routes fill the groups and take tiles
+    of 128 rows; a decode step's 16 x 4 keep the 16-row tiles."""
+    from incubator_mxnet_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda x, w, s: grouped_matmul(x, w, s, use_kernel=True),
+                    x, w, sizes)
+    assert "moe_grouped_matmul" in text
+
+
 def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch):
     """One layer of the cell's denoising forward (16 rows x 4 positions
     over 16-block tables of bfloat16 pools, published widths, the whole
@@ -207,6 +223,94 @@ def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch):
                     shape((16,), jnp.int32), shape((16, 16), jnp.int32),
                     pools, pools)
     assert text.count("moe_grouped_matmul") >= 3
+
+
+# -------------------------------------------------- the latent-cache decoder
+XING4_SLOTS, XING4_MAX_LEN, XING4_LAYERS = 16, 16896, 2
+
+
+def _xing4_programs(one_chip, monkeypatch):
+    """The cell's configuration at its published widths, cut for the
+    compile to one dense and one expert layer, as abstract arguments for
+    a described chip: -> (adapter over shapes, the forward's arguments
+    for tokens of a shape, the cache's pool shape)."""
+    from benchmarks import spec
+    from benchmarks.families import xing4
+    from incubator_mxnet_tpu.generate import MLAPagedLM
+    from incubator_mxnet_tpu.models import mla_moe
+    from incubator_mxnet_tpu.ops.pallas import grouped_matmul as gm
+    monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
+    cfg = spec.load_json(spec.ROOT + "/benchmarks/configs/xing4_29b_a4b.json")
+    program = dict(xing4.program_config(cfg), num_layers=XING4_LAYERS)
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    shapes = mla_moe.mla_param_shapes(mla_moe.mla_config(program))
+    model = MLAPagedLM({}, program)     # the weights are a call's argument
+    model.params = {n: shape(s) for n, s in shapes.items()}
+    blocks = XING4_MAX_LEN // 16
+    pool = shape((XING4_SLOTS * blocks, 16, 640))
+
+    def arguments(S, C):
+        return (shape((S, C), jnp.int32), shape((S,), jnp.int32),
+                shape((S, blocks), jnp.int32), [pool] * XING4_LAYERS)
+    return model, arguments, xing4.assumed(cfg, "prefill_chunk")
+
+
+def test_latent_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The cell's decode step, 16 rows of one token over 1,056-block
+    tables: the absorbed path, the grouped launch in the expert layer and
+    the head over the whole vocabulary."""
+    model, arguments, _chunk = _xing4_programs(one_chip, monkeypatch)
+    compiled = model.lower(*arguments(XING4_SLOTS, 1)).compile()
+    text = compiled.as_text()
+    assert text.count("moe_grouped_matmul") >= 3
+    # no per-head key or value of the whole table: the step reads rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_latent_prefill_chunk_fits_beside_the_weights_on_v5e(one_chip,
+                                                              monkeypatch):
+    """The prefill chunk the family sets, over a table of 16,384 cached
+    positions and more: no (C, L, 32) score array exists (4.3 GB at C =
+    2048), a tile's temporaries stay under 2 GB beside 8.1 GB of weights
+    and 1.7 GB of pools."""
+    model, arguments, chunk = _xing4_programs(one_chip, monkeypatch)
+    compiled = model.lower(*arguments(1, chunk), head="none").compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+    # a prefill's last layer stops at its cache rows: no expert product
+    assert "moe_grouped_matmul" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("chunk", [(16, 1), (1, 2048)],
+                         ids=["step", "prefill_chunk"])
+def test_the_latent_commit_copies_no_pool_on_v5e(one_chip, chunk):
+    """One entry a layer: the commit program of a latent cache takes the
+    pools, the rows and the positions, aliases every pool and copies
+    none. A row is 576 values in 640, five whole lane tiles (11 % of the
+    pools, 0.17 GB of the cell's 1.73): the chip holds that pool in row
+    order, and one of 576-wide rows with its BLOCKS minor, which every
+    forward would copy into row order to gather from."""
+    wide = jax.ShapeDtypeStruct((16 * XING4_MAX_LEN // 16, 16, 576),
+                                jnp.bfloat16, sharding=one_chip)
+    held = jax.jit(lambda p: p).lower(wide).compile().input_formats[0][0]
+    assert tuple(held.layout.major_to_minor) == (1, 2, 0)
+    from incubator_mxnet_tpu.generate.paged_kv import store_program_for
+    layers, pool = 5, (16 * XING4_MAX_LEN // 16, 16, 640)
+    kv = jax.ShapeDtypeStruct(pool, jnp.bfloat16, sharding=one_chip)
+    new = jax.ShapeDtypeStruct((layers,) + chunk + pool[2:], jnp.bfloat16,
+                               sharding=one_chip)
+    rows = jax.ShapeDtypeStruct(chunk, jnp.int32, sharding=one_chip)
+    held = jax.jit(lambda p: p).lower(kv).compile().input_formats[0][0]
+    assert tuple(held.layout.major_to_minor) == (0, 1, 2)
+    compiled = store_program_for(1).lower(
+        [kv] * layers, new, rows, ((0, 1, 2),) * layers).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= layers * kv.size * 2
+    assert memory.temp_size_in_bytes < 1 << 20
+    copies = re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
+    assert not [dims for dims in copies
+                if sorted(map(int, dims.split(","))) == sorted(pool)]
 
 
 # ------------------------------------------------- one whole BERT-base step
