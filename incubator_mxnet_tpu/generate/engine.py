@@ -24,8 +24,16 @@ rejected suffixes); the model adapter is a pure shape-cached forward.
 ``GPTPagedLM`` adapts ``models/gpt.py`` to that contract. The K/V pools
 live on the device (``paged_kv``): a forward is given them where it runs,
 returns the chunk's K and V as device arrays, and ``cache.commit`` stores
-those there; the host fetches only what it reads (the logits of a decode
-step; ``x0`` and its confidence; the expert loads).
+those there; the host fetches only what it reads (a greedy step's token
+ids, a sampled step's logits; ``x0`` and its confidence; the expert
+loads).
+
+Between two forwards of a greedy plain decode nothing is waited for
+(``_plain_loop``): the adapter's ``forward_token`` chooses the next token
+in the forward's program and leaves it on the device, the next forward
+is fed that array, and the host reads ids and expert loads ONE forward
+behind, after it has launched the next. A prompt's prefill chunks are
+read the same way (``prefill_slot``).
 
 The step itself is four functions over (adapter, cache, slots), the one
 implementation under this engine's loops and under ``serving.DecodeLoop``
@@ -102,14 +110,26 @@ def _dispatch(fn, params, args, programs=None, **attrs):
 
 
 def _fetch(arrays):
-    """`arrays` (what the caller reads of a forward's outputs) as host
-    arrays, under the forward's ``lm.fetch`` span: the wait for the
-    device, then the copies, all started before the first is waited for;
-    the span counts the bytes copied."""
+    """`arrays` (what the caller reads of a forward's outputs: a list or
+    a dict of them) as host arrays, under an ``lm.fetch`` span: the wait
+    for the device, then the copies, all started before the first is
+    waited for; the span counts the bytes copied."""
     with _tr.span("lm.fetch") as sp:
-        out = jax.device_get(list(arrays))
-        sp.set_attr("d2h_bytes", sum(a.nbytes for a in out))
+        out = jax.device_get(arrays)
+        sp.set_attr("d2h_bytes", sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(out)))
     return out
+
+
+def _fetch_behind(adapter, read, note):
+    """`read` (what a forward that fetched nothing left on the device for
+    the host: a dict of arrays, ``{}`` where there is no such forward) as
+    host arrays, fetched once a later forward is launched; `note` is
+    told (the expert loads' tally, one forward late)."""
+    host = _fetch(read)
+    if note is not None and host:
+        note(adapter, host)
+    return host
 
 
 def forward_slots(adapter, cache, slots, tokens, call=None, note=None):
@@ -117,9 +137,11 @@ def forward_slots(adapter, cache, slots, tokens, call=None, note=None):
     returns ``(logits, *new)``, `new` what the chunk adds to each cache
     entry (new_k, new_v), WITHOUT committing. `call`: another forward of
     the adapter's with the same arguments (the block loop's
-    ``forward_choice`` / ``forward_kv``). `note`: called with the adapter
-    after the forward (the engine's tallies of what it last did: an
-    expert layer's loads, a latent cache's attention path)."""
+    ``forward_choice`` / ``forward_kv``, the plain loop's
+    ``forward_token``); `tokens` may then be a device array. `note`:
+    called with the adapter after the forward (the engine's tallies of
+    what it last did: an expert layer's loads, a latent cache's attention
+    path)."""
     with _tr.span("kv.gather"):
         inputs = cache.forward_inputs(slots)
     out = (call or adapter.forward)(tokens, *inputs)
@@ -144,7 +166,11 @@ def commit_slots(cache, slots, *new_and_count):
 def step_slots(adapter, cache, slots, tokens, count=1, note=None):
     """Feed one token per slot ((S, 1)); commit what it adds to the cache
     (of the rows whose `count` is 1: a serving grid's active ones);
-    return the (S, V) next-token logits."""
+    return the (S, V) next-token logits, on the host: the caller chooses
+    from them (temperature sampling, a speculative round's draft,
+    ``serving.DecodeLoop``, which admits and retires rows a step). It
+    waits for the forward; a greedy ``GenerateEngine`` call does not take
+    it (``_plain_loop``)."""
     logits, *new = forward_slots(adapter, cache, slots, tokens, note=note)
     commit_slots(cache, slots, *new, count)
     return logits[:, -1]
@@ -157,15 +183,25 @@ def prefill_slot(adapter, cache, slot, tokens_1d, chunk, note=None):
     position's attention window and they are simply not committed; under
     a block mask the valid tokens are whole blocks, so the pads begin a
     later block). No logits are read: the adapter's ``forward_kv``, where
-    it has one, fetches none."""
+    it has one, fetches nothing, and what it leaves for the host (an
+    expert layer's loads) is read one chunk behind: chunk n's after chunk
+    n + 1 and its commit are launched, the last chunk's before returning.
+    The last commit is NOT waited for (``cache.sync``)."""
     call = getattr(adapter, "forward_kv", None)
+    behind = None
     for start in range(0, len(tokens_1d), chunk):
         piece = tokens_1d[start:start + chunk]
         padded = np.zeros((1, chunk), np.int32)
         padded[0, :len(piece)] = piece
-        _logits, *new = forward_slots(adapter, cache, [slot], padded, call,
-                                      note)
+        read, *new = forward_slots(adapter, cache, [slot], padded, call,
+                                   note)
         commit_slots(cache, [slot], *new, len(piece))
+        if call is not None:
+            if behind is not None:
+                _fetch_behind(adapter, behind, note)
+            behind = read
+    if behind is not None:
+        _fetch_behind(adapter, behind, note)
 
 
 class _PagedLM:
@@ -192,13 +228,26 @@ class GPTPagedLM(_PagedLM):
     a host array, new_k / new_v DEVICE arrays (layers, S, C, H, D) for
     ``cache.commit``, one array each: on the chip's host a launch costs
     some 50 us an output buffer, so the 2 x 48 per-layer arrays are
-    stacked in the program. ``forward_kv`` is the same program for a caller
-    that reads no logits (prefill) and fetches nothing. One XLA program
-    per (S, C) shape — the engine keeps shapes fixed (padded prefill
-    chunks, fixed spec width), so steady state is two programs: prefill
-    (S, chunk) and decode (S, 1) plus (1, k+1) for speculative verify.
+    stacked in the program.
 
-    ``programs``: (S, C) -> a shipped executable of the same program
+    Two more forwards of the same arguments fetch NOTHING and return
+    ``(read, new_k, new_v)``, `read` a dict of the device arrays the host
+    may fetch when it will (``engine._fetch_behind``):
+
+    - ``forward_token`` (the token head): ``read["token"]`` (S, 1) int32,
+      the argmax of the last chunk position's logits, taken in the
+      program on the logits ``forward`` returns; shaped as the next
+      step's `tokens`, which it may be given as (any forward takes
+      `tokens` as a host or a device array). The logits are no output of
+      that program;
+    - ``forward_kv`` (prefill): the ``forward`` program, ``read`` empty.
+
+    One XLA program per head and (S, C) shape — the engine keeps shapes
+    fixed (padded prefill chunks, fixed spec width), so steady state is
+    two programs: prefill (S, chunk) and decode (S, 1) plus (1, k+1) for
+    speculative verify.
+
+    ``programs``: (S, C) -> a shipped executable of ``forward``'s program
     (``lower(...)`` compiled), put there by an owner that ships them (the
     serving family's bind / warm grid) as ``cache.programs`` holds the
     commit's; a call prefers it to the jit and retires it when it refuses
@@ -217,32 +266,42 @@ class GPTPagedLM(_PagedLM):
             "kv", ((H, self.config["units"] // H), jnp.float32))
         self.programs = {}
 
-        def pure(params, tokens, lengths, tables, kps, vps):
-            logits, nk, nv = gpt_forward_paged(
-                params, self.config, tokens, lengths, tables, kps, vps,
-                use_kernel=use_kernel, interpret=interpret)
-            return logits, jnp.stack(nk), jnp.stack(nv)
-        self._fn = jax.jit(pure)
+        def program(head):
+            def pure(params, tokens, lengths, tables, kps, vps):
+                out, nk, nv = gpt_forward_paged(
+                    params, self.config, tokens, lengths, tables, kps, vps,
+                    use_kernel=use_kernel, interpret=interpret, head=head)
+                if head == "token":
+                    out = out[:, None]
+                return out, jnp.stack(nk), jnp.stack(nv)
+            return jax.jit(pure)
+        self._fns = {head: program(head) for head in ("logits", "token")}
 
-    def lower(self, tokens, lengths, tables, k_pools, v_pools):
-        """The one program lowered for arguments of these shapes: what an
-        owner compiles into ``programs``."""
-        return self._fn.lower(self.params, tokens, lengths, tables, k_pools,
-                              v_pools)
+    def lower(self, tokens, lengths, tables, k_pools, v_pools,
+              head="logits"):
+        """A forward's program lowered for arguments of these shapes:
+        ``forward``'s is what an owner compiles into ``programs``."""
+        return self._fns[head].lower(self.params, tokens, lengths, tables,
+                                     k_pools, v_pools)
 
     def forward(self, tokens, lengths, tables, k_pools, v_pools):
         logits, nk, nv = _dispatch(
-            self._fn, self.params,
+            self._fns["logits"], self.params,
             (tokens, lengths, tables, k_pools, v_pools), self.programs)
         (logits,) = _fetch([logits])
         return logits, nk, nv
 
+    def forward_token(self, tokens, lengths, tables, k_pools, v_pools):
+        token, nk, nv = _dispatch(
+            self._fns["token"], self.params,
+            (tokens, lengths, tables, k_pools, v_pools))
+        return {"token": token}, nk, nv
+
     def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
         _logits, nk, nv = _dispatch(
-            self._fn, self.params,
+            self._fns["logits"], self.params,
             (tokens, lengths, tables, k_pools, v_pools), self.programs)
-        _fetch([])
-        return None, nk, nv
+        return {}, nk, nv
 
 
 class SDARPagedLM(_PagedLM):
@@ -261,14 +320,17 @@ class SDARPagedLM(_PagedLM):
       None, None)``, each (S, C), the argmax token and its softmax
       probability computed on the device; the program returns no K and
       V, as nothing is stored;
-    - ``forward_kv`` — prefill and a block's store pass: ``(None, new_k,
-      new_v)``, no final norm, no head.
+    - ``forward_kv`` — prefill and a block's store pass: ``(read, new_k,
+      new_v)``, no final norm, no head, and nothing fetched:
+      ``read["expert_loads"]`` stays on the device for the host to fetch
+      when it will (``engine._fetch_behind``).
 
     Tokens, lengths and tables are host arrays, the pools the cache's
     device arrays; logits, ``x0`` and confidence come back as host
     arrays, new_k / new_v stay on the device, (layers, S, C, Hkv, D) each,
-    for ``cache.commit``. After every forward ``last_expert_loads`` holds
-    the (layers, experts) routes each expert got, on the host.
+    for ``cache.commit``. After a forward that fetched,
+    ``last_expert_loads`` holds the (layers, experts) routes each expert
+    got, on the host; after ``forward_kv`` None.
     """
 
     def __init__(self, params, config, dtype="bfloat16"):
@@ -301,27 +363,27 @@ class SDARPagedLM(_PagedLM):
                      for head in ("logits", "choice", "none")}
 
     def _call(self, head, *args):
-        """One forward -> (the head's outputs as host arrays, (new_k,
-        new_v) on the device or None); the expert loads are fetched with
-        the former and kept for the engine to count."""
-        read, kv = _dispatch(self._fns[head], self.params, args)
-        *read, self.last_expert_loads = _fetch(read)
-        return read, kv
+        """One forward, nothing fetched -> (the head's outputs then the
+        expert loads, (new_k, new_v) or None), all on the device."""
+        self.last_expert_loads = None
+        return _dispatch(self._fns[head], self.params, args)
 
     def forward(self, tokens, lengths, tables, k_pools, v_pools):
-        (logits,), (nk, nv) = self._call("logits", tokens, lengths, tables,
-                                         k_pools, v_pools)
+        read, (nk, nv) = self._call("logits", tokens, lengths, tables,
+                                    k_pools, v_pools)
+        logits, self.last_expert_loads = _fetch(read)
         return logits, nk, nv
 
     def forward_choice(self, tokens, lengths, tables, k_pools, v_pools):
-        choice, _none = self._call("choice", tokens, lengths, tables,
-                                   k_pools, v_pools)
-        return tuple(choice), None, None
+        read, _none = self._call("choice", tokens, lengths, tables,
+                                 k_pools, v_pools)
+        x0, confidence, self.last_expert_loads = _fetch(read)
+        return (x0, confidence), None, None
 
     def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
-        _read, (nk, nv) = self._call("none", tokens, lengths, tables,
-                                     k_pools, v_pools)
-        return None, nk, nv
+        (loads,), (nk, nv) = self._call("none", tokens, lengths, tables,
+                                        k_pools, v_pools)
+        return {"expert_loads": loads}, nk, nv
 
 
 class MLAPagedLM(_PagedLM):
@@ -338,18 +400,23 @@ class MLAPagedLM(_PagedLM):
 
     - ``forward`` — ``(logits (S, C, V), new_rows)``, the logits a host
       array;
-    - ``forward_kv`` — prefill: ``(None, new_rows)``, no final norm, no
-      head (a 2048-position chunk's logits over a 131,072-wide vocabulary
-      would be 1 GB).
+    - ``forward_token`` — the token head, as ``GPTPagedLM``'s: ``(read,
+      new_rows)`` with ``read["token"]`` (S, 1) int32 and
+      ``read["expert_loads"]`` left on the device, nothing fetched (the
+      (16, 131,072) logits of a step, 8.4 MB, are no output);
+    - ``forward_kv`` — prefill: ``(read, new_rows)``, the loads alone
+      left on the device, no final norm, no head (a 2048-position
+      chunk's logits over a 131,072-wide vocabulary would be 1 GB).
 
-    Tokens, lengths and tables are host arrays, the pools the cache's
-    device arrays; new_rows stays on the device, (layers, S, C,
-    cache_row_width), for ``cache.commit``. After every forward
-    ``last_expert_loads`` holds the (expert layers, experts) routes each
-    routed expert got, on the host, and ``last_latent_path`` which
-    attention path the chunk's width chose and the cached rows it
-    expanded again: ``("absorbed", 0)`` for a decode step, ``("expanded",
-    the sequences' committed lengths summed)`` for any wider chunk.
+    Lengths and tables are host arrays, tokens a host or a device array,
+    the pools the cache's device arrays; new_rows stays on the device,
+    (layers, S, C, cache_row_width), for ``cache.commit``. After a forward
+    that fetched, ``last_expert_loads`` holds the (expert layers, experts)
+    routes each routed expert got, on the host (None after one that did
+    not), and after every forward ``last_latent_path`` which attention
+    path the chunk's width chose and the cached rows it expanded again:
+    ``("absorbed", 0)`` for a decode step, ``("expanded", the sequences'
+    committed lengths summed)`` for any wider chunk.
     """
 
     def __init__(self, params, config, dtype="bfloat16"):
@@ -370,10 +437,13 @@ class MLAPagedLM(_PagedLM):
                     params, self.config, tokens, lengths, tables, pools,
                     head=head)
                 # -> (what the host reads, what stays on the device)
+                if head == "token":
+                    out = out[:, None]
                 return ((loads,) if out is None else (out, loads),
                         jnp.stack(rows))
             return jax.jit(pure)
-        self._fns = {head: program(head) for head in ("logits", "none")}
+        self._fns = {head: program(head)
+                     for head in ("logits", "token", "none")}
 
     def lower(self, tokens, lengths, tables, pools, head="logits"):
         """A forward's program lowered for arguments of these shapes."""
@@ -381,23 +451,30 @@ class MLAPagedLM(_PagedLM):
                                      pools)
 
     def _call(self, head, tokens, lengths, tables, pools):
+        """One forward, nothing fetched -> (the head's outputs then the
+        expert loads, new_rows), all on the device."""
         path = latent_path(tokens.shape[1])
         read, rows = _dispatch(self._fns[head], self.params,
                                (tokens, lengths, tables, pools),
                                mla_path=path)
-        *read, self.last_expert_loads = _fetch(read)
+        self.last_expert_loads = None
         self.last_latent_path = (
             path, int(np.sum(lengths)) if path == "expanded" else 0)
         return read, rows
 
     def forward(self, tokens, lengths, tables, pools):
-        (logits,), rows = self._call("logits", tokens, lengths, tables,
-                                     pools)
+        read, rows = self._call("logits", tokens, lengths, tables, pools)
+        logits, self.last_expert_loads = _fetch(read)
         return logits, rows
 
+    def forward_token(self, tokens, lengths, tables, pools):
+        (token, loads), rows = self._call("token", tokens, lengths, tables,
+                                          pools)
+        return {"token": token, "expert_loads": loads}, rows
+
     def forward_kv(self, tokens, lengths, tables, pools):
-        _read, rows = self._call("none", tokens, lengths, tables, pools)
-        return None, rows
+        (loads,), rows = self._call("none", tokens, lengths, tables, pools)
+        return {"expert_loads": loads}, rows
 
 
 class GenerateEngine:
@@ -408,6 +485,18 @@ class GenerateEngine:
     speculative decoding (greedy only — temperature sampling with a
     draft raises, the acceptance rule here is the deterministic
     argmax-match variant).
+
+    A model with a token head (``forward_token``) is decoded greedily
+    without a wait between two forwards (``_plain_loop``): the forward
+    chooses the next token in its program, the next step is fed that
+    device array, and the host reads ids and expert loads one forward
+    behind. Chosen from what the engine sees (`temperature` <= 0, no
+    speculative rounds, no ``block_length``, the head), by no flag.
+    Temperature sampling, speculative rounds, the block loop and
+    ``serving.DecodeLoop`` read a forward's own outputs and wait for it.
+    Every wait for the device lies inside a timed region, so
+    ``last_stats["prefill_seconds"]`` + ``["decode_seconds"]`` is the
+    call's time, and the device's.
 
     A model that declares ``block_length`` is decoded by blocks
     (``_block_loop``): ``denoise_steps`` forwards a block at most
@@ -463,21 +552,27 @@ class GenerateEngine:
                       or hasattr(model, "last_latent_path") else None)
 
     # ---------------------------------------------------------- plumbing
-    def _note_forward(self, model):
-        """What `model`'s forward just made says of itself, into this
-        call's ``last_stats``: ``last_latent_path`` (the attention path
-        over a latent cache and the cached rows it expanded) into
-        ``"mla"``, ``last_expert_loads`` (layers, experts: the routes each
-        expert got) into ``"moe"``."""
-        path = getattr(model, "last_latent_path", None)
-        if path is not None:
-            mla = self._tallies["mla"]
-            mla[path[0] + "_forwards"] += 1
-            mla["expanded_rows"] += path[1]
-            (_cat.mla_absorbed_forwards if path[0] == "absorbed"
-             else _cat.mla_expanded_forwards).inc(model=self.name)
-            _cat.mla_expanded_rows.inc(path[1], model=self.name)
-        loads = getattr(model, "last_expert_loads", None)
+    def _note_forward(self, model, read=None):
+        """What a forward of `model`'s says of itself, into this call's
+        ``last_stats``. Right after it (`read` None): ``last_latent_path``
+        (the attention path over a latent cache and the cached rows it
+        expanded) into ``"mla"``, and ``last_expert_loads`` (layers,
+        experts: the routes each expert got) into ``"moe"`` if the forward
+        fetched them. One that fetched nothing left them on the device:
+        they are tallied from `read`, its outputs as the host fetched
+        them a forward later."""
+        if read is None:
+            path = getattr(model, "last_latent_path", None)
+            if path is not None:
+                mla = self._tallies["mla"]
+                mla[path[0] + "_forwards"] += 1
+                mla["expanded_rows"] += path[1]
+                (_cat.mla_absorbed_forwards if path[0] == "absorbed"
+                 else _cat.mla_expanded_forwards).inc(model=self.name)
+                _cat.mla_expanded_rows.inc(path[1], model=self.name)
+            loads = getattr(model, "last_expert_loads", None)
+        else:
+            loads = read.get("expert_loads")
         if loads is None:
             return
         moe = self._tallies["moe"]
@@ -551,7 +646,9 @@ class GenerateEngine:
             # that one reading.
             # A block model prefills the prompt's WHOLE blocks, with the
             # forward that skips the head; the tail opens the first
-            # generated block.
+            # generated block. A prefill waits for no commit, so the last
+            # region waits for the last: the device's time for the prompts
+            # lies inside the regions that launched it.
             B = self.block_length
             for s in seqs:
                 n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
@@ -567,6 +664,11 @@ class GenerateEngine:
                                          s["dslot"], s["ctx"][:n],
                                          self.prefill_chunk)
                         stats["prefill_tokens"] += n
+                    if s is seqs[-1]:
+                        with _tr.span("kv.sync"):
+                            self.cache.sync()
+                            if self.draft is not None:
+                                self.draft_cache.sync()
                     dt = time.monotonic() - t0
                     sp.set_duration(dt)
                 stats["prefill_seconds"] += dt
@@ -597,33 +699,90 @@ class GenerateEngine:
     # ------------------------------------------------------ plain decode
     def _plain_loop(self, seqs, max_new_tokens, eos_id, stats):
         """Batched autoregressive decode: one (S, 1) forward per step
-        over the still-active rows."""
+        over the still-active rows.
+
+        A greedy call over an adapter with a token head waits for nothing
+        between two forwards. The forward chooses each row's next token
+        in its program (``forward_token``) and the next step is fed that
+        DEVICE array; the host fetches a forward's ids and expert loads
+        one forward behind, after the next forward and its commit are
+        launched, and only then appends them, tests `eos_id` and tallies
+        the loads. A step after which the rows change (one has its
+        ``max_new_tokens``, one was found to have emitted `eos_id`, none
+        is left) fetches its own ids too before its region closes, and
+        the step after it is fed from the host: the one wait, inside a
+        timed region as every wait is. A row found to have stopped at
+        step n was fed once more by then: that token is dropped, and its
+        cache row lies under ``max_len`` (the row stopped short of
+        ``max_new_tokens``; one that has them is never launched again).
+
+        Temperature sampling draws from the logits on the host
+        (``_sample``, the engine's ``RandomState`` stream), as does an
+        adapter without the head: a step then waits for its forward.
+
+        ``stats`` gains ``decode_steps`` and ``decode_steps_fed_on_device``
+        (of a call with no stop token: all but the first)."""
+        on_device = (self.temperature <= 0
+                     and hasattr(self.model, "forward_token"))
+        stats["decode_steps"] = stats["decode_steps_fed_on_device"] = 0
+
+        def take(rows, tokens):
+            """`tokens`: one a row of `rows`, for those still going."""
+            taken = 0
+            for s, tok in zip(rows, tokens):
+                if s["done"]:
+                    continue
+                s["ctx"].append(tok)
+                s["out"].append(tok)
+                taken += 1
+                if tok == eos_id or len(s["out"]) >= max_new_tokens:
+                    s["done"] = True
+            stats["decode_tokens"] += taken
+            return taken
+
+        def ids_of(read):
+            host = _fetch_behind(self.model, read, self._note)
+            return host["token"].ravel().tolist() if host else []
+
+        live, read = [], {}     # the last forward's rows, its ids if unread
         while True:
-            live = [s for s in seqs if not s["done"]]
-            if not live:
-                return
+            if read:    # the same rows, fed what their forward chose
+                tokens, fed = read["token"], "device"
+            else:
+                live = [s for s in seqs if not s["done"]]
+                if not live:
+                    return
+                tokens, fed = np.asarray([[s["ctx"][-1]] for s in live],
+                                         np.int32), "host"
+            slots = [s["slot"] for s in live]
             with _tr.span("gen.decode_step", model=self.name,
-                          rows=len(live)) as sp:
+                          rows=len(live), fed=fed) as sp:
                 t0 = time.monotonic()
-                tokens = np.asarray([[s["ctx"][-1]] for s in live],
-                                    np.int32)
-                logits = step_slots(self.model, self.cache,
-                                    [s["slot"] for s in live], tokens,
-                                    note=self._note)
-                committed = 0
-                for row, s in enumerate(live):
-                    tok = self._sample(logits[row])
-                    s["ctx"].append(tok)
-                    s["out"].append(tok)
-                    stats["decode_tokens"] += 1
-                    committed += 1
-                    if tok == eos_id or len(s["out"]) >= max_new_tokens:
-                        s["done"] = True
+                if on_device:
+                    behind = read
+                    read, *new = forward_slots(
+                        self.model, self.cache, slots, tokens,
+                        self.model.forward_token, self._note)
+                    commit_slots(self.cache, slots, *new, 1)
+                    committed = take(live, ids_of(behind))
+                    # with the token in flight: do the same rows go on?
+                    if any(s["done"] or len(s["out"]) + 1 >= max_new_tokens
+                           for s in live):
+                        committed += take(live, ids_of(read))
+                        read = {}
+                else:
+                    logits = step_slots(self.model, self.cache, slots,
+                                        tokens, note=self._note)
+                    committed = take(live, [self._sample(row)
+                                            for row in logits])
                 sp.set_attr("tokens_committed", committed)
                 dt = time.monotonic() - t0
                 sp.set_duration(dt)
             stats["decode_seconds"] += dt
+            stats["decode_steps"] += 1
+            stats["decode_steps_fed_on_device"] += fed == "device"
             _cat.gen_decode_seconds.observe(dt, model=self.name)
+            _cat.gen_decode_steps.inc(model=self.name, fed=fed)
 
     # ------------------------------------------------------ block decode
     @staticmethod
@@ -706,10 +865,11 @@ class GenerateEngine:
                 with _tr.span("gen.block_store", model=self.name,
                               rows=rows) as sp:
                     t1 = time.monotonic()
-                    _out, *new = forward_slots(
+                    read, *new = forward_slots(
                         self.model, self.cache, slots, tokens,
                         self.model.forward_kv, self._note)
                     commit_slots(self.cache, slots, *new, B)
+                    _fetch_behind(self.model, read, self._note)
                     sp.set_duration(time.monotonic() - t1)
                 stats["block_forwards"]["store"] += 1
                 stats["block_row_forwards"] += rows

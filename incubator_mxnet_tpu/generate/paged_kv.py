@@ -37,7 +37,7 @@ The paged extras feed the flash-decode kernel:
 - ``commit(slots, *new, count)`` — store what a forward produced, entry
   by entry in the same order (``commit(slots, new_k, new_v, count)``;
   ``lower_commit``: that program lowered, for an owner that ships
-  executables),
+  executables; ``sync()`` waits for the commits launched so far),
 - ``tables_array(slots)`` — an ``(S, max_blocks_per_slot)`` int32 block
   table, padded with block 0 (padded fetches are masked by ``lengths``
   so any valid pool row is safe),
@@ -434,6 +434,13 @@ class PagedKVCache:
         return store_program_for(len(pools)).lower(
             *pools, *new, jax.ShapeDtypeStruct(new[0].shape[1:3], np.int32),
             orders)
+
+    def sync(self):
+        """Wait until every commit launched so far has stored its rows
+        (and so every forward before it has run): `commit` itself waits
+        for nothing."""
+        pools, _orders = self._layer_pools()
+        jax.block_until_ready(pools)
 
     def advance(self, slot):
         self._check(slot)

@@ -135,7 +135,7 @@ def gpt_logits(params, cfg, tokens):
 
 def gpt_forward_paged(params, cfg, tokens, lengths, block_tables,
                       k_pools, v_pools, use_kernel=False,
-                      interpret=False):
+                      interpret=False, head="logits"):
     """Incremental decode forward over the paged KV cache.
 
     tokens (S, C) int32 — C new tokens per slot (C=1 decode, C>1
@@ -149,7 +149,14 @@ def gpt_forward_paged(params, cfg, tokens, lengths, block_tables,
     at ``max_len - 1`` so an over-length feed cannot index out of the
     position table (the cache's own max_len guard fires first in
     practice).
+
+    ``head="token"`` returns in the logits' place the greedy choice,
+    (S,) int32: the argmax of the last chunk position's logits (the
+    first index on a tie, as ``np.argmax``), so the (S, C, V) array is
+    no output of the program.
     """
+    if head not in ("logits", "token"):
+        raise ValueError("no such head: %r" % (head,))
     cfg = gpt_config(cfg)
     S, C = tokens.shape
     H = cfg["num_heads"]
@@ -175,7 +182,10 @@ def gpt_forward_paged(params, cfg, tokens, lengths, block_tables,
         h2 = _ln(x, params[p + "ln2_g"], params[p + "ln2_b"])
         x = x + _ffn(h2.reshape(S * C, d), params, p, cfg).reshape(S, C, d)
     x = _ln(x, params["lnf_g"], params["lnf_b"])
-    return x @ params["wte"].T, new_k, new_v
+    logits = x @ params["wte"].T
+    if head == "token":
+        logits = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    return logits, new_k, new_v
 
 
 class GPTDecoder(HybridBlock):

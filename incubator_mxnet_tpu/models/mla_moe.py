@@ -267,8 +267,11 @@ def mla_forward_paged(params, cfg, tokens, lengths, block_tables, pools,
     cache_row_width), the chunk's cache rows for the caller to commit; ``loads``
     (expert layers, E) int32, the routes each routed expert got in this
     forward. `head`: ``"logits"`` -> out (S, C, V) float32, position c's
-    logits choose token c + 1; ``"none"`` -> None: a forward run for its
-    rows alone (prefill) skips the final norm and the head.
+    logits choose token c + 1; ``"token"`` -> (S,) int32, the argmax of
+    the last chunk position's logits (the first index on a tie, as
+    ``np.argmax``), so the (S, C, V) array is no output of the program;
+    ``"none"`` -> None: a forward run for its rows alone (prefill) skips
+    the final norm and the head.
 
     A chunk of one position runs the absorbed attention path, any wider
     chunk the expanded one (``ops/pallas/paged_latent.py``)."""
@@ -300,9 +303,12 @@ def mla_forward_paged(params, cfg, tokens, lengths, block_tables, pools,
              else jnp.zeros((0, cfg["num_experts"]), jnp.int32))
     if head == "none":
         return None, new_rows, loads
-    if head != "logits":
+    if head not in ("logits", "token"):
         raise ValueError("no such head: %r" % (head,))
     x = jnp.sum(X.astype(jnp.float32), axis=0).astype(X.dtype)
     logits = jnp.dot(_rmsnorm(x, params["final_norm"], cfg["rms_eps"]),
                      params["head"], preferred_element_type=jnp.float32)
-    return logits.reshape(S, C, -1), new_rows, loads
+    logits = logits.reshape(S, C, -1)
+    if head == "token":
+        logits = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    return logits, new_rows, loads

@@ -281,6 +281,13 @@ gen_decode_seconds = _m.histogram(
     "mxtpu_gen_decode_seconds",
     "Decode-phase wall time per engine step by model (one plain step "
     "or one speculative propose+verify round)")
+gen_decode_steps = _m.counter(
+    "mxtpu_gen_decode_steps_total",
+    "Plain decode steps by model and by where the step's tokens came "
+    "from (fed=device: the array the forward before chose them into, "
+    "nothing waited for | host: built from the sequences, after a wait "
+    "— a call's first step, one after the live rows changed, every "
+    "sampled step)")
 gen_tokens_committed = _m.counter(
     "mxtpu_gen_tokens_committed_total",
     "Tokens committed to sequences by model and phase (prefill|decode) "

@@ -48,8 +48,32 @@ THREE_CONFIGURATIONS = (
     "test_the_real_benchmark_as_it_stands_with_the_block_cell")
 
 
+# Two more (closed to a perf_opt PR) assert what ISSUE 34 ended: a greedy
+# token chosen on the host. One plants its fault by patching
+# ``GenerateEngine._sample``, which a greedy call no longer goes through
+# (the forward's program chooses the token); the other counts a token a
+# row among the bytes a decode step ships, and a step is now fed the
+# device array the forward before left. All else they asserted is
+# asserted by tests/test_generate.py::test_an_altered_token_at_the_token_
+# heads_output_fails_the_toy_cell and ::test_a_traced_toy_latent_run_
+# reports_every_layer_a_cpu_can_read. Strict: once a benchmark PR plants
+# the fault at the program's output and drops the token's 4 B from the
+# expected bytes they pass, these marks fail the run, and the marks go.
+TOKEN_CHOSEN_ON_THE_HOST = (
+    "tests/benchmark/test_benchmark_harness.py::"
+    "test_a_fault_under_the_timed_path_turns_correct_false[token_altered]",
+    "tests/benchmark/test_benchmark_latent.py::"
+    "test_a_traced_run_reports_every_layer_a_cpu_can_read")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(TOKEN_CHOSEN_ON_THE_HOST):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="asserts that a greedy token is chosen on the host "
+                       "and shipped to the next step (ISSUE 34); PERF.md "
+                       "section 7 items 15, 16"))
         if item.nodeid.endswith(POOLS_CROSS_IN_EVERY_STEP):
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,

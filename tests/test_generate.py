@@ -616,12 +616,15 @@ def test_engine_prefill_chunk_invariance(target_lm):
 
 def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
     """One generate call under a parent span: every forward leaves
-    kv.gather, lm.dispatch, lm.fetch and kv.commit as children of its
-    gen.prefill / gen.decode_step, at most 5 records a forward, and the
-    spans count what crosses where it crosses: lm.dispatch ships tokens,
-    lengths and tables and no pool (they live on the device), lm.fetch
-    copies back the logits of a decode step and nothing of a prefill
-    chunk, kv.commit says how many pool rows an entry it stored."""
+    kv.gather, lm.dispatch and kv.commit as children of its gen.prefill /
+    gen.decode_step and an lm.fetch for what the host read BEHIND it, at
+    most 5 records a forward, and the spans count what crosses where it
+    crosses: lm.dispatch ships lengths and tables, the tokens only where
+    the host feeds them (a prefill chunk, a call's first step), and no
+    pool; lm.fetch copies back the ids of the forward before (the last
+    step its own too) and nothing of a prefill chunk; kv.commit says how
+    many pool rows an entry it stored; the last prefill region waits for
+    the last commit (kv.sync)."""
     from incubator_mxnet_tpu.telemetry import tracing
     cache = target_lm.make_cache(2, max_len=64)
     eng = GenerateEngine(target_lm, cache, prefill_chunk=4)
@@ -632,30 +635,39 @@ def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
     assert [len(o) for o in out] == [3, 3]
     recs = tracing.recent_spans()
     by_id = {r["span_id"]: r for r in recs}
-    phases = ("kv.gather", "lm.dispatch", "lm.fetch", "kv.commit")
+    phases = ("kv.gather", "lm.dispatch", "kv.commit", "lm.fetch")
     forwards = [r for r in recs if r["name"] == "lm.dispatch"]
     # prefill: 5 tokens in chunks of 4 is two forwards, 2 tokens one;
     # decode: three steps of both rows
     assert len(forwards) == 3 + 3
-    assert len(recs) == 1 + 2 + 3 + 4 * len(forwards) <= 1 + 5 * len(forwards)
+    # the call, 2 prefills, 3 steps, 4 phases a forward, the last step's
+    # second fetch and the prefill's one wait
+    assert len(recs) == 1 + 2 + 3 + 4 * len(forwards) + 1 + 1 \
+        <= 1 + 5 * len(forwards) + 2
     for r in recs:
-        if r["name"] in phases:
+        if r["name"] in phases + ("kv.sync",):
             assert r["dur_us"] > 0
             assert by_id[r["parent_id"]]["name"] in ("gen.prefill",
                                                      "gen.decode_step")
         elif r["name"] != "test.call":
             assert r["parent_id"] == call.span_id
     steps = [r for r in recs if r["name"] == "gen.decode_step"]
-    for step in steps:
-        kids = {r["name"]: r for r in recs
-                if r.get("parent_id") == step["span_id"]}
-        assert tuple(kids) == phases            # in the order they ran
-        # tokens (2, 1), lengths (2,) and tables (2, 64 / 16), all int32
-        assert kids["lm.dispatch"]["h2d_bytes"] == 8 + 8 + 32
-        # the logits (2, 1, 29) float32, and no K or V
-        assert kids["lm.fetch"]["d2h_bytes"] == 2 * 29 * 4
-        assert kids["kv.commit"]["rows"] == 2
-        assert sum(k["dur_us"] for k in kids.values()) <= step["dur_us"]
+    assert [s["fed"] for s in steps] == ["host", "device", "device"]
+    for n, step in enumerate(steps):
+        kids = [r for r in recs if r.get("parent_id") == step["span_id"]]
+        # in the order they ran: the fetch comes after the launches
+        assert tuple(k["name"] for k in kids) \
+            == phases + ("lm.fetch",) * (n == 2)
+        # lengths (2,) and tables (2, 64 / 16), int32; the first step's
+        # tokens (2, 1) too
+        assert kids[1]["h2d_bytes"] == 8 * (n == 0) + 8 + 32
+        # ids (2, 1) int32 of the step before, the last step's own too:
+        # no logits (2, 1, 29), and no K or V
+        assert [k["d2h_bytes"] for k in kids[3:]] \
+            == [[0], [8], [8, 8]][n]
+        assert kids[2]["rows"] == 2
+        assert step["tokens_committed"] == [0, 2, 4][n]
+        assert sum(k["dur_us"] for k in kids) <= step["dur_us"]
     chunks = [r for r in recs
               if by_id.get(r.get("parent_id"), {}).get("name")
               == "gen.prefill"]
@@ -666,6 +678,8 @@ def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
     # tokens (1, 4), lengths (1,), tables (1, 4)
     assert {r["h2d_bytes"] for r in chunks if r["name"] == "lm.dispatch"} \
         == {16 + 4 + 16}
+    assert [by_id[r["parent_id"]]["slot"] for r in chunks
+            if r["name"] == "kv.sync"] == [1]       # the last prompt's
     # one reading a region: the spans' durations ARE last_stats' seconds
     assert sum(s["dur_us"] for s in steps) / 1e6 == pytest.approx(
         eng.last_stats["decode_seconds"], rel=1e-6)
@@ -681,7 +695,8 @@ def test_the_span_readers_split_a_decode_step_with_the_pools_on_the_device(
     bottleneck ISSUE 30 removed: tests/conftest.py marks it): the
     benchmark's own readers find all four parts of a step, each longer
     than 0, their medians sum to the median step within a half, and a
-    step ships less than 4,096 bytes."""
+    step ships less than 4,096 bytes: lengths and tables, and since ISSUE
+    34 no token (the median is over steps fed on the device)."""
     from benchmarks import span_metrics
     from incubator_mxnet_tpu.telemetry import tracing
     cache = target_lm.make_cache(4, max_len=64)
@@ -703,7 +718,7 @@ def test_the_span_readers_split_a_decode_step_with_the_pools_on_the_device(
     assert sum(parts) == pytest.approx(
         np.median([s["dur_us"] for s in steps]) / 1e3, rel=0.5)
     h2d = span_metrics.median_per_step(facts, ("lm.dispatch",), "h2d_bytes")
-    assert 0 < h2d == 16 + 16 + 64 < 4096
+    assert 0 < h2d == 16 + 64 < 4096
     pools = sum(cache.pool(n).nbytes for n in cache.spec)
     assert pools == 4 * 4 * 16 * 24 * 4 * 4 > h2d
 
@@ -789,6 +804,268 @@ def test_engine_eos_and_slot_release(target_lm):
     # telemetry: committed decode tokens account every generated token
     assert cat.gen_tokens_committed.value(model="gpt", phase="decode") \
         == len(out) + len(got)
+
+
+# ------------------------------------- the token head, read one forward behind
+_LAG_PROMPTS = [[3, 5, 7, 2, 11, 1, 4, 9, 8, 6, 2, 13, 12], [9, 8],
+                [4, 6, 1, 7, 7, 2, 5, 3, 10]]
+_LAG_NEW, _LAG_CHUNK = 12, 4
+
+
+@pytest.fixture(scope="module")
+def latent_lm():
+    """The ``xing4_0`` family at the toy size of the benchmark's tests: a
+    latent cache, one dense and two expert layers of 8 experts."""
+    from benchmarks import spec
+    from benchmarks.families import xing4
+    from benchmarks.reference import xing4 as reference
+    from incubator_mxnet_tpu.generate import MLAPagedLM
+    cfg = spec.load_json(os.path.join(
+        spec.ROOT, "tests", "benchmark", "configs", "xing4_tiny.json"))
+    return MLAPagedLM(reference.init_weights(cfg, 3),
+                      xing4.program_config(cfg), dtype="float32")
+
+
+@pytest.fixture(scope="module")
+def varied_lm():
+    """`target_lm`'s decoder with weights drawn four times as wide: its
+    rows do not all lock onto the same two tokens, so a stop token can
+    end one row and leave the others going."""
+    return GPTPagedLM(_params(_TCFG, 7, scale=0.2), _TCFG)
+
+
+@pytest.fixture(params=["gpt", "latent"])
+def lagged_lm(request, varied_lm, latent_lm):
+    return {"gpt": varied_lm, "latent": latent_lm}[request.param]
+
+
+def _logits_loop(lm, prompts, max_new_tokens, eos_id=None, max_len=64):
+    """Greedy decoding as it was before the token head, written out:
+    every step waits for ``adapter.forward``'s logits (``step_slots``)
+    and takes ``np.argmax`` on the host; a row stops at `eos_id` or at
+    `max_new_tokens` and the others go on."""
+    cache = lm.make_cache(len(prompts), max_len=max_len)
+    rows = [{"ctx": list(p), "out": [], "slot": cache.alloc()}
+            for p in prompts]
+    for r in rows:
+        prefill_slot(lm, cache, r["slot"], r["ctx"][:-1], _LAG_CHUNK)
+    while True:
+        live = [r for r in rows if len(r["out"]) < max_new_tokens
+                and r["out"][-1:] != [eos_id]]
+        if not live:
+            return [r["out"] for r in rows]
+        logits = step_slots(lm, cache, [r["slot"] for r in live],
+                            np.asarray([[r["ctx"][-1]] for r in live],
+                                       np.int32))
+        for r, row in zip(live, logits):
+            r["ctx"].append(int(np.argmax(row)))
+            r["out"].append(r["ctx"][-1])
+
+
+def _stops_one_row(out, lo, hi, rows=None):
+    """(row, index, token) of a token that no other row holds and whose
+    FIRST place in its row lies in [lo, hi): as `eos_id` it stops that
+    row there, not before, and no other row."""
+    for r in range(len(out)) if rows is None else rows:
+        for k in range(lo, hi):
+            tok = out[r][k]
+            if out[r].index(tok) == k and not any(
+                    tok in other for o, other in enumerate(out) if o != r):
+                return r, k, tok
+    raise AssertionError("no token of one row alone in [%d, %d)" % (lo, hi))
+
+
+@pytest.mark.parametrize("case", ["no_stop", "a_row_stops_midway",
+                                  "a_row_stops_at_its_last_step",
+                                  "the_cache_is_full"])
+def test_the_lagged_loop_returns_the_logits_loops_tokens(lagged_lm, case):
+    """`temperature` 0 over an adapter with the token head takes the loop
+    that reads one forward behind; its tokens are those of the loop that
+    waits for every forward's logits, whatever ends a row: nothing, a stop
+    token midway while the others go on (the row was fed once more by the
+    time the host saw it: that token never reaches `out`), a stop token at
+    the row's very last step, the cache's last position (prompt +
+    max_new_tokens == max_len: no row is stored past it)."""
+    free = _logits_loop(lagged_lm, _LAG_PROMPTS, _LAG_NEW)
+    new, eos, max_len = _LAG_NEW, None, 64
+    if case == "a_row_stops_midway":
+        row, at, eos = _stops_one_row(free, 2, _LAG_NEW - 2)
+    elif case == "a_row_stops_at_its_last_step":
+        row, at, eos = _stops_one_row(free, 3, _LAG_NEW)
+        new = at + 1
+    elif case == "the_cache_is_full":
+        # the longest prompt's row stops one step short of the count: it
+        # is launched once more, into the cache's last position
+        row, at, eos = _stops_one_row(free, 3, _LAG_NEW - 1, rows=[0])
+        new = at + 2
+        max_len = len(_LAG_PROMPTS[0]) + new
+    want = _logits_loop(lagged_lm, _LAG_PROMPTS, new, eos, max_len)
+    if eos is not None:
+        assert [len(w) for w in want] \
+            == [at + 1 if r == row else new for r in range(3)]
+    cache = lagged_lm.make_cache(3, max_len=max_len)
+    stored = []
+    commit = cache.commit
+
+    def watched(slots, *new_and_count):
+        commit(slots, *new_and_count)
+        stored.append(int(cache.lengths.max()))
+    cache.commit = watched
+    eng = GenerateEngine(lagged_lm, cache, prefill_chunk=_LAG_CHUNK)
+    got = eng.generate(_LAG_PROMPTS, max_new_tokens=new, eos_id=eos)
+    assert got == want
+    assert max(stored) <= max_len and cache.in_use == 0
+    if case == "the_cache_is_full":     # the forward too many took place
+        assert max(stored) == max_len - 1 == len(_LAG_PROMPTS[0]) + at + 1
+    st = eng.last_stats
+    assert st["decode_tokens"] == sum(map(len, got))
+    if eos is None:     # the same rows from the first step to the last
+        assert (st["decode_steps"], st["decode_steps_fed_on_device"]) \
+            == (new, new - 1)
+    else:               # a wait where the rows changed, and only there
+        assert 0 < st["decode_steps_fed_on_device"] < st["decode_steps"]
+    # temperature sampling keeps the logits path and the engine's stream
+    hot = GenerateEngine(lagged_lm, lagged_lm.make_cache(3, max_len=64),
+                         prefill_chunk=_LAG_CHUNK, temperature=0.8, seed=5)
+    again = GenerateEngine(lagged_lm, lagged_lm.make_cache(3, max_len=64),
+                           prefill_chunk=_LAG_CHUNK, temperature=0.8, seed=5)
+    assert hot.generate(_LAG_PROMPTS, 4) == again.generate(_LAG_PROMPTS, 4)
+    assert hot.last_stats["decode_steps_fed_on_device"] == 0
+
+
+def test_a_greedy_call_waits_for_nothing_between_two_forwards(lagged_lm):
+    """What a greedy call of the lagged loop leaves behind: every
+    gen.decode_step has the four phases as children, all steps but the
+    first are fed on the device (attribute, ``last_stats``, counter),
+    lm.fetch copies ids and expert loads and never a vocabulary's logits,
+    the expert layer and the latent cache count every forward as before
+    (one forward late), and the timed regions cover the call: no wait for
+    the device lies outside them."""
+    import time
+    from incubator_mxnet_tpu.telemetry import tracing
+    latent = hasattr(lagged_lm, "last_latent_path")
+    name = "latent" if latent else "gpt"
+    eng = GenerateEngine(lagged_lm, lagged_lm.make_cache(3, max_len=64),
+                         prefill_chunk=_LAG_CHUNK, name=name)
+    eng.generate(_LAG_PROMPTS, max_new_tokens=2)        # compiles
+    _met.reset()
+    shares = []
+    for _ in range(3):
+        tracing.clear_spans()
+        t0 = time.monotonic()
+        with tracing.Span("test.call"):
+            out = eng.generate(_LAG_PROMPTS, max_new_tokens=_LAG_NEW)
+        wall = time.monotonic() - t0
+        st = eng.last_stats
+        shares.append((st["prefill_seconds"] + st["decode_seconds"]) / wall)
+    assert [len(o) for o in out] == [_LAG_NEW] * 3
+    assert 0.9 < max(shares) <= 1.0
+    recs = tracing.recent_spans()
+    steps = [r for r in recs if r["name"] == "gen.decode_step"]
+    assert [s["fed"] for s in steps] == ["host"] + ["device"] * (_LAG_NEW - 1)
+    assert (st["decode_steps"], st["decode_steps_fed_on_device"]) \
+        == (_LAG_NEW, _LAG_NEW - 1)
+    assert cat.gen_decode_steps.value(model=name, fed="device") \
+        == 3 * (_LAG_NEW - 1)
+    assert cat.gen_decode_steps.value(model=name, fed="host") == 3
+    # ids (3, 1) int32 and, of an expert layer, the loads (2, 8) int32
+    behind = 3 * 4 + (2 * 8 * 4 if latent else 0)
+    vocab = lagged_lm.config["vocab_size"]
+    for n, step in enumerate(steps):
+        kids = [r for r in recs if r.get("parent_id") == step["span_id"]]
+        assert {k["name"] for k in kids} == {"kv.gather", "lm.dispatch",
+                                             "lm.fetch", "kv.commit"}
+        fetched = sum(k.get("d2h_bytes", 0) for k in kids)
+        assert fetched == behind * ((n > 0) + (n == _LAG_NEW - 1))
+        assert fetched < 3 * vocab * 4
+    # prompts of 13, 2 and 9 commit 12, 1 and 8 tokens in chunks of 4
+    chunks = 3 + 1 + 2
+    if latent:
+        assert st["moe"]["forwards"] == chunks + _LAG_NEW
+        assert len(st["moe"]["load_max_over_mean"]) == chunks + _LAG_NEW
+        # two expert layers, two routes a token: a prefill chunk's 4
+        # positions (pads too), a step's 3 rows
+        assert st["moe"]["routes"] == (chunks * 4 + _LAG_NEW * 3) * 2 * 2
+        assert st["mla"] == {"absorbed_forwards": _LAG_NEW,
+                             "expanded_forwards": chunks,
+                             "expanded_rows": 4 + 8 + 4}
+    else:
+        assert "moe" not in st and "mla" not in st
+
+
+# ---- what two closed tests under tests/benchmark/ asserted, with the token
+# ---- chosen in the forward's program (tests/conftest.py marks them)
+_BENCH_PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
+                "hbm_bytes": 1e10}
+_BENCH_SEED = 2 ** 31 + 77
+
+
+def test_an_altered_token_at_the_token_heads_output_fails_the_toy_cell(
+        monkeypatch):
+    """test_benchmark_harness.py's planted fault ``token_altered``, moved
+    to where a greedy token is now produced: one id in seven of the token
+    head's output altered (served, and fed on to the next step), through
+    the same ``drive`` of ``gpt2_tiny.generate_tiny``."""
+    from benchmarks import run, spec
+    forward_token = GPTPagedLM.forward_token
+    made = {"n": 0}
+
+    def faulty(self, *args):
+        read, nk, nv = forward_token(self, *args)
+        ids = np.array(read["token"])
+        for row in range(len(ids)):
+            made["n"] += 1
+            if made["n"] % 7 == 0:
+                ids[row] = (ids[row] + 1) % self.config["vocab_size"]
+        return dict(read, token=jnp.asarray(ids)), nk, nv
+    monkeypatch.setattr(GPTPagedLM, "forward_token", faulty)
+    bench = spec.load_json(os.path.join(spec.ROOT, "tests", "benchmark",
+                                        "BENCHMARK_tiny.json"))
+    result = run.drive(bench, "gpt2_tiny.generate_tiny", _BENCH_SEED, 1.0,
+                       False, jax.devices(), peaks=_BENCH_PEAKS)
+    assert made["n"] > 7
+    assert result["correct"] is False
+    assert any(row["value"] > row["limit"]
+               for row in result["compared"].values())
+
+
+def test_a_traced_toy_latent_run_reports_every_layer_a_cpu_can_read(
+        tmp_path):
+    """test_benchmark_latent.py's traced run, line for line, but for the
+    bytes a decode step ships: a length and a table a row, and no token
+    (the median is over steps fed on the device; a call's first step
+    still ships its tokens). The run keeps its trace under a root of its
+    own: the closed test still runs, in another worker perhaps, and
+    removes ``.bench_trace/<cell>`` of the checkout."""
+    from benchmarks import run, spec
+    for name in ("benchmarks", "tests"):
+        os.symlink(os.path.join(spec.ROOT, name), str(tmp_path / name))
+    cell = "xing4_tiny.generate_long_tiny"
+    bench = spec.load_json(os.path.join(spec.ROOT, "tests", "benchmark",
+                                        "BENCHMARK_latent_tiny.json"))
+    result = run.drive(bench, cell, _BENCH_SEED, 0.3, True, jax.devices(),
+                       root=str(tmp_path), peaks=_BENCH_PEAKS)
+    # a CPU has no device plane: idle share, peak memory and the expert
+    # products' roofline are left out, never reported as 0
+    assert set(result["metrics"]) == {
+        "prefill_share", "gen_mfu", "compile_s", "decode_step_p50_ms",
+        "gen_step_self_ms", "lm_dispatch_ms_per_step",
+        "lm_fetch_wait_ms_per_step", "kv_host_ms_per_step",
+        "kv_h2d_bytes_per_step", "moe_load_max_over_mean", "prefill_mfu",
+        "mla_expanded_rows_per_prompt_token"}
+    values = {k: v["value"] for k, v in result["metrics"].items()}
+    # prompts of 9, 12, 17 and 23 commit 8, 11, 16 and 22 tokens in chunks
+    # of 8: the chunks after a prompt's first find 8, 8 and 8 + 16 rows
+    assert values["mla_expanded_rows_per_prompt_token"] == pytest.approx(
+        40 / 57)
+    assert 0 < values["prefill_mfu"] < 100 and 0 < values["gen_mfu"] < 100
+    # a length and a table of 2 blocks of 16 a row, 4 B each
+    assert values["kv_h2d_bytes_per_step"] == 4 * (4 + 2 * 4)
+    assert values["decode_step_p50_ms"] > values["gen_step_self_ms"] > 0
+    assert values["lm_fetch_wait_ms_per_step"] > 0
+    assert values["moe_load_max_over_mean"] >= 1
+    assert result["correct"] is True
+    assert not os.path.exists(str(tmp_path / ".bench_trace" / cell))
 
 
 # ------------------------------------------- serving: accounting + loop

@@ -142,6 +142,49 @@ def test_atexit_flush_emits_trace_and_flight_dumps(tmp_path, monkeypatch):
         telemetry.disable()
 
 
+# ------------------------------------------- the decode steps' counter
+
+def test_decode_steps_are_counted_by_where_their_tokens_came_from():
+    """``mxtpu_gen_decode_steps_total``, labels `model` and `fed`: a
+    greedy call's first step is fed from the host and every other the
+    device array the forward before chose its tokens into; a sampled
+    step is always fed from the host. The series render with both
+    labels."""
+    import numpy as np
+    from incubator_mxnet_tpu.generate import GenerateEngine, GPTPagedLM
+    from incubator_mxnet_tpu.models.gpt import gpt_config, gpt_param_shapes
+    from incubator_mxnet_tpu.telemetry import catalog, export, metrics
+    cfg = gpt_config({"vocab_size": 29, "units": 24, "num_layers": 1,
+                      "num_heads": 2, "max_len": 32})
+    rng = np.random.RandomState(0)
+    lm = GPTPagedLM({n: (rng.randn(*s) * 0.05).astype(np.float32)
+                     for n, s in gpt_param_shapes(cfg).items()}, cfg)
+    telemetry.enable()
+    try:
+        metrics.reset()
+        steps = catalog.gen_decode_steps
+        assert steps.name == "mxtpu_gen_decode_steps_total"
+        assert "fed=device" in steps.help
+        GenerateEngine(lm, lm.make_cache(2, max_len=32), name="obs"
+                       ).generate([[3, 5, 7], [9, 8]], max_new_tokens=5)
+        assert steps.value(model="obs", fed="host") == 1
+        assert steps.value(model="obs", fed="device") == 4
+        GenerateEngine(lm, lm.make_cache(2, max_len=32), name="obs",
+                       temperature=0.7).generate([[3, 5, 7]],
+                                                 max_new_tokens=3)
+        assert steps.value(model="obs", fed="host") == 1 + 3
+        assert steps.value(model="obs", fed="device") == 4
+        assert sorted(steps.labels()) == [
+            (("fed", "device"), ("model", "obs")),
+            (("fed", "host"), ("model", "obs"))]
+        text = export.render_prometheus()
+        assert 'mxtpu_gen_decode_steps_total{fed="device",model="obs"} 4' \
+            in text
+    finally:
+        metrics.reset()
+        telemetry.disable()
+
+
 # --------------------------------------------------------------- debugz
 
 def test_debugz_endpoints_in_process():

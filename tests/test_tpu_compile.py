@@ -269,6 +269,52 @@ def test_latent_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def _gpt2_xl_step(one_chip):
+    """Cell 2's decode step: GPT-2-XL's widths cut to two layers, 4 rows
+    of one token over a cache of 96 positions, float32."""
+    from benchmarks import spec
+    from benchmarks.families import gpt2
+    from incubator_mxnet_tpu.generate import GPTPagedLM
+    from incubator_mxnet_tpu.models.gpt import gpt_param_shapes
+    cfg = spec.load_json(spec.ROOT + "/benchmarks/configs/gpt2_xl.json")
+    program = dict(gpt2.program_config(cfg), num_layers=2)
+
+    def shape(s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    model = GPTPagedLM({}, program)     # the weights are a call's argument
+    model.params = {n: shape(s)
+                    for n, s in gpt_param_shapes(model.config).items()}
+    pools = [shape((4 * 6, 16, 25, 64))] * 2
+    return model, (shape((4, 1), jnp.int32), shape((4,), jnp.int32),
+                   shape((4, 6), jnp.int32), pools, pools)
+
+
+@pytest.mark.parametrize("cell", ["gpt2_xl", "xing4"])
+def test_the_token_head_step_returns_no_vocabulary_on_v5e(one_chip,
+                                                          monkeypatch, cell):
+    """The decode steps of cells 2 and 5 with the token head: the program
+    compiles for the chip and returns the rows' ids, (S, 1) int32, and
+    what the chunk adds to the cache (the expert loads where there are
+    experts), and NO output as wide as the vocabulary: the logits (0.8 MB
+    and 8.4 MB a step) stay inside."""
+    if cell == "gpt2_xl":
+        model, arguments = _gpt2_xl_step(one_chip)
+    else:
+        model, of, _chunk = _xing4_programs(one_chip, monkeypatch)
+        arguments = of(XING4_SLOTS, 1)
+    rows, vocab = arguments[0].shape[0], model.config["vocab_size"]
+    lowered = model.lower(*arguments, head="token")
+    outputs = jax.tree_util.tree_leaves(lowered.out_info)
+    assert (rows, 1) in [o.shape for o in outputs]
+    assert not any(vocab in o.shape for o in outputs)
+    with_logits = jax.tree_util.tree_leaves(
+        model.lower(*arguments).out_info)
+    assert (rows, 1, vocab) in [o.shape for o in with_logits]
+    compiled = lowered.compile()
+    assert compiled.memory_analysis().output_size_in_bytes \
+        < rows * vocab * 4
+
+
 def test_latent_prefill_chunk_fits_beside_the_weights_on_v5e(one_chip,
                                                               monkeypatch):
     """The prefill chunk the family sets, over a table of 16,384 cached
