@@ -388,8 +388,10 @@ class SDARPagedLM(_PagedLM):
 
 class MLAPagedLM(_PagedLM):
     """Shape-cached jit adapter over ``mla_forward_paged``: the
-    latent-attention decoder of ``models/mla_moe.py``, served in `dtype`
-    (bfloat16: weights, activations and the cache).
+    latent-attention decoders of ``models/mla_moe.py`` (one residual
+    stream, or several hyper-connected ones; every expert held, or the
+    configuration's ``experts_held`` share of each expert layer), served in
+    `dtype` (bfloat16: weights, activations and the cache).
 
     Its cache holds ONE entry a layer, ``c``: a row of ``kv_rank +
     rope_dim`` values a position (the normalised latent and the rotated
@@ -403,17 +405,20 @@ class MLAPagedLM(_PagedLM):
     - ``forward_token`` — the token head, as ``GPTPagedLM``'s: ``(read,
       new_rows)`` with ``read["token"]`` (S, 1) int32 and
       ``read["expert_loads"]`` left on the device, nothing fetched (the
-      (16, 131,072) logits of a step, 8.4 MB, are no output);
+      (S, V) float32 logits of a step, megabytes, are no output);
     - ``forward_kv`` — prefill: ``(read, new_rows)``, the loads alone
-      left on the device, no final norm, no head (a 2048-position
-      chunk's logits over a 131,072-wide vocabulary would be 1 GB).
+      left on the device, no final norm, no head (a chunk's logits,
+      positions x V float32, can pass a gigabyte).
 
     Lengths and tables are host arrays, tokens a host or a device array,
     the pools the cache's device arrays; new_rows stays on the device,
     (layers, S, C, cache_row_width), for ``cache.commit``. After a forward
-    that fetched, ``last_expert_loads`` holds the (expert layers, experts)
-    routes each routed expert got, on the host (None after one that did
-    not), and after every forward ``last_latent_path`` which attention
+    that fetched, ``last_expert_loads`` holds the forward's loads on the
+    host (None after one that did not): (expert layers, experts), the
+    routes each routed expert got; where the configuration holds a share,
+    the routes of the experts HELD here and two columns more, a layer's
+    ``routes_elsewhere`` and ``rows_moved`` (``split_loads`` parts them);
+    and after every forward ``last_latent_path`` which attention
     path the chunk's width chose and the cached rows it expanded again:
     ``("absorbed", 0)`` for a decode step, ``("expanded", the sequences'
     committed lengths summed)`` for any wider chunk.
@@ -461,6 +466,19 @@ class MLAPagedLM(_PagedLM):
         self.last_latent_path = (
             path, int(np.sum(lengths)) if path == "expanded" else 0)
         return read, rows
+
+    def split_loads(self, loads):
+        """A forward's ``expert_loads`` as the host fetched them -> (the
+        routes each expert held here got (expert layers, experts held),
+        what a held share says of itself: ``{"routes_elsewhere",
+        "rows_moved"}`` summed over the layers, ``{}`` where every expert
+        is held)."""
+        held = self.config["experts_held"]
+        if held is None:
+            return loads, {}
+        return loads[:, :held[1]], {
+            "routes_elsewhere": int(loads[:, held[1]].sum()),
+            "rows_moved": int(loads[:, held[1] + 1].sum())}
 
     def forward(self, tokens, lengths, tables, pools):
         read, rows = self._call("logits", tokens, lengths, tables, pools)
@@ -561,6 +579,7 @@ class GenerateEngine:
         fetched them. One that fetched nothing left them on the device:
         they are tallied from `read`, its outputs as the host fetched
         them a forward later."""
+        split = getattr(model, "split_loads", None)
         if read is None:
             path = getattr(model, "last_latent_path", None)
             if path is not None:
@@ -575,16 +594,23 @@ class GenerateEngine:
             loads = read.get("expert_loads")
         if loads is None:
             return
+        loads, share = split(loads) if split else (loads, {})
         moe = self._tallies["moe"]
         routes, hit = int(loads.sum()), int((loads > 0).sum())
-        uneven = float(np.mean(loads.max(axis=1) / loads.mean(axis=1)))
         moe["forwards"] += 1
         moe["routes"] += routes
         moe["experts_hit"] += hit
-        moe["load_max_over_mean"].append(uneven)
         _cat.moe_routes.inc(routes, model=self.name)
         _cat.moe_experts_hit.inc(hit, model=self.name)
-        _cat.moe_load_max_over_mean.observe(uneven, model=self.name)
+        for key, count in share.items():
+            moe[key] = moe.get(key, 0) + count
+            getattr(_cat, "moe_" + key).inc(count, model=self.name)
+        # a share's layer may get no route at all: it has no fullest expert
+        routed = loads[loads.sum(axis=1) > 0]
+        if len(routed):
+            uneven = float(np.mean(routed.max(axis=1) / routed.mean(axis=1)))
+            moe["load_max_over_mean"].append(uneven)
+            _cat.moe_load_max_over_mean.observe(uneven, model=self.name)
 
     def _sample(self, logits_row):
         if self.temperature <= 0:

@@ -1,14 +1,23 @@
-"""A latent-attention decoder with hyper-connected residual streams and a
-sigmoid-scored expert layer behind leading dense layers.
+"""A latent-attention decoder with a sigmoid-scored expert layer behind
+leading dense layers, over one residual stream or several hyper-connected
+ones.
 
-The ``xing4_0`` family (XingChen-AGI Xing4.0-29B-A4B's ``config.json``):
 DeepSeek-V3's layer (multi-head latent attention with a low-rank query,
 YaRN rotary frequencies; sigmoid router scores with a choice bias, a shared
-expert, leading dense layers) with the one residual stream replaced by
-``n`` streams that every sublayer reads through, and writes back through,
-per-token mappings (manifold-constrained hyper-connections,
-arXiv:2512.24880). With ``X`` (n, d) a token's streams and ``Fn`` a
-sublayer (attention, or the feed-forward), each with maps of its own::
+expert, leading dense layers), for two families that the configuration
+tells apart:
+
+- ``streams`` absent or None (``kimi_k2``: moonshotai Kimi-K2's
+  ``config.json``): ONE residual stream, plain pre-norm, ``x <- x +
+  Fn(rmsnorm(x))`` around both sublayers, the final norm on the stream; no
+  ``hc_*`` leaves;
+- ``streams`` n (``xing4_0``: XingChen-AGI Xing4.0-29B-A4B's
+  ``config.json``): the one stream replaced by ``n`` streams that every
+  sublayer reads through, and writes back through, per-token mappings
+  (manifold-constrained hyper-connections, arXiv:2512.24880).
+
+With ``X`` (n, d) a token's streams and ``Fn`` a sublayer (attention, or
+the feed-forward), each with maps of its own::
 
     x      = vec(X) / sqrt(mean(vec(X)^2) + eps)                  (n d,)
     H_pre  = sigmoid(a_pre (x phi_pre) + b_pre)                   (n,)
@@ -29,6 +38,15 @@ path for a chunk, the absorbed path for a decode step). The feed-forward is a ga
 the first ``dense_layers`` layers and ``parallel.moe.moe_dropless`` after
 them (sigmoid scores, the top-k of score + bias, weights from the scores
 renormalised and scaled, a shared expert every token visits).
+
+``experts_held`` ``(first, count)``: this chip's share of every expert
+layer under expert parallelism. The router's leaves keep all
+``num_experts`` outputs, ``gate_w / up_w / down_w`` hold the `count` experts
+``[first, first + count)``, and a layer adds the routes that fall on those
+and the shared expert: its PART of the layer, which is what goes on to the
+next layer (``moe_dropless``'s ``held``; no exchange, no stand-in for the
+other chips). Embedding and head are as tall as ``vocab_size`` says: a
+slice of a vocabulary is a smaller vocabulary.
 
 One layer body, one forward (:func:`mla_forward_paged`, the contract of
 ``gpt_forward_paged`` over a latent cache). The full-sequence float32
@@ -55,7 +73,7 @@ __all__ = ["mla_config", "mla_param_shapes", "mla_forward_paged",
 _REQUIRED = ("vocab_size", "units", "num_layers", "num_heads", "q_rank",
              "kv_rank", "nope_dim", "rope_dim", "v_dim", "dense_layers",
              "dense_hidden", "num_experts", "experts_per_token",
-             "expert_hidden", "streams")
+             "expert_hidden")
 
 
 def mla_config(config):
@@ -65,6 +83,10 @@ def mla_config(config):
     for key in _REQUIRED:
         if key not in cfg:
             raise ValueError("mla_moe config missing %r" % key)
+    cfg.setdefault("streams", None)
+    cfg.setdefault("experts_held", None)
+    if cfg["experts_held"] is not None:
+        cfg["experts_held"] = tuple(int(v) for v in cfg["experts_held"])
     cfg.setdefault("shared_experts", 1)
     cfg.setdefault("route_scale", 1.0)
     cfg.setdefault("sinkhorn_iters", 20)
@@ -78,11 +100,13 @@ def mla_config(config):
 
 
 def mla_param_shapes(cfg):
-    """Flat ``name -> shape`` map of every parameter."""
-    d, H, n = cfg["units"], cfg["num_heads"], cfg["streams"]
+    """Flat ``name -> shape`` map of every parameter: the ``hc_*`` maps
+    where there are streams, the expert leaves of the experts held."""
+    d, H, n = cfg["units"], cfg["num_heads"], cfg.get("streams")
     r_q, r_kv = cfg["q_rank"], cfg["kv_rank"]
     d_n, d_r, d_v = cfg["nope_dim"], cfg["rope_dim"], cfg["v_dim"]
     E, f = cfg["num_experts"], cfg["expert_hidden"]
+    held = cfg["experts_held"][1] if cfg.get("experts_held") else E
     shapes = {"embed": (cfg["vocab_size"], d), "final_norm": (d,),
               "head": (d, cfg["vocab_size"])}
     for i in range(cfg["num_layers"]):
@@ -93,7 +117,7 @@ def mla_param_shapes(cfg):
             p + "kv_a": (d, r_kv + d_r), p + "kv_a_norm": (r_kv,),
             p + "kv_b": (r_kv, H * (d_n + d_v)), p + "o_w": (H * d_v, d),
             p + "ffn_norm": (d,)})
-        for sub in ("attn", "ffn"):
+        for sub in ("attn", "ffn") if n else ():
             # phi's columns: pre (n), post (n), res (n x n, row-major);
             # alpha: one a map; b: the biases in phi's order
             shapes[p + sub + "_hc_phi"] = (n * d, 2 * n + n * n)
@@ -107,8 +131,8 @@ def mla_param_shapes(cfg):
             fs = cfg["shared_experts"] * f
             shapes.update({
                 p + "router_w": (d, E), p + "router_bias": (E,),
-                p + "gate_w": (E, d, f), p + "up_w": (E, d, f),
-                p + "down_w": (E, f, d), p + "shared_gate_w": (d, fs),
+                p + "gate_w": (held, d, f), p + "up_w": (held, d, f),
+                p + "down_w": (held, f, d), p + "shared_gate_w": (d, fs),
                 p + "shared_up_w": (d, fs), p + "shared_down_w": (fs, d)})
     return shapes
 
@@ -236,8 +260,16 @@ def _attention(params, p, cfg, h, positions, inv_freq, pool, block_tables,
     return out.reshape(S, C, H * d_v) @ params[p + "o_w"], rows
 
 
+def _residual(x, norm, cfg, fn):
+    """One sublayer `fn` on the one stream x (T, d): ``x + fn(rmsnorm(x))``,
+    summed in float32."""
+    y = fn(_rmsnorm(x, norm, cfg["rms_eps"]))
+    return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype)
+
+
 def _feed_forward(params, p, cfg, h, dense, loads):
-    """h (T, d) normed -> (T, d); an expert layer appends its loads."""
+    """h (T, d) normed -> (T, d); an expert layer appends its loads (a
+    held share's with its ``routes_elsewhere`` and ``rows_moved``)."""
     if dense:
         gate = jnp.dot(h, params[p + "gate_w"],
                        preferred_element_type=jnp.float32)
@@ -250,8 +282,10 @@ def _feed_forward(params, p, cfg, h, dense, loads):
         scoring="sigmoid", choice_bias=params[p + "router_bias"],
         route_scale=cfg["route_scale"],
         shared=(params[p + "shared_gate_w"], params[p + "shared_up_w"],
-                params[p + "shared_down_w"]))
-    loads.append(stats["expert_load"])
+                params[p + "shared_down_w"]), held=cfg["experts_held"])
+    loads.append(stats["expert_load"] if cfg["experts_held"] is None
+                 else jnp.concatenate([stats["expert_load"], jnp.stack(
+                     [stats["routes_elsewhere"], stats["rows_moved"]])]))
     return out
 
 
@@ -266,7 +300,10 @@ def mla_forward_paged(params, cfg, tokens, lengths, block_tables, pools,
     Returns ``(out, new_rows, loads)``: new_rows by layer (S, C,
     cache_row_width), the chunk's cache rows for the caller to commit; ``loads``
     (expert layers, E) int32, the routes each routed expert got in this
-    forward. `head`: ``"logits"`` -> out (S, C, V) float32, position c's
+    forward. Under ``experts_held`` it is (expert layers, count + 2): the
+    routes each HELD expert got, then the layer's ``routes_elsewhere`` and
+    ``rows_moved`` (one array: an output buffer costs a launch more than
+    two numbers do). `head`: ``"logits"`` -> out (S, C, V) float32, position c's
     logits choose token c + 1; ``"token"`` -> (S,) int32, the argmax of
     the last chunk position's logits (the first index on a tie, as
     ``np.argmax``), so the (S, C, V) array is no output of the program;
@@ -278,12 +315,13 @@ def mla_forward_paged(params, cfg, tokens, lengths, block_tables, pools,
     cfg = mla_config(cfg)
     S, C = tokens.shape
     n, d = cfg["streams"], cfg["units"]
+    held = cfg["experts_held"]
     lengths = jnp.asarray(lengths, jnp.int32)
     positions = lengths[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
     inv_freq = yarn_inv_freq(cfg["rope_dim"], cfg["rope_theta"], cfg["yarn"])
-    # the streams, major: (n, S C, d) keeps a TPU's tiles on (tokens, d)
-    X = jnp.broadcast_to(params["embed"][tokens].reshape(1, S * C, d),
-                         (n, S * C, d))
+    X = params["embed"][tokens].reshape(S * C, d)
+    if n:   # the streams, major: (n, S C, d) keeps a TPU's tiles on (tokens, d)
+        X = jnp.broadcast_to(X[None], (n, S * C, d))
     new_rows, loads = [], []
     for i in range(cfg["num_layers"]):
         p = "l%d_" % i
@@ -294,18 +332,25 @@ def mla_forward_paged(params, cfg, tokens, lengths, block_tables, pools,
                                    block_tables, lengths)
             new_rows.append(rows)
             return out.reshape(S * C, d)
-        X = _hyper(params, p + "attn_", cfg, X, params[p + "attn_norm"],
-                   attention)
-        X = _hyper(params, p + "ffn_", cfg, X, params[p + "ffn_norm"],
-                   lambda h, p=p, i=i: _feed_forward(
-                       params, p, cfg, h, i < cfg["dense_layers"], loads))
-    loads = (jnp.stack(loads) if loads
-             else jnp.zeros((0, cfg["num_experts"]), jnp.int32))
+
+        def feed_forward(h, p=p, i=i):
+            return _feed_forward(params, p, cfg, h, i < cfg["dense_layers"],
+                                 loads)
+        if n:
+            X = _hyper(params, p + "attn_", cfg, X, params[p + "attn_norm"],
+                       attention)
+            X = _hyper(params, p + "ffn_", cfg, X, params[p + "ffn_norm"],
+                       feed_forward)
+        else:
+            X = _residual(X, params[p + "attn_norm"], cfg, attention)
+            X = _residual(X, params[p + "ffn_norm"], cfg, feed_forward)
+    loads = (jnp.stack(loads) if loads else jnp.zeros(
+        (0, cfg["num_experts"] if held is None else held[1] + 2), jnp.int32))
     if head == "none":
         return None, new_rows, loads
     if head not in ("logits", "token"):
         raise ValueError("no such head: %r" % (head,))
-    x = jnp.sum(X.astype(jnp.float32), axis=0).astype(X.dtype)
+    x = jnp.sum(X.astype(jnp.float32), axis=0).astype(X.dtype) if n else X
     logits = jnp.dot(_rmsnorm(x, params["final_norm"], cfg["rms_eps"]),
                      params["head"], preferred_element_type=jnp.float32)
     logits = logits.reshape(S, C, -1)

@@ -16,6 +16,13 @@ tile and its weights never leave HBM. The number of tiles is static (the
 worst case, every group ending in a nearly empty tile); the tiles past the
 last used one point at the last used group (no fetch) and write zeros.
 
+A layer that holds a SHARE of its experts (``parallel/moe.py``
+``moe_dropless(held=...)``) lays a pass's routes out in tiles itself, once
+for its three products, and calls ``grouped_matmul_tiles`` on that layout
+(12 groups of 7,168 x 2,048, a few rows each, in the benchmark's
+``kimi_k2_7_code`` cell: 87 % of the HBM roofline of the experts hit,
+PERF.md, PR 35).
+
 Decode-sized batches (a few rows an expert, 3 MB of weights a group) are
 bound by the weight reads; the short tiles keep the MXU work under them. A
 batch that fills its groups (a prefill chunk of 2,048 tokens x 4 routes over

@@ -4,7 +4,10 @@ dropless one.
 Two layers with two contracts. ``moe_apply`` (below, first) is the
 Switch/GShard capacity layer; ``moe_dropless`` (further down) routes every
 token to its top-k experts whatever the load, sorts the routes by expert
-and runs one grouped matrix product over the experts held.
+and runs one grouped matrix product over the experts held: all of them, or
+(``held``) the range of them that this chip holds of a layer shared by
+expert parallelism, whose routes it picks out first and whose part of the
+result it returns (no exchange: one chip's share, alone).
 
 The capacity layer.
 
@@ -163,9 +166,115 @@ def _routes(logits, k, scoring, choice_bias, route_scale):
     return top_e, weights
 
 
+def share_bound(tokens, top_k, experts, count):
+    """The routes one pass of a layer that holds `count` of `experts`
+    experts computes: twice the ``tokens top_k count / experts`` that even
+    routing sends here, in whole 16-row tiles, two tiles at the least."""
+    mean = -(-tokens * top_k * count // experts)
+    return max(32, -(-2 * mean // 16) * 16)
+
+
+def _pass_layout(sizes, slots, tile_rows):
+    """Where a pass's routes (in their order, `sizes` (G,) of them a held
+    expert) sit once every group is padded to whole tiles of `tile_rows`
+    slots, read from the SLOT's side, so that the layout is filled by
+    gathers alone: -> (the row of the order in each slot (slots,), whether
+    the slot holds one (slots,) bool, the group of each tile (T,), the
+    tiles used (1,)). Tiles past the used ones repeat the last used group
+    (``grouped_matmul_tiles`` fetches nothing for them); with tiles of one
+    slot the layout is the order itself."""
+    groups = sizes.shape[0]
+    per_group = (sizes + tile_rows - 1) // tile_rows
+    tile_end = jnp.cumsum(per_group)                # inclusive, by group
+    used = tile_end[-1]
+    tile = jnp.minimum(jnp.arange(slots // tile_rows, dtype=jnp.int32),
+                       jnp.maximum(used - 1, 0))
+    tile_group = jnp.minimum(jnp.sum(tile[:, None] >= tile_end[None, :],
+                                     axis=1, dtype=jnp.int32), groups - 1)
+    group = jnp.repeat(tile_group, tile_rows, total_repeat_length=slots)
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    rank = slot - (tile_end - per_group)[group] * tile_rows
+    live = (rank < sizes[group]) & (slot // tile_rows < used)
+    return ((jnp.cumsum(sizes) - sizes)[group] + rank, live, tile_group,
+            used.reshape(1))
+
+
+def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
+                 gated, use_kernel, interpret):
+    """The routed part of a layer that holds the experts ``[first, first +
+    count)`` alone -> (the held routes' weighted outputs summed a token
+    (N, d) float32, stats).
+
+    The N k routes are sorted on "which held expert, or none" (a stable
+    sort; the routes of other chips' experts last), so the routes that
+    fall here lead the order, grouped by expert, and their number is
+    known. They are computed ``share_bound`` routes a pass, in as many
+    passes as it takes (a ``fori_loop`` whose trip count is the held
+    routes over the bound, rounded up: none where no route falls here,
+    N k over the bound where every route does): dropless under any skew,
+    with static shapes, and a pass moves its own rows and no others. A
+    pass lays its routes out in the grouped product's tiles ONCE
+    (``_pass_layout``; off the TPU the layout is the order itself and the
+    product ``ragged_dot``), gathers the tokens' rows into that layout,
+    runs the three products on it and brings the rows home by ONE product
+    with the (N, slots) matrix that holds a route's weight where the slot
+    is the token's: one gather in, one weighted sum out, no scatter (a
+    TPU walks a scatter's updates one by one: 0.9 ms of a 14 ms decode
+    step at 128 rows x 7,168, PERF.md section 6, PR 35)."""
+    from ..ops.pallas.grouped_matmul import (grouped_matmul_available,
+                                             grouped_matmul_tiles,
+                                             tile_rows_for)
+    n, k = top_e.shape
+    first, count = held
+    routes = n * k
+    key = jnp.where((top_e >= first) & (top_e < first + count),
+                    top_e - first, count).reshape(routes)
+    bound = min(share_bound(n, k, experts, count), routes)
+    order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
+                    (0, bound))                     # held routes first
+    load = jnp.sum(key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
+                   axis=0, dtype=jnp.int32)
+    end = jnp.cumsum(load)                  # a group's end in the order
+    here = end[-1]
+    if use_kernel is None:
+        use_kernel = grouped_matmul_available()
+    tiled = use_kernel or interpret
+    tile_rows = tile_rows_for(bound, count) if tiled else 1
+    slots = (bound + count * (tile_rows - 1)) // tile_rows * tile_rows
+    flat_w = weights.reshape(routes)
+    tokens = jnp.arange(n, dtype=jnp.int32)
+
+    def one_pass(p, out):
+        lo = p * bound
+        sizes = (jnp.clip(end, lo, lo + bound)
+                 - jnp.clip(end - load, lo, lo + bound))
+        row, live, tile_group, used = _pass_layout(sizes, slots, tile_rows)
+        # a slot's route; a tile's padding reads token 0 under weight 0
+        route = jnp.where(live, order[lo + row], 0)
+        token = route // k
+        moved = x[token]
+
+        def product(rows, w):
+            if tiled:
+                return grouped_matmul_tiles(rows, w, tile_group, used,
+                                            interpret)
+            return jax.lax.ragged_dot(rows, w, sizes,
+                                      preferred_element_type=jnp.float32)
+        y = product(gated(product(moved, gate_w), product(moved, up_w)),
+                    down_w)
+        home = jnp.where(live[None, :] & (token[None, :] == tokens[:, None]),
+                         flat_w[route][None, :], 0.0)       # (N, slots)
+        return out + jnp.dot(home, y, precision="highest")
+    passes = (here + bound - 1) // bound
+    out = jax.lax.fori_loop(0, passes, one_pass,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out, {"expert_load": load, "routes_elsewhere": routes - here,
+                 "rows_moved": passes * slots}
+
+
 def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
                  interpret=False, return_stats=False, scoring="softmax",
-                 choice_bias=None, route_scale=1.0, shared=None):
+                 choice_bias=None, route_scale=1.0, shared=None, held=None):
     """Dropless top-k mixture of gated-SiLU experts, no biases.
 
     x : (N, d) tokens
@@ -196,8 +305,31 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     rounded to x's dtype before the down product, the result after the
     weighted sum.
 
+    `held` ``(first, count)``: this chip holds the experts ``[first,
+    first + count)`` of a layer that several chips share by expert
+    parallelism: ``gate_w / up_w / down_w`` are those `count` experts',
+    ``router_w`` and ``choice_bias`` keep all E. The router decides over
+    all E as published (the top-k, and the weights renormalised over ALL k
+    chosen, held here or not), and the layer returns this chip's PART::
+
+        out_here = sum_{e in top-k, first <= e < first + count} w_e Expert_e(x)
+                   + Expert_shared(x)
+
+    the routes that fall on the held experts (``_held_routes``: picked out
+    of the N k first, computed ``share_bound`` routes a pass, none dropped
+    under any skew) and the shared expert, which every chip computes
+    alike. What the absent experts would add is another chip's to compute
+    and an exchange's to sum: neither is here, and ``out_here`` is what
+    the caller carries on. Summed over the shares of a layer, the shared
+    part counted once, it is the uncut layer. ``held=None`` is the layer
+    that holds every expert, by the body it always had.
+
     -> out (N, d), and with `return_stats` also ``{"expert_load": (E,)
-    int32 routes an expert}``.
+    int32 routes an expert}``; under `held` ``expert_load`` is (count,),
+    the routes each HELD expert got, beside ``routes_elsewhere`` (the
+    routes on other chips' experts: N k less the held ones) and
+    ``rows_moved`` (the rows the passes gathered into their first product:
+    passes x the layout's slots, a compiled shape's number).
     """
     from ..ops.pallas.grouped_matmul import grouped_matmul
     n, d = x.shape
@@ -205,6 +337,14 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     k = int(top_k)
     if not 1 <= k <= experts:
         raise ValueError("top_k must be in [1, %d], got %d" % (experts, k))
+    if held is not None:
+        first, count = held
+        if not (0 <= first and 1 <= count and first + count <= experts
+                and gate_w.shape[0] == count):
+            raise ValueError(
+                "held=(%d, %d) must lie inside the router's %d experts and "
+                "come with %d experts' weights (got %d)"
+                % (first, count, experts, count, gate_w.shape[0]))
 
     def grouped(rows, w, sizes):
         return grouped_matmul(rows, w, sizes, use_kernel=use_kernel,
@@ -215,15 +355,21 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     top_e, weights = _routes(
         jnp.dot(x, router_w, preferred_element_type=jnp.float32), k, scoring,
         choice_bias, route_scale)
-    expert_of_route = top_e.reshape(n * k)
-    order = jnp.argsort(expert_of_route, stable=True)       # sorted -> route
-    load = jnp.zeros((experts,), jnp.int32).at[expert_of_route].add(1)
-    rows = x[order // k]                                    # (N k, d)
-    hidden = gated(grouped(rows, gate_w, load), grouped(rows, up_w, load))
-    y = grouped(hidden, down_w, load)                       # (N k, d) f32
-    back = jnp.zeros((n * k,), jnp.int32).at[order].set(
-        jnp.arange(n * k, dtype=jnp.int32))                 # route -> sorted
-    out = jnp.sum(y[back].reshape(n, k, d) * weights[:, :, None], axis=1)
+    if held is not None:
+        out, stats = _held_routes(x, top_e, weights, experts, gate_w, up_w,
+                                  down_w, held, gated, use_kernel, interpret)
+    else:
+        expert_of_route = top_e.reshape(n * k)
+        order = jnp.argsort(expert_of_route, stable=True)   # sorted -> route
+        load = jnp.zeros((experts,), jnp.int32).at[expert_of_route].add(1)
+        stats = {"expert_load": load}
+        rows = x[order // k]                                # (N k, d)
+        hidden = gated(grouped(rows, gate_w, load),
+                       grouped(rows, up_w, load))
+        y = grouped(hidden, down_w, load)                   # (N k, d) f32
+        back = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32))             # route -> sorted
+        out = jnp.sum(y[back].reshape(n, k, d) * weights[:, :, None], axis=1)
     if shared is not None:
         s_gate, s_up, s_down = shared
         out = out + jnp.dot(
@@ -232,7 +378,7 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
             s_down, preferred_element_type=jnp.float32)
     out = out.astype(x.dtype)
     if return_stats:
-        return out, {"expert_load": load}
+        return out, stats
     return out
 
 

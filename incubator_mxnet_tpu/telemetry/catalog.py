@@ -313,12 +313,27 @@ moe_routes = _m.counter(
     "mxtpu_moe_routes_total",
     "Token-expert routes computed by the dropless expert layer, summed "
     "over layers, by model — tokens x experts a token x layers a forward: "
-    "none is dropped")
+    "none is dropped (of a layer that holds a share of its experts: the "
+    "routes on the experts held here)")
 moe_experts_hit = _m.counter(
     "mxtpu_moe_experts_hit_total",
     "Distinct experts that got at least one route, summed over layers "
     "and forwards, by model (over layers x forwards: the experts whose "
     "weights a forward reads)")
+moe_routes_elsewhere = _m.counter(
+    "mxtpu_moe_routes_elsewhere_total",
+    "Token-expert routes that an expert layer holding a SHARE of its "
+    "experts (``moe_dropless``'s ``held``) left to the chips that hold "
+    "the chosen expert, summed over layers, by model: with moe_routes "
+    "(then the routes on the experts held here) every route the router "
+    "made")
+moe_rows_moved = _m.counter(
+    "mxtpu_moe_rows_moved_total",
+    "Rows that a share-holding expert layer gathered into its first "
+    "grouped product (passes x the slots of a pass's tile layout), "
+    "summed over layers, by model: over moe_routes, 1 is a layer that "
+    "touches its own routes alone, experts / held one that moves every "
+    "route")
 moe_load_max_over_mean = _m.histogram(
     "mxtpu_moe_load_max_over_mean",
     "The fullest expert's routes over the mean expert's, one "

@@ -66,8 +66,27 @@ TOKEN_CHOSEN_ON_THE_HOST = (
     "test_a_traced_run_reports_every_layer_a_cpu_can_read")
 
 
+# One more (closed to a model_config PR) asserts ``len(cells) == 5`` and the
+# exact {name: reduced} of FOUR configurations; ISSUE 35 lists a fifth
+# configuration and a sixth cell. All else it asserted is asserted by
+# tests/benchmark/test_benchmark_share.py::test_the_real_benchmark_as_it_
+# stands_with_the_wide_batch_cell, which names only the configurations and
+# cells it needs (``in``, ``>=``), so that the next configuration breaks
+# nothing. Strict: once a benchmark PR makes the old test ask the same
+# way, it passes, this mark fails the run, and the mark goes.
+FOUR_CONFIGURATIONS = (
+    "tests/benchmark/test_benchmark_latent.py::"
+    "test_the_real_benchmark_as_it_stands_with_the_long_prompt_cell")
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
+        if item.nodeid.endswith(FOUR_CONFIGURATIONS):
+            item.add_marker(pytest.mark.xfail(
+                strict=True, raises=AssertionError,
+                reason="asserts five cells and the reduced lists of exactly "
+                       "four configurations (ISSUE 35 lists a fifth); "
+                       "PERF.md section 7 item 17"))
         if item.nodeid.endswith(TOKEN_CHOSEN_ON_THE_HOST):
             item.add_marker(pytest.mark.xfail(
                 strict=True, raises=AssertionError,

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import nd, serving, telemetry
 from incubator_mxnet_tpu.generate import (GenerateEngine, GPTPagedLM,
+                                          MLAPagedLM,
                                           PagedKVCache,
                                           export_gpt_for_serving)
 from incubator_mxnet_tpu.generate.engine import prefill_slot, step_slots
@@ -1066,6 +1067,91 @@ def test_a_traced_toy_latent_run_reports_every_layer_a_cpu_can_read(
     assert values["moe_load_max_over_mean"] >= 1
     assert result["correct"] is True
     assert not os.path.exists(str(tmp_path / ".bench_trace" / cell))
+
+
+# ------------------------------------------- an expert layer's held share
+@pytest.fixture(scope="module")
+def share_lm():
+    """The ``kimi_k2`` family at the toy size of the benchmark's tests: one
+    residual stream, two expert layers that hold the experts 6-8 of 24
+    (share 2 of 8), top-8."""
+    from benchmarks import spec
+    from benchmarks.families import kimi_k2
+    cfg = spec.load_json(os.path.join(
+        spec.ROOT, "tests", "benchmark", "configs", "kimi_tiny.json"))
+    return MLAPagedLM(kimi_k2.reference.init_weights(cfg, 3),
+                      kimi_k2.program_config(cfg), dtype="float32")
+
+
+def test_a_held_shares_tallies_count_the_work_done_here(share_lm):
+    """``last_stats["moe"]`` keeps its keys with the meaning "of the
+    experts held here": routes and experts hit are what this chip
+    computed (the readers of the grouped products' roofline and of the
+    step's floor divide by them), the routes on other chips' experts are
+    counted apart, and together they are every route the router made."""
+    telemetry.enable()
+    eng = GenerateEngine(share_lm, share_lm.make_cache(3, max_len=64),
+                         prefill_chunk=_LAG_CHUNK, name="share_tally")
+    before = {c: c.value(model="share_tally") for c in (
+        cat.moe_routes, cat.moe_experts_hit, cat.moe_routes_elsewhere,
+        cat.moe_rows_moved)}
+    out = eng.generate(_LAG_PROMPTS, max_new_tokens=_LAG_NEW)
+    assert out == _logits_loop(share_lm, _LAG_PROMPTS, _LAG_NEW)
+    moe = eng.last_stats["moe"]
+    assert set(moe) == {"forwards", "routes", "experts_hit",
+                        "load_max_over_mean", "routes_elsewhere",
+                        "rows_moved"}
+    # prompts of 13, 2 and 9 commit 12, 1 and 8 tokens in chunks of 4 (a
+    # chunk's padding routes too): 3 + 1 + 2 chunks of 4 positions, then
+    # 12 steps of 3 rows; 2 expert layers, 8 routes a position
+    positions = 6 * 4 + 12 * 3
+    assert moe["forwards"] == 6 + 12
+    assert moe["routes"] + moe["routes_elsewhere"] == 2 * 8 * positions
+    # 3 of 24 experts held: about an eighth of the routes fall here
+    assert 0 < moe["routes"] < moe["routes_elsewhere"] / 3
+    assert 0 < moe["experts_hit"] <= 2 * 3 * moe["forwards"]
+    # a pass of 3 or 4 tokens lays 32 routes out (the bound's floor); a
+    # layer-forward with no route here moves nothing
+    assert moe["rows_moved"] % 32 == 0
+    assert 32 <= moe["rows_moved"] <= 2 * 32 * moe["forwards"]
+    assert all(u >= 1 for u in moe["load_max_over_mean"])
+    assert len(moe["load_max_over_mean"]) <= moe["forwards"]
+    for counter, key in ((cat.moe_routes, "routes"),
+                         (cat.moe_experts_hit, "experts_hit"),
+                         (cat.moe_routes_elsewhere, "routes_elsewhere"),
+                         (cat.moe_rows_moved, "rows_moved")):
+        assert counter.value(model="share_tally") - before[counter] \
+            == moe[key]
+
+
+def test_a_model_that_holds_every_expert_tallies_no_share(latent_lm):
+    eng = GenerateEngine(latent_lm, latent_lm.make_cache(3, max_len=64),
+                         prefill_chunk=_LAG_CHUNK, name="whole_tally")
+    eng.generate(_LAG_PROMPTS, max_new_tokens=2)
+    assert set(eng.last_stats["moe"]) == {
+        "forwards", "routes", "experts_hit", "load_max_over_mean"}
+    loads = np.arange(16).reshape(2, 8)
+    assert latent_lm.split_loads(loads)[1] == {}
+    assert latent_lm.split_loads(loads)[0] is loads
+
+
+def test_a_share_whose_experts_get_no_route_has_no_fullest_expert(share_lm):
+    """A forward in which no layer's held experts got a route appends no
+    ``load_max_over_mean`` (0 / 0), and counts as a forward all the
+    same."""
+    eng = GenerateEngine(share_lm, share_lm.make_cache(1, max_len=16),
+                         name="idle_share")
+    eng._tallies = {"moe": {"forwards": 0, "routes": 0, "experts_hit": 0,
+                            "load_max_over_mean": [], "routes_elsewhere": 0,
+                            "rows_moved": 0}}
+    idle = np.asarray([[0, 0, 0, 8, 0], [0, 0, 0, 8, 0]], np.int32)
+    eng._note_forward(share_lm, {"expert_loads": idle})
+    one = np.asarray([[0, 3, 1, 4, 32], [0, 0, 0, 8, 0]], np.int32)
+    eng._note_forward(share_lm, {"expert_loads": one})
+    assert eng._tallies["moe"] == {
+        "forwards": 2, "routes": 4, "experts_hit": 2,
+        "load_max_over_mean": [3 / (4 / 3)], "routes_elsewhere": 28,
+        "rows_moved": 32}
 
 
 # ------------------------------------------- serving: accounting + loop

@@ -20,7 +20,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from benchmarks.families import kimi_k2 as share_family
 from benchmarks.families import xing4 as family
+from benchmarks.reference import kimi_k2 as share_reference
 from benchmarks.reference import xing4 as reference
 from incubator_mxnet_tpu import telemetry
 from incubator_mxnet_tpu.generate import GenerateEngine, MLAPagedLM
@@ -447,3 +449,224 @@ def test_the_grouped_product_takes_tall_tiles_where_the_groups_are_full(
     got = grouped_matmul(x, w, jnp.asarray(sizes), interpret=True)
     want = jax.lax.ragged_dot(x, w, jnp.asarray(sizes))
     np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+# ===================================================== one chip's share
+# The ``kimi_k2`` family on the same layer body: ONE residual stream (no
+# ``hc_*`` leaves) and an expert layer that holds a SHARE of its experts:
+# 24 experts top-8 over 8 shares of 3, this chip share 2 (the experts 6-8).
+KIMI = {"hidden_size": 32, "num_attention_heads": 4, "q_lora_rank": 16,
+        "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+        "v_head_dim": 8, "intermediate_size": 64, "n_routed_experts": 3,
+        "router_width": 24, "num_experts_per_tok": 8,
+        "moe_intermediate_size": 16, "n_shared_experts": 1,
+        "first_k_dense_replace": 1, "num_hidden_layers": 3,
+        "vocab_size": 128, "rms_norm_eps": 1e-5, "rope_theta": 50000,
+        "routed_scaling_factor": 2.827, "max_position_embeddings": 64,
+        "rope_scaling": YARN, "dtype": "float32", "seed_weight_range": 0.2,
+        "assumed": {"initializer_range": {"value": 0.02},
+                    "router_bias_range": {"value": 0.1},
+                    "prefill_chunk": {"value": 8},
+                    "expert_share_index": {"value": 2}}}
+
+
+@pytest.fixture(scope="module")
+def share_weights():
+    return share_reference.init_weights(KIMI, 5)
+
+
+@pytest.fixture(scope="module")
+def share_model(share_weights):
+    return MLAPagedLM(share_weights, share_family.program_config(KIMI),
+                      dtype="float32")
+
+
+def _share_reference_at(weights, tokens, positions):
+    return share_reference.logits(weights, KIMI, [tokens], [positions],
+                                  block_rows=8)[0]
+
+
+def _share_logits_through_the_cache(model, prompt, length, chunk):
+    cache = model.make_cache(2, max_len=32, block_size=4)
+    slot = cache.alloc()
+    prefill_slot(model, cache, slot, prompt[:length], chunk)
+    return np.stack([step_slots(
+        model, cache, [slot],
+        np.asarray([[prompt[length + i]]], np.int32))[0] for i in range(3)])
+
+
+@pytest.mark.parametrize("length,chunk", [(21, 8), (9, 8), (6, 8)],
+                         ids=["chunks_and_tail", "one_over",
+                              "under_a_chunk"])
+def test_one_stream_and_a_held_share_through_the_cache_is_the_full_forward(
+        share_weights, share_model, length, chunk):
+    """Prefill (the expanded path) then decode (the absorbed one) with one
+    residual stream and the experts 6-8 of 24 held, against the reference's
+    full forward, which routes over all 24 and adds only the held experts'
+    part. Tolerance 1e-4 on logits of spread ~1: float32 sums in another
+    order (the cache's tiles, the absorbed products, the layer's sorted
+    routes) read under 1e-5 here; the same model served in bfloat16 reads
+    over 1e-2 (the next test), so a lower precision fails it a hundredfold."""
+    prompt = _prompt(length + 3, seed=length)
+    ours = _share_logits_through_the_cache(share_model, prompt, length,
+                                           chunk)
+    theirs = _share_reference_at(share_weights, prompt,
+                                 np.arange(length, length + 3))
+    np.testing.assert_allclose(ours, theirs, atol=1e-4)
+
+
+def test_a_bfloat16_run_of_the_float32_toy_fails_that_tolerance(
+        share_weights):
+    prompt = _prompt(24, seed=21)
+    low = MLAPagedLM(share_weights, share_family.program_config(KIMI),
+                     dtype="bfloat16")
+    ours = _share_logits_through_the_cache(low, prompt, 21, 8)
+    theirs = _share_reference_at(share_weights, prompt, np.arange(21, 24))
+    assert np.abs(ours - theirs).max() > 1e-2
+
+
+def test_one_stream_has_no_hyper_connection_leaves_and_holds_its_share(
+        share_model):
+    cfg = share_model.config
+    assert cfg["streams"] is None and cfg["experts_held"] == (6, 3)
+    shapes = mla_moe.mla_param_shapes(cfg)
+    assert set(shapes) == set(share_model.params)
+    assert not [n for n in shapes if "hc_" in n]
+    assert shapes["l1_router_w"] == (32, 24)        # the router: all 24
+    assert shapes["l1_router_bias"] == (24,)
+    assert shapes["l1_gate_w"] == shapes["l1_up_w"] == (3, 32, 16)
+    assert shapes["l1_down_w"] == (3, 16, 32)       # the experts: its 3
+    # ``streams`` is not required: a configuration without it is one stream
+    assert "streams" not in share_family.program_config(KIMI)
+
+
+def _share_layer(rng, experts=24, d=16, f=12, tokens=40):
+    router_w, gate_w, up_w, down_w = _experts(rng, experts, d, f)
+    shared = [jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+              for s in ((d, f), (d, f), (f, d))]
+    x = jnp.asarray(rng.normal(size=(tokens, d)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=experts) * 0.1, jnp.float32)
+    return x, router_w, gate_w, up_w, down_w, shared, bias
+
+
+def _held(x, router_w, gate_w, up_w, down_w, bias, first, count, **kw):
+    return moe_dropless(
+        x, router_w, gate_w[first:first + count], up_w[first:first + count],
+        down_w[first:first + count], 8, return_stats=True,
+        scoring="sigmoid", choice_bias=bias, route_scale=2.827,
+        held=(first, count), **kw)
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["ragged_dot", "tiles_interpreted"])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(interpret):
+    """THE SHARE TEST: 24 experts in 8 shares of 3. The eight ``out_here``
+    less the shared expert's part, summed, plus the shared part once, are
+    the uncut layer: ``moe_dropless(held=None)``'s, a float64 loop's, and
+    the plain reference's (its share loop at all 24 held). Off the TPU a
+    pass's product is ``ragged_dot`` on the order itself; `interpret` runs
+    the launch's tile layout."""
+    rng = np.random.default_rng(11)
+    x, router_w, gate_w, up_w, down_w, shared, bias = _share_layer(rng)
+    whole, stats = moe_dropless(
+        x, router_w, gate_w, up_w, down_w, 8, return_stats=True,
+        scoring="sigmoid", choice_bias=bias, route_scale=2.827,
+        shared=shared)
+    gate = x @ shared[0]
+    shared_part = (jax.nn.silu(gate) * (x @ shared[1])) @ shared[2]
+    total, loads, elsewhere = shared_part, [], 0
+    for share in range(8):
+        out_here, here = _held(x, router_w, gate_w, up_w, down_w, bias,
+                               3 * share, 3, shared=shared,
+                               interpret=interpret)
+        total = total + (out_here - shared_part)
+        loads.append(np.asarray(here["expert_load"]))
+        elsewhere += int(here["routes_elsewhere"])
+        assert loads[-1].shape == (3,)
+        assert int(here["routes_elsewhere"]) == 40 * 8 - loads[-1].sum()
+    np.testing.assert_allclose(total, whole, atol=3e-5)
+    want, _chosen = _sigmoid_loop(x, router_w, gate_w, up_w, down_w, 8,
+                                  bias, 2.827, shared)
+    np.testing.assert_allclose(total, want, atol=3e-5)
+    assert np.concatenate(loads).tolist() == np.asarray(
+        stats["expert_load"]).tolist()
+    assert elsewhere == 7 * 40 * 8      # every route is someone's, once
+    # the plain reference, told it holds all 24, and told it holds 3
+    cfg = dict(KIMI, hidden_size=16, moe_intermediate_size=12)
+    w = {"router_w": router_w, "router_bias": bias, "gate_w": gate_w,
+         "up_w": up_w, "down_w": down_w, "shared_gate_w": shared[0],
+         "shared_up_w": shared[1], "shared_down_w": shared[2]}
+    uncut = share_reference._Frozen(dict(cfg, n_routed_experts=24,
+                                         assumed={"expert_share_index":
+                                                  {"value": 0}}))
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            share_reference._experts_here(w, "", uncut, "float32", x, 40),
+            total, atol=3e-5)
+        for share in (0, 5):
+            part = dict(w, **{n: w[n][3 * share:3 * share + 3]
+                              for n in ("gate_w", "up_w", "down_w")})
+            theirs = share_reference._experts_here(
+                part, "", share_reference._Frozen(dict(
+                    cfg, assumed={"expert_share_index": {"value": share}})),
+                "float32", x, 40)
+            ours, _ = _held(x, router_w, gate_w, up_w, down_w, bias,
+                            3 * share, 3, shared=shared)
+            np.testing.assert_allclose(ours, theirs, atol=3e-5)
+
+
+def test_a_skewed_router_drops_nothing_and_an_idle_share_adds_nothing():
+    """Every token's 8 routes on the 8 held experts: 8 times the even
+    load, computed in several passes of the bound, none dropped. And a
+    share that no token chooses returns the shared part alone and moves
+    no row."""
+    from incubator_mxnet_tpu.parallel.moe import share_bound
+    rng = np.random.default_rng(12)
+    x, router_w, gate_w, up_w, down_w, shared, bias = _share_layer(rng)
+    planted = bias.at[8:16].add(5.0)         # the held range: all chosen
+    out, stats = _held(x, router_w, gate_w, up_w, down_w, planted, 8, 8)
+    want, chosen = _sigmoid_loop(x, router_w, gate_w, up_w, down_w, 8,
+                                 planted, 2.827)
+    assert all(c == list(range(8, 16)) for c in chosen)
+    np.testing.assert_allclose(out, want, atol=3e-5)
+    assert np.asarray(stats["expert_load"]).tolist() == [40] * 8
+    assert int(stats["routes_elsewhere"]) == 0
+    bound = share_bound(40, 8, 24, 8)
+    assert bound == 224 < 320       # twice the even load, under the skew's
+    assert int(stats["rows_moved"]) == 2 * bound    # two passes, no tiles
+    # the same through the launch's tiles: 16-row tiles, 8 groups
+    tiled, tiled_stats = _held(x, router_w, gate_w, up_w, down_w, planted,
+                               8, 8, interpret=True)
+    np.testing.assert_allclose(tiled, want, atol=3e-5)
+    assert int(tiled_stats["rows_moved"]) == 2 * ((bound + 8 * 15) // 16
+                                                  * 16)
+    shunned = bias.at[8:16].add(-5.0)       # the held range: never chosen
+    gate = x @ shared[0]
+    for interpret in (False, True):
+        out, stats = _held(x, router_w, gate_w, up_w, down_w, shunned, 8, 8,
+                           shared=shared, interpret=interpret)
+        np.testing.assert_allclose(
+            out, (jax.nn.silu(gate) * (x @ shared[1])) @ shared[2],
+            atol=2e-5)
+        assert not np.asarray(stats["expert_load"]).any()
+        assert int(stats["routes_elsewhere"]) == 320
+        assert int(stats["rows_moved"]) == 0
+
+
+def test_a_layer_that_holds_every_expert_is_the_layer_with_no_share():
+    rng = np.random.default_rng(13)
+    x, router_w, gate_w, up_w, down_w, shared, bias = _share_layer(rng)
+    whole, stats = moe_dropless(
+        x, router_w, gate_w, up_w, down_w, 8, return_stats=True,
+        scoring="sigmoid", choice_bias=bias, route_scale=2.827,
+        shared=shared)
+    held, held_stats = _held(x, router_w, gate_w, up_w, down_w, bias, 0, 24,
+                             shared=shared)
+    np.testing.assert_allclose(held, whole, atol=2e-5)
+    assert np.array_equal(held_stats["expert_load"], stats["expert_load"])
+    assert int(held_stats["routes_elsewhere"]) == 0
+    assert set(stats) == {"expert_load"}        # no share, no share's tally
+    with pytest.raises(ValueError, match=r"held=\(20, 8\) must lie inside"):
+        _held(x, router_w, gate_w, up_w, down_w, bias, 20, 8)
+    with pytest.raises(ValueError, match="3 experts' weights"):
+        moe_dropless(x, router_w, gate_w, up_w, down_w, 8, held=(0, 3))

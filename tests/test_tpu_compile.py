@@ -269,6 +269,48 @@ def test_latent_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_a_held_shares_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The wide-batch cell's decode step at its published widths (hidden
+    7,168, 64 heads, 12 of 384 experts of 7,168 x 2,048 held, an eighth of
+    the vocabulary), cut for the compile to one dense and one expert
+    layer: 128 rows of one token over 56-block tables, the token head. The
+    share's passes are a loop around the three grouped launches, on 240
+    slots of a tile layout (64 routes a pass in 16-row tiles of 12
+    groups), and nothing as tall as the 1,024 routes of the step is 7,168
+    wide."""
+    from benchmarks import spec
+    from benchmarks.families import kimi_k2
+    from incubator_mxnet_tpu.generate import MLAPagedLM
+    from incubator_mxnet_tpu.models import mla_moe
+    from incubator_mxnet_tpu.ops.pallas import grouped_matmul as gm
+    monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
+    cfg = spec.load_json(spec.ROOT
+                         + "/benchmarks/configs/kimi_k2_7_code.json")
+    program = dict(kimi_k2.program_config(cfg), num_layers=2)
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    shapes = mla_moe.mla_param_shapes(mla_moe.mla_config(program))
+    assert shapes["l1_gate_w"] == (12, 7168, 2048)
+    assert shapes["l1_router_w"] == (7168, 384)
+    model = MLAPagedLM({}, program)     # the weights are a call's argument
+    model.params = {n: shape(s) for n, s in shapes.items()}
+    rows, blocks = 128, 896 // 16
+    pools = [shape((rows * blocks, 16, 640))] * 2
+    lowered = model.lower(shape((rows, 1), jnp.int32),
+                          shape((rows,), jnp.int32),
+                          shape((rows, blocks), jnp.int32), pools,
+                          head="token")
+    outputs = [o.shape for o in jax.tree_util.tree_leaves(lowered.out_info)]
+    # the ids, the held loads with the share's two numbers, the cache rows
+    assert sorted(outputs) == [(1, 14), (2, 128, 1, 640), (128, 1)]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("moe_grouped_matmul") >= 3
+    assert "bf16[240,7168]" in text and "[1024,7168]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def _gpt2_xl_step(one_chip):
     """Cell 2's decode step: GPT-2-XL's widths cut to two layers, 4 rows
     of one token over a cache of 96 positions, float32."""
