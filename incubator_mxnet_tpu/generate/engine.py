@@ -60,7 +60,10 @@ import time
 import jax
 import numpy as np
 
-from ..ops.pallas.paged_latent import cache_row_width, latent_path
+from ..ops.pallas.paged_latent import (cache_row_width, latent_block_size,
+                                       latent_path,
+                                       paged_latent_decode_available,
+                                       rows_walked)
 from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
 from .paged_kv import PagedKVCache, _env_int
@@ -419,9 +422,14 @@ class MLAPagedLM(_PagedLM):
     the routes of the experts HELD here and two columns more, a layer's
     ``routes_elsewhere`` and ``rows_moved`` (``split_loads`` parts them);
     and after every forward ``last_latent_path`` which attention
-    path the chunk's width chose and the cached rows it expanded again:
-    ``("absorbed", 0)`` for a decode step, ``("expanded", the sequences'
-    committed lengths summed)`` for any wider chunk.
+    path the chunk's width chose and the rows it counts, by
+    ``last_stats["mla"]``'s names: ``("expanded", {"expanded_rows": the
+    sequences' committed lengths summed})`` for a chunk, the cached rows
+    it expanded again; ``("absorbed", {"absorbed_rows_live": the same
+    sum, "absorbed_rows_read": the rows a layer's walk over the past
+    fetches for them})`` for a decode step (``paged_latent.rows_walked``:
+    the kernel's walk reads each sequence's own blocks, the ``lax`` walk
+    every sequence's tiles up to the longest one's).
     """
 
     def __init__(self, params, config, dtype="bfloat16"):
@@ -450,6 +458,18 @@ class MLAPagedLM(_PagedLM):
         self._fns = {head: program(head)
                      for head in ("logits", "token", "none")}
 
+    def make_cache(self, slots, max_len=None, **kw):
+        """The cache of ``_PagedLM.make_cache`` in blocks sized by the
+        row's bytes (``paged_latent.latent_block_size``: 128 positions of
+        640 bfloat16 lanes), not by ``MXTPU_GEN_BLOCK_SIZE``: a row is a
+        twentieth of a per-head cache's position, and the decode kernel
+        copies the cache block by block. A `block_size` given wins."""
+        max_len = max_len or self.config["max_len"]
+        (shape, dtype), = self.kv_entries.values()
+        kw.setdefault("block_size", latent_block_size(
+            shape[0] * dtype.itemsize, max_len))
+        return super().make_cache(slots, max_len=max_len, **kw)
+
     def lower(self, tokens, lengths, tables, pools, head="logits"):
         """A forward's program lowered for arguments of these shapes."""
         return self._fns[head].lower(self.params, tokens, lengths, tables,
@@ -463,8 +483,13 @@ class MLAPagedLM(_PagedLM):
                                (tokens, lengths, tables, pools),
                                mla_path=path)
         self.last_expert_loads = None
-        self.last_latent_path = (
-            path, int(np.sum(lengths)) if path == "expanded" else 0)
+        live = int(np.sum(lengths))
+        self.last_latent_path = (path, {"expanded_rows": live}) \
+            if path == "expanded" else (path, {
+                "absorbed_rows_live": live,
+                "absorbed_rows_read": rows_walked(
+                    lengths, pools[0].shape[1], tables.shape[1],
+                    paged_latent_decode_available(pools[0]))})
         return read, rows
 
     def split_loads(self, loads):
@@ -574,9 +599,9 @@ class GenerateEngine:
         """What a forward of `model`'s says of itself, into this call's
         ``last_stats``. Right after it (`read` None): ``last_latent_path``
         (the attention path over a latent cache and the cached rows it
-        expanded) into ``"mla"``, and ``last_expert_loads`` (layers,
-        experts: the routes each expert got) into ``"moe"`` if the forward
-        fetched them. One that fetched nothing left them on the device:
+        expanded, or walked) into ``"mla"``, and ``last_expert_loads``
+        (layers, experts: the routes each expert got) into ``"moe"`` if the
+        forward fetched them. One that fetched nothing left them on the device:
         they are tallied from `read`, its outputs as the host fetched
         them a forward later."""
         split = getattr(model, "split_loads", None)
@@ -584,11 +609,10 @@ class GenerateEngine:
             path = getattr(model, "last_latent_path", None)
             if path is not None:
                 mla = self._tallies["mla"]
-                mla[path[0] + "_forwards"] += 1
-                mla["expanded_rows"] += path[1]
-                (_cat.mla_absorbed_forwards if path[0] == "absorbed"
-                 else _cat.mla_expanded_forwards).inc(model=self.name)
-                _cat.mla_expanded_rows.inc(path[1], model=self.name)
+                for key, count in {path[0] + "_forwards": 1,
+                                   **path[1]}.items():
+                    mla[key] += count
+                    getattr(_cat, "mla_" + key).inc(count, model=self.name)
             loads = getattr(model, "last_expert_loads", None)
         else:
             loads = read.get("expert_loads")
@@ -647,8 +671,9 @@ class GenerateEngine:
             stats["moe"] = {"forwards": 0, "routes": 0, "experts_hit": 0,
                             "load_max_over_mean": []}
         if hasattr(self.model, "last_latent_path"):
-            stats["mla"] = {"absorbed_forwards": 0, "expanded_forwards": 0,
-                            "expanded_rows": 0}
+            stats["mla"] = dict.fromkeys(
+                ("absorbed_forwards", "expanded_forwards", "expanded_rows",
+                 "absorbed_rows_live", "absorbed_rows_read"), 0)
         self._tallies = stats
         seqs = []      # per sequence: dict(ctx, slot, dslot, out, done)
         try:

@@ -34,10 +34,12 @@ their sum is normed and meets the untied head.
 Attention caches ONE row a position and layer, ``[rmsnorm(c) | rope(k_r)]``
 from ``h W_kva`` (``kv_rank + rope_dim`` values, and zeros up to whole
 lanes), shared by all heads (``ops/pallas/paged_latent.py``: the expanded
-path for a chunk, the absorbed path for a decode step). The feed-forward is a gated-SiLU MLP in
-the first ``dense_layers`` layers and ``parallel.moe.moe_dropless`` after
-them (sigmoid scores, the top-k of score + bias, weights from the scores
-renormalised and scaled, a shared expert every token visits).
+path for a chunk, the absorbed path for a decode step, whose walk over the
+past is the launch ``paged_latent_decode`` on a TPU). The feed-forward is
+a gated-SiLU MLP in the first ``dense_layers`` layers and
+``parallel.moe.moe_dropless`` after them (sigmoid scores, the top-k of
+score + bias, weights from the scores renormalised and scaled, a shared
+expert every token visits).
 
 ``experts_held`` ``(first, count)``: this chip's share of every expert
 layer under expert parallelism. The router's leaves keep all
@@ -310,8 +312,10 @@ def mla_forward_paged(params, cfg, tokens, lengths, block_tables, pools,
     ``"none"`` -> None: a forward run for its rows alone (prefill) skips
     the final norm and the head.
 
-    A chunk of one position runs the absorbed attention path, any wider
-    chunk the expanded one (``ops/pallas/paged_latent.py``)."""
+    A chunk of one position runs the absorbed attention path (one
+    ``paged_latent_decode`` launch a layer on a TPU, each sequence's own
+    blocks read once), any wider chunk the expanded one
+    (``ops/pallas/paged_latent.py``)."""
     cfg = mla_config(cfg)
     S, C = tokens.shape
     n, d = cfg["streams"], cfg["units"]

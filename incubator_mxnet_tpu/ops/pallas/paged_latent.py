@@ -25,16 +25,36 @@ Two paths compute the same numbers, and the chunk width chooses:
   row, values its first ``r``.
 
 Both walk the block table in tiles of ``key_tile`` positions under a running
-softmax (no (C, L, H) score array exists), as many tiles as the longest
-sequence of the call has: a cache sized for long sequences costs a short
-one nothing. Plain ``lax``: the TPU's compiler refuses the Mosaic kernel of
-``flash_decode.py``, and an absorbed-path kernel is not written yet.
-Products take their operands in the cache's dtype and accumulate in
-float32; scores, the softmax and its running statistics are float32.
+softmax (no (C, L, H) score array exists), so a cache sized for long
+sequences costs a short one nothing. Products take their operands in the
+cache's dtype and accumulate in float32; scores, the softmax and its running
+statistics are float32.
+
+The expanded path is plain ``lax`` (``_over_past``): a step gathers a tile
+of EVERY sequence's blocks into a copy and walks as many tiles as the
+longest sequence of the call has. The absorbed path's walk over the past is
+ONE Pallas launch a layer on a TPU, ``paged_latent_decode``: block tables
+and lengths are scalar-prefetch operands, the pool stays in HBM, a grid
+step is a sequence, and the kernel copies that sequence's own live blocks
+(no block past its length, whatever the table names there) into a tile in
+VMEM, the next tile's copies (this sequence's, or the next sequence's
+first) in flight under the tile's two products. Every live row crosses HBM
+once, rounded up to a block; ``rows_walked`` is that count, on the host,
+for the engine's ``last_stats["mla"]``. The launch returns the running
+state of the past; the step's own row (not yet in the pool) and ``W_uv``
+stay in ``lax``. Off the TPU, and as the numerics oracle, the absorbed path
+takes the ``lax`` walk too (same tiles, same precisions: bfloat16 agrees to
+the bit). ``flash_decode.py``'s Mosaic kernel (per-head K/V) is refused by
+the TPU's compiler; this one compiles and runs on a v5e (PERF.md, PR 36).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
+
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -44,12 +64,29 @@ KEY_TILE = 512
 #: a cache row is whole lanes wide
 LANES = 128
 
-__all__ = ["paged_latent_attention", "latent_path", "cache_row_width"]
+#: bytes a block of a latent cache, and so a copy of the decode kernel's:
+#: 128 positions of 640 bfloat16 lanes, what a 16-position block of a
+#: per-head cache holds. At 20 KB a copy (16 positions) the kernel's walk
+#: is bound by issuing its copies, not by HBM (PERF.md, PR 36)
+BLOCK_BYTES = 160 << 10
+
+__all__ = ["paged_latent_attention", "paged_latent_decode",
+           "paged_latent_decode_available", "latent_path", "cache_row_width",
+           "latent_block_size", "rows_walked"]
 
 
 def cache_row_width(kv_rank, rope_dim):
     """The width of a cache row: ``[c | k_r]`` and zeros up to whole lanes."""
     return -(-(kv_rank + rope_dim) // LANES) * LANES
+
+
+def latent_block_size(row_bytes, max_len):
+    """Positions a block of a latent cache whose row is `row_bytes` wide,
+    for sequences of `max_len` positions at most: whole groups of 16 worth
+    ``BLOCK_BYTES``, and no more than half of `max_len` (a sequence wastes
+    half a block on average: that stays under a quarter of what it may
+    hold, and a slot's table keeps two entries), 16 at least."""
+    return max(16, min(BLOCK_BYTES // row_bytes, max_len // 2) // 16 * 16)
 
 
 def latent_path(chunk):
@@ -75,13 +112,18 @@ def _fold(state, scores, mask, values, spec):
     return m_new, l * alpha + jnp.sum(p, axis=-1), acc
 
 
+def _tile_blocks(key_tile, block_size, blocks):
+    """Blocks a tile of the walk, over tables `blocks` wide."""
+    return max(1, min(key_tile // block_size, blocks))
+
+
 def _over_past(state, fold_rows, pool, block_tables, lengths, key_tile):
     """`fold_rows(state, rows (S, T, W), live (S, 1, 1, T))` over the
     cached rows of every sequence, tile by tile of its block table, up to
     the longest sequence's length."""
     S, blocks = block_tables.shape
     block_size = pool.shape[1]
-    tile_blocks = max(1, min(key_tile // block_size, blocks))
+    tile_blocks = _tile_blocks(key_tile, block_size, blocks)
     T = tile_blocks * block_size
     block_tables = jnp.pad(block_tables,
                            ((0, 0), (0, -blocks % tile_blocks)))
@@ -96,8 +138,154 @@ def _over_past(state, fold_rows, pool, block_tables, lengths, key_tile):
                              state)
 
 
+def paged_latent_decode_available(pool=None):
+    """Whether the absorbed walk over `pool` is the launch: the backend is
+    a TPU, and a block is whole tiles of the device (rows of whole lanes,
+    16 positions of a 2-byte dtype, 8 of a 4-byte one), which a copy into
+    VMEM needs; any latent cache of ``latent_block_size`` is."""
+    return jax.default_backend() == "tpu" and (pool is None or (
+        pool.shape[2] % LANES == 0
+        and pool.shape[1] % (32 // pool.dtype.itemsize) == 0))
+
+
+def rows_walked(lengths, block_size, blocks, kernel, key_tile=KEY_TILE):
+    """The cache rows a layer's absorbed walk fetches for sequences of
+    `lengths` committed positions (host arithmetic): with the kernel each
+    sequence's own, rounded up to whole blocks; on the ``lax`` path every
+    sequence's tiles up to the longest sequence's last."""
+    lengths = [int(n) for n in lengths]
+    if kernel:
+        return sum(-(-n // block_size) * block_size for n in lengths)
+    tile = _tile_blocks(key_tile, block_size, blocks) * block_size
+    return len(lengths) * (-(-max(lengths, default=0) // tile) * tile)
+
+
+def _decode_kernel(tables_ref, lengths_ref, q_ref, pool_ref, m_ref, l_ref,
+                   acc_ref, buf, sems, state, *, scale, rank):
+    """One sequence a grid step: its live blocks fetched tile by tile into
+    the two halves of `buf`, the next tile's (this sequence's, or the next
+    sequence's first) under the products of the one at hand. `state`
+    carries from step to step the half the step's first tile goes to and
+    whether the step before has already asked for it."""
+    s, last = pl.program_id(0), pl.num_programs(0) - 1
+    _two, tile_blocks, block_size, width = buf.shape
+    T = tile_blocks * block_size
+    length = lengths_ref[s]
+    tiles = pl.cdiv(length, T)
+
+    def each_block(seq, j, slot, act):
+        """`act` on the copy of every live block of tile j of `seq`."""
+        live = pl.cdiv(lengths_ref[seq], block_size) - j * tile_blocks
+
+        def one(b, _):
+            act(pltpu.make_async_copy(
+                pool_ref.at[tables_ref[seq, j * tile_blocks + b]],
+                buf.at[slot, b], sems.at[slot]))
+            return 0
+        jax.lax.fori_loop(0, jnp.minimum(live, tile_blocks), one, 0)
+
+    def start(seq, j, slot):
+        each_block(seq, j, slot, lambda copy: copy.start())
+
+    @pl.when(s == 0)
+    def _first():
+        # a row never fetched is multiplied by p = 0: it has to be finite
+        buf[...] = jnp.zeros_like(buf)
+        state[0] = 0
+        state[1] = 0
+    slot0 = state[0]
+
+    @pl.when((tiles > 0) & (state[1] == 0))
+    def _own():
+        start(s, 0, slot0)
+    m_ref[0] = jnp.full(m_ref.shape[1:], _NEG_INF, jnp.float32)
+    l_ref[0] = jnp.zeros(l_ref.shape[1:], jnp.float32)
+    acc_ref[0] = jnp.zeros(acc_ref.shape[1:], jnp.float32)
+    after = jnp.minimum(s + 1, last)
+    hands_on = (tiles > 0) & (s < last) & (lengths_ref[after] > 0)
+
+    def one_tile(j, _):
+        slot = (slot0 + j) & 1
+
+        @pl.when(j + 1 < tiles)
+        def _next():
+            start(s, j + 1, 1 - slot)
+
+        @pl.when((j + 1 == tiles) & hands_on)
+        def _next_sequence():
+            start(after, 0, 1 - slot)
+        each_block(s, j, slot, lambda copy: copy.wait())
+        rows = buf[slot].reshape(T, width)
+        scores = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale         # (H, T)
+        mask = (j * T + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                < length)
+        scores = jnp.where(mask, scores, _NEG_INF)
+        m = m_ref[0]
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc_ref[0] = acc_ref[0] * alpha + jnp.dot(
+            p.astype(rows.dtype), rows[:, :rank],
+            preferred_element_type=jnp.float32)
+        l_ref[0] = l_ref[0] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[0] = m_new
+        return 0
+    jax.lax.fori_loop(0, tiles, one_tile, 0)
+    state[0] = (slot0 + tiles) & 1
+    state[1] = hands_on.astype(jnp.int32)
+
+
+def paged_latent_decode(query, pool, block_tables, lengths, scale, rank,
+                        key_tile=KEY_TILE, interpret=False):
+    """The absorbed path's walk over the past, one launch: query (S, H, W)
+    in the pool's dtype against each sequence's own live rows, scores over
+    the whole row, values its first `rank` columns.
+
+    -> the running softmax's state over the past, float32: the row maximum
+    m (S, H, 1), the denominator l (S, H, 1) and the unnormalised output
+    acc (S, H, rank); a sequence with no past keeps ``(-1e30, 0, 0)``.
+
+    `block_tables` and `lengths` are scalar-prefetch operands, the pool
+    stays in HBM: a grid step is a sequence, and it copies its live blocks
+    (and no other: a table's padding is never read) into a tile of
+    `key_tile` positions in VMEM, two tiles in flight."""
+    S, H, width = query.shape
+    block_size = pool.shape[1]
+    tile_blocks = _tile_blocks(key_tile, block_size, block_tables.shape[1])
+    state = [(S, H, 1), (S, H, 1), (S, H, rank)]           # m, l, acc
+
+    def of_sequence(shape):
+        return pl.BlockSpec((1,) + shape[1:],
+                            lambda s, tables, lengths: (s, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S,),
+        in_specs=[of_sequence(query.shape),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[of_sequence(shape) for shape in state],
+        scratch_shapes=[
+            pltpu.VMEM((2, tile_blocks, block_size, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, rank=rank),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(shape, jnp.float32)
+                   for shape in state],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_decode",
+    )(block_tables, lengths, query, pool)
+
+
 def paged_latent_attention(q_nope, q_rope, new_rows, kv_b, pool,
-                           block_tables, lengths, scale, key_tile=KEY_TILE):
+                           block_tables, lengths, scale, key_tile=KEY_TILE,
+                           interpret=False):
     """Attention of a chunk over its sequence's cached rows and itself.
 
     q_nope (S, C, H, d_n), q_rope (S, C, H, d_r) rotated: the chunk's
@@ -109,7 +297,9 @@ def paged_latent_attention(q_nope, q_rope, new_rows, kv_b, pool,
     past position and the chunk's positions <= c.
 
     -> (S, C, H, d_v), in q_nope's dtype. C = 1 runs the absorbed path,
-    any other width the expanded one (``latent_path``)."""
+    any other width the expanded one (``latent_path``). The absorbed
+    path's walk over the past is the launch ``paged_latent_decode`` where
+    the backend is a TPU (`interpret` runs the launch anywhere)."""
     S, C, H, d_n = q_nope.shape
     r, d_r = kv_b.shape[0], q_rope.shape[-1]
     dtype = q_nope.dtype
@@ -149,11 +339,18 @@ def paged_latent_attention(q_nope, q_rope, new_rows, kv_b, pool,
             return _fold(state, scores * scale, mask, values.astype(dtype),
                          "shct,sthv->shcv")
 
-    state = (jnp.full((S, H, C), _NEG_INF, jnp.float32),
-             jnp.zeros((S, H, C), jnp.float32),
-             jnp.zeros((S, H, C, width), jnp.float32))
-    state = _over_past(state, fold_rows, pool, block_tables, lengths,
-                       key_tile)
+    if latent_path(C) == "absorbed" and (
+            interpret or paged_latent_decode_available(pool)):
+        m, l, acc = paged_latent_decode(
+            query[:, 0], pool, block_tables, lengths, scale, r, key_tile,
+            interpret)
+        state = (m, l, acc[:, :, None])
+    else:
+        state = (jnp.full((S, H, C), _NEG_INF, jnp.float32),
+                 jnp.zeros((S, H, C), jnp.float32),
+                 jnp.zeros((S, H, C, width), jnp.float32))
+        state = _over_past(state, fold_rows, pool, block_tables, lengths,
+                           key_tile)
     # the chunk itself, causal; its diagonal gives every row a live key
     causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]
     for lo in range(0, C, key_tile):

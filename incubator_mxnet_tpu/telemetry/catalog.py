@@ -356,6 +356,16 @@ mla_expanded_rows = _m.counter(
     "committed lengths of their sequences, summed): the price of "
     "prefilling in chunks, some L^2 / 2c a prompt of L in chunks of c, "
     "by model")
+mla_absorbed_rows_live = _m.counter(
+    "mxtpu_mla_absorbed_rows_live_total",
+    "Cached latent rows that absorbed forwards had to read (the "
+    "committed lengths of their sequences, summed; a layer's), by model")
+mla_absorbed_rows_read = _m.counter(
+    "mxtpu_mla_absorbed_rows_read_total",
+    "Cached latent rows that a layer's absorbed walk fetched for them: "
+    "each sequence's own blocks under the paged_latent_decode kernel, "
+    "every sequence's tiles up to the longest one's on the lax path; "
+    "read / live is the walk's waste, by model")
 gen_kv_blocks_in_use = _m.gauge(
     "mxtpu_gen_kv_blocks_in_use",
     "Paged-KV pool blocks currently mapped into live slot block tables")

@@ -987,6 +987,8 @@ def test_a_greedy_call_waits_for_nothing_between_two_forwards(lagged_lm):
         # two expert layers, two routes a token: a prefill chunk's 4
         # positions (pads too), a step's 3 rows
         assert st["moe"]["routes"] == (chunks * 4 + _LAG_NEW * 3) * 2 * 2
+        live = st["mla"].pop("absorbed_rows_live")
+        assert 0 < live <= st["mla"].pop("absorbed_rows_read")
         assert st["mla"] == {"absorbed_forwards": _LAG_NEW,
                              "expanded_forwards": chunks,
                              "expanded_rows": 4 + 8 + 4}

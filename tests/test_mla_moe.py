@@ -31,7 +31,8 @@ from incubator_mxnet_tpu.generate.engine import (forward_slots, prefill_slot,
 from incubator_mxnet_tpu.generate.paged_kv import PagedKVCache
 from incubator_mxnet_tpu.models import mla_moe
 from incubator_mxnet_tpu.ops.pallas.paged_latent import (
-    cache_row_width, latent_path, paged_latent_attention)
+    cache_row_width, latent_block_size, latent_path, paged_latent_attention,
+    paged_latent_decode, rows_walked)
 from incubator_mxnet_tpu.parallel.moe import moe_dropless
 from incubator_mxnet_tpu.telemetry import catalog as cat
 from incubator_mxnet_tpu.telemetry import tracing
@@ -196,7 +197,176 @@ def test_the_tiles_walked_follow_the_longest_sequence():
     assert np.isfinite(np.asarray(out)).all()
 
 
+# ------------------------------------------------ the absorbed path's kernel
+def _decode_case(rng, heads, dtype, lengths, blocks=8, bs=4, rank=16,
+                 width=32):
+    """A step's operands over a pool whose block tables are a permutation
+    of the blocks, padded past a length with any valid id."""
+    S = len(lengths)
+    lengths = np.asarray(lengths, np.int32)
+    tables = rng.permutation(S * blocks).reshape(S, blocks).astype(np.int32)
+    for s, n in enumerate(lengths):
+        used = -(-int(n) // bs)
+        tables[s, used:] = rng.integers(0, S * blocks, size=blocks - used)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), dtype)
+    return {"q_nope": draw(S, 1, heads, 8), "q_rope": draw(S, 1, heads, 8),
+            "new_rows": draw(S, 1, width),
+            "kv_b": draw(rank, heads, 16) * jnp.asarray(0.3, dtype),
+            "pool": draw(S * blocks, bs, width), "block_tables": tables,
+            "lengths": lengths, "scale": 0.25}
+
+
+# a tile is 8 positions (two blocks of 4); tables hold 32
+_DECODE_LENGTHS = {
+    "none_and_around_a_tile": [0, 7, 8, 9],
+    "around_the_second_tile": [15, 16, 17],
+    "longest_beside_shortest": [32, 1, 0, 31, 3],
+    "one_sequence": [21],
+}
+
+
+@pytest.mark.parametrize("lengths", list(_DECODE_LENGTHS.values()),
+                         ids=list(_DECODE_LENGTHS))
+@pytest.mark.parametrize("dtype,atol", [("float32", 2e-6), ("bfloat16", 0.0)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [2, 8])
+def test_the_decode_kernel_is_the_lax_walk(heads, dtype, atol, lengths):
+    """``paged_latent_decode`` (interpreted) under the absorbed path
+    against the ``lax`` walk on the same tiles: the same products in the
+    same precisions, so bfloat16 agrees to the bit and float32 to an
+    accumulation order."""
+    case = _decode_case(np.random.default_rng(len(lengths) * heads), heads,
+                        jnp.dtype(dtype), lengths)
+    want = paged_latent_attention(**case, key_tile=8)
+    got = paged_latent_attention(**case, key_tile=8, interpret=True)
+    assert got.dtype == want.dtype and got.shape == (len(lengths), 1, heads, 8)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol)
+
+
+def test_the_decode_kernel_returns_the_pasts_running_state():
+    """(m, l, acc) of each sequence's past alone: a sequence with none
+    keeps the empty state, and acc / l is the softmax-weighted mean of its
+    rows' first `rank` columns."""
+    rng = np.random.default_rng(5)
+    case = _decode_case(rng, 2, jnp.float32, [0, 11])
+    query = jnp.asarray(rng.normal(size=(2, 2, 32)), jnp.float32)
+    m, l, acc = paged_latent_decode(query, case["pool"], case["block_tables"],
+                                    case["lengths"], 0.25, 16, key_tile=8,
+                                    interpret=True)
+    assert (m.shape, l.shape, acc.shape) == ((2, 2, 1), (2, 2, 1), (2, 2, 16))
+    assert (np.asarray(m[0]) == -1e30).all()
+    assert not np.asarray(l[0]).any() and not np.asarray(acc[0]).any()
+    rows = np.asarray(case["pool"])[case["block_tables"][1, :3]]
+    rows = rows.reshape(12, 32)[:11]
+    scores = np.asarray(query[1]) @ rows.T * 0.25               # (H, 11)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    np.testing.assert_allclose(np.asarray(m[1, :, 0]), scores.max(-1),
+                               rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(acc[1] / l[1]),
+                               p @ rows[:, :16] / p.sum(-1, keepdims=True),
+                               atol=1e-5)
+
+
+def test_the_decode_kernel_reads_no_row_past_a_length():
+    """The kernel twin of the test above: a sequence with no past beside
+    one of three tiles. Whatever lies past a length changes nothing: the
+    rest of a last block is masked, and a block past it (its table entry
+    any valid id) is never fetched, so it may hold what would poison a
+    product."""
+    rng = np.random.default_rng(1)
+    case = _decode_case(rng, 2, jnp.float32, [0, 21, 6], blocks=8)
+    out = paged_latent_attention(**case, key_tile=8, interpret=True)
+    # no past: the softmax is over the row itself, so the output is its value
+    alone = np.einsum("r,rhv->hv", np.asarray(case["new_rows"][0, 0, :16]),
+                      np.asarray(case["kv_b"][..., 8:]))
+    np.testing.assert_allclose(out[0, 0], alone, atol=1e-5)
+    pool = np.asarray(case["pool"]).copy()
+    tables, live = case["block_tables"], np.zeros(len(pool), bool)
+    for s, n in enumerate(case["lengths"]):
+        used = -(-int(n) // 4)
+        live[tables[s, :used]] = True
+        if n % 4:
+            pool[tables[s, used - 1], n % 4:] = 1e4     # masked, finite
+    pool[~live] = np.nan                                # never fetched
+    tables = tables.copy()
+    tables[0, :] = np.flatnonzero(~live)[0]
+    again = paged_latent_attention(**dict(
+        case, pool=jnp.asarray(pool), block_tables=tables), key_tile=8,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(out))
+
+
+@pytest.mark.parametrize("pool,dtype,runs", [
+    ((8, 128, 640), "bfloat16", True),      # a latent cache's block
+    ((8, 16, 640), "bfloat16", True),
+    ((8, 8, 640), "bfloat16", False),       # half a bfloat16 tile
+    ((8, 8, 128), "float32", True),
+    ((8, 4, 128), "float32", False),        # the toys of this file
+    ((8, 16, 576), "bfloat16", False),      # rows that are no whole lanes
+], ids=["latent_block", "16", "half_tile", "float32", "toy", "576_wide"])
+def test_the_launch_is_chosen_where_a_block_is_whole_tiles(monkeypatch, pool,
+                                                           dtype, runs):
+    """On a TPU, by what the pool shows; never off it."""
+    from incubator_mxnet_tpu.ops.pallas import paged_latent
+    pool = jax.ShapeDtypeStruct(pool, jnp.dtype(dtype))
+    assert not paged_latent.paged_latent_decode_available(pool)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged_latent.paged_latent_decode_available()
+    assert paged_latent.paged_latent_decode_available(pool) is runs
+
+
+def test_the_kernels_walk_reads_each_sequences_own_rows():
+    """``rows_walked`` at the long-prompt cell's lengths (16 prompts of
+    2,048 to 16,384 tokens, 16-position blocks, tables of 1,056): the
+    ``lax`` walk fetches the longest sequence's tiles for every row, 2.5
+    times the live rows; the kernel's each sequence's own blocks."""
+    from benchmarks import spec
+    traffic = spec.load_json(spec.ROOT
+                             + "/benchmarks/traffic/generate_long_prompts.json")
+    lengths = [n - 1 + 128 for n in traffic["prompt_lens"]]     # mid-call
+    live = sum(lengths)
+    blocks = traffic["cache_max_len"] // 16
+    assert rows_walked(lengths, 16, blocks, kernel=False) \
+        == 16 * 33 * 512 > 2.4 * live
+    kernel = rows_walked(lengths, 16, blocks, kernel=True)
+    assert live <= kernel < 1.01 * live
+    assert rows_walked([0, 0], 16, blocks, kernel=True) == 0
+    assert rows_walked([0, 1], 16, blocks, kernel=False) == 2 * 512
+    # tables narrower than a tile: the tile is the table
+    assert rows_walked([5, 9], 4, 8, kernel=False) == 2 * 32
+    assert rows_walked([5, 9], 4, 8, kernel=True) == 8 + 12
+
+
 # ------------------------------------------------------------------ the cache
+@pytest.mark.parametrize("row_bytes,max_len,block", [
+    (640 * 2, 16896, 128),      # the long-prompt cell: 160 KB a block
+    (640 * 2, 896, 128),        # the wide-batch cell
+    (640 * 2, 200, 96),         # no more than half of max_len, in 16s
+    (128 * 4, 32, 16),          # the toy: two blocks of 16 a slot
+    (128 * 4, 4096, 320),
+    (1 << 20, 4096, 16),        # 16 at least
+], ids=["long_prompts", "wide_batch", "short_cache", "toy", "toy_long",
+        "wide_row"])
+def test_a_latent_caches_block_follows_its_rows_bytes(row_bytes, max_len,
+                                                      block):
+    assert latent_block_size(row_bytes, max_len) == block
+
+
+def test_the_adapter_sizes_its_caches_blocks_and_a_given_size_wins(model):
+    """``MLAPagedLM.make_cache`` takes the block size from the row (the
+    toy's float32 rows of 128 lanes: 320 positions, held to half of
+    `max_len`), whatever ``MXTPU_GEN_BLOCK_SIZE`` says."""
+    assert model.make_cache(2, max_len=32).block_size == 16
+    assert model.make_cache(2, max_len=2048).block_size == 320
+    assert model.make_cache(2, max_len=2048, block_size=4).block_size == 4
+    served = MLAPagedLM({}, dict(model.config), dtype="bfloat16")
+    served.kv_entries = {"c": ((640,), jnp.dtype("bfloat16"))}
+    cache = served.make_cache(1, max_len=896)
+    assert (cache.block_size, cache.max_blocks_per_slot) == (128, 7)
+    assert cache.pool("c0").shape == (7, 128, 640)
+
+
 def test_the_cache_holds_one_latent_row_a_position_and_no_k_or_v(model):
     # 16 + 8 values in a row of whole lanes (576 in 640 as published)
     assert cache_row_width(16, 8) == 128 and cache_row_width(512, 64) == 640
@@ -383,23 +553,37 @@ def test_the_dropless_layers_defaults_are_the_softmax_layer_as_it_was():
 
 
 # -------------------------------------------------------------------- tallies
-def test_a_traced_call_says_which_path_every_forward_ran(model):
-    """Prefill runs the expanded path and counts the cached rows it
-    expands again; a decode step runs the absorbed one."""
+@pytest.fixture()
+def _metrics():
+    """Metrics on for one test: left on, every later test of the worker
+    records spans with nothing listening."""
     telemetry.enable()
+    yield
+    telemetry.disable()
+
+
+def test_a_traced_call_says_which_path_every_forward_ran(model, _metrics):
+    """Prefill runs the expanded path and counts the cached rows it
+    expands again; a decode step runs the absorbed one and counts the rows
+    its walk had to read and did."""
     engine = GenerateEngine(model, model.make_cache(2, max_len=32,
                                                     block_size=4),
                             prefill_chunk=4, name="mla_tally")
     before = {c: c.value(model="mla_tally") for c in (
         cat.mla_absorbed_forwards, cat.mla_expanded_forwards,
-        cat.mla_expanded_rows)}
+        cat.mla_expanded_rows, cat.mla_absorbed_rows_live,
+        cat.mla_absorbed_rows_read)}
     tracing.clear_spans()
     with tracing.Span("test.call"):
         engine.generate([_prompt(10), _prompt(6)], max_new_tokens=3)
     mla = engine.last_stats["mla"]
     # 9 tokens in chunks of 4 at lengths 0, 4, 8; 5 tokens at 0, 4
+    # the steps' lengths (9, 5), (10, 6), (11, 7); off the TPU the walk is
+    # the lax one: both rows' tiles up to the longest, a tile the whole
+    # table of 8 blocks of 4
     assert mla == {"absorbed_forwards": 3, "expanded_forwards": 5,
-                   "expanded_rows": 4 + 8 + 4}
+                   "expanded_rows": 4 + 8 + 4, "absorbed_rows_live": 48,
+                   "absorbed_rows_read": 3 * 2 * 32}
     moe = engine.last_stats["moe"]
     assert moe["forwards"] == 8
     # two expert layers: a step of 2 rows makes 2 x 2 routes in each
@@ -410,6 +594,10 @@ def test_a_traced_call_says_which_path_every_forward_ran(model):
         - before[cat.mla_expanded_forwards] == 5
     assert cat.mla_expanded_rows.value(model="mla_tally") \
         - before[cat.mla_expanded_rows] == 16
+    assert cat.mla_absorbed_rows_live.value(model="mla_tally") \
+        - before[cat.mla_absorbed_rows_live] == 48
+    assert cat.mla_absorbed_rows_read.value(model="mla_tally") \
+        - before[cat.mla_absorbed_rows_read] == 192
     paths = [s["mla_path"] for s in tracing.recent_spans()
              if s["name"] == "lm.dispatch"]
     assert paths == ["expanded"] * 5 + ["absorbed"] * 3

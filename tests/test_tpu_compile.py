@@ -229,6 +229,37 @@ def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch):
 XING4_SLOTS, XING4_MAX_LEN, XING4_LAYERS = 16, 16896, 2
 
 
+@pytest.mark.parametrize("rows,heads,max_len", [(16, 32, 16896),
+                                               (128, 64, 896)],
+                         ids=["long_prompts", "wide_batch"])
+def test_latent_decode_launch_compiles_for_v5e(one_chip, rows, heads,
+                                               max_len):
+    """The absorbed path's walk alone at the two latent cells' shapes: the
+    absorbed queries of a step against a pool of 640-lane rows in the
+    blocks a latent cache takes (128 positions), the block tables and
+    lengths as scalar-prefetch operands, the pool left in HBM and copied
+    by the launch itself, a tile of 512 positions in each half of its
+    buffer."""
+    from incubator_mxnet_tpu.ops.pallas.paged_latent import (
+        latent_block_size, paged_latent_decode)
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    block = latent_block_size(640 * 2, max_len)
+    assert block == 128
+    blocks = max_len // block
+    compiled = jax.jit(
+        lambda q, pool, tables, lengths: paged_latent_decode(
+            q, pool, tables, lengths, 0.1, 512)).lower(
+        shape((rows, heads, 640)), shape((rows * blocks, block, 640)),
+        shape((rows, blocks), jnp.int32), shape((rows,), jnp.int32)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_latent_decode" in text
+    # the state of the past and nothing else: no copy of a tile in HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def _xing4_programs(one_chip, monkeypatch):
     """The cell's configuration at its published widths, cut for the
     compile to one dense and one expert layer, as abstract arguments for
@@ -239,7 +270,10 @@ def _xing4_programs(one_chip, monkeypatch):
     from incubator_mxnet_tpu.generate import MLAPagedLM
     from incubator_mxnet_tpu.models import mla_moe
     from incubator_mxnet_tpu.ops.pallas import grouped_matmul as gm
+    from incubator_mxnet_tpu.ops.pallas import paged_latent
     monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
+    monkeypatch.setattr(paged_latent, "paged_latent_decode_available",
+                        lambda pool=None: True)
     cfg = spec.load_json(spec.ROOT + "/benchmarks/configs/xing4_29b_a4b.json")
     program = dict(xing4.program_config(cfg), num_layers=XING4_LAYERS)
 
@@ -248,8 +282,9 @@ def _xing4_programs(one_chip, monkeypatch):
     shapes = mla_moe.mla_param_shapes(mla_moe.mla_config(program))
     model = MLAPagedLM({}, program)     # the weights are a call's argument
     model.params = {n: shape(s) for n, s in shapes.items()}
-    blocks = XING4_MAX_LEN // 16
-    pool = shape((XING4_SLOTS * blocks, 16, 640))
+    block = paged_latent.latent_block_size(640 * 2, XING4_MAX_LEN)
+    blocks = XING4_MAX_LEN // block
+    pool = shape((XING4_SLOTS * blocks, block, 640))
 
     def arguments(S, C):
         return (shape((S, C), jnp.int32), shape((S,), jnp.int32),
@@ -258,13 +293,17 @@ def _xing4_programs(one_chip, monkeypatch):
 
 
 def test_latent_decode_step_compiles_for_v5e(one_chip, monkeypatch):
-    """The cell's decode step, 16 rows of one token over 1,056-block
-    tables: the absorbed path, the grouped launch in the expert layer and
-    the head over the whole vocabulary."""
+    """The cell's decode step, 16 rows of one token over 132-block
+    tables: the absorbed path with its walk as one launch a layer, the
+    grouped launch in the expert layer and the head over the whole
+    vocabulary."""
     model, arguments, _chunk = _xing4_programs(one_chip, monkeypatch)
     compiled = model.lower(*arguments(XING4_SLOTS, 1)).compile()
     text = compiled.as_text()
     assert text.count("moe_grouped_matmul") >= 3
+    assert "paged_latent_decode" in text
+    # no tile of every row's blocks gathered into a copy
+    assert not re.search(r"bf16\[(16,512|16,4,128|64,128),640\]", text)
     # no per-head key or value of the whole table: the step reads rows
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
@@ -273,9 +312,9 @@ def test_a_held_shares_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     """The wide-batch cell's decode step at its published widths (hidden
     7,168, 64 heads, 12 of 384 experts of 7,168 x 2,048 held, an eighth of
     the vocabulary), cut for the compile to one dense and one expert
-    layer: 128 rows of one token over 56-block tables, the token head. The
-    share's passes are a loop around the three grouped launches, on 240
-    slots of a tile layout (64 routes a pass in 16-row tiles of 12
+    layer: 128 rows of one token over 7-block tables, the token head,
+    the absorbed walk one launch a layer. The share's passes are a loop
+    around the three grouped launches, on 240 slots of a tile layout (64 routes a pass in 16-row tiles of 12
     groups), and nothing as tall as the 1,024 routes of the step is 7,168
     wide."""
     from benchmarks import spec
@@ -283,7 +322,10 @@ def test_a_held_shares_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     from incubator_mxnet_tpu.generate import MLAPagedLM
     from incubator_mxnet_tpu.models import mla_moe
     from incubator_mxnet_tpu.ops.pallas import grouped_matmul as gm
+    from incubator_mxnet_tpu.ops.pallas import paged_latent
     monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
+    monkeypatch.setattr(paged_latent, "paged_latent_decode_available",
+                        lambda pool=None: True)
     cfg = spec.load_json(spec.ROOT
                          + "/benchmarks/configs/kimi_k2_7_code.json")
     program = dict(kimi_k2.program_config(cfg), num_layers=2)
@@ -295,8 +337,8 @@ def test_a_held_shares_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     assert shapes["l1_router_w"] == (7168, 384)
     model = MLAPagedLM({}, program)     # the weights are a call's argument
     model.params = {n: shape(s) for n, s in shapes.items()}
-    rows, blocks = 128, 896 // 16
-    pools = [shape((rows * blocks, 16, 640))] * 2
+    rows, blocks = 128, 896 // 128
+    pools = [shape((rows * blocks, 128, 640))] * 2
     lowered = model.lower(shape((rows, 1), jnp.int32),
                           shape((rows,), jnp.int32),
                           shape((rows, blocks), jnp.int32), pools,
@@ -308,6 +350,8 @@ def test_a_held_shares_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     text = compiled.as_text()
     assert text.count("moe_grouped_matmul") >= 3
     assert "bf16[240,7168]" in text and "[1024,7168]" not in text
+    assert "paged_latent_decode" in text
+    assert not re.search(r"bf16\[(128,512|128,4,128|512,128),640\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
@@ -370,13 +414,15 @@ def test_latent_prefill_chunk_fits_beside_the_weights_on_v5e(one_chip,
     assert "moe_grouped_matmul" not in compiled.as_text()
 
 
+@pytest.mark.parametrize("block", [128, 16], ids=["latent_block", "16"])
 @pytest.mark.parametrize("chunk", [(16, 1), (1, 2048)],
                          ids=["step", "prefill_chunk"])
-def test_the_latent_commit_copies_no_pool_on_v5e(one_chip, chunk):
+def test_the_latent_commit_copies_no_pool_on_v5e(one_chip, chunk, block):
     """One entry a layer: the commit program of a latent cache takes the
     pools, the rows and the positions, aliases every pool and copies
-    none. A row is 576 values in 640, five whole lane tiles (11 % of the
-    pools, 0.17 GB of the cell's 1.73): the chip holds that pool in row
+    none, in the 128-position blocks ``MLAPagedLM.make_cache`` takes and
+    in blocks of 16. A row is 576 values in 640, five whole lane tiles
+    (11 % of the pools, 0.17 GB of the cell's 1.73): the chip holds that pool in row
     order, and one of 576-wide rows with its BLOCKS minor, which every
     forward would copy into row order to gather from."""
     wide = jax.ShapeDtypeStruct((16 * XING4_MAX_LEN // 16, 16, 576),
@@ -384,7 +430,7 @@ def test_the_latent_commit_copies_no_pool_on_v5e(one_chip, chunk):
     held = jax.jit(lambda p: p).lower(wide).compile().input_formats[0][0]
     assert tuple(held.layout.major_to_minor) == (1, 2, 0)
     from incubator_mxnet_tpu.generate.paged_kv import store_program_for
-    layers, pool = 5, (16 * XING4_MAX_LEN // 16, 16, 640)
+    layers, pool = 5, (16 * XING4_MAX_LEN // block, block, 640)
     kv = jax.ShapeDtypeStruct(pool, jnp.bfloat16, sharding=one_chip)
     new = jax.ShapeDtypeStruct((layers,) + chunk + pool[2:], jnp.bfloat16,
                                sharding=one_chip)
