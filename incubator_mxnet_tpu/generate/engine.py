@@ -590,6 +590,7 @@ class GenerateEngine:
         # a model with an expert layer or a latent cache: every forward of
         # its is tallied into the call's ``last_stats["moe"]`` / ``["mla"]``
         self._tallies = {}
+        self._phase = "prefill"     # of the forward in flight: ``_run``
         self._note = (self._note_forward
                       if hasattr(model, "last_expert_loads")
                       or hasattr(model, "last_latent_path") else None)
@@ -621,13 +622,16 @@ class GenerateEngine:
         loads, share = split(loads) if split else (loads, {})
         moe = self._tallies["moe"]
         routes, hit = int(loads.sum()), int((loads > 0).sum())
-        moe["forwards"] += 1
-        moe["routes"] += routes
-        moe["experts_hit"] += hit
-        _cat.moe_routes.inc(routes, model=self.name)
-        _cat.moe_experts_hit.inc(hit, model=self.name)
+        # the totals, and the same a second time under the forward's phase
+        for tally in (moe, moe["by_phase"][self._phase]):
+            tally["forwards"] += 1
+            tally["routes"] += routes
+            tally["experts_hit"] += hit
+            for key, count in share.items():
+                tally[key] = tally.get(key, 0) + count
+        _cat.moe_routes.inc(routes, model=self.name, phase=self._phase)
+        _cat.moe_experts_hit.inc(hit, model=self.name, phase=self._phase)
         for key, count in share.items():
-            moe[key] = moe.get(key, 0) + count
             getattr(_cat, "moe_" + key).inc(count, model=self.name)
         # a share's layer may get no route at all: it has no fullest expert
         routed = loads[loads.sum(axis=1) > 0]
@@ -649,10 +653,42 @@ class GenerateEngine:
     def generate(self, prompts, max_new_tokens, eos_id=None):
         """Generate continuations for ``prompts`` (lists of int token
         ids); returns a list of generated-token lists (prompt excluded).
-        Stats for the run land in ``self.last_stats``."""
+        Stats for the run land in ``self.last_stats``. Where spans are
+        real the call is one ``gen.call`` span whose children are
+        ``gen.admit`` (the prompts checked and given their slots), the
+        regions (``gen.prefill``, then ``gen.decode_step`` or
+        ``gen.block``) and ``gen.release`` (the slots freed)."""
         prompts = [list(map(int, p)) for p in prompts]
         if not prompts:
             return []
+        # a child of the caller's span, or (metrics on, no caller's span) a
+        # root, whose journey ``tracing`` keeps whole
+        with _tr.span("gen.call", model=self.name, rows=len(prompts),
+                      prompt_tokens=sum(map(len, prompts)),
+                      max_new_tokens=max_new_tokens) as call:
+            seqs = []      # per sequence: dict(ctx, slot, dslot, out, done)
+            try:
+                with _tr.span("gen.admit"):
+                    stats = self._admit(prompts, max_new_tokens, seqs)
+                self._run(seqs, max_new_tokens, eos_id, stats)
+                call.set_attr("tokens_committed", stats["decode_tokens"])
+                self.last_stats = stats
+                return [s["out"] for s in seqs]
+            finally:
+                with _tr.span("gen.release"):
+                    for s in seqs:
+                        if (s["slot"] is not None
+                                and s["slot"] in self.cache._live):
+                            self.cache.free(s["slot"])
+                        if (s["dslot"] is not None
+                                and s["dslot"] in self.draft_cache._live):
+                            self.draft_cache.free(s["dslot"])
+
+    def _admit(self, prompts, max_new_tokens, seqs):
+        """Check that every prompt fits, open the call's tallies and give
+        each prompt its cache slots, appended to `seqs` one by one (the
+        caller frees what was given before a refusal). -> the call's
+        ``stats``."""
         for p in prompts:
             if not p:
                 raise ValueError("empty prompt")
@@ -669,83 +705,85 @@ class GenerateEngine:
                  "proposed": 0, "accepted": 0}
         if hasattr(self.model, "last_expert_loads"):
             stats["moe"] = {"forwards": 0, "routes": 0, "experts_hit": 0,
-                            "load_max_over_mean": []}
+                            "load_max_over_mean": [],
+                            "by_phase": {phase: dict.fromkeys(
+                                ("forwards", "routes", "experts_hit"), 0)
+                                for phase in ("prefill", "decode")}}
         if hasattr(self.model, "last_latent_path"):
             stats["mla"] = dict.fromkeys(
                 ("absorbed_forwards", "expanded_forwards", "expanded_rows",
                  "absorbed_rows_live", "absorbed_rows_read"), 0)
         self._tallies = stats
-        seqs = []      # per sequence: dict(ctx, slot, dslot, out, done)
-        try:
-            for p in prompts:
-                slot = self.cache.alloc()
-                if slot is None:
-                    raise ValueError("no free KV slot for prompt %d"
-                                     % len(seqs))
-                dslot = None
-                if self.draft is not None:
-                    dslot = self.draft_cache.alloc()
-                    if dslot is None:
-                        raise ValueError("no free draft KV slot")
-                seqs.append({"ctx": list(p), "slot": slot, "dslot": dslot,
-                             "out": [], "done": False})
+        for p in prompts:
+            slot = self.cache.alloc()
+            if slot is None:
+                raise ValueError("no free KV slot for prompt %d"
+                                 % len(seqs))
+            dslot = None
+            if self.draft is not None:
+                dslot = self.draft_cache.alloc()
+                if dslot is None:
+                    raise ValueError("no free draft KV slot")
+            seqs.append({"ctx": list(p), "slot": slot, "dslot": dslot,
+                         "out": [], "done": False})
+        return stats
 
-            # prefill: commit ctx[:-1]; the last prompt token is fed by
-            # the first decode step (its logits choose token 1). Each
-            # region (a prompt's prefill, a decode step or round) is
-            # timed once: its span, its histogram and last_stats hold
-            # that one reading.
-            # A block model prefills the prompt's WHOLE blocks, with the
-            # forward that skips the head; the tail opens the first
-            # generated block. A prefill waits for no commit, so the last
-            # region waits for the last: the device's time for the prompts
-            # lies inside the regions that launched it.
-            B = self.block_length
-            for s in seqs:
-                n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
-                with _tr.span("gen.prefill", model=self.name,
-                              slot=s["slot"], tokens=max(n, 0)) as sp:
-                    t0 = time.monotonic()
-                    if n > 0:
-                        prefill_slot(self.model, self.cache, s["slot"],
-                                     s["ctx"][:n], self.prefill_chunk,
-                                     self._note)
+    def _run(self, seqs, max_new_tokens, eos_id, stats):
+        """The admitted call: every prompt's prefill, then the decode loop
+        the model and the engine's options choose. ``self._phase`` says
+        which of the two a forward belongs to (``_note_forward``'s tallies
+        by phase): what a forward leaves on the device is read before its
+        phase ends (``prefill_slot`` reads the last chunk's loads before it
+        returns, the loops their last step's), so a tally is its forward's
+        phase's."""
+        # prefill: commit ctx[:-1]; the last prompt token is fed by
+        # the first decode step (its logits choose token 1). Each
+        # region (a prompt's prefill, a decode step or round) is
+        # timed once: its span, its histogram and last_stats hold
+        # that one reading.
+        # A block model prefills the prompt's WHOLE blocks, with the
+        # forward that skips the head; the tail opens the first
+        # generated block. A prefill waits for no commit, so the last
+        # region waits for the last: the device's time for the prompts
+        # lies inside the regions that launched it.
+        B = self.block_length
+        self._phase = "prefill"
+        for s in seqs:
+            n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
+            with _tr.span("gen.prefill", model=self.name,
+                          slot=s["slot"], tokens=max(n, 0)) as sp:
+                t0 = time.monotonic()
+                if n > 0:
+                    prefill_slot(self.model, self.cache, s["slot"],
+                                 s["ctx"][:n], self.prefill_chunk,
+                                 self._note)
+                    if self.draft is not None:
+                        prefill_slot(self.draft, self.draft_cache,
+                                     s["dslot"], s["ctx"][:n],
+                                     self.prefill_chunk)
+                    stats["prefill_tokens"] += n
+                if s is seqs[-1]:
+                    with _tr.span("kv.sync"):
+                        self.cache.sync()
                         if self.draft is not None:
-                            prefill_slot(self.draft, self.draft_cache,
-                                         s["dslot"], s["ctx"][:n],
-                                         self.prefill_chunk)
-                        stats["prefill_tokens"] += n
-                    if s is seqs[-1]:
-                        with _tr.span("kv.sync"):
-                            self.cache.sync()
-                            if self.draft is not None:
-                                self.draft_cache.sync()
-                    dt = time.monotonic() - t0
-                    sp.set_duration(dt)
-                stats["prefill_seconds"] += dt
-                _cat.gen_prefill_seconds.observe(dt, model=self.name)
+                            self.draft_cache.sync()
+                dt = time.monotonic() - t0
+                sp.set_duration(dt)
+            stats["prefill_seconds"] += dt
+            _cat.gen_prefill_seconds.observe(dt, model=self.name)
 
-            if B:
-                self._block_loop(seqs, max_new_tokens, eos_id, stats)
-            elif self.draft is not None and self.spec_k > 0:
-                for s in seqs:
-                    self._speculative_loop(s, max_new_tokens, eos_id,
-                                           stats)
-            else:
-                self._plain_loop(seqs, max_new_tokens, eos_id, stats)
-            _cat.gen_tokens_committed.inc(
-                stats["prefill_tokens"], model=self.name, phase="prefill")
-            _cat.gen_tokens_committed.inc(
-                stats["decode_tokens"], model=self.name, phase="decode")
-            self.last_stats = stats
-            return [s["out"] for s in seqs]
-        finally:
+        self._phase = "decode"
+        if B:
+            self._block_loop(seqs, max_new_tokens, eos_id, stats)
+        elif self.draft is not None and self.spec_k > 0:
             for s in seqs:
-                if s["slot"] is not None and s["slot"] in self.cache._live:
-                    self.cache.free(s["slot"])
-                if (s["dslot"] is not None
-                        and s["dslot"] in self.draft_cache._live):
-                    self.draft_cache.free(s["dslot"])
+                self._speculative_loop(s, max_new_tokens, eos_id, stats)
+        else:
+            self._plain_loop(seqs, max_new_tokens, eos_id, stats)
+        _cat.gen_tokens_committed.inc(
+            stats["prefill_tokens"], model=self.name, phase="prefill")
+        _cat.gen_tokens_committed.inc(
+            stats["decode_tokens"], model=self.name, phase="decode")
 
     # ------------------------------------------------------ plain decode
     def _plain_loop(self, seqs, max_new_tokens, eos_id, stats):

@@ -62,6 +62,7 @@ import numpy as np
 from ..telemetry import catalog as _cat
 from ..telemetry import flight as _flight
 from ..telemetry import memz as _memz
+from ..telemetry import metrics as _metrics
 
 __all__ = ["PagedKVCache", "KVPoolExhausted"]
 
@@ -539,13 +540,17 @@ class PagedKVCache:
         free = self.num_blocks - in_use
         if in_use > self._peak_blocks:
             self._peak_blocks = in_use
-        _cat.gen_kv_blocks_in_use.set(in_use, name=self.name)
-        _cat.gen_kv_blocks_free.set(free, name=self.name)
-        _cat.gen_kv_free_fraction.set(free / float(self.num_blocks),
-                                      name=self.name)
-        _cat.gen_kv_blocks_in_use_peak.set(self._peak_blocks,
-                                           name=self.name)
-        _cat.gen_kv_fragmentation.set(self.fragmentation(), name=self.name)
+        # the gauges drop what they are handed while metrics are off, and
+        # fragmentation() is a sum over the live slots, on every commit
+        if _metrics.enabled():
+            _cat.gen_kv_blocks_in_use.set(in_use, name=self.name)
+            _cat.gen_kv_blocks_free.set(free, name=self.name)
+            _cat.gen_kv_free_fraction.set(free / float(self.num_blocks),
+                                          name=self.name)
+            _cat.gen_kv_blocks_in_use_peak.set(self._peak_blocks,
+                                               name=self.name)
+            _cat.gen_kv_fragmentation.set(self.fragmentation(),
+                                          name=self.name)
         _memz.note_kv(self)
         # near-exhaustion flight event, edge-triggered so a pool parked
         # at 95% doesn't spam the ring on every append

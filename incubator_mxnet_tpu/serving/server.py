@@ -321,7 +321,7 @@ class ModelServer:
             tid = meta.get("trace_id")
             if tid is not None:
                 return {"trace_id": tid, "timeline":
-                        _tr.build_timeline(_tr.recent_spans(),
+                        _tr.build_timeline(_tr.spans_for_trace(tid),
                                            trace_id=tid)}, b""
             n = int(meta.get("limit", 256))
             return {"spans": _tr.recent_spans(n)}, b""

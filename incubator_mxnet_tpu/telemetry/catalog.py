@@ -107,15 +107,6 @@ jit_compiles = _m.counter(
 jit_compile_seconds = _m.counter(
     "mxtpu_jit_compile_seconds_total",
     "Cumulative XLA backend_compile seconds via jax.monitoring, by where")
-# DEPRECATED aliases (PR 3 names): un-labeled process-wide totals kept so
-# existing dashboards don't break; new consumers read mxtpu_jit_*.
-trainer_jit_compiles = _m.counter(
-    "mxtpu_trainer_jit_compiles_total",
-    "DEPRECATED alias of mxtpu_jit_compiles_total (label-free total; "
-    "counts serving/warmup compiles too despite the trainer_ name)")
-trainer_jit_compile_seconds = _m.counter(
-    "mxtpu_trainer_jit_compile_seconds_total",
-    "DEPRECATED alias of mxtpu_jit_compile_seconds_total")
 
 # -- data pipeline (gluon/data/dataloader.py) ------------------------
 dataloader_batches = _m.counter(
@@ -312,14 +303,16 @@ gen_block_positions_committed = _m.counter(
 moe_routes = _m.counter(
     "mxtpu_moe_routes_total",
     "Token-expert routes computed by the dropless expert layer, summed "
-    "over layers, by model — tokens x experts a token x layers a forward: "
-    "none is dropped (of a layer that holds a share of its experts: the "
-    "routes on the experts held here)")
+    "over layers, by model and phase (prefill|decode: the forward's; a "
+    "block loop's denoising and store forwards are decode) — tokens x "
+    "experts a token x layers a forward: none is dropped (of a layer that "
+    "holds a share of its experts: the routes on the experts held here)")
 moe_experts_hit = _m.counter(
     "mxtpu_moe_experts_hit_total",
     "Distinct experts that got at least one route, summed over layers "
-    "and forwards, by model (over layers x forwards: the experts whose "
-    "weights a forward reads)")
+    "and forwards, by model and phase (over layers x forwards: the experts "
+    "whose weights a forward reads; a prefill chunk hits most, a decode "
+    "step few)")
 moe_routes_elsewhere = _m.counter(
     "mxtpu_moe_routes_elsewhere_total",
     "Token-expert routes that an expert layer holding a SHARE of its "
@@ -637,15 +630,14 @@ def compiling(where):
 
 def compile_events(where=None):
     """Current backend_compile event count — ``where=None`` sums every
-    label (the process-wide total the deprecated alias also carries)."""
+    label (the process-wide total)."""
     if where is not None:
         return jit_compiles.value(where=where)
     return sum(jit_compiles.snapshot().values())
 
 
 def install_jax_compile_hook():
-    """Register a jax.monitoring listener feeding the mxtpu_jit_* metrics
-    (and their deprecated trainer_jit_* aliases)."""
+    """Register a jax.monitoring listener feeding the mxtpu_jit_* metrics."""
     with _hook_lock:
         if _hook_state["installed"]:
             return
@@ -663,5 +655,3 @@ def _on_jax_event_duration(event, duration, **_kw):
         where = getattr(_compile_ctx, "where", None) or "other"
         jit_compiles.inc(where=where)
         jit_compile_seconds.inc(duration, where=where)
-        trainer_jit_compiles.inc()              # deprecated aliases
-        trainer_jit_compile_seconds.inc(duration)

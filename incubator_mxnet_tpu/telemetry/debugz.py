@@ -150,8 +150,9 @@ class _Handler(BaseHTTPRequestHandler):
                     # journey lookup: the stitched timeline for one
                     # trace id (exemplars in /metrics.json and flight
                     # events in /flightz carry the ids to ask with)
-                    tl = tracing.build_timeline(tracing.recent_spans(),
-                                                trace_id=tid)
+                    # (a kept journey whole, then the ring)
+                    tl = tracing.build_timeline(
+                        tracing.spans_for_trace(tid), trace_id=tid)
                     if "format=text" in query:
                         body = tracing.render_timeline(tl) + "\n"
                         ctype = "text/plain; charset=utf-8"

@@ -24,6 +24,18 @@ it leaves the queue), and ``build_timeline()`` stitches one trace id's
 spans — local + fetched from remote processes — into a parent/child
 tree tolerant of orphan parents and duplicate ids.
 
+Journeys kept whole: a real ROOT span (one with no parent) opens a
+list for its trace id, every record that finishes under that trace id
+joins it as well as the ring, and when the root closes the list, root
+last, moves to a short deque of finished journeys
+(``recent_journeys()``). A call that makes more records than the ring
+holds (a ``generate`` call of 128 rows makes some 2,600) is still read
+whole, by ``spans_for_trace`` / ``/tracez?trace_id=`` and by the
+benchmark's readers. A journey stops collecting at
+``JOURNEY_MAX_SPANS`` records and its root record then says how many it
+left out (``journey_dropped``); ``JOURNEYS_KEPT`` of them are kept.
+Nothing is collected unless spans are real.
+
 One clock with the device: while a ``jax.profiler`` session is running
 (the benchmark's, an operator's ``start_trace``), every real span also
 enters a ``jax.profiler.TraceAnnotation`` of its name for its lifetime,
@@ -55,7 +67,8 @@ __all__ = ["span", "from_meta", "current", "inject", "extract",
            "merge_traces", "Span", "recent_spans", "clear_spans",
            "dump_spans", "request_span", "record_span", "sample_rate",
            "set_sample_rate", "spans_for_trace", "build_timeline",
-           "render_timeline"]
+           "render_timeline", "recent_journeys", "JOURNEY_MAX_SPANS",
+           "JOURNEYS_KEPT"]
 
 # RPC meta keys the propagation rides on (underscore-prefixed like the
 # idempotency keys _client/_seq so servers treat them as annotations).
@@ -92,11 +105,22 @@ def set_sample_rate(rate):
 _tls = threading.local()
 
 
+# Two ``generate`` calls of the widest cell the benchmark has fit the
+# ring (128 rows x 384 new tokens: 2,566 records a call); some 5 MB full.
+DEFAULT_MAX_SPANS = 8192
+# A journey collects at most this many records, and this many finished
+# journeys are kept: constants, so what roots can hold is bounded.
+JOURNEY_MAX_SPANS = 32768
+JOURNEYS_KEPT = 4
+_JOURNEYS_OPEN = 256    # roots open at once; the oldest's journey goes
+
+
 def _default_max_spans():
     try:
-        return max(16, int(os.environ.get("MXTPU_TRACE_MAX_SPANS", "4096")))
+        return max(16, int(os.environ.get("MXTPU_TRACE_MAX_SPANS",
+                                          DEFAULT_MAX_SPANS)))
     except ValueError:
-        return 4096
+        return DEFAULT_MAX_SPANS
 
 
 # Bounded retention of finished spans.  profiler._events only records
@@ -106,6 +130,10 @@ def _default_max_spans():
 # and week-long jobs can't grow span storage without bound.
 _finished_lock = threading.Lock()
 _finished = deque(maxlen=_default_max_spans())
+# Under the same lock: trace id -> [the records of a root still open, how
+# many the cap left out], and the journeys of the roots that closed.
+_open_journeys = {}
+_journeys = deque(maxlen=JOURNEYS_KEPT)
 
 
 def _resize(maxlen):
@@ -115,12 +143,26 @@ def _resize(maxlen):
         _finished = deque(_finished, maxlen=max(1, int(maxlen)))
 
 
-def _retain(rec):
+def _retain(rec, root=False):
+    """`rec` into the ring and into its trace's open journey; `root`: the
+    record of the span that opened the journey, which closes it."""
     dropped = False
     with _finished_lock:
         if len(_finished) == _finished.maxlen:
             dropped = True
         _finished.append(rec)
+        journey = _open_journeys.get(rec["trace_id"])
+        if journey is not None:
+            if root:
+                del _open_journeys[rec["trace_id"]]
+                if journey[1]:
+                    rec["journey_dropped"] = journey[1]
+                journey[0].append(rec)
+                _journeys.append(journey[0])
+            elif len(journey[0]) < JOURNEY_MAX_SPANS - 1:
+                journey[0].append(rec)
+            else:
+                journey[1] += 1
     if dropped and _metrics._state["enabled"]:
         from . import catalog as _cat  # late: catalog imports this module's package
         _cat.telemetry_spans_dropped.inc()
@@ -134,8 +176,22 @@ def recent_spans(n=None):
 
 
 def clear_spans():
+    """Empty the ring. The kept journeys and the ones still open are left
+    alone: a reader that drains the ring a call (the benchmark's runners)
+    must not cut a root's journey short."""
     with _finished_lock:
         _finished.clear()
+
+
+def recent_journeys(root_name=None):
+    """The finished journeys, oldest first: each the list of the records
+    that finished under one root span's trace id while it was open, the
+    root's own record last (``journey_dropped`` on it where the journey
+    passed ``JOURNEY_MAX_SPANS``). `root_name`: those of roots so named."""
+    with _finished_lock:
+        journeys = list(_journeys)
+    return [j for j in journeys
+            if root_name is None or j[-1]["name"] == root_name]
 
 
 def dump_spans(path=None):
@@ -175,7 +231,7 @@ class Span:
     """A timed region; use as a context manager."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "sampled", "_t0", "_dur", "_annotation")
+                 "sampled", "_t0", "_dur", "_annotation", "_opened")
 
     def __init__(self, name, trace_id=None, parent_id=None, attrs=None,
                  sampled=False):
@@ -186,6 +242,7 @@ class Span:
         self.attrs = attrs or {}
         self.sampled = sampled
         self._t0 = self._dur = self._annotation = None
+        self._opened = False    # this span's journey: a root's alone
 
     def set_attr(self, key, value):
         """Attributes set before the span is entered also ride its
@@ -203,6 +260,13 @@ class Span:
     def __enter__(self):
         self._t0 = time.time() * 1e6
         _stack().append(self)
+        if self.parent_id is None:
+            with _finished_lock:
+                if self.trace_id not in _open_journeys:
+                    if len(_open_journeys) >= _JOURNEYS_OPEN:   # leaked roots
+                        del _open_journeys[next(iter(_open_journeys))]
+                    _open_journeys[self.trace_id] = [[], 0]
+                    self._opened = True
         if _TraceAnnotation.is_enabled():
             self._annotation = _TraceAnnotation(self.name, **self.attrs)
             self._annotation.__enter__()
@@ -229,7 +293,8 @@ class Span:
                          dur=dur, args=args)
         rec = {"name": self.name, "ts_us": self._t0, "dur_us": dur}
         rec.update(args)
-        _retain(rec)
+        _retain(rec, root=self._opened)
+        self._opened = False
         return False
 
 
@@ -357,9 +422,17 @@ def record_span(name, trace_id, parent_id=None, t0=None, t1=None,
 
 def spans_for_trace(trace_id, spans=None):
     """The retained spans (or ``spans``, if given) carrying this trace
-    id, oldest first."""
-    pool = recent_spans() if spans is None else spans
-    out = [s for s in pool if s.get("trace_id") == trace_id]
+    id, oldest first: the kept journey of that trace id whole, then what
+    the ring holds beside it (a journey still open, records that came
+    after the root closed), each record once."""
+    if spans is None:
+        with _finished_lock:
+            spans = [s for j in _journeys
+                     if j[-1].get("trace_id") == trace_id for s in j]
+            spans += _open_journeys.get(trace_id, ((),))[0]
+        seen = {id(s) for s in spans}
+        spans += [s for s in recent_spans() if id(s) not in seen]
+    out = [s for s in spans if s.get("trace_id") == trace_id]
     out.sort(key=lambda s: s.get("ts_us") or 0)
     return out
 

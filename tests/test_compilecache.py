@@ -257,13 +257,15 @@ def test_compiling_context_labels_events(tele):
     assert cat.compile_events(where="warmup") == base + 1
 
 
-def test_deprecated_trainer_jit_aliases_still_count(tele):
+def test_an_event_outside_any_region_counts_under_other(tele):
+    # (the deprecated trainer_jit_* aliases this test read are gone)
     x = jnp.ones((3,)) * 3.0
-    old = cat.trainer_jit_compiles.value()
+    old = cat.compile_events(where="other")
     new = cat.compile_events()
     jax.jit(lambda v: v * 31.7 - 0.77)(x)
-    assert cat.trainer_jit_compiles.value() == old + 1
+    assert cat.compile_events(where="other") == old + 1
     assert cat.compile_events() == new + 1
+    assert not hasattr(cat, "trainer_jit_compiles")
 
 
 # ------------------------------------------------------- warmup env knobs

@@ -641,17 +641,20 @@ def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
     # prefill: 5 tokens in chunks of 4 is two forwards, 2 tokens one;
     # decode: three steps of both rows
     assert len(forwards) == 3 + 3
-    # the call, 2 prefills, 3 steps, 4 phases a forward, the last step's
-    # second fetch and the prefill's one wait
-    assert len(recs) == 1 + 2 + 3 + 4 * len(forwards) + 1 + 1 \
-        <= 1 + 5 * len(forwards) + 2
+    # the caller's span, the engine's call with its admission and release,
+    # 2 prefills, 3 steps, 4 phases a forward, the last step's second
+    # fetch and the prefill's one wait
+    assert len(recs) == 1 + 3 + 2 + 3 + 4 * len(forwards) + 1 + 1 \
+        <= 4 + 5 * len(forwards) + 2
+    (gen_call,) = [r for r in recs if r["name"] == "gen.call"]
+    assert gen_call["parent_id"] == call.span_id
     for r in recs:
         if r["name"] in phases + ("kv.sync",):
             assert r["dur_us"] > 0
             assert by_id[r["parent_id"]]["name"] in ("gen.prefill",
                                                      "gen.decode_step")
-        elif r["name"] != "test.call":
-            assert r["parent_id"] == call.span_id
+        elif r["name"] not in ("test.call", "gen.call"):
+            assert r["parent_id"] == gen_call["span_id"]
     steps = [r for r in recs if r["name"] == "gen.decode_step"]
     assert [s["fed"] for s in steps] == ["host", "device", "device"]
     for n, step in enumerate(steps):
@@ -687,6 +690,58 @@ def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
     assert sum(r["dur_us"] for r in recs if r["name"] == "gen.prefill") \
         / 1e6 == pytest.approx(eng.last_stats["prefill_seconds"], rel=1e-6)
     assert cat.gen_decode_seconds.count(model="gpt") == 3
+
+
+def test_a_call_is_one_gen_call_with_its_admission_regions_and_release(
+        target_lm):
+    """Under a caller's span a call is ONE ``gen.call`` (what it was asked
+    and what it committed as attributes) whose children are ``gen.admit``,
+    the prefills, the steps and ``gen.release``, in that order; the slots
+    are given inside the admission and freed inside the release. Alone
+    with metrics on the call is a root, and its journey is kept whole."""
+    from incubator_mxnet_tpu.telemetry import tracing
+    cache = target_lm.make_cache(2, max_len=64)
+    eng = GenerateEngine(target_lm, cache, prefill_chunk=4, name="owned")
+    prompts = [[3, 5, 7, 2, 11, 1], [9, 8, 4]]
+    with tracing.Span("test.call") as caller:
+        eng.generate(prompts, max_new_tokens=3)
+    journey = tracing.recent_journeys("test.call")[-1]
+    (call,) = [r for r in journey if r["name"] == "gen.call"]
+    assert call["parent_id"] == caller.span_id
+    assert {k: call[k] for k in ("model", "rows", "prompt_tokens",
+                                 "max_new_tokens", "tokens_committed")} \
+        == {"model": "owned", "rows": 2, "prompt_tokens": 9,
+            "max_new_tokens": 3, "tokens_committed": 6}
+    kids = sorted((r for r in journey
+                   if r.get("parent_id") == call["span_id"]),
+                  key=lambda r: r["ts_us"])
+    assert [k["name"] for k in kids] == (
+        ["gen.admit"] + ["gen.prefill"] * 2 + ["gen.decode_step"] * 3
+        + ["gen.release"])
+    assert all(call["ts_us"] <= k["ts_us"] and k["ts_us"] + k["dur_us"]
+               <= call["ts_us"] + call["dur_us"] + 1 for k in kids)
+    assert cache.in_use == 0
+    # a refused call has its admission and its release all the same
+    with tracing.Span("test.refused"):
+        with pytest.raises(ValueError, match="exceeds cache"):
+            eng.generate([[1] * 70], max_new_tokens=3)
+    refused = [r["name"] for r in tracing.recent_journeys("test.refused")[-1]]
+    assert refused == ["gen.admit", "gen.release", "gen.call",
+                       "test.refused"]
+    # alone, metrics on: the call is the root of its own journey
+    telemetry.enable()
+    try:
+        eng.generate(prompts, max_new_tokens=3)
+    finally:
+        telemetry.disable()
+    own = tracing.recent_journeys("gen.call")[-1]
+    assert own[-1]["name"] == "gen.call" and "parent_id" not in own[-1]
+    assert len(own) == len(journey) - 1
+    # telemetry idle: no span is real and nothing is kept
+    kept = len(tracing.recent_journeys())
+    eng.generate(prompts, max_new_tokens=3)
+    assert len(tracing.recent_journeys()) == kept
+    assert tracing.recent_journeys("gen.call")[-1] is own
 
 
 def test_the_span_readers_split_a_decode_step_with_the_pools_on_the_device(
@@ -1094,7 +1149,10 @@ def test_a_held_shares_tallies_count_the_work_done_here(share_lm):
     telemetry.enable()
     eng = GenerateEngine(share_lm, share_lm.make_cache(3, max_len=64),
                          prefill_chunk=_LAG_CHUNK, name="share_tally")
-    before = {c: c.value(model="share_tally") for c in (
+    def counted(counter):      # over the counter's other labels (phase)
+        return sum(value for labels, value in counter.snapshot().items()
+                   if ("model", "share_tally") in labels)
+    before = {c: counted(c) for c in (
         cat.moe_routes, cat.moe_experts_hit, cat.moe_routes_elsewhere,
         cat.moe_rows_moved)}
     out = eng.generate(_LAG_PROMPTS, max_new_tokens=_LAG_NEW)
@@ -1102,7 +1160,7 @@ def test_a_held_shares_tallies_count_the_work_done_here(share_lm):
     moe = eng.last_stats["moe"]
     assert set(moe) == {"forwards", "routes", "experts_hit",
                         "load_max_over_mean", "routes_elsewhere",
-                        "rows_moved"}
+                        "rows_moved", "by_phase"}
     # prompts of 13, 2 and 9 commit 12, 1 and 8 tokens in chunks of 4 (a
     # chunk's padding routes too): 3 + 1 + 2 chunks of 4 positions, then
     # 12 steps of 3 rows; 2 expert layers, 8 routes a position
@@ -1122,8 +1180,48 @@ def test_a_held_shares_tallies_count_the_work_done_here(share_lm):
                          (cat.moe_experts_hit, "experts_hit"),
                          (cat.moe_routes_elsewhere, "routes_elsewhere"),
                          (cat.moe_rows_moved, "rows_moved")):
-        assert counter.value(model="share_tally") - before[counter] \
+        assert counted(counter) - before[counter] == moe[key]
+
+
+def test_the_expert_tallies_by_phase_sum_to_the_totals(latent_lm):
+    """``last_stats["moe"]["by_phase"]`` parts the prefill chunks' loads
+    from the decode steps'; the loads are read one forward behind, and
+    still each lands in the phase of the forward that made it: the last
+    chunk's before its prefill returns, the last step's before the loop
+    ends."""
+    telemetry.enable()
+    eng = GenerateEngine(latent_lm, latent_lm.make_cache(3, max_len=64),
+                         prefill_chunk=_LAG_CHUNK, name="phased")
+    phases = []
+    note = eng._note_forward
+
+    def watched(model, read=None):
+        if read is not None and "expert_loads" in read:
+            phases.append(eng._phase)
+        return note(model, read)
+    eng._note = watched
+    eng.generate(_LAG_PROMPTS, max_new_tokens=_LAG_NEW)
+    moe = eng.last_stats["moe"]
+    by_phase = moe["by_phase"]
+    # prompts of 13, 2 and 9: 3 + 1 + 2 chunks of 4, then 12 steps
+    assert by_phase["prefill"]["forwards"] == 6
+    assert by_phase["decode"]["forwards"] == _LAG_NEW
+    assert phases == ["prefill"] * 6 + ["decode"] * _LAG_NEW
+    # two expert layers, 2 routes a position: a chunk's 4 positions (the
+    # padding routes too), a step's 3 rows
+    assert by_phase["prefill"]["routes"] == 6 * 4 * 2 * 2
+    assert by_phase["decode"]["routes"] == _LAG_NEW * 3 * 2 * 2
+    for key in ("forwards", "routes", "experts_hit"):
+        assert by_phase["prefill"][key] + by_phase["decode"][key] \
             == moe[key]
+    # a chunk of 4 positions hits more experts a forward than 3 rows do
+    assert by_phase["prefill"]["experts_hit"] / 6 \
+        >= by_phase["decode"]["experts_hit"] / _LAG_NEW
+    for phase, tally in by_phase.items():
+        assert cat.moe_routes.value(model="phased", phase=phase) \
+            == tally["routes"]
+        assert cat.moe_experts_hit.value(model="phased", phase=phase) \
+            == tally["experts_hit"]
 
 
 def test_a_model_that_holds_every_expert_tallies_no_share(latent_lm):
@@ -1131,7 +1229,10 @@ def test_a_model_that_holds_every_expert_tallies_no_share(latent_lm):
                          prefill_chunk=_LAG_CHUNK, name="whole_tally")
     eng.generate(_LAG_PROMPTS, max_new_tokens=2)
     assert set(eng.last_stats["moe"]) == {
-        "forwards", "routes", "experts_hit", "load_max_over_mean"}
+        "forwards", "routes", "experts_hit", "load_max_over_mean",
+        "by_phase"}
+    assert all(set(tally) == {"forwards", "routes", "experts_hit"}
+               for tally in eng.last_stats["moe"]["by_phase"].values())
     loads = np.arange(16).reshape(2, 8)
     assert latent_lm.split_loads(loads)[1] == {}
     assert latent_lm.split_loads(loads)[0] is loads
@@ -1145,15 +1246,18 @@ def test_a_share_whose_experts_get_no_route_has_no_fullest_expert(share_lm):
                          name="idle_share")
     eng._tallies = {"moe": {"forwards": 0, "routes": 0, "experts_hit": 0,
                             "load_max_over_mean": [], "routes_elsewhere": 0,
-                            "rows_moved": 0}}
+                            "rows_moved": 0, "by_phase": {"prefill": {
+                                "forwards": 0, "routes": 0,
+                                "experts_hit": 0}}}}
     idle = np.asarray([[0, 0, 0, 8, 0], [0, 0, 0, 8, 0]], np.int32)
     eng._note_forward(share_lm, {"expert_loads": idle})
     one = np.asarray([[0, 3, 1, 4, 32], [0, 0, 0, 8, 0]], np.int32)
     eng._note_forward(share_lm, {"expert_loads": one})
-    assert eng._tallies["moe"] == {
-        "forwards": 2, "routes": 4, "experts_hit": 2,
-        "load_max_over_mean": [3 / (4 / 3)], "routes_elsewhere": 28,
-        "rows_moved": 32}
+    counted = {"forwards": 2, "routes": 4, "experts_hit": 2,
+               "routes_elsewhere": 28, "rows_moved": 32}
+    assert eng._tallies["moe"] == dict(
+        counted, load_max_over_mean=[3 / (4 / 3)],
+        by_phase={"prefill": counted})
 
 
 # ------------------------------------------- serving: accounting + loop

@@ -377,7 +377,21 @@ def test_the_block_loop_keeps_its_invariants(model, _metrics, monkeypatch):
     fed = 4 * 8 + 3 * (4 + 4 + 4 + 1) * 4          # prefill chunks; blocks
     assert moe["forwards"] == 4 + 12 and moe["routes"] == fed * 2 * 2
     assert len(moe["load_max_over_mean"]) == 16
-    assert cat.moe_routes.value(model="gpt") == moe["routes"]
+    # by phase: the 4 prefill chunks; the denoising and store forwards
+    # are all "decode", and the two phases sum to the totals
+    by_phase = moe["by_phase"]
+    assert by_phase["prefill"]["forwards"] == 4
+    assert by_phase["prefill"]["routes"] == 4 * 8 * 2 * 2
+    assert by_phase["decode"]["forwards"] == 8 + 4
+    for key in ("forwards", "routes", "experts_hit"):
+        assert by_phase["prefill"][key] + by_phase["decode"][key] \
+            == moe[key]
+        assert by_phase["prefill"][key] > 0 < by_phase["decode"][key]
+    for phase, tally in by_phase.items():
+        assert cat.moe_routes.value(model="gpt", phase=phase) \
+            == tally["routes"]
+        assert cat.moe_experts_hit.value(model="gpt", phase=phase) \
+            == tally["experts_hit"]
     assert cat.gen_block_forwards.value(model="gpt", phase="denoise") == 8
     assert cat.gen_block_forwards.value(model="gpt", phase="store") == 4
     assert cat.gen_block_positions_committed.value(model="gpt") == 52

@@ -235,6 +235,164 @@ def test_from_meta_links_server_span():
     assert telemetry.from_meta("rpc.x", {"op": "x"}) is tracing.NULL_SPAN
 
 
+# ------------------------------------------------------------- journeys
+
+@pytest.fixture
+def ring_of_16():
+    size = tracing._finished.maxlen
+    tracing._resize(16)
+    tracing.clear_spans()
+    yield 16
+    tracing._resize(size)
+
+
+def test_a_root_keeps_its_whole_tree_whatever_the_ring_lost(ring_of_16):
+    with tracing.Span("journey.root") as root:
+        for i in range(100):
+            with telemetry.span("journey.child", i=i):
+                pass
+    (journey,) = [j for j in tracing.recent_journeys("journey.root")
+                  if j[-1]["span_id"] == root.span_id]
+    assert len(journey) == 101 and journey[-1]["name"] == "journey.root"
+    assert "journey_dropped" not in journey[-1]
+    assert [r["i"] for r in journey[:-1]] == list(range(100))
+    assert all(r["parent_id"] == root.span_id for r in journey[:-1])
+    # the ring holds the last 16, the same records
+    ring = tracing.recent_spans()
+    assert len(ring) == ring_of_16 and ring == journey[-16:]
+    assert all(a is b for a, b in zip(ring, journey[-16:]))
+    # the timeline of that trace id is whole too: the journey, not the ring
+    assert tracing.spans_for_trace(root.trace_id) \
+        == sorted(journey, key=lambda r: r["ts_us"])
+    timeline = tracing.build_timeline(tracing.spans_for_trace(root.trace_id))
+    assert len(timeline["roots"]) == 1
+    assert len(timeline["roots"][0]["children"]) == 100
+    # clearing the ring leaves the journeys alone
+    tracing.clear_spans()
+    assert tracing.recent_spans() == []
+    assert tracing.recent_journeys("journey.root")[-1] is journey
+    assert len(tracing.spans_for_trace(root.trace_id)) == 101
+
+
+def test_many_threads_roots_do_not_mix(monkeypatch):
+    """More threads than cores, switching every 10 us, each under a root
+    of its own: every journey holds its own thread's records, all of
+    them, and a lost update would leave one short."""
+    import collections
+    import sys
+    workers = 16
+    monkeypatch.setattr(tracing, "_journeys",
+                        collections.deque(maxlen=workers))
+    start = threading.Barrier(workers)
+
+    def call(tag):
+        start.wait(10.0)
+        with tracing.Span("journey.thread", attrs={"tag": tag}):
+            for i in range(200):
+                with telemetry.span("journey.step", tag=tag):
+                    pass
+    threads = [threading.Thread(target=call, args=(tag,))
+               for tag in range(workers)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30.0)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    journeys = tracing.recent_journeys("journey.thread")
+    assert sorted(j[-1]["tag"] for j in journeys) == list(range(workers))
+    for journey in journeys:
+        assert len(journey) == 201
+        assert {r["tag"] for r in journey} == {journey[-1]["tag"]}
+        assert {r["trace_id"] for r in journey} \
+            == {journey[-1]["trace_id"]}
+    assert tracing._open_journeys == {}
+
+
+def test_a_journey_stops_at_its_cap_and_its_root_says_so(monkeypatch):
+    monkeypatch.setattr(tracing, "JOURNEY_MAX_SPANS", 10)
+    with tracing.Span("journey.capped"):
+        for i in range(25):
+            with telemetry.span("journey.child", i=i):
+                pass
+    journey = tracing.recent_journeys("journey.capped")[-1]
+    assert len(journey) == 10           # nine children and the root
+    assert [r["i"] for r in journey[:-1]] == list(range(9))
+    assert journey[-1]["name"] == "journey.capped"
+    assert journey[-1]["journey_dropped"] == 16
+    # one under the cap drops nothing
+    with tracing.Span("journey.capped"):
+        for i in range(9):
+            with telemetry.span("journey.child", i=i):
+                pass
+    whole = tracing.recent_journeys("journey.capped")[-1]
+    assert len(whole) == 10 and "journey_dropped" not in whole[-1]
+
+
+def test_the_kept_journeys_are_bounded_and_oldest_first():
+    for i in range(tracing.JOURNEYS_KEPT + 3):
+        with tracing.Span("journey.many", attrs={"i": i}):
+            pass
+    kept = tracing.recent_journeys()
+    assert len(kept) == tracing.JOURNEYS_KEPT
+    assert [j[-1]["i"] for j in kept] == list(range(
+        3, tracing.JOURNEYS_KEPT + 3))
+    assert tracing.recent_journeys("journey.none") == []
+    # nothing stays open once its root has closed
+    assert tracing._open_journeys == {}
+
+
+def test_record_span_joins_an_open_journey():
+    with tracing.Span("journey.root") as root:
+        rec = tracing.record_span("queue.wait", root.trace_id,
+                                  parent_id=root.span_id,
+                                  t0=time.time() - 0.5, t1=time.time())
+        tracing.record_span("elsewhere", "another-trace")
+    journey = tracing.recent_journeys("journey.root")[-1]
+    assert [r["name"] for r in journey] == ["queue.wait", "journey.root"]
+    assert journey[0] is rec
+    # a span that continues a remote parent's trace is no root
+    with tracing.from_meta("rpc.push", {tracing.TRACE_KEY: "remote",
+                                        tracing.PARENT_KEY: "p"}):
+        pass
+    assert all(j[-1]["name"] != "rpc.push"
+               for j in tracing.recent_journeys())
+
+
+def test_nothing_is_kept_while_telemetry_is_idle():
+    telemetry.disable()
+    before = [j[-1]["span_id"] for j in tracing.recent_journeys()]
+    assert telemetry.span("journey.idle") is tracing.NULL_SPAN
+    with telemetry.span("journey.idle"):
+        with telemetry.span("journey.idle.child"):
+            pass
+    assert [j[-1]["span_id"] for j in tracing.recent_journeys()] == before
+    assert tracing._open_journeys == {}
+
+
+def test_tracez_builds_a_large_calls_timeline_from_its_journey(ring_of_16):
+    from incubator_mxnet_tpu.telemetry import debugz
+    import urllib.request
+    with tracing.Span("journey.served") as root:
+        for i in range(40):
+            with telemetry.span("journey.child", i=i):
+                pass
+    server = debugz.start(0)
+    try:
+        body = json.loads(urllib.request.urlopen(
+            "http://127.0.0.1:%d/tracez?trace_id=%s"
+            % (server.server_address[1], root.trace_id),
+            timeout=10).read())
+    finally:
+        debugz.stop()
+    assert len(body["timeline"]["spans"]) == 41
+    assert len(body["timeline"]["roots"][0]["children"]) == 40
+
+
 def test_merge_traces(tmp_path):
     a = {"traceEvents": [{"name": "w", "ph": "X", "pid": 0, "tid": 1,
                           "ts": 0, "dur": 5}]}
@@ -390,10 +548,10 @@ def test_trainer_jit_compile_hook():
     # the hook is installed by ShardedTrainer.__init__; the first step
     # triggers a backend compile which jax.monitoring reports
     tr, X, y = _tiny_trainer()
-    compiles0 = catalog.trainer_jit_compiles.value()
+    compiles0 = catalog.compile_events()
     tr.step([nd.array(X)], nd.array(y))
-    assert catalog.trainer_jit_compiles.value() > compiles0
-    assert catalog.trainer_jit_compile_seconds.value() > 0
+    assert catalog.compile_events() > compiles0
+    assert sum(catalog.jit_compile_seconds.snapshot().values()) > 0
 
 
 def test_trainer_step_scan_counts_all_steps():
@@ -408,10 +566,10 @@ def test_trainer_step_scan_counts_all_steps():
 
 def test_jax_event_listener_folds_compile_events():
     catalog.install_jax_compile_hook()
-    before = catalog.trainer_jit_compiles.value()
+    before = catalog.compile_events()
     catalog._on_jax_event_duration(catalog._COMPILE_EVENT, 0.25)
     catalog._on_jax_event_duration("/jax/unrelated", 9.0)
-    assert catalog.trainer_jit_compiles.value() == before + 1
+    assert catalog.compile_events() == before + 1
 
 
 # ----------------------------------------- dataloader instrumentation

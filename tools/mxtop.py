@@ -143,7 +143,7 @@ def frame(scheduler, serving, prev_totals, prev_ts, stream=None,
         totals[key + "/retries"] = _series_sum(
             reg, "mxtpu_rpc_retries_total", where=key)
         compile_s = _series_sum(
-            reg, "mxtpu_trainer_jit_compile_seconds_total", where=key)
+            reg, "mxtpu_jit_compile_seconds_total", where=key)
         skips = _series_sum(
             reg, "mxtpu_guard_skipped_steps_total", where=key)
         r = _rates({k: prev_totals.get(k, 0.0) for k in totals},
