@@ -63,6 +63,7 @@ import numpy as np
 from ..ops.pallas.paged_latent import (cache_row_width, latent_block_size,
                                        latent_path,
                                        paged_latent_decode_available,
+                                       paged_latent_prefill_available,
                                        rows_walked)
 from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
@@ -424,7 +425,9 @@ class MLAPagedLM(_PagedLM):
     and after every forward ``last_latent_path`` which attention
     path the chunk's width chose and the rows it counts, by
     ``last_stats["mla"]``'s names: ``("expanded", {"expanded_rows": the
-    sequences' committed lengths summed})`` for a chunk, the cached rows
+    sequences' committed lengths summed, "expanded_kernel_forwards": 1
+    where the chunk's attention was the launch ``paged_latent_prefill``,
+    else 0})`` for a chunk, the cached rows
     it expanded again; ``("absorbed", {"absorbed_rows_live": the same
     sum, "absorbed_rows_read": the rows a layer's walk over the past
     fetches for them})`` for a decode step (``paged_latent.rows_walked``:
@@ -484,12 +487,21 @@ class MLAPagedLM(_PagedLM):
                                mla_path=path)
         self.last_expert_loads = None
         live = int(np.sum(lengths))
-        self.last_latent_path = (path, {"expanded_rows": live}) \
-            if path == "expanded" else (path, {
+        if path == "expanded":
+            cfg = self.config
+            counts = {
+                "expanded_rows": live,
+                "expanded_kernel_forwards": int(
+                    paged_latent_prefill_available(
+                        pools[0], tokens.shape[1], cfg["kv_rank"],
+                        cfg["nope_dim"], cfg["v_dim"]))}
+        else:
+            counts = {
                 "absorbed_rows_live": live,
                 "absorbed_rows_read": rows_walked(
                     lengths, pools[0].shape[1], tables.shape[1],
-                    paged_latent_decode_available(pools[0]))})
+                    paged_latent_decode_available(pools[0]))}
+        self.last_latent_path = (path, counts)
         return read, rows
 
     def split_loads(self, loads):
@@ -711,7 +723,8 @@ class GenerateEngine:
                                 for phase in ("prefill", "decode")}}
         if hasattr(self.model, "last_latent_path"):
             stats["mla"] = dict.fromkeys(
-                ("absorbed_forwards", "expanded_forwards", "expanded_rows",
+                ("absorbed_forwards", "expanded_forwards",
+                 "expanded_kernel_forwards", "expanded_rows",
                  "absorbed_rows_live", "absorbed_rows_read"), 0)
         self._tallies = stats
         for p in prompts:

@@ -34,8 +34,9 @@ their sum is normed and meets the untied head.
 Attention caches ONE row a position and layer, ``[rmsnorm(c) | rope(k_r)]``
 from ``h W_kva`` (``kv_rank + rope_dim`` values, and zeros up to whole
 lanes), shared by all heads (``ops/pallas/paged_latent.py``: the expanded
-path for a chunk, the absorbed path for a decode step, whose walk over the
-past is the launch ``paged_latent_decode`` on a TPU). The feed-forward is
+path for a chunk, the absorbed path for a decode step; on a TPU each is
+one launch a layer, ``paged_latent_prefill`` and ``paged_latent_decode``,
+plain ``lax`` elsewhere). The feed-forward is
 a gated-SiLU MLP in the first ``dense_layers`` layers and
 ``parallel.moe.moe_dropless`` after them (sigmoid scores, the top-k of
 score + bias, weights from the scores renormalised and scaled, a shared
@@ -314,8 +315,10 @@ def mla_forward_paged(params, cfg, tokens, lengths, block_tables, pools,
 
     A chunk of one position runs the absorbed attention path (one
     ``paged_latent_decode`` launch a layer on a TPU, each sequence's own
-    blocks read once), any wider chunk the expanded one
-    (``ops/pallas/paged_latent.py``)."""
+    blocks read once), any wider chunk the expanded one (one
+    ``paged_latent_prefill`` launch a layer on a TPU: the chunk against
+    its sequence's own cached rows and itself, rows up-projected and
+    scores kept in VMEM; ``ops/pallas/paged_latent.py``)."""
     cfg = mla_config(cfg)
     S, C = tokens.shape
     n, d = cfg["streams"], cfg["units"]

@@ -30,22 +30,36 @@ sequences costs a short one nothing. Products take their operands in the
 cache's dtype and accumulate in float32; scores, the softmax and its running
 statistics are float32.
 
-The expanded path is plain ``lax`` (``_over_past``): a step gathers a tile
-of EVERY sequence's blocks into a copy and walks as many tiles as the
-longest sequence of the call has. The absorbed path's walk over the past is
-ONE Pallas launch a layer on a TPU, ``paged_latent_decode``: block tables
-and lengths are scalar-prefetch operands, the pool stays in HBM, a grid
-step is a sequence, and the kernel copies that sequence's own live blocks
-(no block past its length, whatever the table names there) into a tile in
-VMEM, the next tile's copies (this sequence's, or the next sequence's
-first) in flight under the tile's two products. Every live row crosses HBM
-once, rounded up to a block; ``rows_walked`` is that count, on the host,
-for the engine's ``last_stats["mla"]``. The launch returns the running
-state of the past; the step's own row (not yet in the pool) and ``W_uv``
-stay in ``lax``. Off the TPU, and as the numerics oracle, the absorbed path
-takes the ``lax`` walk too (same tiles, same precisions: bfloat16 agrees to
-the bit). ``flash_decode.py``'s Mosaic kernel (per-head K/V) is refused by
-the TPU's compiler; this one compiles and runs on a v5e (PERF.md, PR 36).
+On a TPU each path's walk is ONE Pallas launch a layer: block tables and
+lengths are scalar-prefetch operands, the pool stays in HBM, and the
+kernel copies a sequence's own live blocks (no block past its length,
+whatever the table names there) into a tile in VMEM, the next tile's
+copies in flight under the tile's products. Off the TPU, and as the
+numerics oracle, both paths are plain ``lax`` (``_over_past``: a step
+gathers a tile of EVERY sequence's blocks into a copy and walks as many
+tiles as the longest sequence of the call has; same tiles, same
+precisions).
+
+- ``paged_latent_prefill`` (the expanded path whole): a grid step is a
+  sequence and a group of heads. A tile's rows are up-projected to each
+  head's keys and values IN the kernel (rounded to the cache's dtype, as
+  the ``lax`` path rounds them) and folded into the running softmax of
+  every query tile of the chunk; the chunk's own rows, not yet in the
+  pool, come in as an ordinary operand and follow causally, the key tiles
+  that lie wholly after a query tile skipped. Scores, exponents and
+  statistics never leave VMEM (the ``lax`` path writes a (H, C, 512)
+  float32 score array to HBM and reads it back three times a tile), and
+  the result leaves normalised, a head in its own lanes of (S, C, H v).
+- ``paged_latent_decode`` (the absorbed path's walk over the past): a grid
+  step is a sequence, the next sequence's first tile in flight under the
+  last products of the one at hand. Every live row crosses HBM once,
+  rounded up to a block; ``rows_walked`` is that count, on the host, for
+  the engine's ``last_stats["mla"]``. The launch returns the running state
+  of the past; the step's own row (not yet in the pool) and ``W_uv`` stay
+  in ``lax``; bfloat16 agrees with the ``lax`` walk to the bit.
+
+``flash_decode.py``'s Mosaic kernel (per-head K/V) is refused by the TPU's
+compiler; these two compile and run on a v5e (PERF.md, PRs 36 and 38).
 """
 
 import functools
@@ -71,7 +85,8 @@ LANES = 128
 BLOCK_BYTES = 160 << 10
 
 __all__ = ["paged_latent_attention", "paged_latent_decode",
-           "paged_latent_decode_available", "latent_path", "cache_row_width",
+           "paged_latent_decode_available", "paged_latent_prefill",
+           "paged_latent_prefill_available", "latent_path", "cache_row_width",
            "latent_block_size", "rows_walked"]
 
 
@@ -160,6 +175,22 @@ def rows_walked(lengths, block_size, blocks, kernel, key_tile=KEY_TILE):
     return len(lengths) * (-(-max(lengths, default=0) // tile) * tile)
 
 
+def _each_block(tables_ref, lengths_ref, pool_ref, buf, sems, seq, j, slot,
+                act):
+    """`act` on the copy of every live block of tile j of sequence `seq`
+    from the pool in HBM into half `slot` of `buf` (2, tile_blocks,
+    block_size, W): no block past the length, whatever the table names."""
+    _two, tile_blocks, block_size, _width = buf.shape
+    live = pl.cdiv(lengths_ref[seq], block_size) - j * tile_blocks
+
+    def one(b, _):
+        act(pltpu.make_async_copy(
+            pool_ref.at[tables_ref[seq, j * tile_blocks + b]],
+            buf.at[slot, b], sems.at[slot]))
+        return 0
+    jax.lax.fori_loop(0, jnp.minimum(live, tile_blocks), one, 0)
+
+
 def _decode_kernel(tables_ref, lengths_ref, q_ref, pool_ref, m_ref, l_ref,
                    acc_ref, buf, sems, state, *, scale, rank):
     """One sequence a grid step: its live blocks fetched tile by tile into
@@ -174,15 +205,8 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, pool_ref, m_ref, l_ref,
     tiles = pl.cdiv(length, T)
 
     def each_block(seq, j, slot, act):
-        """`act` on the copy of every live block of tile j of `seq`."""
-        live = pl.cdiv(lengths_ref[seq], block_size) - j * tile_blocks
-
-        def one(b, _):
-            act(pltpu.make_async_copy(
-                pool_ref.at[tables_ref[seq, j * tile_blocks + b]],
-                buf.at[slot, b], sems.at[slot]))
-            return 0
-        jax.lax.fori_loop(0, jnp.minimum(live, tile_blocks), one, 0)
+        _each_block(tables_ref, lengths_ref, pool_ref, buf, sems, seq, j,
+                    slot, act)
 
     def start(seq, j, slot):
         each_block(seq, j, slot, lambda copy: copy.start())
@@ -283,6 +307,211 @@ def paged_latent_decode(query, pool, block_tables, lengths, scale, rank,
     )(block_tables, lengths, query, pool)
 
 
+def paged_latent_prefill_available(pool, chunk, rank, nope_dim, v_dim):
+    """Whether the expanded path of a chunk `chunk` positions wide over
+    `pool` is the launch: the backend is a TPU, a block is whole tiles of
+    the device (``paged_latent_decode_available``), the chunk is whole
+    sublane tiles of the pool's dtype, and the latent, a head's keys and
+    its values are whole lanes wide (the kernel slices rows and weights
+    there, and writes a head's output into its own lanes)."""
+    return (paged_latent_decode_available(pool)
+            and chunk % (32 // pool.dtype.itemsize) == 0
+            and rank % LANES == 0 and nope_dim % LANES == 0
+            and v_dim % LANES == 0)
+
+
+def _head_group(heads, chunk):
+    """Heads a grid step of the prefill launch: the most that divide
+    `heads` and keep the group's running state (a float32 (chunk, v) and
+    two lane-padded statistics a head) at 4,096 query rows."""
+    return max(g for g in range(1, heads + 1)
+               if heads % g == 0 and (g == 1 or g * chunk <= 4096))
+
+
+def _prefill_kernel(tables_ref, lengths_ref, q_ref, new_ref, w_ref, pool_ref,
+                    out_ref, buf, sems, m_ref, l_ref, acc_ref, *, scale,
+                    rank, nope_dim, rope_dim, key_tile):
+    """A sequence and a group of heads a grid step: the chunk's queries of
+    those heads against the sequence's live cached rows, fetched tile by
+    tile into the two halves of `buf` (the next tile's copies under the
+    products of the one at hand), then against the chunk's own rows,
+    causally. A tile's rows are up-projected to a head's keys and values
+    here, once a head, and every query tile of the chunk folds them into
+    the head's running softmax: scores, exponents and statistics never
+    leave VMEM."""
+    s = pl.program_id(0)
+    group, chunk = q_ref.shape[1], q_ref.shape[2]
+    _two, tile_blocks, block_size, _width = buf.shape
+    T = tile_blocks * block_size
+    v_dim = acc_ref.shape[-1]
+    dtype = q_ref.dtype
+    length = lengths_ref[s]
+    tiles = pl.cdiv(length, T)
+
+    def tiles_of(rows):
+        return [(lo, min(rows, chunk - lo)) for lo in range(0, chunk, rows)]
+    # the chunk's own keys, and the queries they meet, in tiles of
+    # key_tile; against a tile of the past, query tiles of twice that (on
+    # a v5e 1,024 rows a tile beat 512 by 7 %: PERF.md, PR 38)
+    spans, past_spans = tiles_of(key_tile), tiles_of(2 * key_tile)
+
+    def each_block(j, slot, act):
+        _each_block(tables_ref, lengths_ref, pool_ref, buf, sems, s, j, slot,
+                    act)
+
+    @pl.when((s == 0) & (pl.program_id(1) == 0))
+    def _first():
+        # a row never fetched is multiplied by p = 0: it has to be finite
+        buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(tiles > 0)
+    def _own():
+        each_block(0, 0, lambda copy: copy.start())
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def fold(h, latent, k_rope, folds):
+        """A tile of rows, `latent` (n, r) and `k_rope` (n, d_r), into head
+        h's state: for each of `folds` (first query row, query rows, what
+        masks the (rows, n) scores)."""
+        kv = jnp.dot(latent, w_ref[h],
+                     preferred_element_type=jnp.float32).astype(dtype)
+        keys = jnp.concatenate([kv[:, :nope_dim], k_rope], axis=1)
+        values = kv[:, nope_dim:]
+        for lo, n, mask in folds:
+            rows = pl.ds(lo, n)
+            scores = mask(jax.lax.dot_general(
+                q_ref[0, h, rows, :], keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale)
+            m = m_ref[h, rows]
+            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+            # a masked score is -1e30 under a live one: its p is 0
+            p = jnp.exp(scores - m_new)
+            alpha = jnp.exp(m - m_new)
+            acc_ref[h, rows] = acc_ref[h, rows] * alpha + jnp.dot(
+                p.astype(dtype), values, preferred_element_type=jnp.float32)
+            l_ref[h, rows] = l_ref[h, rows] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
+            m_ref[h, rows] = m_new
+
+    def each_head(latent, k_rope, folds):
+        def one(h, _):
+            fold(h, latent(), k_rope(), folds)
+            return 0
+        jax.lax.fori_loop(0, group, one, 0)
+
+    def one_tile(j, _):
+        slot = j & 1
+
+        @pl.when(j + 1 < tiles)
+        def _next():
+            each_block(j + 1, 1 - slot, lambda copy: copy.start())
+        each_block(j, slot, lambda copy: copy.wait())
+        # every tile walked holds a live key: no query row is left empty
+        dead = jnp.where(
+            j * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) < length,
+            0.0, _NEG_INF)
+        each_head(
+            lambda: buf[slot, :, :, :rank].reshape(T, rank),
+            lambda: buf[slot, :, :, rank:rank + rope_dim].reshape(
+                T, rope_dim),
+            [(lo, n, lambda scores: scores + dead) for lo, n in past_spans])
+        return 0
+    jax.lax.fori_loop(0, tiles, one_tile, 0)
+
+    def causal(scores):
+        return jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+            >= jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1),
+            scores, _NEG_INF)
+    # the chunk itself: a key tile meets its own query tile under the
+    # diagonal, the later ones whole, the earlier ones not at all
+    for lo, n in spans:
+        each_head(
+            lambda lo=lo, n=n: new_ref[0, pl.ds(lo, n), :rank],
+            lambda lo=lo, n=n: new_ref[0, pl.ds(lo, n),
+                                       rank:rank + rope_dim],
+            [(q_lo, q_n, causal if q_lo == lo else lambda scores: scores)
+             for q_lo, q_n in spans if q_lo >= lo])
+    for h in range(group):
+        out_ref[0, :, h * v_dim:(h + 1) * v_dim] = (
+            acc_ref[h] / l_ref[h]).astype(out_ref.dtype)
+
+
+# jitted, so that a program traces and lowers the kernel once, not once a layer
+@functools.partial(jax.jit, static_argnames=("scale", "key_tile", "interpret"))
+def paged_latent_prefill(q_nope, q_rope, new_rows, kv_b, pool, block_tables,
+                         lengths, scale, key_tile=KEY_TILE, interpret=False):
+    """The expanded path, one launch: the arguments and the result of
+    ``paged_latent_attention`` for a chunk wider than one position.
+
+    `block_tables` and `lengths` are scalar-prefetch operands, the pool
+    stays in HBM: a grid step is a sequence and a group of heads
+    (``_head_group``), and it copies the sequence's live blocks (and no
+    other) into a tile of `key_tile` positions in VMEM, two tiles in
+    flight, up-projects a tile's rows to each head's keys and values
+    there (rounded to the cache's dtype) and folds them into the running
+    softmax of every query tile of the chunk; the chunk's own rows, an
+    ordinary operand, follow causally, the key tiles that lie wholly
+    after a query tile skipped. The result is normalised in the kernel
+    and written a head to its own lanes of (S, C, H v)."""
+    S, C, H, d_n = q_nope.shape
+    r, d_r, d_v = kv_b.shape[0], q_rope.shape[-1], kv_b.shape[-1] - d_n
+    width = pool.shape[2]
+    dtype = q_nope.dtype
+    block_size = pool.shape[1]
+    tile_blocks = _tile_blocks(key_tile, block_size, block_tables.shape[1])
+    T = tile_blocks * block_size
+    group = _head_group(H, C)
+    # a head's queries [q_n | q_r] and its slice of W_kvb, heads major
+    query = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
+    weights = kv_b.transpose(1, 0, 2)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, H // group),
+        in_specs=[
+            pl.BlockSpec((1, group, C, d_n + d_r),
+                         lambda s, g, tables, lengths: (s, g, 0, 0)),
+            pl.BlockSpec((1, C, width),
+                         lambda s, g, tables, lengths: (s, 0, 0)),
+            pl.BlockSpec((group, r, d_n + d_v),
+                         lambda s, g, tables, lengths: (g, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, C, group * d_v),
+                               lambda s, g, tables, lengths: (s, 0, g)),
+        scratch_shapes=[
+            pltpu.VMEM((2, tile_blocks, block_size, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((group, C, 1), jnp.float32),             # m
+            pltpu.VMEM((group, C, 1), jnp.float32),             # l
+            pltpu.VMEM((group, C, d_v), jnp.float32),           # acc
+        ],
+    )
+    # what a grid step holds, minor dimensions in whole lanes: the blocks
+    # the pipeline keeps twice and the tile buffer; m, l and acc; one
+    # head's keys and values of a tile and its scores three times over
+    item, lanes = dtype.itemsize, lambda n: -(-n // LANES) * LANES
+    held = (2 * item * (group * C * lanes(d_n + d_r) + C * width
+                        + group * r * (d_n + d_v) + C * group * d_v
+                        + T * width)
+            + 4 * group * C * (2 * LANES + d_v)
+            + T * (d_n + d_v) * (4 + item)
+            + 3 * 4 * min(2 * key_tile, C) * T)
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=scale, rank=r,
+                          nope_dim=d_n, rope_dim=d_r, key_tile=key_tile),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, C, H * d_v), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(100 << 20, max(32 << 20, 2 * held))),
+        interpret=interpret,
+        name="paged_latent_prefill",
+    )(block_tables, lengths, query, new_rows, weights, pool)
+    return out.reshape(S, C, H, d_v)
+
+
 def paged_latent_attention(q_nope, q_rope, new_rows, kv_b, pool,
                            block_tables, lengths, scale, key_tile=KEY_TILE,
                            interpret=False):
@@ -297,9 +526,11 @@ def paged_latent_attention(q_nope, q_rope, new_rows, kv_b, pool,
     past position and the chunk's positions <= c.
 
     -> (S, C, H, d_v), in q_nope's dtype. C = 1 runs the absorbed path,
-    any other width the expanded one (``latent_path``). The absorbed
-    path's walk over the past is the launch ``paged_latent_decode`` where
-    the backend is a TPU (`interpret` runs the launch anywhere)."""
+    any other width the expanded one (``latent_path``). Where the backend
+    is a TPU and the shapes allow (``paged_latent_*_available``) the
+    absorbed path's walk over the past is the launch
+    ``paged_latent_decode`` and the expanded path the launch
+    ``paged_latent_prefill`` (`interpret` runs the launches anywhere)."""
     S, C, H, d_n = q_nope.shape
     r, d_r = kv_b.shape[0], q_rope.shape[-1]
     dtype = q_nope.dtype
@@ -324,6 +555,11 @@ def paged_latent_attention(q_nope, q_rope, new_rows, kv_b, pool,
                          "shct,str->shcr")
     else:
         width = w_v.shape[-1]
+        if interpret or paged_latent_prefill_available(pool, C, r, d_n,
+                                                       width):
+            return paged_latent_prefill(
+                q_nope, q_rope, new_rows, kv_b, pool, block_tables, lengths,
+                scale, key_tile, interpret=interpret)
 
         def fold_rows(state, rows, mask):
             latent, k_rope = rows[..., :r], rows[..., r:r + d_r]

@@ -343,6 +343,11 @@ mla_expanded_forwards = _m.counter(
     "Forwards over a latent cache that ran the expanded attention path "
     "(a wider chunk: prefill up-projects rows to per-head keys and "
     "values), by model")
+mla_expanded_kernel_forwards = _m.counter(
+    "mxtpu_mla_expanded_kernel_forwards_total",
+    "Expanded forwards whose attention was the paged_latent_prefill "
+    "launch (a TPU, a cache of whole tiles, whole-lane widths); the rest "
+    "took the lax path, by model")
 mla_expanded_rows = _m.counter(
     "mxtpu_mla_expanded_rows_total",
     "Cached latent rows that expanded forwards up-projected AGAIN (the "

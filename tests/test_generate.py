@@ -1046,6 +1046,7 @@ def test_a_greedy_call_waits_for_nothing_between_two_forwards(lagged_lm):
         assert 0 < live <= st["mla"].pop("absorbed_rows_read")
         assert st["mla"] == {"absorbed_forwards": _LAG_NEW,
                              "expanded_forwards": chunks,
+                             "expanded_kernel_forwards": 0,
                              "expanded_rows": 4 + 8 + 4}
     else:
         assert "moe" not in st and "mla" not in st

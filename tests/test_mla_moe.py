@@ -571,8 +571,8 @@ def test_a_traced_call_says_which_path_every_forward_ran(model, _metrics):
                             prefill_chunk=4, name="mla_tally")
     before = {c: c.value(model="mla_tally") for c in (
         cat.mla_absorbed_forwards, cat.mla_expanded_forwards,
-        cat.mla_expanded_rows, cat.mla_absorbed_rows_live,
-        cat.mla_absorbed_rows_read)}
+        cat.mla_expanded_kernel_forwards, cat.mla_expanded_rows,
+        cat.mla_absorbed_rows_live, cat.mla_absorbed_rows_read)}
     tracing.clear_spans()
     with tracing.Span("test.call"):
         engine.generate([_prompt(10), _prompt(6)], max_new_tokens=3)
@@ -581,7 +581,9 @@ def test_a_traced_call_says_which_path_every_forward_ran(model, _metrics):
     # the steps' lengths (9, 5), (10, 6), (11, 7); off the TPU the walk is
     # the lax one: both rows' tiles up to the longest, a tile the whole
     # table of 8 blocks of 4
+    # table of 8 blocks of 4; off the TPU no chunk's attention is the launch
     assert mla == {"absorbed_forwards": 3, "expanded_forwards": 5,
+                   "expanded_kernel_forwards": 0,
                    "expanded_rows": 4 + 8 + 4, "absorbed_rows_live": 48,
                    "absorbed_rows_read": 3 * 2 * 32}
     moe = engine.last_stats["moe"]
@@ -592,6 +594,8 @@ def test_a_traced_call_says_which_path_every_forward_ran(model, _metrics):
         - before[cat.mla_absorbed_forwards] == 3
     assert cat.mla_expanded_forwards.value(model="mla_tally") \
         - before[cat.mla_expanded_forwards] == 5
+    assert cat.mla_expanded_kernel_forwards.value(model="mla_tally") \
+        == before[cat.mla_expanded_kernel_forwards]
     assert cat.mla_expanded_rows.value(model="mla_tally") \
         - before[cat.mla_expanded_rows] == 16
     assert cat.mla_absorbed_rows_live.value(model="mla_tally") \
@@ -601,6 +605,75 @@ def test_a_traced_call_says_which_path_every_forward_ran(model, _metrics):
     paths = [s["mla_path"] for s in tracing.recent_spans()
              if s["name"] == "lm.dispatch"]
     assert paths == ["expanded"] * 5 + ["absorbed"] * 3
+
+
+def test_where_the_launch_is_available_every_chunk_runs_it(weights, model,
+                                                           monkeypatch,
+                                                           _metrics):
+    """``paged_latent_prefill_available`` answering yes (as on a TPU over
+    a cache of whole tiles) and the launch interpreted: every expanded
+    forward of a call is counted as the kernel's,
+    ``expanded_kernel_forwards`` beside ``expanded_forwards`` in
+    ``last_stats["mla"]`` and on its counter, and the call serves the
+    tokens of the ``lax`` path."""
+    from incubator_mxnet_tpu.generate import engine as engine_module
+    from incubator_mxnet_tpu.ops.pallas import paged_latent
+    prompts = [_prompt(10), _prompt(6, seed=1)]
+
+    def generate(lm, name):
+        engine = GenerateEngine(lm, lm.make_cache(2, max_len=32,
+                                                  block_size=4),
+                                prefill_chunk=4, name=name)
+        return engine.generate(prompts, max_new_tokens=3), engine.last_stats
+    want, stats = generate(model, "mla_lax")
+    assert stats["mla"]["expanded_kernel_forwards"] == 0
+    launches, prefill = [], paged_latent.paged_latent_prefill
+
+    def launch(*args, **kw):
+        launches.append(args[0].shape)
+        return prefill(*args, **dict(kw, interpret=True))
+    for module in (paged_latent, engine_module):
+        monkeypatch.setattr(module, "paged_latent_prefill_available",
+                            lambda *shapes: True)
+    monkeypatch.setattr(paged_latent, "paged_latent_prefill", launch)
+    before = cat.mla_expanded_kernel_forwards.value(model="mla_kernel")
+    # a model of its own: the fixture's programs are traced already
+    fresh = MLAPagedLM(weights, family.program_config(CFG), dtype="float32")
+    got, stats = generate(fresh, "mla_kernel")
+    mla = stats["mla"]
+    assert mla["expanded_kernel_forwards"] == mla["expanded_forwards"] == 5
+    assert cat.mla_expanded_kernel_forwards.value(model="mla_kernel") \
+        - before == 5
+    # one trace of the prefill program: a launch a layer, a chunk of (1, 4)
+    assert launches == [(1, 4, 4, 8)] * 3
+    assert got == want
+
+
+def test_the_prefill_launch_is_chosen_by_backend_and_shapes(monkeypatch):
+    """On a TPU, by what the pool, the chunk and the widths show; never
+    off it: a block of whole tiles, a chunk of whole sublane tiles, the
+    latent, a head's keys and its values whole lanes wide."""
+    from incubator_mxnet_tpu.ops.pallas import paged_latent
+    available = paged_latent.paged_latent_prefill_available
+    pool = jax.ShapeDtypeStruct((8, 128, 640), jnp.bfloat16)
+    assert not available(pool, 1024, 512, 128, 128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert available(pool, 1024, 512, 128, 128)         # the long prompts
+    assert available(pool, 512, 512, 128, 128)          # the wide batch
+    assert available(pool, 16, 512, 128, 128)
+    assert not available(pool, 8, 512, 128, 128)        # half a tile of rows
+    assert not available(pool, 1024, 512, 128, 64)      # values of half lanes
+    assert not available(pool, 1024, 512, 192, 128)
+    assert not available(pool, 1024, 576, 128, 128)
+    assert not available(jax.ShapeDtypeStruct((8, 8, 640), jnp.bfloat16),
+                         1024, 512, 128, 128)
+    # the toys of this file stay on the lax path
+    assert not available(jax.ShapeDtypeStruct((8, 4, 128), jnp.float32),
+                         4, 16, 8, 8)
+    assert paged_latent._head_group(32, 1024) == 4
+    assert paged_latent._head_group(64, 512) == 8
+    assert paged_latent._head_group(4, 8) == 4
+    assert paged_latent._head_group(7, 8192) == 1
 
 
 def test_the_configuration_maps_every_published_width(model):

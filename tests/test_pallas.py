@@ -576,3 +576,89 @@ def test_the_list_of_kernel_files_is_whole():
             if _pallas_calls(ast.parse(f.read())):
                 having.append(os.path.basename(path))
     assert sorted(having) == _KERNEL_FILES
+
+
+# ------------------------------------- the expanded path of latent attention
+def _prefill_case(rng, dtype, lengths, chunk, heads, poison=False, bs=4,
+                  blocks=8, rank=16, nope=8, rope=8, v=8):
+    """A chunk's operands over a pool whose block tables are a permutation
+    of the blocks, padded past a length with any valid id, or (`poison`)
+    with the id of a block full of NaN."""
+    from incubator_mxnet_tpu.ops.pallas.paged_latent import cache_row_width
+    S, width = len(lengths), cache_row_width(rank, rope)
+    lengths = np.asarray(lengths, np.int32)
+    pool = rng.normal(size=(S * blocks + 1, bs, width))
+    tables = rng.permutation(S * blocks).reshape(S, blocks).astype(np.int32)
+    for s, n in enumerate(lengths):
+        used = -(-int(n) // bs)
+        tables[s, used:] = rng.integers(0, S * blocks, size=blocks - used)
+        if poison:
+            tables[s, used:] = S * blocks
+    if poison:
+        live = np.zeros(len(pool), bool)
+        for s, n in enumerate(lengths):
+            live[tables[s, :-(-int(n) // bs)]] = True
+        pool[~live] = np.nan
+    # keys, values and scores of deviation 1 whatever the widths
+
+    def draw(*shape, over=1):
+        return jnp.asarray(rng.normal(size=shape) * over ** -0.5, dtype)
+    return {"q_nope": draw(S, chunk, heads, nope, over=nope + rope),
+            "q_rope": draw(S, chunk, heads, rope, over=nope + rope),
+            "new_rows": draw(S, chunk, width),
+            "kv_b": draw(rank, heads, nope + v, over=rank),
+            "pool": jnp.asarray(pool, dtype), "block_tables": tables,
+            "lengths": lengths, "scale": 1.0}
+
+
+# toys: blocks of 4 positions, a tile of 8, tables of 32; the two latent
+# cells' chunks and heads at a quarter of the rank over a cache's own
+# blocks of 128 and tiles of 512
+_PREFILL_CASES = {
+    "no_past": dict(lengths=[0], chunk=8, heads=4),
+    "past_of_no_whole_block": dict(lengths=[5], chunk=8, heads=4),
+    "past_of_no_whole_tile": dict(lengths=[13], chunk=16, heads=2),
+    "two_unequal_sequences": dict(lengths=[21, 3], chunk=12, heads=4),
+    "padding_names_a_nan_block": dict(lengths=[6, 0, 17], chunk=8, heads=2,
+                                      poison=True),
+    "a_chunk_of_three_tiles": dict(lengths=[9], chunk=20, heads=3),
+    "long_prompts_c1024_h32": dict(
+        lengths=[700], chunk=1024, heads=32, bs=128, rank=128, nope=128,
+        rope=64, v=128, key_tile=512),
+    "wide_batch_c512_h64": dict(
+        lengths=[0], chunk=512, heads=64, bs=128, rank=128, nope=128,
+        rope=64, v=128, key_tile=512),
+}
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 1e-5), ("bfloat16", 0.0)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(_PREFILL_CASES))
+def test_the_prefill_kernel_is_the_lax_path(case, dtype, atol):
+    """``paged_latent_prefill`` (interpreted) against the ``lax`` expanded
+    path on the same tiles: each sequence's own live rows and no other
+    (a block past a length may hold NaN), then the chunk itself under
+    the diagonal. The same products in the same precisions: float32
+    agrees to an accumulation order, bfloat16 to the bit at the toys'
+    widths, as the decode kernel does; at the cells' widths the launch
+    sums a score's 192 terms in one product where the ``lax`` path adds
+    two, and an exponent rounded the other way moves an output by one
+    bfloat16 step (of values of deviation 1)."""
+    from incubator_mxnet_tpu.ops.pallas.paged_latent import (
+        paged_latent_attention)
+    case = dict(_PREFILL_CASES[case])
+    key_tile = case.pop("key_tile", 8)
+    rtol = 0.0
+    if key_tile == 512 and dtype == "bfloat16":
+        atol = rtol = 2 ** -7
+    case = _prefill_case(np.random.default_rng(case["chunk"]),
+                         jnp.dtype(dtype), **case)
+    got = paged_latent_attention(**case, key_tile=key_tile, interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    # the oracle reads a table's padding too (masked): give it clean blocks
+    case["pool"] = jnp.nan_to_num(case["pool"])
+    want = paged_latent_attention(**case, key_tile=key_tile)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol,
+                               rtol=rtol)

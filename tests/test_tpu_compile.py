@@ -260,6 +260,39 @@ def test_latent_decode_launch_compiles_for_v5e(one_chip, rows, heads,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("chunk,heads,max_len", [(1024, 32, 16896),
+                                                 (512, 64, 896)],
+                         ids=["long_prompts", "wide_batch"])
+def test_latent_prefill_launch_compiles_for_v5e(one_chip, chunk, heads,
+                                                max_len):
+    """The expanded path alone at the two latent cells' shapes: one
+    prompt's chunk of queries, its own rows and ``W_kvb`` against a pool
+    of 640-lane rows in blocks of 128 positions, tables and lengths as
+    scalar-prefetch operands, the pool left in HBM. What the launch holds
+    (queries, weights, rows and state of a head group, a tile's keys,
+    values and scores) fits the VMEM it asks for, and nothing of a tile
+    is a temporary in HBM."""
+    from incubator_mxnet_tpu.ops.pallas.paged_latent import (
+        latent_block_size, paged_latent_prefill)
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    block = latent_block_size(640 * 2, max_len)
+    blocks = max_len // block
+    compiled = jax.jit(
+        lambda q_n, q_r, new, kv_b, pool, tables, lengths:
+        paged_latent_prefill(q_n, q_r, new, kv_b, pool, tables, lengths,
+                             0.1)).lower(
+        shape((1, chunk, heads, 128)), shape((1, chunk, heads, 64)),
+        shape((1, chunk, 640)), shape((512, heads, 256)),
+        shape((16 * blocks, block, 640)), shape((1, blocks), jnp.int32),
+        shape((1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_latent_prefill" in text
+    # the queries and the weights heads-major, and no more: 24 MB at most
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
 def _xing4_programs(one_chip, monkeypatch):
     """The cell's configuration at its published widths, cut for the
     compile to one dense and one expert layer, as abstract arguments for
@@ -274,6 +307,8 @@ def _xing4_programs(one_chip, monkeypatch):
     monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
     monkeypatch.setattr(paged_latent, "paged_latent_decode_available",
                         lambda pool=None: True)
+    monkeypatch.setattr(paged_latent, "paged_latent_prefill_available",
+                        lambda *shapes: True)
     cfg = spec.load_json(spec.ROOT + "/benchmarks/configs/xing4_29b_a4b.json")
     program = dict(xing4.program_config(cfg), num_layers=XING4_LAYERS)
 
@@ -412,6 +447,32 @@ def test_latent_prefill_chunk_fits_beside_the_weights_on_v5e(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
     # a prefill's last layer stops at its cache rows: no expert product
     assert "moe_grouped_matmul" not in compiled.as_text()
+
+
+def test_latent_prefill_chunk_is_one_launch_a_layer_on_v5e(one_chip,
+                                                           monkeypatch):
+    """The cell's prefill program with a head (a prompt's last chunk: no
+    layer is dropped), 1,024 positions over a table of 132 blocks: the
+    expanded attention of each layer is the launch
+    ``paged_latent_prefill``, so the program holds no float32 score tile
+    of 32 heads x 1,024 queries x 512 keys in any order of dimensions (67
+    MB that the ``lax`` path writes and reads three times a tile), and no
+    loop that up-projects rows (a ``while`` whose body multiplies by
+    ``W_kvb``: the ``lax`` path's walk over the past)."""
+    model, arguments, chunk = _xing4_programs(one_chip, monkeypatch)
+    assert chunk == 1024
+    compiled = model.lower(*arguments(1, chunk), head="token").compile()
+    text = compiled.as_text()
+    assert text.count("paged_latent_prefill") >= XING4_LAYERS
+    for shape in re.findall(r"f32\[([\d,]+)\]", text):
+        dims = sorted(int(n) for n in shape.split(",") if int(n) > 1)
+        assert dims != [32, 512, 1024], "a score tile in HBM: f32[%s]" % shape
+    # a loop that up-projects carries W_kvb's halves, (512, 32, 128) each
+    for loop in re.findall(r"= \((.*?)\) while\(", text):
+        for shape in re.findall(r"bf16\[([\d,]+)\]", loop):
+            dims = [int(n) for n in shape.split(",")]
+            assert not {512, 32} <= set(dims), "W_kvb in a loop: %s" % shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
 @pytest.mark.parametrize("block", [128, 16], ids=["latent_block", "16"])
