@@ -16,7 +16,8 @@ Importing this package registers the serving family.
 """
 
 from .paged_kv import PagedKVCache
-from .engine import GenerateEngine, GPTPagedLM, MLAPagedLM, SDARPagedLM
+from .engine import (EvaPagedLM, GenerateEngine, GPTPagedLM, MLAPagedLM,
+                     SDARPagedLM)
 from . import family  # noqa: F401  (registers the gpt_decoder family)
 from .family import export_gpt_for_serving
 
@@ -26,5 +27,6 @@ __all__ = [
     "GPTPagedLM",
     "SDARPagedLM",
     "MLAPagedLM",
+    "EvaPagedLM",
     "export_gpt_for_serving",
 ]

@@ -42,6 +42,13 @@ a forward's inputs and calls the adapter, ``commit_slots`` stores what it
 returned, ``step_slots`` is both for one token a slot, ``prefill_slot``
 both for a prompt in padded chunks.
 
+A model adapter that declares a ``window`` (``EvaPagedLM`` over
+``models/eva_byte.py``) keeps a window of exact rows beside summaries of
+the windows that closed, in two groups of cache entries. The loops are the
+same: ``commit_slots``, told the adapter, asks it after every commit to
+close the windows the commit filled (``close_windows``: known from the
+cache's host lengths, nothing fetched).
+
 A model adapter that declares a ``block_length`` (``SDARPagedLM`` over
 ``models/sdar_moe.py``) is a block-diffusion decoder, and the engine
 drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
@@ -69,7 +76,8 @@ from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
 from .paged_kv import PagedKVCache, _env_int
 
-__all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM", "MLAPagedLM"]
+__all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM", "MLAPagedLM",
+           "EvaPagedLM"]
 
 
 def default_prefill_chunk():
@@ -154,17 +162,26 @@ def forward_slots(adapter, cache, slots, tokens, call=None, note=None):
     return out
 
 
-def commit_slots(cache, slots, *new_and_count):
+def commit_slots(cache, slots, *new_and_count, adapter=None, phase="decode",
+                 note=None):
     """``commit_slots(cache, slots, *new, count)``: store the first
     `count` (one number, or one a row) chunk positions of every row (row
     r belongs to ``slots[r]``) of `new` (a forward's additions, entry by
     entry: new_k, new_v) in the cache; the span counts the pool rows
-    written an entry."""
+    written an entry. `adapter`: the forward's; one that declares
+    ``close_windows`` is then asked to close the windows this commit
+    filled (a ``gen.window_close`` span a closing, of the engine's `phase`;
+    `note` is told what was closed)."""
     count = new_and_count[-1]
     rows = (len(slots) * count if isinstance(count, int)
             else int(np.sum(count)))
     with _tr.span("kv.commit", rows=rows):
         cache.commit(slots, *new_and_count)
+    close = getattr(adapter, "close_windows", None)
+    if close is not None:
+        closed = close(cache, slots, phase)
+        if note is not None and closed:
+            note(adapter, closed=closed)
 
 
 def step_slots(adapter, cache, slots, tokens, count=1, note=None):
@@ -176,7 +193,7 @@ def step_slots(adapter, cache, slots, tokens, count=1, note=None):
     waits for the forward; a greedy ``GenerateEngine`` call does not take
     it (``_plain_loop``)."""
     logits, *new = forward_slots(adapter, cache, slots, tokens, note=note)
-    commit_slots(cache, slots, *new, count)
+    commit_slots(cache, slots, *new, count, adapter=adapter, note=note)
     return logits[:, -1]
 
 
@@ -199,7 +216,8 @@ def prefill_slot(adapter, cache, slot, tokens_1d, chunk, note=None):
         padded[0, :len(piece)] = piece
         read, *new = forward_slots(adapter, cache, [slot], padded, call,
                                    note)
-        commit_slots(cache, [slot], *new, len(piece))
+        commit_slots(cache, [slot], *new, len(piece), adapter=adapter,
+                     phase="prefill", note=note)
         if call is not None:
             if behind is not None:
                 _fetch_behind(adapter, behind, note)
@@ -532,6 +550,172 @@ class MLAPagedLM(_PagedLM):
         return {"expert_loads": loads}, rows
 
 
+class EvaPagedLM(_PagedLM):
+    """Shape-cached jit adapter over ``eva_forward_paged``: the byte-level
+    decoder of ``models/eva_byte.py`` (EVA attention: a window of exact
+    keys and values beside a summary a chunk of every closed window),
+    served in `dtype` (bfloat16: weights, activations and the cache).
+
+    Its cache holds two GROUPS of entries a layer
+    (``paged_kv.PagedKVCache(groups=...)``): ``window``, the leading one
+    (``wk``, ``wv``: at most ``window`` rows a slot in blocks of 256, row
+    ``t % window``), and ``summary`` (``sk``, ``sv``: ``window // chunk``
+    rows a closing, a block a closing); a row holds all heads side by
+    side (H x D lanes). On a TPU a decode step walks each group by one
+    launch a layer (``ops/pallas/paged_heads.py``; `interpret` runs the
+    launches anywhere), elsewhere and for every wider chunk by the
+    ``lax`` gather. It declares ``window``: the engine
+    then keeps a prefill chunk from straddling a closing, and
+    ``commit_slots`` asks ``close_windows`` after every commit.
+
+    - ``forward`` — ``(logits (S, C, V), new_k, new_v)``: the served
+      head's logits (head 0: position t scores byte t + 1) on the host;
+      ``last_pred_logits`` (S, C, pred_heads, V) holds every head's;
+    - ``forward_token`` — ``(read, new_k, new_v)`` with ``read["token"]``
+      (S, 1) int32, head 0's argmax at the last chunk position, nothing
+      fetched;
+    - ``forward_kv`` — prefill: ``(read, new_k, new_v)``, no final norm,
+      no head; ``last_stream`` is the last layer's stream (S, C, d)
+      float32 on the device, what a pipeline stage hands on;
+      ``read["window_rows"]`` (S,) int32 is the program's smallest output,
+      left for ``prefill_slot`` to fetch one chunk behind: a chunk's keys,
+      values and temporaries hold some 0.8 GB of HBM until its commit has
+      run, so the host keeps to one chunk ahead of the device (the device
+      never waits: the next chunk is queued by then);
+    - ``close_windows(cache, slots, phase)`` — for each of `slots` whose
+      window group holds ``window`` rows (the cache's host lengths: no
+      fetch): one launch that reads the window's rows and pools them into
+      its summaries, their commit to the summary group, and the window
+      restarted in place; a ``gen.window_close`` span a closing. ->
+      ``{"windows_closed", "summary_rows_written", "window_rows_read"}``,
+      None where nothing closed.
+
+    Each group's lengths and tables are host arrays, tokens a host or a
+    device array, the pools the cache's device arrays; new_k / new_v stay
+    on the device, (layers, S, C, H D), for ``cache.commit``. A chunk that
+    finds every window empty (a prefill chunk of ``window`` positions)
+    takes a program that leaves the window pools unread. After every
+    forward ``last_eva`` holds what it read, by ``last_stats["eva"]``'s
+    names: ``window_rows_read`` (the live window rows and the chunk's
+    own), ``summary_rows_read``, ``positions`` (the contexts' lengths
+    after the chunk, summed over the rows).
+    """
+
+    def __init__(self, params, config, dtype="bfloat16", interpret=False):
+        import jax.numpy as jnp
+        from ..models.eva_byte import (eva_close_window, eva_config,
+                                       eva_forward_paged)
+        self.config = cfg = eva_config(config)
+        self.dtype = jnp.dtype(dtype)
+        self.params = {n: jnp.asarray(v, self.dtype)
+                       for n, v in params.items()}
+        self.num_layers = cfg["num_layers"]
+        self.window = int(cfg["window"])
+        self.summaries_per_window = self.window // int(cfg["chunk"])
+        # a row holds all heads side by side: H x D lanes
+        self.kv_entries = dict.fromkeys(
+            ("wk", "wv", "sk", "sv"), ((cfg["units"],), self.dtype))
+        self.kv_groups = {"window": ("wk", "wv"), "summary": ("sk", "sv")}
+        self.last_eva = self.last_pred_logits = self.last_stream = None
+
+        def program(head, fresh):
+            def pure(params, tokens, wlen, wtables, slen, stables, wk, wv,
+                     sk, sv):
+                out, nk, nv = eva_forward_paged(
+                    params, cfg, tokens, wlen, wtables, slen, stables, wk,
+                    wv, sk, sv, head=head, fresh=fresh, interpret=interpret)
+                if head == "token":
+                    out = out[:, None]
+                elif head == "none":    # the stream; the window rows after
+                    out = (out, wlen + tokens.shape[1])
+                return out, jnp.stack(nk), jnp.stack(nv)
+            return jax.jit(pure)
+        self._fns = {(head, fresh): program(head, fresh)
+                     for head in ("logits", "token", "none")
+                     for fresh in (False, True)}
+
+        def close(params, wtables, wk, wv):
+            sk, sv = eva_close_window(params, cfg, wtables, wk, wv)
+            return jnp.stack(sk), jnp.stack(sv)
+        self._close = jax.jit(close)
+
+    def cache_spec(self):
+        return PagedKVCache.layer_spec(self.num_layers, self.kv_entries,
+                                       groups=self.kv_groups)
+
+    def make_cache(self, slots, max_len=None, **kw):
+        """The grouped cache: the window group in blocks of 256 rows (a
+        whole window where it is shorter), the summary group a closing a
+        block and as many as ``max_len`` positions close."""
+        max_len = max_len or self.config["max_len"]
+        per = self.summaries_per_window
+        kw.setdefault("groups", {
+            "window": {"max_len": self.window,
+                       "block_size": min(self.window, 256)},
+            "summary": {"max_len": max(1, max_len // self.window) * per,
+                        "block_size": per}})
+        return super().make_cache(slots, max_len=max_len, **kw)
+
+    def lower(self, tokens, wlen, wtables, slen, stables, wk, wv, sk, sv,
+              head="logits", fresh=False):
+        """A forward's program lowered for arguments of these shapes."""
+        return self._fns[head, fresh].lower(
+            self.params, tokens, wlen, wtables, slen, stables, wk, wv, sk, sv)
+
+    def lower_close(self, wtables, wk, wv):
+        """The closing's program lowered likewise."""
+        return self._close.lower(self.params, wtables, wk, wv)
+
+    def _call(self, head, tokens, wlen, *rest):
+        """One forward, nothing fetched -> (out, new_k, new_v), all on
+        the device."""
+        slen = rest[1]
+        chunk = tokens.shape[1]
+        window_rows = int(wlen.sum()) + chunk * len(wlen)
+        self.last_eva = {
+            "window_rows_read": window_rows,
+            "summary_rows_read": int(slen.sum()),
+            "positions": int((slen // self.summaries_per_window).sum())
+            * self.window + window_rows}
+        fresh = chunk > 1 and not wlen.any()
+        return _dispatch(self._fns[head, fresh], self.params,
+                         (tokens, wlen) + rest)
+
+    def forward(self, *args):
+        logits, nk, nv = self._call("logits", *args)
+        (logits,) = _fetch([logits])
+        V = self.config["vocab_size"]
+        self.last_pred_logits = logits.reshape(logits.shape[:2] + (-1, V))
+        return logits[..., :V], nk, nv
+
+    def forward_token(self, *args):
+        token, nk, nv = self._call("token", *args)
+        return {"token": token}, nk, nv
+
+    def forward_kv(self, *args):
+        (self.last_stream, reached), nk, nv = self._call("none", *args)
+        return {"window_rows": reached}, nk, nv
+
+    def close_windows(self, cache, slots, phase):
+        full = cache.group_lengths("window")
+        due = [slot for slot in slots if full[slot] >= self.window]
+        if not due:
+            return None
+        per = self.summaries_per_window
+        for slot in due:        # one slot a launch: one compiled shape
+            with _tr.span("gen.window_close", slots=1, rows_written=per,
+                          phase=phase):
+                sk, sv = _dispatch(
+                    self._close, self.params,
+                    (cache.tables_array([slot], "window"),)
+                    + cache.group_pools("window"))
+                cache.commit([slot], sk, sv, per, group="summary")
+                cache.restart(slot, "window")
+        return {"windows_closed": len(due),
+                "summary_rows_written": per * len(due),
+                "window_rows_read": self.window * len(due)}
+
+
 class GenerateEngine:
     """Drives one model (plus optional draft) over paged KV caches.
 
@@ -598,19 +782,36 @@ class GenerateEngine:
                     % (self.prefill_chunk, self.block_length))
             if self.denoise_steps < 1:
                 raise ValueError("denoise_steps must be >= 1")
+        window = int(getattr(model, "window", 0) or 0)
+        if window:
+            if self.draft is not None:
+                raise ValueError(
+                    "a model that closes windows takes no draft model: a "
+                    "rejected suffix may lie across a closing, which the "
+                    "cache does not roll back")
+            if window % self.prefill_chunk:
+                raise ValueError(
+                    "prefill_chunk (%d) must divide the model's window "
+                    "(%d): a chunk must not straddle a closing"
+                    % (self.prefill_chunk, window))
         self.last_stats = {}
-        # a model with an expert layer or a latent cache: every forward of
-        # its is tallied into the call's ``last_stats["moe"]`` / ``["mla"]``
+        # a model with an expert layer, a latent cache or windows that
+        # close: every forward of its is tallied into the call's
+        # ``last_stats["moe"]`` / ``["mla"]`` / ``["eva"]``
         self._tallies = {}
         self._phase = "prefill"     # of the forward in flight: ``_run``
         self._note = (self._note_forward
-                      if hasattr(model, "last_expert_loads")
-                      or hasattr(model, "last_latent_path") else None)
+                      if any(hasattr(model, said) for said in (
+                          "last_expert_loads", "last_latent_path",
+                          "last_eva")) else None)
 
     # ---------------------------------------------------------- plumbing
-    def _note_forward(self, model, read=None):
+    def _note_forward(self, model, read=None, closed=None):
         """What a forward of `model`'s says of itself, into this call's
-        ``last_stats``. Right after it (`read` None): ``last_latent_path``
+        ``last_stats``. `closed`: what a commit's window closings counted
+        (``EvaPagedLM.close_windows``), into ``"eva"`` under the phase.
+        Right after a forward (`read` None): ``last_eva`` (the rows it
+        read) likewise with the forward counted, ``last_latent_path``
         (the attention path over a latent cache and the cached rows it
         expanded, or walked) into ``"mla"``, and ``last_expert_loads``
         (layers, experts: the routes each expert got) into ``"moe"`` if the
@@ -618,6 +819,16 @@ class GenerateEngine:
         they are tallied from `read`, its outputs as the host fetched
         them a forward later."""
         split = getattr(model, "split_loads", None)
+        eva = closed
+        if closed is None and read is None and getattr(model, "last_eva",
+                                                       None):
+            eva = {"forwards": 1, **model.last_eva}
+        for key, count in (eva or {}).items():
+            self._tallies["eva"][self._phase][key] += count
+            getattr(_cat, "eva_" + key).inc(count, model=self.name,
+                                            phase=self._phase)
+        if closed is not None:
+            return
         if read is None:
             path = getattr(model, "last_latent_path", None)
             if path is not None:
@@ -726,6 +937,11 @@ class GenerateEngine:
                 ("absorbed_forwards", "expanded_forwards",
                  "expanded_kernel_forwards", "expanded_rows",
                  "absorbed_rows_live", "absorbed_rows_read"), 0)
+        if hasattr(self.model, "last_eva"):
+            stats["eva"] = {phase: dict.fromkeys(
+                ("forwards", "windows_closed", "summary_rows_written",
+                 "window_rows_read", "summary_rows_read", "positions"), 0)
+                for phase in ("prefill", "decode")}
         self._tallies = stats
         for p in prompts:
             slot = self.cache.alloc()
@@ -865,7 +1081,8 @@ class GenerateEngine:
                     read, *new = forward_slots(
                         self.model, self.cache, slots, tokens,
                         self.model.forward_token, self._note)
-                    commit_slots(self.cache, slots, *new, 1)
+                    commit_slots(self.cache, slots, *new, 1,
+                                 adapter=self.model, note=self._note)
                     committed = take(live, ids_of(behind))
                     # with the token in flight: do the same rows go on?
                     if any(s["done"] or len(s["out"]) + 1 >= max_new_tokens

@@ -458,6 +458,25 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_mask=None,
     return out.reshape(B, H, T, D)
 
 
+def flash_attention_lse(q, k, v, scale=None, causal=False, block_q=None,
+                        block_k=None, interpret=False):
+    """The tiled forward alone, with its softmax's log-sum: q/k/v (B, H, T,
+    D) -> (out (B, H, T, D) in q's dtype, lse (B, H, T) float32), so that
+    a caller merges further keys under the same softmax (a window's own
+    causal part beside its summaries, ``models/eva_byte.py``). No
+    gradient. T as ``flash_attention`` requires it."""
+    B, H, T, D = q.shape
+    if T % 128 and (T > 128 or T % 8):
+        raise ValueError("flash_attention_lse requires seq_len % 128 == 0")
+    bq0, bk0 = _default_blocks(T)
+    out, lse = _fwd_call(
+        q.reshape(B * H, T, D), k.reshape(B * H, T, D),
+        v.reshape(B * H, T, D), None,
+        float(D ** -0.5 if scale is None else scale), bool(causal),
+        int(block_q or bq0), int(block_k or bk0), bool(interpret))
+    return out.reshape(B, H, T, D), lse[:, 0].reshape(B, H, T)
+
+
 # ----------------------------------------------------------------------
 # one tile: the whole sequence of a batch row in one program instance
 # ----------------------------------------------------------------------

@@ -333,6 +333,31 @@ moe_load_max_over_mean = _m.histogram(
     "observation a forward (mean over layers), by model — 1 is even "
     "routing",
     buckets=(1, 1.5, 2, 3, 4, 6, 8, 12, 16, 32, 64, 128))
+eva_forwards = _m.counter(
+    "mxtpu_eva_forwards_total",
+    "Forwards over a cache of windows and summaries (EvaPagedLM), by "
+    "model and phase (prefill, decode)")
+eva_windows_closed = _m.counter(
+    "mxtpu_eva_windows_closed_total",
+    "Windows closed: a slot's window group reached its rows, its chunks "
+    "were pooled into summaries and the window restarted in place, by "
+    "model and phase")
+eva_summary_rows_written = _m.counter(
+    "mxtpu_eva_summary_rows_written_total",
+    "Rows committed to the summary group by closings (window // chunk a "
+    "closing), by model and phase")
+eva_window_rows_read = _m.counter(
+    "mxtpu_eva_window_rows_read_total",
+    "Exact key/value rows read a layer: a forward's live window rows and "
+    "its own chunk, a closing's whole window, by model and phase")
+eva_summary_rows_read = _m.counter(
+    "mxtpu_eva_summary_rows_read_total",
+    "Summary rows read a layer by forwards, by model and phase")
+eva_positions = _m.counter(
+    "mxtpu_eva_positions_total",
+    "Context lengths after the chunk, summed over the rows of every "
+    "forward: what a cache that kept every row would read, by model and "
+    "phase")
 mla_absorbed_forwards = _m.counter(
     "mxtpu_mla_absorbed_forwards_total",
     "Forwards over a latent cache that ran the absorbed attention path "

@@ -25,6 +25,7 @@ from incubator_mxnet_tpu.generate import (GenerateEngine, GPTPagedLM,
                                           MLAPagedLM,
                                           PagedKVCache,
                                           export_gpt_for_serving)
+from incubator_mxnet_tpu.generate import paged_kv
 from incubator_mxnet_tpu.generate.engine import prefill_slot, step_slots
 from incubator_mxnet_tpu.generate.paged_kv import KVPoolExhausted
 from incubator_mxnet_tpu.models.gpt import (GPTDecoder, gpt_config,
@@ -347,6 +348,174 @@ def test_commit_refuses_a_cache_whose_entries_are_not_layers():
     named.append("keys", slot, np.ones((2, 4)))     # the slow surface
     named.advance(slot)
     assert named.prefix("keys", slot).shape == (1, 2, 4)
+
+
+# ------------------------------------------------ groups of kv entries
+def _grouped_cache(slots=2, layers=2, window=8, per=2, closings=3,
+                   block=4):
+    """A window of `window` exact rows in blocks of `block` beside `per`
+    summary rows a closing, a block a closing: the leading group first."""
+    spec = PagedKVCache.layer_spec(
+        layers, dict.fromkeys(("wk", "wv", "sk", "sv"),
+                              ((2, 4), np.float32)),
+        groups={"window": ("wk", "wv"), "summary": ("sk", "sv")})
+    return PagedKVCache(slots, spec, max_len=window * closings + 3, groups={
+        "window": {"max_len": window, "block_size": block},
+        "summary": {"max_len": per * closings, "block_size": per}})
+
+
+def test_a_grouped_specs_entries_name_their_groups():
+    spec = PagedKVCache.layer_spec(
+        2, {"wk": ((2, 4), np.float32), "sk": ((2, 4), np.float32)},
+        groups={"window": ("wk",), "summary": ("sk",)})
+    assert spec == {"wk0": ("kv", (2, 4), np.float32, "window"),
+                    "sk0": ("kv", (2, 4), np.float32, "summary"),
+                    "wk1": ("kv", (2, 4), np.float32, "window"),
+                    "sk1": ("kv", (2, 4), np.float32, "summary")}
+    with pytest.raises(ValueError, match="do not part the entries"):
+        PagedKVCache.layer_spec(1, {"wk": ((2,), np.float32)},
+                                groups={"window": ("wk", "wv")})
+    with pytest.raises(ValueError, match="has no geometry"):
+        PagedKVCache(1, spec, groups={"window": {}})
+    assert PagedKVCache.layer_spec(1, {"k": ((2,), np.float32)}) \
+        == {"k0": ("kv", (2,), np.float32)}
+
+
+@pytest.mark.parametrize("case", ["lengths_and_tables", "window_reused",
+                                  "free_returns_both", "truncate",
+                                  "slow_surface", "full"])
+def test_a_grouped_cache_keeps_a_length_and_a_table_a_group(case):
+    rng = np.random.RandomState(3)
+    cache = _grouped_cache()
+    a, b = cache.alloc(), cache.alloc()
+    window, summary = cache.group_lengths("window"), \
+        cache.group_lengths("summary")
+
+    def step(slots, count, C=None):
+        nk, nv = _chunk(rng, 2, len(slots), C or max(np.atleast_1d(count)))
+        cache.commit(slots, nk, nv, count)
+        return nk, nv
+
+    def close(slot):
+        sk, sv = _chunk(rng, 2, 1, 2)
+        cache.commit([slot], sk, sv, 2, group="summary")
+        cache.restart(slot, "window")
+        return sk, sv
+
+    if case == "lengths_and_tables":
+        step([a, b], [5, 2], C=5)
+        assert cache.lengths[[a, b]].tolist() == [5, 2]
+        assert window[[a, b]].tolist() == [5, 2]
+        assert summary[[a, b]].tolist() == [0, 0]
+        assert [len(cache.table(s)) for s in (a, b)] == [2, 1]
+        assert cache.table(a, "summary") == []
+        sk, _sv = close(a)
+        assert (cache.lengths[a], window[a], summary[a]) == (5, 0, 2)
+        np.testing.assert_array_equal(cache.prefix("sk1", a),
+                                      np.asarray(sk[1])[0])
+        wlen, wtab, slen, stab, *pools = cache.forward_inputs([b, a])
+        assert wlen.tolist() == [2, 0] and slen.tolist() == [0, 2]
+        assert wlen.dtype == slen.dtype == np.int32
+        assert wtab.shape == (2, 2) and stab.shape == (2, 3)
+        assert stab[1, 0] == cache.table(a, "summary")[0]
+        assert [len(p) for p in pools] == [2, 2, 2, 2]
+        assert pools[0][0] is cache.pool("wk0")
+        assert pools[3][1] is cache.pool("sv1")
+        assert pools[0][0].shape == (4, 4, 2, 4)       # 2 slots x 8 / 4
+        assert pools[2][0].shape == (6, 2, 2, 4)       # 2 slots x 3 x 2 / 2
+        assert cache.group_pools("window") == tuple(pools[:2])
+        assert cache.tables_array(group="summary").shape == (2, 3)
+    elif case == "window_reused":
+        step([a], 8)
+        blocks = cache.table(a)
+        assert window[a] == 8 and len(blocks) == 2
+        close(a)
+        nk, _nv = step([a], 3)
+        # no growth past a window's rows: the same blocks, rewritten from
+        # row 0; the sequence's positions go on
+        assert cache.table(a) == blocks and window[a] == 3
+        assert cache.lengths[a] == 11 and summary[a] == 2
+        np.testing.assert_array_equal(cache.prefix("wk0", a),
+                                      np.asarray(nk[0])[0, :3])
+        step([a], 5)
+        close(a)
+        assert (cache.lengths[a], window[a], summary[a]) == (16, 0, 4)
+        assert cache.table(a) == blocks and len(cache.table(a, "summary")) == 2
+    elif case == "free_returns_both":
+        step([a, b], [8, 3], C=8)
+        close(a)
+        assert cache.blocks_in_use == 2 + 1 + 1
+        assert cache.num_blocks == 4 + 6
+        assert 0 < cache.fragmentation() < 1
+        cache.free(a)
+        assert cache.blocks_in_use == 1 and summary[a] == window[a] == 0
+        cache.free(b)
+        assert cache.blocks_in_use == 0 and cache.blocks_free == 10
+        c = cache.alloc()
+        assert (cache.lengths[c], cache.table(c),
+                cache.table(c, "summary")) == (0, [], [])
+    elif case == "truncate":
+        step([a], 8)
+        close(a)
+        with pytest.raises(ValueError, match="the rest were closed"):
+            cache.truncate(a, 7)        # across the closing
+        step([a], 6)
+        cache.truncate(a, 20)           # not a roll back: a no-op
+        cache.truncate(a, 9)            # inside the live window
+        assert (cache.lengths[a], window[a], len(cache.table(a))) \
+            == (9, 1, 1)
+        with pytest.raises(ValueError, match="the rest were closed"):
+            cache.truncate(a, 7)
+        cache.truncate(a, 8)
+        assert (cache.lengths[a], window[a], summary[a]) == (8, 0, 2)
+    elif case == "slow_surface":
+        cache.append("wk0", a, np.ones((2, 4)))
+        cache.advance(a)
+        cache.append("sk0", a, np.full((2, 4), 2.0))
+        cache.advance(a, "summary")
+        assert (cache.lengths[a], window[a], summary[a]) == (1, 1, 1)
+        assert cache.prefix("sk0", a)[0, 0, 0] == 2.0
+        with pytest.raises(ValueError, match="no group 'recent'"):
+            cache.advance(a, "recent")
+    else:
+        step([a], 8)
+        with pytest.raises(ValueError, match=r"slot 0 is full \(max_len=8\)"):
+            step([a], 1)                # a full window takes no row
+        close(a)
+        step([a], 8)
+        close(a)
+        step([a], 8)
+        close(a)
+        step([a], 3)
+        with pytest.raises(ValueError,
+                           match=r"slot 0 is full \(max_len=27\)"):
+            step([a], 1)                # the sequence's positions
+        with pytest.raises(ValueError, match=r"slot 0 is full \(max_len=6\)"):
+            close(a)                    # the summaries' rows
+
+
+def test_an_ungrouped_specs_inputs_and_commit_are_what_they_were():
+    """A spec that names no group: ``forward_inputs`` is (lengths, tables,
+    K pools, V pools), ``commit`` runs the one program of two entries, and
+    the cache's own arrays ARE its one group's."""
+    rng = np.random.RandomState(1)
+    cache = PagedKVCache(2, _kv_spec(2), max_len=8, block_size=4)
+    a, b = cache.alloc(), cache.alloc()
+    new_k, new_v = _chunk(rng, 2, 2, 3)
+    before = paged_kv.store_program_for(2)._cache_size()
+    cache.commit([a, b], new_k, new_v, [3, 1])
+    assert paged_kv.store_program_for(2)._cache_size() == before + 1
+    assert paged_kv.store_program_for(2) is paged_kv.store_program
+    lengths, tables, k_pools, v_pools = cache.forward_inputs([b, a])
+    assert lengths.tolist() == [1, 3] and lengths.dtype == np.int32
+    assert tables.shape == (2, 2) and tables.dtype == np.int32
+    assert k_pools[1] is cache.pool("k1") and v_pools[0] is cache.pool("v0")
+    assert cache.group_lengths(None) is cache.lengths
+    assert cache.group_pools() == (k_pools, v_pools)
+    assert (cache.num_blocks, cache.block_size,
+            cache.max_blocks_per_slot) == (4, 4, 2)
+    with pytest.raises(ValueError, match="no group 'window'"):
+        cache.commit([a], new_k, new_v, 1, group="window")
 
 
 @pytest.mark.parametrize("shape,order", [

@@ -535,7 +535,8 @@ def test_int8_matmul_kernel_numerics():
 # ------------------------------------------------------- names on the device
 _KERNEL_FILES = ["flash_attention.py", "flash_decode.py",
                  "fused_bottleneck.py", "fused_norm.py", "fused_optim.py",
-                 "grouped_matmul.py", "int8_matmul.py", "paged_latent.py"]
+                 "grouped_matmul.py", "int8_matmul.py", "paged_heads.py",
+                 "paged_latent.py"]
 
 
 def _pallas_calls(tree):

@@ -676,3 +676,73 @@ def test_bert_train_step_compiles_for_v5e_mesh(topo, monkeypatch):
     name = next(n for n in shapes[0] if n.endswith("ffn1_weight"))
     assert shapes[0][name].sharding.shard_shape(shapes[0][name].shape) == \
         (3072 // 2, 768)
+
+
+# ------------------------------------------- windows and summaries (cell 7)
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_step",
+                                     "closing"])
+def test_evabytes_three_programs_compile_at_published_widths(
+        one_chip, monkeypatch, program):
+    """Cell 7's programs at ``evabyte_6_5b``'s widths cut to two layers,
+    over the cell's own pools (24 slots: a window of 2,048 rows in blocks
+    of 256, 768 summary rows in blocks of 128, rows of all 32 heads side
+    by side), as the chip takes them: a prefill chunk of one window that
+    opens on an empty window, its own causal part one tiled
+    ``flash_attention_fwd`` a layer, the window pools unread; a decode step
+    of 24 rows with the token head, whose walks over the two groups are
+    two ``paged_heads_decode`` launches a layer and gather nothing; and
+    the closing of one slot's window (``lax``)."""
+    from benchmarks import spec
+    from benchmarks.families import evabyte
+    from incubator_mxnet_tpu.generate import EvaPagedLM
+    from incubator_mxnet_tpu.models import eva_byte
+    monkeypatch.setattr(eva_byte, "paged_heads_decode_available",
+                        lambda pool, heads: True)
+    monkeypatch.setattr(eva_byte, "flash_attention_available", lambda: True)
+    cfg = spec.load_json(spec.ROOT + "/benchmarks/configs/evabyte_6_5b.json")
+    config = dict(evabyte.program_config(cfg), num_layers=2)
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    shapes = eva_byte.eva_param_shapes(eva_byte.eva_config(config))
+    assert shapes["l1_q_w"] == (4096, 4096) and shapes["l1_mu"] == (32, 128)
+    assert shapes["l1_gate_w"] == (4096, 11008)
+    assert shapes["head"] == (4096, 8 * 320)
+    model = EvaPagedLM({}, config)      # the weights are a call's argument
+    model.params = {n: shape(s) for n, s in shapes.items()}
+    slots = 24
+    window = [shape((slots * 8, 256, 4096))] * 2
+    summary = [shape((slots * 6, 128, 4096))] * 2
+
+    def inputs(rows, chunk):
+        return (shape((rows, chunk), jnp.int32), shape((rows,), jnp.int32),
+                shape((rows, 8), jnp.int32), shape((rows,), jnp.int32),
+                shape((rows, 6), jnp.int32), window, window, summary,
+                summary)
+    if program == "prefill_chunk":
+        lowered = model.lower(*inputs(1, 2048), head="none", fresh=True)
+        # the stage's stream, the window rows after, the keys and values
+        wanted = [(1, 2048, 4096), (1,), (2, 1, 2048, 4096),
+                  (2, 1, 2048, 4096)]
+    elif program == "decode_step":
+        lowered = model.lower(*inputs(slots, 1), head="token")
+        wanted = [(24, 1), (2, 24, 1, 4096), (2, 24, 1, 4096)]
+    else:
+        lowered = model.lower_close(shape((1, 8), jnp.int32), window,
+                                    window)
+        wanted = [(2, 1, 128, 4096)] * 2
+    outputs = [o.shape for o in jax.tree_util.tree_leaves(lowered.out_info)]
+    assert outputs == wanted
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    if program == "decode_step":
+        assert text.count("paged_heads_decode") >= 4
+        assert not re.search(r"bf16\[24,(8,256|6,128|2048|768),", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+    elif program == "prefill_chunk":    # no window block is gathered
+        assert text.count("flash_attention_fwd") >= 2
+        assert "bf16[1,8,256,4096]" not in text
+        assert not re.search(r"f32\[1,32,(512|2048),(512|1024|2048)\]", text)
+    else:
+        assert "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
