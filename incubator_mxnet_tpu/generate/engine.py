@@ -56,15 +56,22 @@ drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
     block:  the row's next B positions, the prompt's tail fixed, the
             rest MASK
             up to `denoise_steps` forwards over the block that STORE
-            NOTHING; after each, the ceil(masked / steps left) most
+            NOTHING; in each, the ceil(masked / steps left) most
             confident masked positions take their argmax token
             once none is masked, one forward over the final tokens
             whose K and V are committed: B positions a row at once
+
+The schedule is applied in the denoising forward's program
+(``forward_denoise``), which hands the next forward its tokens and mask as
+device arrays; the host reads every block forward one forward behind, as
+the plain loop reads its tokens.
 """
 
+import functools
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..ops.pallas.paged_latent import (cache_row_width, latent_block_size,
@@ -149,7 +156,7 @@ def forward_slots(adapter, cache, slots, tokens, call=None, note=None):
     returns ``(logits, *new)``, `new` what the chunk adds to each cache
     entry (new_k, new_v), WITHOUT committing. `call`: another forward of
     the adapter's with the same arguments (the block loop's
-    ``forward_choice`` / ``forward_kv``, the plain loop's
+    ``forward_denoise`` / ``forward_kv``, the plain loop's
     ``forward_token``); `tokens` may then be a device array. `note`:
     called with the adapter after the forward (the engine's tallies of
     what it last did: an expert layer's loads, a latent cache's attention
@@ -278,7 +285,6 @@ class GPTPagedLM(_PagedLM):
     """
 
     def __init__(self, params, config, use_kernel=False, interpret=False):
-        import jax.numpy as jnp
         from ..models.gpt import gpt_config, gpt_forward_paged
         self.config = gpt_config(config)
         self.params = {n: jnp.asarray(v) for n, v in params.items()}
@@ -338,25 +344,34 @@ class SDARPagedLM(_PagedLM):
 
     - ``forward`` — the ``GPTPagedLM`` contract, ``(logits (S, C, V),
       new_k, new_v)``;
-    - ``forward_choice`` — a denoising forward: ``((x0, confidence),
-      None, None)``, each (S, C), the argmax token and its softmax
-      probability computed on the device; the program returns no K and
-      V, as nothing is stored;
+    - ``forward_denoise(tokens, ..., masked=, steps_left=)`` — the block
+      loop's denoising forward: the argmax token ``x0`` of every position
+      and its softmax probability (the confidence), then the static
+      schedule applied in the program (``GenerateEngine.
+      _fix_most_confident``, traced: of each row's masked positions the
+      ``ceil(masked / steps_left)`` most confident take ``x0``).
+      ``steps_left`` is an int32 operand, so one program serves every
+      step. -> ``(read, next_tokens, next_masked)``, all on the device,
+      nothing fetched: the next forward's `tokens` and `masked` (S, C),
+      and ``read`` ``{"x0", "confidence", "masked" (next_masked),
+      "expert_loads"}`` for the host to fetch when it will
+      (``engine._fetch_behind``); the positions it fixed are ``masked &
+      ~read["masked"]``. No K and V come back: nothing is stored;
     - ``forward_kv`` — prefill and a block's store pass: ``(read, new_k,
       new_v)``, no final norm, no head, and nothing fetched:
       ``read["expert_loads"]`` stays on the device for the host to fetch
       when it will (``engine._fetch_behind``).
 
-    Tokens, lengths and tables are host arrays, the pools the cache's
-    device arrays; logits, ``x0`` and confidence come back as host
-    arrays, new_k / new_v stay on the device, (layers, S, C, Hkv, D) each,
-    for ``cache.commit``. After a forward that fetched,
-    ``last_expert_loads`` holds the (layers, experts) routes each expert
-    got, on the host; after ``forward_kv`` None.
+    Tokens (and ``forward_denoise``'s mask) are host or device arrays,
+    lengths and tables host arrays, the pools the cache's device arrays;
+    ``forward``'s logits come back as a host array, new_k / new_v stay on
+    the device, (layers, S, C, Hkv, D) each, for ``cache.commit``. After
+    ``forward``, ``last_expert_loads`` holds the (layers, experts) routes
+    each expert got, on the host; after a forward that fetched nothing,
+    None.
     """
 
     def __init__(self, params, config, dtype="bfloat16"):
-        import jax.numpy as jnp
         from ..models.sdar_moe import sdar_config, sdar_forward_paged
         self.config = sdar_config(config)
         self.dtype = jnp.dtype(dtype)
@@ -376,17 +391,26 @@ class SDARPagedLM(_PagedLM):
                     params, self.config, tokens, lengths, tables, kps, vps,
                     head=head)
                 # -> (what the host reads, what stays on the device)
-                if head == "choice":        # (x0, confidence): no K, V
-                    return out + (loads,), None
                 kv = (jnp.stack(nk), jnp.stack(nv))
                 return ((loads,) if out is None else (out, loads)), kv
             return jax.jit(pure)
-        self._fns = {head: program(head)
-                     for head in ("logits", "choice", "none")}
+        self._fns = {head: program(head) for head in ("logits", "none")}
+
+        def denoise(params, tokens, masked, steps_left, lengths, tables,
+                    kps, vps):
+            (x0, confidence), _nk, _nv, loads = sdar_forward_paged(
+                params, self.config, tokens, lengths, tables, kps, vps,
+                head="choice")
+            # looked up when traced: the schedule has one definition
+            fixed = GenerateEngine._fix_most_confident(masked, confidence,
+                                                       steps_left)
+            return (jnp.where(fixed, x0, tokens), masked & ~fixed, x0,
+                    confidence, loads)
+        self._fns["denoise"] = jax.jit(denoise)
 
     def _call(self, head, *args):
-        """One forward, nothing fetched -> (the head's outputs then the
-        expert loads, (new_k, new_v) or None), all on the device."""
+        """One forward, nothing fetched -> the program's outputs, all on
+        the device."""
         self.last_expert_loads = None
         return _dispatch(self._fns[head], self.params, args)
 
@@ -396,11 +420,13 @@ class SDARPagedLM(_PagedLM):
         logits, self.last_expert_loads = _fetch(read)
         return logits, nk, nv
 
-    def forward_choice(self, tokens, lengths, tables, k_pools, v_pools):
-        read, _none = self._call("choice", tokens, lengths, tables,
-                                 k_pools, v_pools)
-        x0, confidence, self.last_expert_loads = _fetch(read)
-        return (x0, confidence), None, None
+    def forward_denoise(self, tokens, lengths, tables, k_pools, v_pools, *,
+                        masked, steps_left):
+        nxt, masked, x0, confidence, loads = self._call(
+            "denoise", tokens, masked, np.int32(steps_left), lengths, tables,
+            k_pools, v_pools)
+        return ({"x0": x0, "confidence": confidence, "masked": masked,
+                 "expert_loads": loads}, nxt, masked)
 
     def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
         (loads,), (nk, nv) = self._call("none", tokens, lengths, tables,
@@ -454,7 +480,6 @@ class MLAPagedLM(_PagedLM):
     """
 
     def __init__(self, params, config, dtype="bfloat16"):
-        import jax.numpy as jnp
         from ..models.mla_moe import mla_config, mla_forward_paged
         self.config = mla_config(config)
         self.dtype = jnp.dtype(dtype)
@@ -602,7 +627,6 @@ class EvaPagedLM(_PagedLM):
     """
 
     def __init__(self, params, config, dtype="bfloat16", interpret=False):
-        import jax.numpy as jnp
         from ..models.eva_byte import (eva_close_window, eva_config,
                                        eva_forward_paged)
         self.config = cfg = eva_config(config)
@@ -731,15 +755,17 @@ class GenerateEngine:
     device array, and the host reads ids and expert loads one forward
     behind. Chosen from what the engine sees (`temperature` <= 0, no
     speculative rounds, no ``block_length``, the head), by no flag.
-    Temperature sampling, speculative rounds, the block loop and
-    ``serving.DecodeLoop`` read a forward's own outputs and wait for it.
+    Temperature sampling, speculative rounds and ``serving.DecodeLoop``
+    read a forward's own outputs and wait for it.
     Every wait for the device lies inside a timed region, so
     ``last_stats["prefill_seconds"]`` + ``["decode_seconds"]`` is the
     call's time, and the device's.
 
     A model that declares ``block_length`` is decoded by blocks
     (``_block_loop``): ``denoise_steps`` forwards a block at most
-    (default: the block length, one position a step), greedy, no draft;
+    (default: the block length, one position a step), greedy, no draft,
+    every forward fed on the device but a block's first and read one
+    forward behind;
     ``prefill_chunk`` is then a multiple of the block length, as a chunk
     must not split a block.
     """
@@ -1109,11 +1135,22 @@ class GenerateEngine:
         """The static low-confidence schedule: of a row's still-masked
         positions the ``ceil(masked / steps_left)`` most confident are
         fixed by this forward (ties: the leftmost). masked (R, B) bool,
-        confidence (R, B) -> fixed (R, B) bool."""
+        confidence (R, B) -> fixed (R, B) bool.
+
+        One definition for numpy arrays and for the denoising program,
+        which traces it (``SDARPagedLM.forward_denoise``). A position's
+        rank is the count of positions ahead of it by a (B, B) comparison:
+        a smaller key, or an equal key further left, where the key is
+        ``-confidence`` and a position not masked comes last; that is a
+        stable argsort's rank, bit for bit."""
+        xp = np if isinstance(confidence, np.ndarray) else jnp
         count = -(-masked.sum(axis=1) // steps_left)
-        order = np.argsort(np.where(masked, -confidence, np.inf), axis=1,
-                           kind="stable")
-        rank = np.argsort(order, axis=1, kind="stable")
+        key = xp.where(masked, -confidence, xp.inf)
+        left = xp.arange(key.shape[1])
+        ahead = ((key[:, None, :] < key[:, :, None])
+                 | ((key[:, None, :] == key[:, :, None])
+                    & (left[None, :] < left[:, None])))
+        rank = ahead.sum(axis=2)
         return masked & (rank < count[:, None])
 
     def _block_loop(self, seqs, max_new_tokens, eos_id, stats):
@@ -1129,18 +1166,62 @@ class GenerateEngine:
         `eos_id`): the last block is denoised and stored whole and cut
         on the way out.
 
-        ``stats`` gains ``block_forwards`` by phase, ``block_row_forwards``
-        (rows summed over forwards), ``block_positions_committed`` and
-        ``blocks``: a record a round of what every forward was given,
-        chose and fixed (docs/GENERATE.md).
+        The host runs one forward ahead of the device's results. A
+        block's first denoising forward is fed from the host; the
+        program applies the schedule (``forward_denoise``) and the next
+        denoising forward, and then the store pass, are fed the tokens
+        and mask it left on the device. A forward's choice and expert
+        loads are fetched after the next forward (and a store pass's
+        commit) is launched, and only then go into the block's record.
+        Whether a denoising forward follows is known without a read: the
+        schedule fixes ``ceil(m / steps left)`` of a row's m masked
+        positions. A block's last denoising forward is read after its
+        store pass is launched, so its tokens reach the rows (and
+        `eos_id` ends them) before the next block is built; the store
+        pass's loads are read after the next block's first forward is
+        launched, or, after the call's last block, inside its region.
+
+        ``stats`` gains ``block_forwards`` by phase,
+        ``block_forwards_launched_ahead`` (those launched while the
+        forward before them was unread: all but a call's first),
+        ``block_row_forwards`` (rows summed over forwards),
+        ``block_positions_committed`` and ``blocks``: a record a round of
+        what every forward was given, chose and fixed (docs/GENERATE.md).
         """
         B, mask_id = self.block_length, self.model.mask_id
         stats.update(block_forwards={"denoise": 0, "store": 0},
-                     block_row_forwards=0, block_positions_committed=0,
-                     blocks=[])
+                     block_forwards_launched_ahead=0, block_row_forwards=0,
+                     block_positions_committed=0, blocks=[])
         for index, s in enumerate(seqs):
             s["index"] = index
             s["open"] = s["ctx"][len(s["ctx"]) // B * B:]   # prompt's tail
+        # the forward launched last and not read yet: (its read, the record
+        # of a denoising forward's block; None for a store pass), and the
+        # block as that denoising forward was fed it, on the host
+        unread, host = None, {}
+
+        def read_behind():
+            nonlocal unread
+            if unread is None:
+                return
+            (read, record), unread = unread, None
+            got = _fetch_behind(self.model, read, self._note)
+            if record is not None:
+                tokens, masked = host["tokens"], host["masked"]
+                fixed = masked & ~got["masked"]
+                record["steps"].append(
+                    {"tokens": tokens, "masked": masked, "fixed": fixed,
+                     "x0": got["x0"], "confidence": got["confidence"]})
+                host.update(tokens=np.where(fixed, got["x0"], tokens),
+                            masked=got["masked"])
+
+        def count(phase, ahead, rows):
+            stats["block_forwards"][phase] += 1
+            stats["block_forwards_launched_ahead"] += ahead
+            stats["block_row_forwards"] += rows
+            _cat.gen_block_forwards.inc(model=self.name, phase=phase,
+                                        ahead=str(ahead).lower())
+
         while True:
             live = [s for s in seqs if not s["done"]]
             if not live:
@@ -1154,49 +1235,49 @@ class GenerateEngine:
                 for r, s in enumerate(live):
                     tokens[r, :len(s["open"])] = s["open"]
                     masked[r, :len(s["open"])] = False
+                host.update(tokens=tokens, masked=masked)
+                left = masked.sum(axis=1)       # a row's masked positions
                 record = {"rows": [s["index"] for s in live],
                           "starts": [int(self.cache.lengths[slot])
                                      for slot in slots],
                           "steps": []}
                 for step in range(self.denoise_steps):
-                    if not masked.any():
+                    if not left.any():
                         break
+                    steps_left = self.denoise_steps - step
+                    fixing = -(-left // steps_left)
+                    ahead = unread is not None
                     with _tr.span("gen.denoise_step", model=self.name,
-                                  rows=rows) as sp:
+                                  rows=rows, ahead=ahead) as sp:
                         t1 = time.monotonic()
-                        (x0, confidence), _nk, _nv = forward_slots(
+                        read, tokens, masked = forward_slots(
                             self.model, self.cache, slots, tokens,
-                            self.model.forward_choice, self._note)
-                        fixed = self._fix_most_confident(
-                            masked, confidence, self.denoise_steps - step)
-                        record["steps"].append(
-                            {"tokens": tokens, "masked": masked,
-                             "fixed": fixed, "x0": x0,
-                             "confidence": confidence})
-                        tokens = np.where(fixed, x0, tokens)
-                        masked = masked & ~fixed
-                        sp.set_attr("fixed", int(fixed.sum()))
+                            functools.partial(self.model.forward_denoise,
+                                              masked=masked,
+                                              steps_left=steps_left),
+                            self._note)
+                        read_behind()
+                        unread = (read, record)
+                        sp.set_attr("fixed", int(fixing.sum()))
                         sp.set_duration(time.monotonic() - t1)
-                    stats["block_forwards"]["denoise"] += 1
-                    stats["block_row_forwards"] += rows
-                    _cat.gen_block_forwards.inc(model=self.name,
-                                                phase="denoise")
+                    left = left - fixing
+                    count("denoise", ahead, rows)
+                ahead = unread is not None
                 with _tr.span("gen.block_store", model=self.name,
-                              rows=rows) as sp:
+                              rows=rows, ahead=ahead) as sp:
                     t1 = time.monotonic()
                     read, *new = forward_slots(
                         self.model, self.cache, slots, tokens,
                         self.model.forward_kv, self._note)
                     commit_slots(self.cache, slots, *new, B)
-                    _fetch_behind(self.model, read, self._note)
+                    read_behind()   # the block's last denoising forward
+                    unread = (read, None)
                     sp.set_duration(time.monotonic() - t1)
-                stats["block_forwards"]["store"] += 1
-                stats["block_row_forwards"] += rows
+                count("store", ahead, rows)
                 stats["block_positions_committed"] += rows * B
-                _cat.gen_block_forwards.inc(model=self.name, phase="store")
                 _cat.gen_block_positions_committed.inc(rows * B,
                                                        model=self.name)
-                record["final"] = tokens
+                tokens = record["final"] = host["tokens"]
                 stats["blocks"].append(record)
                 for r, s in enumerate(live):
                     for tok in tokens[r, len(s["open"]):].tolist():
@@ -1207,6 +1288,8 @@ class GenerateEngine:
                             s["done"] = True
                             break
                     s["open"] = []
+                if all(s["done"] for s in seqs):
+                    read_behind()   # the call's last read, in its region
                 bsp.set_attr("tokens_committed", rows * B)
                 dt = time.monotonic() - t0
                 bsp.set_duration(dt)

@@ -292,9 +292,11 @@ gen_spec_accepted = _m.counter(
     "numerator; denominator is gen_spec_proposed)")
 gen_block_forwards = _m.counter(
     "mxtpu_gen_block_forwards_total",
-    "Forwards of the block-diffusion loop by model and phase (denoise = "
+    "Forwards of the block-diffusion loop by model, phase (denoise = "
     "a forward that stores nothing | store = the one that commits a "
-    "block's K and V)")
+    "block's K and V) and ahead (true: launched while the forward before "
+    "it was still unread, fed its tokens on the device | false: after "
+    "every read, a call's first)")
 gen_block_positions_committed = _m.counter(
     "mxtpu_gen_block_positions_committed_total",
     "Cache positions committed by block store passes, by model (block "

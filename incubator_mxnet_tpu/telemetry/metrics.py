@@ -86,9 +86,16 @@ class Counter(_Instrument):
             self._series[key] = self._series.get(key, 0) + delta
 
     def value(self, **labels):
+        """The series of exactly these labels; where there is none, the
+        sum of the series whose labels include them (a label added to a
+        counter leaves its readers by the older labels whole)."""
         key = _label_key(labels)
         with self._lock:
-            return self._series.get(key, 0)
+            if key in self._series:
+                return self._series[key]
+            given = set(key)
+            return sum(v for k, v in self._series.items()
+                       if given <= set(k))
 
     def snapshot(self):
         with self._lock:

@@ -11,9 +11,12 @@ vocabulary 256, block length 4, seeded weights.
   for GPT-2's call;
 - prefill then block decoding through ``PagedKVCache`` against the plain
   reference's full forward;
-- the block loop's invariants, and the plain loop left as it was.
+- the block loop's invariants, and the plain loop left as it was;
+- the denoising program's schedule against the host's, bit for bit, and
+  the block loop reading every forward one forward behind.
 """
 
+import functools
 import math
 
 import numpy as np
@@ -283,9 +286,13 @@ def test_prefill_then_a_block_through_the_cache_is_the_full_forward(
                               at=np.arange(whole, whole + 4)[None])
     # float32 on both sides; the paged path sums past and chunk apart
     np.testing.assert_allclose(logits[0], theirs[0], atol=2e-4)
-    # the device's choice is the logits' argmax and its softmax share
-    (x0, confidence), _, _ = forward_slots(model, eng.cache, [slot], tokens,
-                                           model.forward_choice)
+    # the device's choice is the logits' argmax and its softmax share;
+    # with one step left every masked position takes it
+    read, final, _left = forward_slots(
+        model, eng.cache, [slot], tokens,
+        functools.partial(model.forward_denoise, masked=tokens == MASK,
+                          steps_left=1))
+    x0, confidence = jax.device_get((read["x0"], read["confidence"]))
     logits = np.array(logits)
     logits[..., MASK] = -np.inf             # a position never takes MASK
     assert x0[0].tolist() == logits[0].argmax(-1).tolist()
@@ -294,7 +301,7 @@ def test_prefill_then_a_block_through_the_cache_is_the_full_forward(
                                rtol=1e-5)
     # a second block after the first is stored: the cache now holds the
     # block's final tokens' K and V
-    final = np.where(tokens == MASK, x0, tokens)
+    assert np.array_equal(final, np.where(tokens == MASK, x0, tokens))
     _none, nk, nv = forward_slots(model, eng.cache, [slot], final,
                                   model.forward_kv)
     commit_slots(eng.cache, [slot], nk, nv, 4)
@@ -395,6 +402,10 @@ def test_the_block_loop_keeps_its_invariants(model, _metrics, monkeypatch):
     assert cat.gen_block_forwards.value(model="gpt", phase="denoise") == 8
     assert cat.gen_block_forwards.value(model="gpt", phase="store") == 4
     assert cat.gen_block_positions_committed.value(model="gpt") == 52
+    # every forward but the call's first is launched ahead of its reads
+    assert st["block_forwards_launched_ahead"] == 11
+    assert cat.gen_block_forwards.value(model="gpt", phase="denoise",
+                                        ahead="false") == 1
     # the same call again gives the same tokens: nothing is left behind
     assert eng.generate(prompts, max_new_tokens=10) == out
 
@@ -432,6 +443,175 @@ def test_the_block_loop_follows_the_reference_forward_by_forward(model,
                     (1 / np.exp(z).sum(-1))[masked], rtol=1e-4)
                 checked += 1
     assert checked >= 6
+
+
+def _argsort_schedule(masked, confidence, steps_left):
+    """The schedule as PR 29 wrote it on the host: a stable argsort's
+    rank of ``where(masked, -confidence, inf)``."""
+    count = -(-masked.sum(axis=1) // steps_left)
+    order = np.argsort(np.where(masked, -confidence, np.inf), axis=1,
+                       kind="stable")
+    rank = np.argsort(order, axis=1, kind="stable")
+    return masked & (rank < count[:, None])
+
+
+@pytest.mark.parametrize("steps_left", range(1, 9))
+def test_the_programs_schedule_is_the_hosts_bit_for_bit(steps_left):
+    """``_fix_most_confident`` traced (one program, `steps_left` an int32
+    operand), on numpy arrays, and the argsort it replaced fix the same
+    positions over seeded (12, 8) rows: every position masked, none
+    masked, confidences drawn from three values (ties, the leftmost
+    first), one row all one value, the rest drawn freely."""
+    rng = np.random.default_rng(steps_left)
+    masked = rng.random((12, 8)) < 0.6
+    confidence = rng.random((12, 8)).astype(np.float32)
+    masked[0] = masked[6] = True
+    masked[1] = False
+    confidence[2:6] = rng.choice(np.float32([0.125, 0.25, 0.5]), (4, 8))
+    confidence[6] = 0.5
+    want = _argsort_schedule(masked, confidence, steps_left)
+    program = jax.jit(GenerateEngine._fix_most_confident)
+    got = np.asarray(program(jnp.asarray(masked), jnp.asarray(confidence),
+                             jnp.int32(steps_left)))
+    assert got.dtype == bool and np.array_equal(got, want)
+    assert np.array_equal(GenerateEngine._fix_most_confident(
+        masked, confidence, steps_left), want)
+    # exactly ceil(m / steps_left) of a row's m masked: the host counts
+    assert np.array_equal(want.sum(1), -(-masked.sum(1) // steps_left))
+    assert not want[1].any() and want[6].tolist() \
+        == [i < -(-8 // steps_left) for i in range(8)]
+
+
+def test_the_denoising_program_fixes_what_the_schedule_fixes(model):
+    """``forward_denoise`` fetches nothing; what it leaves on the device is
+    the logits' choice (argmax and softmax share, MASK left out) with the
+    schedule applied to it: the next mask is the mask less
+    ``_fix_most_confident``'s positions, the next tokens take ``x0``
+    there."""
+    rng = np.random.default_rng(4)
+    eng = _engine(model)
+    slots = [eng.cache.alloc() for _ in range(2)]
+    for slot in slots:
+        prefill_slot(model, eng.cache, slot, rng.integers(0, 250, 8).tolist(),
+                     eng.prefill_chunk)
+    tokens = np.asarray([[17, MASK, MASK, MASK], [MASK] * 4], np.int32)
+    masked = tokens == MASK
+    logits, _, _ = forward_slots(model, eng.cache, slots, tokens)
+    logits = np.array(logits)
+    logits[..., MASK] = -np.inf
+    z = logits - logits.max(-1, keepdims=True)
+    for steps_left in (1, 2, 3):
+        read, nxt, left = forward_slots(
+            model, eng.cache, slots, tokens,
+            functools.partial(model.forward_denoise, masked=masked,
+                              steps_left=steps_left))
+        assert model.last_expert_loads is None
+        assert all(isinstance(a, jax.Array) for a in read.values())
+        host = jax.device_get(read)
+        assert np.array_equal(host["x0"], logits.argmax(-1))
+        np.testing.assert_allclose(host["confidence"],
+                                   1 / np.exp(z).sum(-1), rtol=1e-5)
+        fixed = GenerateEngine._fix_most_confident(
+            masked, host["confidence"], steps_left)
+        assert np.array_equal(host["masked"], masked & ~fixed)
+        assert np.array_equal(np.asarray(left), host["masked"])
+        assert np.array_equal(np.asarray(nxt),
+                              np.where(fixed, host["x0"], tokens))
+        assert host["expert_loads"].shape == (2, 8)
+    for slot in slots:
+        eng.cache.free(slot)
+
+
+def test_the_block_loop_reads_every_forward_one_forward_behind(
+        model, _metrics, monkeypatch):
+    """The order of launches and reads over a call of 12 block forwards
+    (``_dispatch`` and ``_fetch`` logged): every block forward is read
+    once, after the next one is launched, the call's last after nothing;
+    the prefill is read before the first block forward, which is the one
+    forward not launched ahead (``last_stats``, the ``ahead`` attribute of
+    the spans and the counter's label)."""
+    from incubator_mxnet_tpu.generate import engine as engine_mod
+    from incubator_mxnet_tpu.telemetry import tracing
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 250, n).tolist() for n in (8, 9, 10, 11)]
+    eng = _engine(model)
+    want = eng.generate(prompts, max_new_tokens=10)
+    log, owner = [], {}
+    dispatch, fetch = engine_mod._dispatch, engine_mod._fetch
+
+    def logged_dispatch(fn, params, args, *rest, **kw):
+        out = dispatch(fn, params, args, *rest, **kw)
+        n = sum(1 for e in log if e[0] == "dispatch")
+        log.append(("dispatch", n, args[0].shape))
+        for leaf in jax.tree_util.tree_leaves(out):
+            owner[id(leaf)] = (n, leaf)     # the leaf kept: ids stay apart
+        return out
+
+    def logged_fetch(arrays):
+        log.append(("fetch", {owner[id(leaf)][0] for leaf in
+                              jax.tree_util.tree_leaves(arrays)}))
+        return fetch(arrays)
+    monkeypatch.setattr(engine_mod, "_dispatch", logged_dispatch)
+    monkeypatch.setattr(engine_mod, "_fetch", logged_fetch)
+    _met.reset()
+    tracing.clear_spans()
+    assert eng.generate(prompts, max_new_tokens=10) == want
+    blocks = [n for kind, n, *shape in (e for e in log if e[0] == "dispatch")
+              if shape[0][1] == 4]
+    assert len(blocks) == 12 and blocks[0] == 4     # after 4 prefill chunks
+    at = {("dispatch", e[1]): i for i, e in enumerate(log)
+          if e[0] == "dispatch"}
+    reads = {}
+    for i, e in enumerate(log):
+        if e[0] == "fetch":
+            (n,) = e[1]                     # a read is one forward's
+            assert n not in reads           # and made once
+            reads[n] = i
+    assert set(reads) == set(range(16))     # every forward read
+    assert max(reads[n] for n in range(4)) < at["dispatch", blocks[0]]
+    for k, n in enumerate(blocks[:-1]):
+        assert at["dispatch", blocks[k + 1]] < reads[n]
+    assert reads[blocks[-1]] == len(log) - 1
+    assert eng.last_stats["block_forwards_launched_ahead"] == 11
+    recs = tracing.recent_spans()
+    ahead = [r["ahead"] for r in recs
+             if r["name"] in ("gen.denoise_step", "gen.block_store")]
+    assert ahead == [False] + [True] * 11
+    for phase, count in (("denoise", 7), ("store", 4)):
+        assert cat.gen_block_forwards.value(model="gpt", phase=phase,
+                                            ahead="true") == count
+    assert cat.gen_block_forwards.value(model="gpt", phase="store",
+                                        ahead="false") == 0
+    assert "ahead" in cat.gen_block_forwards.help
+
+
+def test_eos_ends_one_row_mid_call_as_the_parents_loop_did(model):
+    """A stop token that row 2 emits as its sixth token, the second of
+    its second block: the row ends there, the other two rows are served
+    whole, no block forward more than before runs (rows 0-2 for two
+    blocks, 0-1 for two more), and the tokens are those of the loop that
+    read every forward before the next (pinned from the tree before ISSUE
+    40)."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 250, n).tolist() for n in (9, 10, 12)]
+    eng = _engine(model)
+    out = eng.generate(prompts, max_new_tokens=12, eos_id=100)
+    assert out == [[165, 165, 227, 252, 207, 207, 252, 165, 165, 227, 227,
+                    213],
+                   [0, 37, 216, 56, 0, 56, 56, 56, 81, 81, 56, 56],
+                   [47, 126, 126, 90, 88, 100]]
+    st = eng.last_stats
+    assert [b["rows"] for b in st["blocks"]] == [[0, 1, 2]] * 2 + [[0, 1]] * 2
+    assert st["block_forwards"] == {"denoise": 8, "store": 4}
+    assert st["block_forwards_launched_ahead"] == 11
+    assert st["decode_tokens"] == 30 and eng.cache.in_use == 0
+    for block in st["blocks"]:      # the records are the host's, whole
+        assert all(isinstance(step[key], np.ndarray)
+                   for step in block["steps"] for key in step)
+        assert not block["steps"][-1]["masked"][
+            ~block["steps"][-1]["fixed"]].any()
+        assert isinstance(block["final"], np.ndarray) \
+            and MASK not in block["final"]
 
 
 def test_block_decoding_takes_no_draft_no_temperature_no_split_chunk(model):

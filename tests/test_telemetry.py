@@ -39,6 +39,19 @@ def test_counter_labels_and_values():
         c.inc(-1)
 
 
+def test_a_counter_read_by_fewer_labels_sums_the_series_it_matches():
+    c = telemetry.counter("t_partial_total", "test counter")
+    c.inc(2, phase="a", ahead="true")
+    c.inc(phase="a", ahead="false")
+    c.inc(5, phase="b", ahead="true")
+    assert c.value(phase="a") == 3          # no series of exactly these
+    assert c.value(ahead="true") == 7
+    assert c.value() == 8
+    assert c.value(phase="c") == 0
+    c.inc(10, phase="a")                    # a series of exactly these
+    assert c.value(phase="a") == 10         # wins over the sum
+
+
 def test_gauge_set_inc_dec():
     g = telemetry.gauge("t_gauge")
     g.set(10, shard="a")

@@ -197,10 +197,14 @@ def test_grouped_matmul_of_full_groups_compiles_for_v5e(one_chip, rows, k, n):
     assert "moe_grouped_matmul" in text
 
 
-def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch):
+@pytest.mark.parametrize("program", ["choice", "denoise"])
+def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch, program):
     """One layer of the cell's denoising forward (16 rows x 4 positions
     over 16-block tables of bfloat16 pools, published widths, the whole
-    vocabulary) with the grouped launch in it."""
+    vocabulary) with the grouped launch in it: the choice alone, and the
+    block loop's program, which applies the schedule to it with the steps
+    left an int32 operand and returns the next tokens and mask."""
+    from incubator_mxnet_tpu.generate import GenerateEngine
     from incubator_mxnet_tpu.models import sdar_moe
     from incubator_mxnet_tpu.ops.pallas import grouped_matmul as gm
     monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
@@ -215,11 +219,17 @@ def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch):
     params = {n: shape(s) for n, s in sdar_moe.sdar_param_shapes(cfg).items()}
     pools = [shape((256, 16, 4, 128))]
 
-    def forward(params, tokens, lengths, tables, kps, vps):
+    def forward(params, tokens, masked, steps_left, lengths, tables, kps,
+                vps):
         (x0, conf), _nk, _nv, loads = sdar_moe.sdar_forward_paged(
             params, cfg, tokens, lengths, tables, kps, vps, head="choice")
-        return x0, conf, loads
+        if program == "choice":
+            return x0, conf, loads
+        fixed = GenerateEngine._fix_most_confident(masked, conf, steps_left)
+        return (jnp.where(fixed, x0, tokens), masked & ~fixed, x0, conf,
+                loads)
     text = _compile(forward, params, shape((16, 4), jnp.int32),
+                    shape((16, 4), jnp.bool_), shape((), jnp.int32),
                     shape((16,), jnp.int32), shape((16, 16), jnp.int32),
                     pools, pools)
     assert text.count("moe_grouped_matmul") >= 3
