@@ -17,7 +17,7 @@ Importing this package registers the serving family.
 
 from .paged_kv import PagedKVCache
 from .engine import (EvaPagedLM, GenerateEngine, GPTPagedLM, MLAPagedLM,
-                     SDARPagedLM)
+                     OuroPagedLM, SDARPagedLM)
 from . import family  # noqa: F401  (registers the gpt_decoder family)
 from .family import export_gpt_for_serving
 
@@ -28,5 +28,6 @@ __all__ = [
     "SDARPagedLM",
     "MLAPagedLM",
     "EvaPagedLM",
+    "OuroPagedLM",
     "export_gpt_for_serving",
 ]

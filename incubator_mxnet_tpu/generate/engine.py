@@ -49,6 +49,13 @@ same: ``commit_slots``, told the adapter, asks it after every commit to
 close the windows the commit filled (``close_windows``: known from the
 cache's host lengths, nothing fetched).
 
+A model adapter that declares ``loop_steps`` (``OuroPagedLM`` over
+``models/ouro.py``) applies its layers T times a token with one set of
+weights, a loop inside its forward's program, and keeps a cache entry a
+layer whose row holds every loop step's keys (values). The loops are the
+same; ``last_stats["loop"]`` counts the steps, the layer passes and the
+cache rows by phase.
+
 A model adapter that declares a ``block_length`` (``SDARPagedLM`` over
 ``models/sdar_moe.py``) is a block-diffusion decoder, and the engine
 drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
@@ -84,7 +91,7 @@ from ..telemetry import tracing as _tr
 from .paged_kv import PagedKVCache, _env_int
 
 __all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM", "MLAPagedLM",
-           "EvaPagedLM"]
+           "EvaPagedLM", "OuroPagedLM"]
 
 
 def default_prefill_chunk():
@@ -178,12 +185,15 @@ def commit_slots(cache, slots, *new_and_count, adapter=None, phase="decode",
     written an entry. `adapter`: the forward's; one that declares
     ``close_windows`` is then asked to close the windows this commit
     filled (a ``gen.window_close`` span a closing, of the engine's `phase`;
-    `note` is told what was closed)."""
+    `note` is told what was closed); `note` is told the positions stored
+    where the adapter declares ``loop_steps``."""
     count = new_and_count[-1]
     rows = (len(slots) * count if isinstance(count, int)
             else int(np.sum(count)))
     with _tr.span("kv.commit", rows=rows):
         cache.commit(slots, *new_and_count)
+    if note is not None and getattr(adapter, "loop_steps", 0):
+        note(adapter, committed=rows)
     close = getattr(adapter, "close_windows", None)
     if close is not None:
         closed = close(cache, slots, phase)
@@ -740,6 +750,122 @@ class EvaPagedLM(_PagedLM):
                 "window_rows_read": self.window * len(due)}
 
 
+class OuroPagedLM(_PagedLM):
+    """Shape-cached jit adapter over ``ouro_forward_paged``: the looped
+    decoder of ``models/ouro.py`` (its ``num_layers`` layers applied
+    ``loop_steps`` times a token with one set of weights, the loop a
+    ``fori_loop`` in the program), served in `dtype` (bfloat16: weights,
+    activations and the cache).
+
+    Its cache holds ONE ``k`` and ONE ``v`` entry a layer whose row is a
+    position's keys (values) of every loop step side by side: T x H D
+    lanes, step u in lanes ``[u H D, (u + 1) H D)``; one block table and
+    one length serve all T steps, a forward takes 2 x L pools and
+    ``cache.commit`` stores its T steps with the one program. Blocks are
+    sized by the bytes of ONE step's row (``paged_latent.
+    latent_block_size``), what a walk copies. It declares ``loop_steps``:
+    the engine then counts ``last_stats["loop"]``. A decode step walks
+    each layer's past at each step by the launch ``paged_heads_decode``
+    given the step (a TPU; `interpret` runs it anywhere), a chunk over
+    empty slots takes ``flash_attention_lse``, any other chunk the ``lax``
+    gather.
+
+    - ``forward`` — ``(logits (S, C, V), new_k, new_v)``, the logits a
+      host array;
+    - ``forward_token`` — ``(read, new_k, new_v)`` with ``read["token"]``
+      (S, 1) int32, the argmax at the last chunk position, nothing
+      fetched;
+    - ``forward_kv`` — prefill: ``(read, new_k, new_v)``, no head;
+      ``read["reached"]`` (S,) int32, the program's smallest output, left
+      for ``prefill_slot`` to fetch one chunk behind: a chunk's keys and
+      values of every step (L x C x T x H D x 2 of each) stay in HBM until
+      its commit has run, so the host keeps one chunk ahead.
+
+    Lengths and tables are host arrays, tokens a host or a device array,
+    the pools the cache's device arrays; new_k / new_v stay on the device,
+    (L, S, C, T H D), for ``cache.commit``. After every forward
+    ``last_loop`` holds what it did by ``last_stats["loop"]``'s names:
+    ``forwards`` 1, ``loop_steps`` T, ``layer_passes`` T x L,
+    ``cache_rows_read`` (the live rows of every (step, layer) entry the
+    chunk attended to: the committed lengths summed, times T x L) and
+    ``positions`` (the committed lengths summed; the commit adds the rows
+    it stored). The exit gate's leaves (``exit_w``, ``exit_b``) are the
+    reference's and no argument of a program.
+    """
+
+    def __init__(self, params, config, dtype="bfloat16", interpret=False):
+        from ..models.ouro import ouro_config, ouro_forward_paged
+        self.config = cfg = ouro_config(config)
+        self.dtype = jnp.dtype(dtype)
+        self.params = {n: jnp.asarray(v, self.dtype)
+                       for n, v in params.items()
+                       if not n.startswith("exit_")}
+        self.num_layers = cfg["num_layers"]
+        self.loop_steps = int(cfg["loop_steps"])
+        self.kv_entries = dict.fromkeys(
+            "kv", ((self.loop_steps * cfg["units"],), self.dtype))
+        self.last_loop = None
+
+        def program(head, fresh):
+            def pure(params, tokens, lengths, tables, kps, vps):
+                out, nk, nv = ouro_forward_paged(
+                    params, cfg, tokens, lengths, tables, kps, vps,
+                    head=head, fresh=fresh, interpret=interpret)
+                if head == "token":
+                    out = out[:, None]
+                return out, nk, nv
+            return jax.jit(pure)
+        self._fns = {(head, fresh): program(head, fresh)
+                     for head in ("logits", "token", "none")
+                     for fresh in (False, True)}
+
+    def make_cache(self, slots, max_len=None, **kw):
+        """The cache of ``_PagedLM.make_cache`` in blocks of ONE loop
+        step's rows worth ``paged_latent.BLOCK_BYTES`` (32 positions of
+        2,048 bfloat16 lanes): a decode walk copies a step's lanes of a
+        block at a time. A `block_size` given wins."""
+        max_len = max_len or self.config["max_len"]
+        kw.setdefault("block_size", latent_block_size(
+            self.config["units"] * self.dtype.itemsize, max_len))
+        return super().make_cache(slots, max_len=max_len, **kw)
+
+    def lower(self, tokens, lengths, tables, k_pools, v_pools,
+              head="logits", fresh=False):
+        """A forward's program lowered for arguments of these shapes."""
+        return self._fns[head, fresh].lower(self.params, tokens, lengths,
+                                            tables, k_pools, v_pools)
+
+    def _call(self, head, tokens, lengths, tables, k_pools, v_pools):
+        """One forward, nothing fetched -> (out, new_k, new_v), all on the
+        device."""
+        steps, layers = self.loop_steps, self.num_layers
+        live = int(np.sum(lengths))
+        self.last_loop = {"forwards": 1, "loop_steps": steps,
+                          "layer_passes": steps * layers,
+                          "cache_rows_read": live * steps * layers,
+                          "positions": live}
+        fresh = tokens.shape[1] > 1 and not np.any(lengths)
+        return _dispatch(self._fns[head, fresh], self.params,
+                         (tokens, lengths, tables, k_pools, v_pools),
+                         loop_steps=steps)
+
+    def forward(self, tokens, lengths, tables, k_pools, v_pools):
+        logits, nk, nv = self._call("logits", tokens, lengths, tables,
+                                    k_pools, v_pools)
+        (logits,) = _fetch([logits])
+        return logits, nk, nv
+
+    def forward_token(self, tokens, lengths, tables, k_pools, v_pools):
+        token, nk, nv = self._call("token", tokens, lengths, tables,
+                                   k_pools, v_pools)
+        return {"token": token}, nk, nv
+
+    def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
+        reached, nk, nv = self._call("none", tokens, lengths, tables,
+                                     k_pools, v_pools)
+        return {"reached": reached}, nk, nv
+
+
 class GenerateEngine:
     """Drives one model (plus optional draft) over paged KV caches.
 
@@ -829,13 +955,15 @@ class GenerateEngine:
         self._note = (self._note_forward
                       if any(hasattr(model, said) for said in (
                           "last_expert_loads", "last_latent_path",
-                          "last_eva")) else None)
+                          "last_eva", "last_loop")) else None)
 
     # ---------------------------------------------------------- plumbing
-    def _note_forward(self, model, read=None, closed=None):
+    def _note_forward(self, model, read=None, closed=None, committed=None):
         """What a forward of `model`'s says of itself, into this call's
         ``last_stats``. `closed`: what a commit's window closings counted
         (``EvaPagedLM.close_windows``), into ``"eva"`` under the phase.
+        `committed`: the positions a commit of a looped model stored, into
+        ``"loop"`` (rows written: one an entry, T x L a position).
         Right after a forward (`read` None): ``last_eva`` (the rows it
         read) likewise with the forward counted, ``last_latent_path``
         (the attention path over a latent cache and the cached rows it
@@ -844,6 +972,18 @@ class GenerateEngine:
         forward fetched them. One that fetched nothing left them on the device:
         they are tallied from `read`, its outputs as the host fetched
         them a forward later."""
+        if committed is not None:
+            loop = self._tallies["loop"][self._phase]
+            loop["cache_rows_written"] += (committed * model.loop_steps
+                                           * model.num_layers)
+            loop["positions"] += committed
+            return
+        if read is None and getattr(model, "last_loop", None):
+            loop = self._tallies["loop"][self._phase]
+            for key, count in model.last_loop.items():
+                loop[key] += count
+            _cat.gen_loop_steps.inc(model.last_loop["loop_steps"],
+                                    model=self.name, phase=self._phase)
         split = getattr(model, "split_loads", None)
         eva = closed
         if closed is None and read is None and getattr(model, "last_eva",
@@ -963,6 +1103,11 @@ class GenerateEngine:
                 ("absorbed_forwards", "expanded_forwards",
                  "expanded_kernel_forwards", "expanded_rows",
                  "absorbed_rows_live", "absorbed_rows_read"), 0)
+        if hasattr(self.model, "last_loop"):
+            stats["loop"] = {phase: dict.fromkeys(
+                ("forwards", "loop_steps", "layer_passes", "cache_rows_read",
+                 "cache_rows_written", "positions"), 0)
+                for phase in ("prefill", "decode")}
         if hasattr(self.model, "last_eva"):
             stats["eva"] = {phase: dict.fromkeys(
                 ("forwards", "windows_closed", "summary_rows_written",
@@ -1002,6 +1147,7 @@ class GenerateEngine:
         # region waits for the last: the device's time for the prompts
         # lies inside the regions that launched it.
         B = self.block_length
+        loops = getattr(self.model, "loop_steps", 0)
         self._phase = "prefill"
         for s in seqs:
             n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
@@ -1017,6 +1163,9 @@ class GenerateEngine:
                                      s["dslot"], s["ctx"][:n],
                                      self.prefill_chunk)
                     stats["prefill_tokens"] += n
+                    if loops:   # a forward a chunk, each T loop steps
+                        sp.set_attr("loop_steps", loops * -(
+                            -n // self.prefill_chunk))
                 if s is seqs[-1]:
                     with _tr.span("kv.sync"):
                         self.cache.sync()

@@ -21,6 +21,13 @@ caller merges several walks and the step's own row under ONE softmax. The
 ``lax`` path (``pool[tables]`` gathered, a product a key row) stays for
 every CPU run and as the oracle; ``interpret=True`` runs the launch
 anywhere.
+
+A looped model (``models/ouro.py``: every layer applied ``T`` times a
+token, a cache entry a layer and loop step) keeps a position's ``T`` rows
+of a layer side by side, ``T x H D`` lanes; the walk of loop step u is
+given u as a third scalar-prefetch operand (``step``) and copies the H D
+lanes ``[u H D, (u + 1) H D)`` of each live block and no others. Without
+``step`` the launch is the one above, lowered as before.
 """
 
 import functools
@@ -43,14 +50,15 @@ __all__ = ["paged_heads_decode", "paged_heads_decode_available",
            "block_diagonal", "own_blocks"]
 
 
-def paged_heads_decode_available(pool, heads):
-    """Whether the walk over `pool` (num_blocks, block_size, H D) is the
-    launch: the backend is a TPU, a head is whole lanes, and a block is
-    whole tiles of the device (16 rows of a 2-byte dtype, 8 of a 4-byte
-    one), which a copy into VMEM needs."""
+def paged_heads_decode_available(pool, heads, steps=1):
+    """Whether the walk over `pool` (num_blocks, block_size, steps x H D)
+    is the launch: the backend is a TPU, a head is whole lanes, and a
+    block is whole tiles of the device (16 rows of a 2-byte dtype, 8 of a
+    4-byte one), which a copy into VMEM needs."""
+    width = pool.shape[-1] // steps
     return (jax.default_backend() == "tpu" and pool.ndim == 3
-            and pool.shape[2] % heads == 0
-            and (pool.shape[2] // heads) % LANES == 0
+            and pool.shape[2] % steps == 0 and width % heads == 0
+            and (width // heads) % LANES == 0
             and pool.shape[1] % (32 // pool.dtype.itemsize) == 0)
 
 
@@ -68,16 +76,22 @@ def own_blocks(acc):
     return jnp.einsum("shhd->shd", acc.reshape(S, H, H, width // H))
 
 
-def _decode_kernel(tables_ref, lengths_ref, q_ref, k_pool, v_pool, m_ref,
-                   l_ref, acc_ref, kbuf, vbuf, ksems, vsems, state, *,
-                   scale):
+def _decode_kernel(tables_ref, lengths_ref, *refs, scale, stepped):
     """One sequence a grid step, as ``paged_latent._decode_kernel``: its
     live blocks of both pools fetched tile by tile into the two halves of
     `kbuf` / `vbuf`; `state` carries from step to step the half the
     step's first tile goes to and whether the step before has already
-    asked for it."""
+    asked for it. `stepped`: the first of `refs` is the loop step, and a
+    copy takes that step's lanes of a row."""
+    first_lane = None
+    if stepped:
+        step_ref, *refs = refs
+    (q_ref, k_pool, v_pool, m_ref, l_ref, acc_ref, kbuf, vbuf, ksems, vsems,
+     state) = refs
     s, last = pl.program_id(0), pl.num_programs(0) - 1
     _two, tile_blocks, block_size, width = kbuf.shape
+    if stepped:
+        first_lane = pl.multiple_of(step_ref[0] * width, LANES)
     T = tile_blocks * block_size
     length = lengths_ref[s]
     tiles = pl.cdiv(length, T)
@@ -85,7 +99,7 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_pool, v_pool, m_ref,
     def each_block(seq, j, slot, act):
         for pool, buf, sems in ((k_pool, kbuf, ksems), (v_pool, vbuf, vsems)):
             _each_block(tables_ref, lengths_ref, pool, buf, sems, seq, j,
-                        slot, act)
+                        slot, act, first_lane)
 
     def start(seq, j, slot):
         each_block(seq, j, slot, lambda copy: copy.start())
@@ -145,11 +159,14 @@ def _decode_kernel(tables_ref, lengths_ref, q_ref, k_pool, v_pool, m_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "key_tile",
                                              "interpret"))
 def paged_heads_decode(q, k_pool, v_pool, block_tables, lengths, scale,
-                       key_tile=KEY_TILE, interpret=False):
+                       key_tile=KEY_TILE, interpret=False, step=None):
     """One query a head and sequence, q (S, H, D) in the pools' dtype,
     against each sequence's own first ``lengths[s]`` rows of `k_pool` /
     `v_pool` (num_blocks, block_size, H D) behind `block_tables` (S, MB):
     head h's query scores, and its output sums, the lanes of head h.
+    `step` (an int32 scalar, traced or not): the pools' rows hold several
+    loop steps' rows side by side, (num_blocks, block_size, steps x H D),
+    and the walk reads step `step`'s lanes alone.
 
     -> the running softmax's state over those rows, float32: the row
     maximum m (S, H), the denominator l (S, H) and the unnormalised
@@ -158,17 +175,21 @@ def paged_heads_decode(q, k_pool, v_pool, block_tables, lengths, scale,
     One launch: a grid step is a sequence, and it copies its live blocks
     (and no other: a table's padding is never read) into tiles of
     `key_tile` rows in VMEM, two tiles of each pool in flight."""
-    S, H, _D = q.shape
-    block_size, width = k_pool.shape[1:]
+    S, H, D = q.shape
+    block_size = k_pool.shape[1]
+    width = H * D
     tile_blocks = _tile_blocks(key_tile, block_size, block_tables.shape[1])
     state = [(S, H, 1), (S, H, 1), (S, H, width)]          # m, l, acc
 
     def of_sequence(shape):
-        return pl.BlockSpec((1,) + shape[1:],
-                            lambda s, tables, lengths: (s, 0, 0))
+        return pl.BlockSpec((1,) + shape[1:], lambda s, *_: (s, 0, 0))
     tile = pltpu.VMEM((2, tile_blocks, block_size, width), k_pool.dtype)
+    scalars = [jnp.asarray(block_tables, jnp.int32),
+               jnp.asarray(lengths, jnp.int32)]
+    if step is not None:
+        scalars.append(jnp.asarray(step, jnp.int32).reshape(1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(S,),
         in_specs=[of_sequence((S, H, width)),
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -179,7 +200,8 @@ def paged_heads_decode(q, k_pool, v_pool, block_tables, lengths, scale,
                         pltpu.SMEM((2,), jnp.int32)],
     )
     m, l, acc = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale),
+        functools.partial(_decode_kernel, scale=scale,
+                          stepped=step is not None),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(shape, jnp.float32)
                    for shape in state],
@@ -187,6 +209,5 @@ def paged_heads_decode(q, k_pool, v_pool, block_tables, lengths, scale,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_heads_decode",
-    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      block_diagonal(q.astype(k_pool.dtype)), k_pool, v_pool)
+    )(*scalars, block_diagonal(q.astype(k_pool.dtype)), k_pool, v_pool)
     return m[..., 0], l[..., 0], own_blocks(acc)

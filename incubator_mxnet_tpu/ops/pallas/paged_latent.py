@@ -176,17 +176,20 @@ def rows_walked(lengths, block_size, blocks, kernel, key_tile=KEY_TILE):
 
 
 def _each_block(tables_ref, lengths_ref, pool_ref, buf, sems, seq, j, slot,
-                act):
+                act, first_lane=None):
     """`act` on the copy of every live block of tile j of sequence `seq`
     from the pool in HBM into half `slot` of `buf` (2, tile_blocks,
-    block_size, W): no block past the length, whatever the table names."""
-    _two, tile_blocks, block_size, _width = buf.shape
+    block_size, W): no block past the length, whatever the table names.
+    `first_lane`: the pool's rows are wider than W, and the copy takes
+    their W lanes from this one on (a looped model's loop step)."""
+    _two, tile_blocks, block_size, width = buf.shape
     live = pl.cdiv(lengths_ref[seq], block_size) - j * tile_blocks
 
     def one(b, _):
-        act(pltpu.make_async_copy(
-            pool_ref.at[tables_ref[seq, j * tile_blocks + b]],
-            buf.at[slot, b], sems.at[slot]))
+        block = tables_ref[seq, j * tile_blocks + b]
+        source = (pool_ref.at[block] if first_lane is None else
+                  pool_ref.at[block, :, pl.ds(first_lane, width)])
+        act(pltpu.make_async_copy(source, buf.at[slot, b], sems.at[slot]))
         return 0
     jax.lax.fori_loop(0, jnp.minimum(live, tile_blocks), one, 0)
 

@@ -279,6 +279,11 @@ gen_decode_steps = _m.counter(
     "nothing waited for | host: built from the sequences, after a wait "
     "— a call's first step, one after the live rows changed, every "
     "sampled step)")
+gen_loop_steps = _m.counter(
+    "mxtpu_gen_loop_steps_total",
+    "Loop steps run by a looped model's forwards (OuroPagedLM: every "
+    "layer applied loop_steps times a token with one set of weights; a "
+    "forward counts loop_steps), by model and phase (prefill|decode)")
 gen_tokens_committed = _m.counter(
     "mxtpu_gen_tokens_committed_total",
     "Tokens committed to sequences by model and phase (prefill|decode) "

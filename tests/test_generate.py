@@ -518,6 +518,44 @@ def test_an_ungrouped_specs_inputs_and_commit_are_what_they_were():
         cache.commit([a], new_k, new_v, 1, group="window")
 
 
+@pytest.mark.parametrize("steps", [1, 4], ids=["unaxed", "four_loop_steps"])
+def test_a_row_of_loop_steps_is_one_entry_behind_one_table(steps):
+    """A looped model's spec (``OuroPagedLM``): one k and one v entry a
+    layer whose row holds every loop step's H D lanes side by side. One
+    table and one length serve all steps, ``forward_inputs`` hands 2 x L
+    pools as for a plain decoder, ONE commit stores a forward's T steps
+    (the one program of two entries, as a plain decoder's), and step u's
+    rows read back from lanes ``[u H D, (u + 1) H D)``; at one step it is
+    the plain per-head cache."""
+    rng = np.random.RandomState(steps)
+    width = 2 * 4                                   # H D
+    spec = PagedKVCache.layer_spec(3, dict.fromkeys(
+        "kv", ((steps * width,), np.float32)))
+    assert sorted(spec) == ["k0", "k1", "k2", "v0", "v1", "v2"]
+    cache = PagedKVCache(2, spec, max_len=16, block_size=4)
+    a, b = cache.alloc(), cache.alloc()
+    # a forward's additions: (L, S, C, T H D), step u in its own lanes
+    new_k, new_v = [jnp.asarray(rng.randn(3, 2, 5, steps * width),
+                                jnp.float32) for _ in "kv"]
+    before = paged_kv.store_program_for(2)._cache_size()
+    cache.commit([a, b], new_k, new_v, [5, 2])
+    assert paged_kv.store_program_for(2)._cache_size() == before + 1
+    lengths, tables, k_pools, v_pools = cache.forward_inputs([a, b])
+    assert lengths.tolist() == [5, 2] and tables.shape == (2, 4)
+    assert len(k_pools) == len(v_pools) == 3
+    assert k_pools[2].shape == (8, 4, steps * width)
+    for u in range(steps):
+        lanes = slice(u * width, (u + 1) * width)
+        for layer in range(3):
+            np.testing.assert_array_equal(
+                cache.prefix("k%d" % layer, a)[:, lanes],
+                np.asarray(new_k)[layer, 0, :5, lanes])
+            np.testing.assert_array_equal(
+                cache.prefix("v%d" % layer, b)[:, lanes],
+                np.asarray(new_v)[layer, 1, :2, lanes])
+    assert cache.table(a) == [tables[0, 0], tables[0, 1]]
+
+
 @pytest.mark.parametrize("shape,order", [
     ((2, 4), (0, 2, 1, 3)), ((2, 4), (2, 0, 1, 3)), ((3, 2, 4), None),
     ((5,), (1, 0, 2)), ((), None)],

@@ -663,3 +663,55 @@ def test_the_prefill_kernel_is_the_lax_path(case, dtype, atol):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=atol,
                                rtol=rtol)
+
+
+# ------------------------------------------- the decode walk of a loop step
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("step", [None, 0, 2], ids=["no_step", "step_0",
+                                                    "step_2"])
+def test_the_walk_of_a_loop_step_is_the_walk_over_that_steps_lanes(step,
+                                                                   dtype):
+    """``paged_heads_decode`` given a loop step over pools whose rows hold
+    three steps' H D lanes side by side is, to the bit, the walk without
+    the operand over that step's lanes alone (what the ``lax`` path
+    gathers, ``models/ouro.py``), and both are the dense softmax over each
+    sequence's own rows; the step may be traced. Without the operand the
+    walk is the one cell 7 runs."""
+    from incubator_mxnet_tpu.ops.pallas import paged_heads
+    S, H, D, steps, block, blocks = 3, 2, 128, 3, 8, 4
+    rng = np.random.default_rng(7)
+    width = H * D
+    lanes = width * (steps if step is not None else 1)
+    pools = [jnp.asarray(rng.normal(size=(S * blocks, block, lanes)), dtype)
+             for _ in "kv"]
+    tables = rng.permutation(S * blocks).reshape(S, blocks).astype(np.int32)
+    lengths = np.asarray([19, 0, 32], np.int32)
+    q = jnp.asarray(rng.normal(size=(S, H, D)), dtype)
+    walk = jax.jit(lambda u, *pools: paged_heads.paged_heads_decode(
+        q, *pools, tables, lengths, scale=D ** -0.5, key_tile=16,
+        interpret=True, step=u))
+    got = walk(step, *pools)
+    first = 0 if step is None else step * width
+    ours = [p[..., first:first + width] for p in pools]
+    alone = paged_heads.paged_heads_decode(
+        q, *ours, tables, lengths, scale=D ** -0.5, key_tile=16,
+        interpret=True)
+    for a, b in zip(got, alone):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    m, l, acc = [np.asarray(a, np.float64) for a in got]
+    for s, n in enumerate(lengths):
+        if n == 0:
+            assert (m[s] <= -1e29).all() and not l[s].any()
+            continue
+        k, v = [np.asarray(p, np.float64)[tables[s]].reshape(-1, H, D)[:n]
+                for p in ours]
+        scores = np.einsum("hd,thd->ht", np.asarray(q[s], np.float64), k) \
+            * D ** -0.5
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        tol = 1e-4 if dtype == "float32" else 0.05
+        assert np.allclose(m[s], scores.max(-1), atol=tol, rtol=tol)
+        assert np.allclose(acc[s] / l[s][:, None],
+                           np.einsum("ht,thd->hd", p, v) / p.sum(-1)[:, None],
+                           atol=tol, rtol=tol)
+    pool = jax.ShapeDtypeStruct((4, 16, steps * width), jnp.bfloat16)
+    assert paged_heads.paged_heads_decode_available(pool, H, steps) is False

@@ -756,3 +756,82 @@ def test_evabytes_three_programs_compile_at_published_widths(
     else:
         assert "tpu_custom_call" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ------------------------------------------------- a looped decoder (cell 8)
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_step"])
+def test_ouros_programs_compile_with_the_loop_at_published_widths(
+        one_chip, monkeypatch, program):
+    """Cell 8's programs at ``ouro_2_6b``'s widths cut to two layers and
+    the published four loop steps, over the cell's own pools (8 slots of
+    512 positions in blocks of 32, a row of 4 x 2,048 lanes: every loop
+    step's keys or values side by side), as the chip takes them: a prefill
+    chunk of 256 over an empty slot, its causal part one tiled
+    ``flash_attention_fwd`` a layer inside the loop, the pools unread; and
+    a decode step of 8 rows with the token head, whose walk is one
+    ``paged_heads_decode`` a layer given the loop step, and which gathers
+    nothing. The loop stays a loop: one ``while`` holds the two layers'
+    launches once."""
+    from benchmarks import spec
+    from benchmarks.families import ouro as family
+    from incubator_mxnet_tpu.generate import OuroPagedLM
+    from incubator_mxnet_tpu.models import ouro
+    monkeypatch.setattr(ouro, "paged_heads_decode_available",
+                        lambda pool, heads, steps=1: True)
+    monkeypatch.setattr(ouro, "flash_attention_available", lambda: True)
+    cfg = spec.load_json(spec.ROOT + "/benchmarks/configs/ouro_2_6b.json")
+    config = dict(family.program_config(cfg), num_layers=2)
+    assert config["loop_steps"] == 4
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    shapes = ouro.ouro_param_shapes(ouro.ouro_config(config))
+    assert shapes["l1_q_w"] == (2048, 2048)
+    assert shapes["l1_gate_w"] == (2048, 5632)
+    assert shapes["head"] == (2048, 49152)
+    model = OuroPagedLM({}, config)     # the weights are a call's argument
+    model.params = {n: shape(s) for n, s in shapes.items()
+                    if not n.startswith("exit_")}
+    pools = [shape((8 * 16, 32, 4 * 2048))] * 2
+
+    def inputs(rows, chunk):
+        return (shape((rows, chunk), jnp.int32), shape((rows,), jnp.int32),
+                shape((rows, 16), jnp.int32), pools, pools)
+    if program == "prefill_chunk":
+        lowered = model.lower(*inputs(1, 256), head="none", fresh=True)
+        wanted = [(1,), (2, 1, 256, 8192), (2, 1, 256, 8192)]
+    else:
+        lowered = model.lower(*inputs(8, 1), head="token")
+        wanted = [(8, 1), (2, 8, 1, 8192), (2, 8, 1, 8192)]
+    outputs = [o.shape for o in jax.tree_util.tree_leaves(lowered.out_info)]
+    assert outputs == wanted
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "while" in text
+    if program == "decode_step":
+        assert text.count("paged_heads_decode") >= 2
+        assert not re.search(r"bf16\[8,16,32,8192\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+    else:
+        assert text.count("flash_attention_fwd") >= 2
+        assert not re.search(r"bf16\[1,16,32,8192\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_the_walk_with_a_loop_step_compiles(one_chip):
+    """``paged_heads_decode`` given a traced loop step over a pool of four
+    steps' rows: one launch that copies the step's 2,048 lanes of a
+    block."""
+    from incubator_mxnet_tpu.ops.pallas import paged_heads
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def walk(q, k, v, tables, lengths, step):
+        return paged_heads.paged_heads_decode(q, k, v, tables, lengths,
+                                              scale=128 ** -0.5, step=step)
+    pool = shape((128, 32, 4 * 2048))
+    text = _compile(walk, shape((8, 16, 128)), pool, pool,
+                    shape((8, 16), jnp.int32), shape((8,), jnp.int32),
+                    shape((), jnp.int32))
+    assert text.count("paged_heads_decode") >= 1
