@@ -32,15 +32,18 @@ Between two forwards of a greedy plain decode nothing is waited for
 (``_plain_loop``): the adapter's ``forward_token`` chooses the next token
 in the forward's program and leaves it on the device, the next forward
 is fed that array, and the host reads ids and expert loads ONE forward
-behind, after it has launched the next. A prompt's prefill chunks are
-read the same way (``prefill_slot``).
+behind, after it has launched the next. The prefill goes in ROUNDS
+(``prefill_slots``): one forward feeds several prompts their next chunk,
+each prompt's chunks in order, and is read the same way, one round
+behind.
 
 The step itself is four functions over (adapter, cache, slots), the one
 implementation under this engine's loops and under ``serving.DecodeLoop``
 (``generate/family.py`` calls them): ``forward_slots`` asks the cache for
 a forward's inputs and calls the adapter, ``commit_slots`` stores what it
-returned, ``step_slots`` is both for one token a slot, ``prefill_slot``
-both for a prompt in padded chunks.
+returned, ``step_slots`` is both for one token a slot, ``prefill_slots``
+both for rounds of several prompts' padded chunks (``prefill_slot``: one
+prompt).
 
 A model adapter that declares a ``window`` (``EvaPagedLM`` over
 ``models/eva_byte.py``) keeps a window of exact rows beside summaries of
@@ -158,7 +161,8 @@ def _fetch_behind(adapter, read, note):
     return host
 
 
-def forward_slots(adapter, cache, slots, tokens, call=None, note=None):
+def forward_slots(adapter, cache, slots, tokens, call=None, note=None,
+                  pads=0):
     """One adapter forward for `slots` (list) feeding `tokens` (S, C);
     returns ``(logits, *new)``, `new` what the chunk adds to each cache
     entry (new_k, new_v), WITHOUT committing. `call`: another forward of
@@ -167,9 +171,15 @@ def forward_slots(adapter, cache, slots, tokens, call=None, note=None):
     ``forward_token``); `tokens` may then be a device array. `note`:
     called with the adapter after the forward (the engine's tallies of
     what it last did: an expert layer's loads, a latent cache's attention
-    path)."""
+    path). `pads`: the last rows are that many pad rows of a prefill
+    round, given length 0 in every group, so they read nothing of the
+    cache (and are committed nothing)."""
     with _tr.span("kv.gather"):
         inputs = cache.forward_inputs(slots)
+        if pads:
+            for lengths in inputs:      # the (S,) host arrays: a group's
+                if isinstance(lengths, np.ndarray) and lengths.ndim == 1:
+                    lengths[len(lengths) - pads:] = 0
     out = (call or adapter.forward)(tokens, *inputs)
     if note is not None:
         note(adapter)
@@ -195,8 +205,8 @@ def commit_slots(cache, slots, *new_and_count, adapter=None, phase="decode",
     if note is not None and getattr(adapter, "loop_steps", 0):
         note(adapter, committed=rows)
     close = getattr(adapter, "close_windows", None)
-    if close is not None:
-        closed = close(cache, slots, phase)
+    if close is not None:   # a prefill round's pad rows repeat a slot
+        closed = close(cache, list(dict.fromkeys(slots)), phase)
         if note is not None and closed:
             note(adapter, closed=closed)
 
@@ -214,33 +224,110 @@ def step_slots(adapter, cache, slots, tokens, count=1, note=None):
     return logits[:, -1]
 
 
-def prefill_slot(adapter, cache, slot, tokens_1d, chunk, note=None):
-    """Chunked prompt ingestion: commit K/V for every prompt token in
-    fixed `chunk`-wide forwards (last chunk padded; pad positions sit
-    AFTER the valid ones, so causality keeps them out of every valid
-    position's attention window and they are simply not committed; under
-    a block mask the valid tokens are whole blocks, so the pads begin a
-    later block). No logits are read: the adapter's ``forward_kv``, where
-    it has one, fetches nothing, and what it leaves for the host (an
-    expert layer's loads) is read one chunk behind: chunk n's after chunk
-    n + 1 and its commit are launched, the last chunk's before returning.
-    The last commit is NOT waited for (``cache.sync``)."""
+#: the widest prefill forward, in positions, that a family compiled at one
+#: prompt a forward (EvaByte's whole window of 2,048 bytes): a round of a
+#: batched prefill is never wider
+PREFILL_ROUND_POSITIONS = 2048
+#: the cache rows a round's forward returns, in bytes: a temporary in device
+#: memory until the round's commit has stored it
+PREFILL_ROUND_BYTES = 128 << 20
+
+
+def position_bytes(adapter):
+    """What a prefill chunk adds to `adapter`'s cache a position, in bytes:
+    every entry of the group a chunk writes (a grouped cache's leading
+    group, ``kv_groups``; every entry where there are none), over the
+    layers."""
+    groups = getattr(adapter, "kv_groups", None)
+    written = next(iter(groups.values())) if groups else adapter.kv_entries
+    return adapter.num_layers * sum(
+        int(np.prod(shape)) * np.dtype(dtype).itemsize
+        for name, (shape, dtype) in adapter.kv_entries.items()
+        if name in written)
+
+
+def prefill_rows(chunk, per_position, prompts):
+    """R, the prompts a round of a batched prefill feeds: at most the
+    `prompts` that have a chunk to feed, as many `chunk`-wide rows as
+    ``PREFILL_ROUND_POSITIONS`` holds, and as many as return at most
+    ``PREFILL_ROUND_BYTES`` of new cache rows at `per_position` bytes a
+    position (``position_bytes``); one at the least."""
+    return min(prompts, max(1, PREFILL_ROUND_POSITIONS // chunk),
+               max(1, PREFILL_ROUND_BYTES // (chunk * per_position)))
+
+
+def prefill_rounds(chunks, rows):
+    """The rounds of a batched prefill of prompts that need `chunks` (a
+    count a prompt) chunk forwards, at most `rows` prompts a round: a list
+    of rounds, each the prompts (indices into `chunks`) it feeds their next
+    chunk. A round takes the prompts with the most chunks left (the earlier
+    of equals first), so that a long prompt does not end the prefill alone;
+    every round is full but those at the tail."""
+    left, rounds = list(chunks), []
+    while any(left):
+        taken = sorted((i for i, n in enumerate(left) if n),
+                       key=lambda i: -left[i])[:rows]
+        for i in taken:
+            left[i] -= 1
+        rounds.append(taken)
+    return rounds
+
+
+def prefill_slots(adapter, cache, slots, prompts, chunk, rows, note=None):
+    """Batched chunked prompt ingestion: commit K/V for every token of
+    `prompts` (prompt i into ``slots[i]``) in ROUNDS (``prefill_rounds``),
+    each ONE `chunk`-wide forward of ``rows`` rows that feeds up to `rows`
+    prompts their next chunk, and one commit with a count a row. A prompt's
+    chunks go in order, chunk j + 1 in a later round than chunk j, so the
+    cache's lengths and causality hold as at one prompt a forward.
+
+    A chunk is padded AFTER its valid tokens (causality keeps the pads out
+    of every valid position's attention window, and they are not
+    committed; under a block mask the valid tokens are whole blocks, so
+    the pads begin a later block). Every round is fed at the width of the
+    first, so a call compiles one prefill program: a round with fewer
+    prompts left is padded with rows of count 0 on a fed row's slot that
+    read nothing of the cache (``forward_slots(pads=)``). No logits are
+    read: the adapter's ``forward_kv``, where it has one, fetches nothing,
+    and what it leaves for the host (an expert layer's loads) is read one
+    round behind: round k's after round k + 1 and its commit are launched,
+    the last round's before the last yield. The last commit is NOT waited
+    for (``cache.sync``).
+
+    A generator: runs a round a step and yields the prompt tokens it
+    fed."""
     call = getattr(adapter, "forward_kv", None)
-    behind = None
-    for start in range(0, len(tokens_1d), chunk):
-        piece = tokens_1d[start:start + chunk]
-        padded = np.zeros((1, chunk), np.int32)
-        padded[0, :len(piece)] = piece
-        read, *new = forward_slots(adapter, cache, [slot], padded, call,
-                                   note)
-        commit_slots(cache, [slot], *new, len(piece), adapter=adapter,
+    rounds = prefill_rounds([-(-len(p) // chunk) for p in prompts], rows)
+    fed, behind = [0] * len(prompts), None
+    for k, taken in enumerate(rounds):
+        pads = len(rounds[0]) - len(taken)
+        tokens = np.zeros((len(taken) + pads, chunk), np.int32)
+        counts = np.zeros(len(taken) + pads, np.int64)
+        for r, i in enumerate(taken):
+            piece = prompts[i][fed[i]:fed[i] + chunk]
+            tokens[r, :len(piece)] = piece
+            counts[r] = len(piece)
+            fed[i] += len(piece)
+        fed_slots = [slots[i] for i in taken] + [slots[taken[0]]] * pads
+        read, *new = forward_slots(adapter, cache, fed_slots, tokens, call,
+                                   note, pads)
+        commit_slots(cache, fed_slots, *new, counts, adapter=adapter,
                      phase="prefill", note=note)
         if call is not None:
             if behind is not None:
                 _fetch_behind(adapter, behind, note)
             behind = read
-    if behind is not None:
-        _fetch_behind(adapter, behind, note)
+        if k == len(rounds) - 1 and behind is not None:
+            _fetch_behind(adapter, behind, note)
+        yield int(counts.sum())
+
+
+def prefill_slot(adapter, cache, slot, tokens_1d, chunk, note=None):
+    """One prompt's prefill into `slot`: ``prefill_slots`` of one row, a
+    round a chunk, in order (``serving.DecodeLoop``'s admission)."""
+    for _tokens in prefill_slots(adapter, cache, [slot], [tokens_1d], chunk,
+                                 1, note):
+        pass
 
 
 class _PagedLM:
@@ -613,7 +700,7 @@ class EvaPagedLM(_PagedLM):
       no head; ``last_stream`` is the last layer's stream (S, C, d)
       float32 on the device, what a pipeline stage hands on;
       ``read["window_rows"]`` (S,) int32 is the program's smallest output,
-      left for ``prefill_slot`` to fetch one chunk behind: a chunk's keys,
+      left for ``prefill_slots`` to fetch one round behind: a chunk's keys,
       values and temporaries hold some 0.8 GB of HBM until its commit has
       run, so the host keeps to one chunk ahead of the device (the device
       never waits: the next chunk is queued by then);
@@ -777,7 +864,7 @@ class OuroPagedLM(_PagedLM):
       fetched;
     - ``forward_kv`` — prefill: ``(read, new_k, new_v)``, no head;
       ``read["reached"]`` (S,) int32, the program's smallest output, left
-      for ``prefill_slot`` to fetch one chunk behind: a chunk's keys and
+      for ``prefill_slots`` to fetch one round behind: a chunk's keys and
       values of every step (L x C x T x H D x 2 of each) stay in HBM until
       its commit has run, so the host keeps one chunk ahead.
 
@@ -946,6 +1033,7 @@ class GenerateEngine:
                     "prefill_chunk (%d) must divide the model's window "
                     "(%d): a chunk must not straddle a closing"
                     % (self.prefill_chunk, window))
+        self._position_bytes = position_bytes(model)
         self.last_stats = {}
         # a model with an expert layer, a latent cache or windows that
         # close: every forward of its is tallied into the call's
@@ -1045,8 +1133,10 @@ class GenerateEngine:
         Stats for the run land in ``self.last_stats``. Where spans are
         real the call is one ``gen.call`` span whose children are
         ``gen.admit`` (the prompts checked and given their slots), the
-        regions (``gen.prefill``, then ``gen.decode_step`` or
-        ``gen.block``) and ``gen.release`` (the slots freed)."""
+        prefill (``gen.prefill`` a prompt, over the regions
+        ``gen.prefill_round`` it leads), the decode regions
+        (``gen.decode_step`` or ``gen.block``) and ``gen.release`` (the
+        slots freed)."""
         prompts = [list(map(int, p)) for p in prompts]
         if not prompts:
             return []
@@ -1129,55 +1219,18 @@ class GenerateEngine:
         return stats
 
     def _run(self, seqs, max_new_tokens, eos_id, stats):
-        """The admitted call: every prompt's prefill, then the decode loop
-        the model and the engine's options choose. ``self._phase`` says
-        which of the two a forward belongs to (``_note_forward``'s tallies
-        by phase): what a forward leaves on the device is read before its
-        phase ends (``prefill_slot`` reads the last chunk's loads before it
-        returns, the loops their last step's), so a tally is its forward's
-        phase's."""
-        # prefill: commit ctx[:-1]; the last prompt token is fed by
-        # the first decode step (its logits choose token 1). Each
-        # region (a prompt's prefill, a decode step or round) is
-        # timed once: its span, its histogram and last_stats hold
-        # that one reading.
-        # A block model prefills the prompt's WHOLE blocks, with the
-        # forward that skips the head; the tail opens the first
-        # generated block. A prefill waits for no commit, so the last
-        # region waits for the last: the device's time for the prompts
-        # lies inside the regions that launched it.
-        B = self.block_length
-        loops = getattr(self.model, "loop_steps", 0)
+        """The admitted call: every prompt's prefill (``_prefill``: in
+        rounds of several prompts a forward, each prompt's chunks in
+        order), then the decode loop the model and the engine's options
+        choose. ``self._phase`` says which of the two a forward belongs to
+        (``_note_forward``'s tallies by phase): what a forward leaves on
+        the device is read before its phase ends (``prefill_slots`` reads
+        the last round's loads before its last yield, the loops their last
+        step's), so a tally is its forward's phase's."""
         self._phase = "prefill"
-        for s in seqs:
-            n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
-            with _tr.span("gen.prefill", model=self.name,
-                          slot=s["slot"], tokens=max(n, 0)) as sp:
-                t0 = time.monotonic()
-                if n > 0:
-                    prefill_slot(self.model, self.cache, s["slot"],
-                                 s["ctx"][:n], self.prefill_chunk,
-                                 self._note)
-                    if self.draft is not None:
-                        prefill_slot(self.draft, self.draft_cache,
-                                     s["dslot"], s["ctx"][:n],
-                                     self.prefill_chunk)
-                    stats["prefill_tokens"] += n
-                    if loops:   # a forward a chunk, each T loop steps
-                        sp.set_attr("loop_steps", loops * -(
-                            -n // self.prefill_chunk))
-                if s is seqs[-1]:
-                    with _tr.span("kv.sync"):
-                        self.cache.sync()
-                        if self.draft is not None:
-                            self.draft_cache.sync()
-                dt = time.monotonic() - t0
-                sp.set_duration(dt)
-            stats["prefill_seconds"] += dt
-            _cat.gen_prefill_seconds.observe(dt, model=self.name)
-
+        self._prefill(seqs, stats)
         self._phase = "decode"
-        if B:
+        if self.block_length:
             self._block_loop(seqs, max_new_tokens, eos_id, stats)
         elif self.draft is not None and self.spec_k > 0:
             for s in seqs:
@@ -1188,6 +1241,100 @@ class GenerateEngine:
             stats["prefill_tokens"], model=self.name, phase="prefill")
         _cat.gen_tokens_committed.inc(
             stats["decode_tokens"], model=self.name, phase="decode")
+
+    # ----------------------------------------------------------- prefill
+    def _prefill(self, seqs, stats):
+        """Commit every prompt's ctx[:-1] (a block model's WHOLE blocks:
+        the tail opens the first generated block); the last prompt token
+        is fed by the first decode step. Prompts with nothing to commit
+        are skipped.
+
+        The prompts go in ROUNDS (``prefill_slots``): a round is one
+        forward of R rows (``prefill_rows``: from the chunk's width and the
+        cache's bytes a position) feeding up to R prompts their next chunk,
+        and one commit; the expert loads are read one round behind. A
+        round is the timed region, ``gen.prefill_round`` (``rows``: the
+        prompts it fed, ``slots``, ``tokens``), timed once: its span,
+        ``last_stats["prefill_seconds"]`` and the forward counters hold
+        that one reading. A prefill waits for no commit, so the last round
+        waits for the last (``kv.sync``): the device's time for the prompts
+        lies inside the rounds that launched it. A prompt's prefill is a
+        ``gen.prefill`` record (``slot``, ``tokens``; ``loop_steps`` over
+        a looped model), a child of ``gen.call``, from the start of its
+        first round to the end of its last round's work (its last chunk's
+        commit launched, the round's reads done) and the parent of the
+        rounds it leads (their first row); the records of prompts that
+        share rounds overlap, and its seconds are the
+        ``mxtpu_gen_prefill_seconds`` of a sequence.
+
+        ``stats`` gains ``prefill_forwards`` (rounds) and ``prefill_rows``
+        (the prompt chunks they fed, pad rows not counted)."""
+        B, chunk = self.block_length, self.prefill_chunk
+        loops = getattr(self.model, "loop_steps", 0)
+        stats["prefill_forwards"] = stats["prefill_rows"] = 0
+        work = []           # (sequence, the tokens it commits)
+        for s in seqs:
+            n = len(s["ctx"]) // B * B if B else len(s["ctx"]) - 1
+            if n > 0:
+                work.append((s, s["ctx"][:n]))
+        if not work:
+            return
+        prompts = [p for _s, p in work]
+        chunks = [-(-len(p) // chunk) for p in prompts]
+        rows = prefill_rows(chunk, self._position_bytes, len(work))
+        rounds = prefill_slots(self.model, self.cache,
+                               [s["slot"] for s, _p in work], prompts, chunk,
+                               rows, self._note)
+        drafts = None if self.draft is None else prefill_slots(
+            self.draft, self.draft_cache, [s["dslot"] for s, _p in work],
+            prompts, chunk, rows)
+        call = _tr.current()        # the gen.call span, where spans are real
+        plan = prefill_rounds(chunks, rows)
+        left, began, ids = list(chunks), {}, {}
+        for k, taken in enumerate(plan):
+            sp = _tr.NULL_SPAN
+            if call is not None:
+                if taken[0] not in ids:
+                    ids[taken[0]] = _tr.new_span_id()
+                sp = _tr.Span("gen.prefill_round", trace_id=call.trace_id,
+                              parent_id=ids[taken[0]], sampled=call.sampled,
+                              attrs={"model": self.name, "rows": len(taken)})
+            wall0, t0 = time.time(), time.monotonic()
+            with sp:
+                sp.set_attr("slots", [work[i][0]["slot"] for i in taken])
+                sp.set_attr("tokens", next(rounds))
+                if drafts is not None:
+                    next(drafts)
+                if k == len(plan) - 1:
+                    with _tr.span("kv.sync"):
+                        self.cache.sync()
+                        if self.draft is not None:
+                            self.draft_cache.sync()
+                stats["prefill_forwards"] += 1
+                stats["prefill_rows"] += len(taken)
+                _cat.gen_prefill_forwards.inc(model=self.name)
+                _cat.gen_prefill_rows.inc(len(taken), model=self.name)
+                for i in taken:     # the prompts whose last chunk this is
+                    began.setdefault(i, (wall0, t0))
+                    left[i] -= 1
+                    if left[i]:
+                        continue
+                    s, prompt = work[i]
+                    stats["prefill_tokens"] += len(prompt)
+                    _cat.gen_prefill_seconds.observe(
+                        time.monotonic() - began[i][1], model=self.name)
+                    if call is not None:
+                        extra = ({"loop_steps": loops * chunks[i]} if loops
+                                 else {})
+                        _tr.record_span(
+                            "gen.prefill", call.trace_id,
+                            parent_id=call.span_id, t0=began[i][0],
+                            sampled=call.sampled, span_id=ids.get(i),
+                            model=self.name, slot=s["slot"],
+                            tokens=len(prompt), **extra)
+                dt = time.monotonic() - t0
+                sp.set_duration(dt)
+            stats["prefill_seconds"] += dt
 
     # ------------------------------------------------------ plain decode
     def _plain_loop(self, seqs, max_new_tokens, eos_id, stats):

@@ -267,7 +267,19 @@ deploy_seconds = _m.histogram(
 gen_prefill_seconds = _m.histogram(
     "mxtpu_gen_prefill_seconds",
     "Chunked-prefill wall time per sequence by model (prompt ingestion "
-    "before the first decode step)")
+    "before the first decode step; under GenerateEngine from the start of "
+    "the round that fed its first chunk to the end of the round that fed "
+    "its last)")
+gen_prefill_forwards = _m.counter(
+    "mxtpu_gen_prefill_forwards_total",
+    "Prefill rounds of GenerateEngine by model: one forward each, feeding "
+    "up to R prompts their next chunk (R from the chunk's width and the "
+    "cache's bytes a position)")
+gen_prefill_rows = _m.counter(
+    "mxtpu_gen_prefill_rows_total",
+    "Prompt chunks the prefill rounds fed, by model (a round's pad rows "
+    "not counted): over mxtpu_gen_prefill_forwards_total, the rows a "
+    "prefill forward")
 gen_decode_seconds = _m.histogram(
     "mxtpu_gen_decode_seconds",
     "Decode-phase wall time per engine step by model (one plain step "

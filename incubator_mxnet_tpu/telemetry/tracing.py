@@ -55,7 +55,6 @@ import os
 import random
 import threading
 import time
-import uuid
 from collections import deque
 
 from jax.profiler import TraceAnnotation as _TraceAnnotation
@@ -218,7 +217,13 @@ def _stack():
 
 
 def _new_id():
-    return uuid.uuid4().hex[:16]
+    return os.urandom(8).hex()
+
+
+def new_span_id():
+    """A span id for a record written later (``record_span(span_id=)``)
+    that spans opened before then name as their parent."""
+    return _new_id()
 
 
 def current():
@@ -395,19 +400,21 @@ def extract(meta):
 
 
 def record_span(name, trace_id, parent_id=None, t0=None, t1=None,
-                sampled=False, **attrs):
+                sampled=False, span_id=None, **attrs):
     """Record an already-timed span without entering a context.
 
     The schedulers know a request's queue wait only at the moment it
     leaves the queue — this writes that region retroactively into the
     retention ring (and the profiler, when running). ``t0``/``t1`` are
     epoch seconds (``time.time()``); ``t1`` defaults to now, ``t0`` to
-    ``t1`` (a zero-width marker). Returns the span record."""
+    ``t1`` (a zero-width marker). ``span_id``: the record's id where
+    spans already name it as their parent (``new_span_id``). Returns the
+    span record."""
     t1 = time.time() if t1 is None else float(t1)
     t0 = t1 if t0 is None else float(t0)
     ts = t0 * 1e6
     dur = max(t1 - t0, 0.0) * 1e6
-    args = {"trace_id": trace_id, "span_id": _new_id()}
+    args = {"trace_id": trace_id, "span_id": span_id or _new_id()}
     if parent_id:
         args["parent_id"] = parent_id
     if sampled:

@@ -296,9 +296,10 @@ def test_a_generate_call_closes_windows_and_tallies_them():
     assert stats["decode_steps"] == 8
     assert stats["decode_steps_fed_on_device"] == 7
     eva = stats["eva"]
-    # prefill commits 60, 19, 69 positions in chunks of 32: 2 + 1 + 3
-    # forwards; windows close at 32 (row 0) and at 32, 64 (row 2)
-    assert eva["prefill"]["forwards"] == 6
+    # prefill commits 60, 19, 69 positions in 2 + 1 + 3 chunks of 32:
+    # three rounds of three rows; windows close at 32 (row 0) and at 32,
+    # 64 (row 2)
+    assert stats["prefill_forwards"] == eva["prefill"]["forwards"] == 3
     assert eva["prefill"]["windows_closed"] == 3
     assert eva["prefill"]["summary_rows_written"] == 24
     # decode: row 0 closes when it commits position 63 (its 4th step)
@@ -316,7 +317,7 @@ def test_a_generate_call_closes_windows_and_tallies_them():
         == [("prefill", 1, 8)] * 3 + [("decode", 1, 8)]
     by_id = {s["span_id"]: s["name"] for s in tracing.recent_spans()}
     assert [by_id[s["parent_id"]] for s in closings] \
-        == ["gen.prefill"] * 3 + ["gen.decode_step"]
+        == ["gen.prefill_round"] * 3 + ["gen.decode_step"]
     for prompt, out in zip(prompts, served):
         sequence = np.asarray(prompt + out)
         ref = reference.logits(weights, cfg, sequence[None], np.arange(

@@ -824,15 +824,16 @@ def test_engine_prefill_chunk_invariance(target_lm):
 
 def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
     """One generate call under a parent span: every forward leaves
-    kv.gather, lm.dispatch and kv.commit as children of its gen.prefill /
-    gen.decode_step and an lm.fetch for what the host read BEHIND it, at
-    most 5 records a forward, and the spans count what crosses where it
-    crosses: lm.dispatch ships lengths and tables, the tokens only where
-    the host feeds them (a prefill chunk, a call's first step), and no
-    pool; lm.fetch copies back the ids of the forward before (the last
-    step its own too) and nothing of a prefill chunk; kv.commit says how
-    many pool rows an entry it stored; the last prefill region waits for
-    the last commit (kv.sync)."""
+    kv.gather, lm.dispatch and kv.commit as children of its
+    gen.prefill_round / gen.decode_step and an lm.fetch for what the host
+    read BEHIND it, at most 5 records a forward, and the spans count what
+    crosses where it crosses: lm.dispatch ships lengths and tables, the
+    tokens only where the host feeds them (a prefill round, a call's first
+    step), and no pool; lm.fetch copies back the ids of the forward before
+    (the last step its own too) and nothing of a prefill round; kv.commit
+    says how many pool rows an entry it stored; the last prefill round
+    waits for the last commit (kv.sync). A prompt's gen.prefill, a child
+    of gen.call, spans its rounds and is the parent of those it leads."""
     from incubator_mxnet_tpu.telemetry import tracing
     cache = target_lm.make_cache(2, max_len=64)
     eng = GenerateEngine(target_lm, cache, prefill_chunk=4)
@@ -845,21 +846,23 @@ def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
     by_id = {r["span_id"]: r for r in recs}
     phases = ("kv.gather", "lm.dispatch", "kv.commit", "lm.fetch")
     forwards = [r for r in recs if r["name"] == "lm.dispatch"]
-    # prefill: 5 tokens in chunks of 4 is two forwards, 2 tokens one;
-    # decode: three steps of both rows
-    assert len(forwards) == 3 + 3
+    # prefill: 5 tokens in chunks of 4 and 2 tokens are two rounds of two
+    # rows (the second padded); decode: three steps of both rows
+    assert len(forwards) == 2 + 3
     # the caller's span, the engine's call with its admission and release,
-    # 2 prefills, 3 steps, 4 phases a forward, the last step's second
-    # fetch and the prefill's one wait
-    assert len(recs) == 1 + 3 + 2 + 3 + 4 * len(forwards) + 1 + 1 \
-        <= 4 + 5 * len(forwards) + 2
+    # 2 prompts' prefills, 2 rounds, 3 steps, 4 phases a forward, the last
+    # step's second fetch and the prefill's one wait
+    assert len(recs) == 1 + 3 + 2 + 2 + 3 + 4 * len(forwards) + 1 + 1 \
+        <= 4 + 6 * len(forwards) + 2
     (gen_call,) = [r for r in recs if r["name"] == "gen.call"]
     assert gen_call["parent_id"] == call.span_id
     for r in recs:
         if r["name"] in phases + ("kv.sync",):
             assert r["dur_us"] > 0
-            assert by_id[r["parent_id"]]["name"] in ("gen.prefill",
+            assert by_id[r["parent_id"]]["name"] in ("gen.prefill_round",
                                                      "gen.decode_step")
+        elif r["name"] == "gen.prefill_round":
+            assert by_id[r["parent_id"]]["name"] == "gen.prefill"
         elif r["name"] not in ("test.call", "gen.call"):
             assert r["parent_id"] == gen_call["span_id"]
     steps = [r for r in recs if r["name"] == "gen.decode_step"]
@@ -879,24 +882,39 @@ def test_generate_under_a_parent_span_leaves_the_forward_phases(target_lm):
         assert kids[2]["rows"] == 2
         assert step["tokens_committed"] == [0, 2, 4][n]
         assert sum(k["dur_us"] for k in kids) <= step["dur_us"]
+    rounds = [r for r in recs if r["name"] == "gen.prefill_round"]
+    assert [(r["rows"], r["slots"], r["tokens"]) for r in rounds] \
+        == [(2, [0, 1], 6), (1, [0], 1)]
     chunks = [r for r in recs
               if by_id.get(r.get("parent_id"), {}).get("name")
-              == "gen.prefill"]
+              == "gen.prefill_round"]
+    # round 1's read after round 2 is launched, round 2's own after it
     assert [r["d2h_bytes"] for r in chunks if r["name"] == "lm.fetch"] \
-        == [0, 0, 0]
+        == [0, 0]
     assert [r["rows"] for r in chunks if r["name"] == "kv.commit"] \
-        == [4, 1, 2]
-    # tokens (1, 4), lengths (1,), tables (1, 4)
+        == [4 + 2, 1]
+    # tokens (2, 4), lengths (2,), tables (2, 4)
     assert {r["h2d_bytes"] for r in chunks if r["name"] == "lm.dispatch"} \
-        == {16 + 4 + 16}
-    assert [by_id[r["parent_id"]]["slot"] for r in chunks
-            if r["name"] == "kv.sync"] == [1]       # the last prompt's
+        == {32 + 8 + 32}
+    assert [by_id[r["parent_id"]]["slots"] for r in chunks
+            if r["name"] == "kv.sync"] == [[0]]     # the last round's
+    # a prompt's prefill: from its first round's start into its last
+    # round, once that round's work is done; the longest leads both
+    prefills = [r for r in recs if r["name"] == "gen.prefill"]
+    assert [(p["slot"], p["tokens"]) for p in prefills] == [(1, 2), (0, 5)]
+    assert [r["parent_id"] for r in rounds] == [prefills[1]["span_id"]] * 2
+    for p in prefills:
+        inside = [r for r in rounds if p["slot"] in r["slots"]]
+        assert p["ts_us"] <= inside[0]["ts_us"]
+        assert inside[-1]["ts_us"] < p["ts_us"] + p["dur_us"] \
+            <= inside[-1]["ts_us"] + inside[-1]["dur_us"]
     # one reading a region: the spans' durations ARE last_stats' seconds
     assert sum(s["dur_us"] for s in steps) / 1e6 == pytest.approx(
         eng.last_stats["decode_seconds"], rel=1e-6)
-    assert sum(r["dur_us"] for r in recs if r["name"] == "gen.prefill") \
-        / 1e6 == pytest.approx(eng.last_stats["prefill_seconds"], rel=1e-6)
+    assert sum(r["dur_us"] for r in rounds) / 1e6 == pytest.approx(
+        eng.last_stats["prefill_seconds"], rel=1e-6)
     assert cat.gen_decode_seconds.count(model="gpt") == 3
+    assert cat.gen_prefill_seconds.count(model="gpt") == 2
 
 
 def test_a_call_is_one_gen_call_with_its_admission_regions_and_release(
@@ -1241,18 +1259,21 @@ def test_a_greedy_call_waits_for_nothing_between_two_forwards(lagged_lm):
         fetched = sum(k.get("d2h_bytes", 0) for k in kids)
         assert fetched == behind * ((n > 0) + (n == _LAG_NEW - 1))
         assert fetched < 3 * vocab * 4
-    # prompts of 13, 2 and 9 commit 12, 1 and 8 tokens in chunks of 4
-    chunks = 3 + 1 + 2
+    # prompts of 13, 2 and 9 commit 12, 1 and 8 tokens in 3 + 1 + 2
+    # chunks of 4: three prefill rounds of three rows (the last two padded)
+    rounds = 3
+    assert (st["prefill_forwards"], st["prefill_rows"]) == (rounds, 6)
     if latent:
-        assert st["moe"]["forwards"] == chunks + _LAG_NEW
-        assert len(st["moe"]["load_max_over_mean"]) == chunks + _LAG_NEW
-        # two expert layers, two routes a token: a prefill chunk's 4
-        # positions (pads too), a step's 3 rows
-        assert st["moe"]["routes"] == (chunks * 4 + _LAG_NEW * 3) * 2 * 2
+        assert st["moe"]["forwards"] == rounds + _LAG_NEW
+        assert len(st["moe"]["load_max_over_mean"]) == rounds + _LAG_NEW
+        # two expert layers, two routes a token: a round's 3 x 4 positions
+        # (pad positions and pad rows too), a step's 3 rows
+        assert st["moe"]["routes"] \
+            == (rounds * 3 * 4 + _LAG_NEW * 3) * 2 * 2
         live = st["mla"].pop("absorbed_rows_live")
         assert 0 < live <= st["mla"].pop("absorbed_rows_read")
         assert st["mla"] == {"absorbed_forwards": _LAG_NEW,
-                             "expanded_forwards": chunks,
+                             "expanded_forwards": rounds,
                              "expanded_kernel_forwards": 0,
                              "expanded_rows": 4 + 8 + 4}
     else:
@@ -1370,10 +1391,11 @@ def test_a_held_shares_tallies_count_the_work_done_here(share_lm):
                         "load_max_over_mean", "routes_elsewhere",
                         "rows_moved", "by_phase"}
     # prompts of 13, 2 and 9 commit 12, 1 and 8 tokens in chunks of 4 (a
-    # chunk's padding routes too): 3 + 1 + 2 chunks of 4 positions, then
-    # 12 steps of 3 rows; 2 expert layers, 8 routes a position
-    positions = 6 * 4 + 12 * 3
-    assert moe["forwards"] == 6 + 12
+    # chunk's padding routes too): 3 prefill rounds of 3 rows of 4
+    # positions (pad rows too), then 12 steps of 3 rows; 2 expert layers,
+    # 8 routes a position
+    positions = 3 * 3 * 4 + 12 * 3
+    assert moe["forwards"] == 3 + 12
     assert moe["routes"] + moe["routes_elsewhere"] == 2 * 8 * positions
     # 3 of 24 experts held: about an eighth of the routes fall here
     assert 0 < moe["routes"] < moe["routes_elsewhere"] / 3
@@ -1411,19 +1433,20 @@ def test_the_expert_tallies_by_phase_sum_to_the_totals(latent_lm):
     eng.generate(_LAG_PROMPTS, max_new_tokens=_LAG_NEW)
     moe = eng.last_stats["moe"]
     by_phase = moe["by_phase"]
-    # prompts of 13, 2 and 9: 3 + 1 + 2 chunks of 4, then 12 steps
-    assert by_phase["prefill"]["forwards"] == 6
+    # prompts of 13, 2 and 9: 3 + 1 + 2 chunks of 4 in 3 rounds of 3
+    # rows, then 12 steps
+    assert by_phase["prefill"]["forwards"] == 3
     assert by_phase["decode"]["forwards"] == _LAG_NEW
-    assert phases == ["prefill"] * 6 + ["decode"] * _LAG_NEW
-    # two expert layers, 2 routes a position: a chunk's 4 positions (the
-    # padding routes too), a step's 3 rows
-    assert by_phase["prefill"]["routes"] == 6 * 4 * 2 * 2
+    assert phases == ["prefill"] * 3 + ["decode"] * _LAG_NEW
+    # two expert layers, 2 routes a position: a round's 3 x 4 positions
+    # (pad positions and pad rows route too), a step's 3 rows
+    assert by_phase["prefill"]["routes"] == 3 * 3 * 4 * 2 * 2
     assert by_phase["decode"]["routes"] == _LAG_NEW * 3 * 2 * 2
     for key in ("forwards", "routes", "experts_hit"):
         assert by_phase["prefill"][key] + by_phase["decode"][key] \
             == moe[key]
-    # a chunk of 4 positions hits more experts a forward than 3 rows do
-    assert by_phase["prefill"]["experts_hit"] / 6 \
+    # a round of 12 positions hits more experts a forward than 3 rows do
+    assert by_phase["prefill"]["experts_hit"] / 3 \
         >= by_phase["decode"]["experts_hit"] / _LAG_NEW
     for phase, tally in by_phase.items():
         assert cat.moe_routes.value(model="phased", phase=phase) \

@@ -577,23 +577,24 @@ def test_a_traced_call_says_which_path_every_forward_ran(model, _metrics):
     with tracing.Span("test.call"):
         engine.generate([_prompt(10), _prompt(6)], max_new_tokens=3)
     mla = engine.last_stats["mla"]
-    # 9 tokens in chunks of 4 at lengths 0, 4, 8; 5 tokens at 0, 4
+    # 9 tokens in chunks of 4 at lengths 0, 4, 8; 5 tokens at 0, 4: three
+    # prefill rounds of two rows, the last padded with a row of length 0;
     # the steps' lengths (9, 5), (10, 6), (11, 7); off the TPU the walk is
     # the lax one: both rows' tiles up to the longest, a tile the whole
-    # table of 8 blocks of 4
     # table of 8 blocks of 4; off the TPU no chunk's attention is the launch
-    assert mla == {"absorbed_forwards": 3, "expanded_forwards": 5,
+    assert mla == {"absorbed_forwards": 3, "expanded_forwards": 3,
                    "expanded_kernel_forwards": 0,
                    "expanded_rows": 4 + 8 + 4, "absorbed_rows_live": 48,
                    "absorbed_rows_read": 3 * 2 * 32}
     moe = engine.last_stats["moe"]
-    assert moe["forwards"] == 8
-    # two expert layers: a step of 2 rows makes 2 x 2 routes in each
-    assert moe["routes"] == 2 * (5 * 4 * 2 + 3 * 2 * 2)
+    assert moe["forwards"] == 6
+    # two expert layers: a round of 2 rows of 4 and a step of 2 rows make
+    # 2 x 4 x 2 and 2 x 2 routes in each
+    assert moe["routes"] == 2 * (3 * 2 * 4 * 2 + 3 * 2 * 2)
     assert cat.mla_absorbed_forwards.value(model="mla_tally") \
         - before[cat.mla_absorbed_forwards] == 3
     assert cat.mla_expanded_forwards.value(model="mla_tally") \
-        - before[cat.mla_expanded_forwards] == 5
+        - before[cat.mla_expanded_forwards] == 3
     assert cat.mla_expanded_kernel_forwards.value(model="mla_tally") \
         == before[cat.mla_expanded_kernel_forwards]
     assert cat.mla_expanded_rows.value(model="mla_tally") \
@@ -604,7 +605,7 @@ def test_a_traced_call_says_which_path_every_forward_ran(model, _metrics):
         - before[cat.mla_absorbed_rows_read] == 192
     paths = [s["mla_path"] for s in tracing.recent_spans()
              if s["name"] == "lm.dispatch"]
-    assert paths == ["expanded"] * 5 + ["absorbed"] * 3
+    assert paths == ["expanded"] * 3 + ["absorbed"] * 3
 
 
 def test_where_the_launch_is_available_every_chunk_runs_it(weights, model,
@@ -641,11 +642,12 @@ def test_where_the_launch_is_available_every_chunk_runs_it(weights, model,
     fresh = MLAPagedLM(weights, family.program_config(CFG), dtype="float32")
     got, stats = generate(fresh, "mla_kernel")
     mla = stats["mla"]
-    assert mla["expanded_kernel_forwards"] == mla["expanded_forwards"] == 5
+    assert mla["expanded_kernel_forwards"] == mla["expanded_forwards"] == 3
     assert cat.mla_expanded_kernel_forwards.value(model="mla_kernel") \
-        - before == 5
-    # one trace of the prefill program: a launch a layer, a chunk of (1, 4)
-    assert launches == [(1, 4, 4, 8)] * 3
+        - before == 3
+    # one trace of the prefill program: a launch a layer, a round of two
+    # rows of 4, (2, 4)
+    assert launches == [(2, 4, 4, 8)] * 3
     assert got == want
 
 

@@ -259,12 +259,13 @@ def test_a_generate_call_runs_the_loop_and_tallies_it(counting):
     assert stats["decode_steps_fed_on_device"] == 7
     loop = stats["loop"]
     T, L = 3, 3
-    # prefill commits 39, 8, 69 positions in chunks of 32: 2 + 1 + 3
-    # forwards, the second chunk of the first and the last two of the
-    # third over cached rows
-    assert loop["prefill"]["forwards"] == 6
-    assert loop["prefill"]["loop_steps"] == 6 * T
-    assert loop["prefill"]["layer_passes"] == 6 * T * L
+    # prefill commits 39, 8, 69 positions in 2 + 1 + 3 chunks of 32: three
+    # rounds of three rows (the last two padded with rows of length 0),
+    # the second chunk of the first and the last two of the third over
+    # cached rows
+    assert loop["prefill"]["forwards"] == 3
+    assert loop["prefill"]["loop_steps"] == 3 * T
+    assert loop["prefill"]["layer_passes"] == 3 * T * L
     assert loop["prefill"]["cache_rows_read"] == (32 + 32 + 64) * T * L
     assert loop["prefill"]["cache_rows_written"] == (39 + 8 + 69) * T * L
     assert loop["decode"]["forwards"] == 8
@@ -277,8 +278,9 @@ def test_a_generate_call_runs_the_loop_and_tallies_it(counting):
     assert catalog.gen_loop_steps.value(model="ouro_toy", phase="decode") \
         - before == 8 * T
     spans = tracing.recent_spans()
+    # a prompt's gen.prefill, recorded when its last chunk is committed
     assert [s["loop_steps"] for s in spans if s["name"] == "gen.prefill"] \
-        == [2 * T, 1 * T, 3 * T]
+        == [1 * T, 2 * T, 3 * T]
     assert {s.get("loop_steps") for s in spans
             if s["name"] == "lm.dispatch"} == {T}
     for prompt, out in zip(prompts, served):

@@ -379,15 +379,17 @@ def test_the_block_loop_keeps_its_invariants(model, _metrics, monkeypatch):
             worst_fixed = conf[step["fixed"][r]].min(initial=np.inf)
             assert (conf[step["masked"][r] & ~step["fixed"][r]]
                     <= worst_fixed).all()
+    # the four prompts' chunks are ONE prefill round of four rows
+    assert (st["prefill_forwards"], st["prefill_rows"]) == (1, 4)
     # no token is dropped: every forward routes tokens x 2 in every layer
     moe = st["moe"]
     fed = 4 * 8 + 3 * (4 + 4 + 4 + 1) * 4          # prefill chunks; blocks
-    assert moe["forwards"] == 4 + 12 and moe["routes"] == fed * 2 * 2
-    assert len(moe["load_max_over_mean"]) == 16
-    # by phase: the 4 prefill chunks; the denoising and store forwards
-    # are all "decode", and the two phases sum to the totals
+    assert moe["forwards"] == 1 + 12 and moe["routes"] == fed * 2 * 2
+    assert len(moe["load_max_over_mean"]) == 13
+    # by phase: the prefill round; the denoising and store forwards are
+    # all "decode", and the two phases sum to the totals
     by_phase = moe["by_phase"]
-    assert by_phase["prefill"]["forwards"] == 4
+    assert by_phase["prefill"]["forwards"] == 1
     assert by_phase["prefill"]["routes"] == 4 * 8 * 2 * 2
     assert by_phase["decode"]["forwards"] == 8 + 4
     for key in ("forwards", "routes", "experts_hit"):
@@ -558,7 +560,9 @@ def test_the_block_loop_reads_every_forward_one_forward_behind(
     assert eng.generate(prompts, max_new_tokens=10) == want
     blocks = [n for kind, n, *shape in (e for e in log if e[0] == "dispatch")
               if shape[0][1] == 4]
-    assert len(blocks) == 12 and blocks[0] == 4     # after 4 prefill chunks
+    # after ONE prefill round of the 4 prompts' chunks
+    assert len(blocks) == 12 and blocks[0] == 1
+    assert log[0] == ("dispatch", 0, (4, 8))
     at = {("dispatch", e[1]): i for i, e in enumerate(log)
           if e[0] == "dispatch"}
     reads = {}
@@ -567,8 +571,8 @@ def test_the_block_loop_reads_every_forward_one_forward_behind(
             (n,) = e[1]                     # a read is one forward's
             assert n not in reads           # and made once
             reads[n] = i
-    assert set(reads) == set(range(16))     # every forward read
-    assert max(reads[n] for n in range(4)) < at["dispatch", blocks[0]]
+    assert set(reads) == set(range(13))     # every forward read
+    assert reads[0] < at["dispatch", blocks[0]]
     for k, n in enumerate(blocks[:-1]):
         assert at["dispatch", blocks[k + 1]] < reads[n]
     assert reads[blocks[-1]] == len(log) - 1

@@ -485,6 +485,47 @@ def test_latent_prefill_chunk_is_one_launch_a_layer_on_v5e(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
+@pytest.mark.parametrize("cell,rows,slots,max_len", [
+    ("xing4_29b_a4b", 2, 16, 16896), ("kimi_k2_7_code", 4, 128, 896)],
+    ids=["long_prompts", "wide_batch"])
+def test_a_prefill_round_of_the_latent_cells_compiles_for_v5e(
+        one_chip, monkeypatch, cell, rows, slots, max_len):
+    """A batched prefill round at the R the engine's rule gives the cell
+    (2 rows of 1,024 positions; 4 of 512), at the published widths cut to
+    one dense and one expert layer: every row's expanded attention in the
+    one launch a layer, and the round's temporaries far under the chip
+    beside the cell's 8.1 and 8.35 GB of weights."""
+    from benchmarks import spec
+    from incubator_mxnet_tpu.generate import MLAPagedLM
+    from incubator_mxnet_tpu.models import mla_moe
+    from incubator_mxnet_tpu.ops.pallas import grouped_matmul as gm
+    from incubator_mxnet_tpu.ops.pallas import paged_latent
+    monkeypatch.setattr(gm, "grouped_matmul_available", lambda: True)
+    monkeypatch.setattr(paged_latent, "paged_latent_prefill_available",
+                        lambda *shapes: True)
+    cfg = spec.load_json(spec.ROOT + "/benchmarks/configs/%s.json" % cell)
+    family = importlib.import_module(cfg["family"])
+    program = dict(family.program_config(cfg), num_layers=2)
+
+    def shape(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    model = MLAPagedLM({}, program)     # the weights are a call's argument
+    model.params = {n: shape(s) for n, s in mla_moe.mla_param_shapes(
+        mla_moe.mla_config(program)).items()}
+    block = paged_latent.latent_block_size(640 * 2, max_len)
+    blocks = max_len // block
+    pool = shape((slots * blocks, block, 640))
+    chunk = family.assumed(cfg, "prefill_chunk")
+    assert rows * chunk == 2048
+    compiled = model.lower(
+        shape((rows, chunk), jnp.int32), shape((rows,), jnp.int32),
+        shape((rows, blocks), jnp.int32), [pool] * 2, head="none").compile()
+    text = compiled.as_text()
+    assert text.count("paged_latent_prefill") >= 2
+    assert re.search(r"bf16\[2,%d,%d,640\]" % (rows, chunk), text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("block", [128, 16], ids=["latent_block", "16"])
 @pytest.mark.parametrize("chunk", [(16, 1), (1, 2048)],
                          ids=["step", "prefill_chunk"])
