@@ -377,8 +377,10 @@ class GPTPagedLM(_PagedLM):
     (``lower(...)`` compiled), put there by an owner that ships them (the
     serving family's bind / warm grid) as ``cache.programs`` holds the
     commit's; a call prefers it to the jit and retires it when it refuses
-    its arguments. ``params`` is passed a call, so replacing the dict (of
-    the same shapes and dtypes) swaps the weights under every program.
+    its arguments. A program takes ``weights()``: ``params`` and the
+    embedding's lookup table derived from them, a call, so replacing the
+    dict (of the same shapes and dtypes) swaps the weights under every
+    program.
     """
 
     def __init__(self, params, config, use_kernel=False, interpret=False):
@@ -392,39 +394,72 @@ class GPTPagedLM(_PagedLM):
         self.programs = {}
 
         def program(head):
-            def pure(params, tokens, lengths, tables, kps, vps):
+            def pure(weights, tokens, lengths, tables, kps, vps):
+                params, lookup_rows = weights
                 out, nk, nv = gpt_forward_paged(
                     params, self.config, tokens, lengths, tables, kps, vps,
-                    use_kernel=use_kernel, interpret=interpret, head=head)
+                    lookup_rows, use_kernel=use_kernel, interpret=interpret,
+                    head=head)
                 if head == "token":
                     out = out[:, None]
                 return out, jnp.stack(nk), jnp.stack(nv)
             return jax.jit(pure)
         self._fns = {head: program(head) for head in ("logits", "token")}
 
+    @property
+    def params(self):
+        """The weights by name, as a checkpoint holds them."""
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        """Replacing the weights (a serving family's swap) derives their
+        lookup table at once (``models.gpt.gpt_lookup_rows``: at GPT-2-XL a
+        (25129, 3200) array of its own, so that no forward copies the
+        embedding into the order a gather reads), so no table is stale and
+        a forward compiles nothing of its own. Weights given as shapes (a
+        compile for a described chip) hold none."""
+        from ..models.gpt import gpt_lookup_rows
+        # the old table is freed before the new one is made
+        self._params, self._lookup_rows = params, None
+        if isinstance(params.get("wte"), jax.Array):
+            self._lookup_rows = gpt_lookup_rows(params["wte"])
+
+    def weights(self):
+        """What a program takes for its weights: ``(params,
+        lookup_rows)``."""
+        return self._params, self._lookup_rows
+
     def lower(self, tokens, lengths, tables, k_pools, v_pools,
               head="logits"):
         """A forward's program lowered for arguments of these shapes:
-        ``forward``'s is what an owner compiles into ``programs``."""
-        return self._fns[head].lower(self.params, tokens, lengths, tables,
-                                     k_pools, v_pools)
+        ``forward``'s is what an owner compiles into ``programs``. The
+        params may be shapes (``jax.ShapeDtypeStruct``), and the lookup
+        table is given as the shape ``weights()`` derives."""
+        from ..models.gpt import gpt_lookup_rows
+        wte = self.params["wte"]
+        rows = jax.eval_shape(gpt_lookup_rows, wte)
+        rows = jax.ShapeDtypeStruct(rows.shape, rows.dtype,
+                                    sharding=wte.sharding)
+        return self._fns[head].lower((self.params, rows), tokens, lengths,
+                                     tables, k_pools, v_pools)
 
     def forward(self, tokens, lengths, tables, k_pools, v_pools):
         logits, nk, nv = _dispatch(
-            self._fns["logits"], self.params,
+            self._fns["logits"], self.weights(),
             (tokens, lengths, tables, k_pools, v_pools), self.programs)
         (logits,) = _fetch([logits])
         return logits, nk, nv
 
     def forward_token(self, tokens, lengths, tables, k_pools, v_pools):
         token, nk, nv = _dispatch(
-            self._fns["token"], self.params,
+            self._fns["token"], self.weights(),
             (tokens, lengths, tables, k_pools, v_pools))
         return {"token": token}, nk, nv
 
     def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
         _logits, nk, nv = _dispatch(
-            self._fns["logits"], self.params,
+            self._fns["logits"], self.weights(),
             (tokens, lengths, tables, k_pools, v_pools), self.programs)
         return {}, nk, nv
 

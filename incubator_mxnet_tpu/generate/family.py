@@ -222,7 +222,7 @@ def _build_gpt_decoder(config, params, quantize):
         (_name, table, shape, adapter, commit), = entry
         pools = ([0] * adapter.num_layers,) * len(adapter.kv_entries)
         args = (pools + (0,) * len(pools) + (0,) if commit else
-                (adapter.params, 0, 0, 0) + pools)
+                ((adapter.params, 0), 0, 0, 0) + pools)   # as `weights()`
         compiled = _aot.deserialize_compiled(blob)
         if compiled.in_tree != jax.tree_util.tree_structure((args, {})):
             log.info("serving: executable %r was exported under another "
@@ -274,7 +274,8 @@ def _build_gpt_decoder(config, params, quantize):
         untouched — the weights, the paged K/V pools and the block tables
         are inputs to the programs, not captured state, so in-flight
         sessions that survive the server's drain keep their committed
-        prefix and the next step simply reads the new weights. Both
+        prefix and the next step simply reads the new weights (and the
+        lookup table each adapter derives from the new ``wte``). Both
         adapters' params are validated BEFORE either is touched (an aval
         drift on the draft must not leave the target half-swapped)."""
         staged = [(target, _stage_swap(target, params, "target"))]

@@ -31,10 +31,11 @@ import jax.numpy as jnp
 from ..gluon.block import HybridBlock, current_trace
 from ..gluon.nn.basic_layers import _init_of
 from ..ops.pallas.flash_decode import paged_causal_attention
+from ..ops.pallas.paged_latent import LANES
 from ..parallel.moe import moe_ffn
 
 __all__ = ["GPTDecoder", "gpt_config", "gpt_param_shapes", "gpt_logits",
-           "gpt_forward_paged", "gpt_sharding_rules"]
+           "gpt_lookup_rows", "gpt_forward_paged", "gpt_sharding_rules"]
 
 
 def gpt_config(config):
@@ -133,15 +134,47 @@ def gpt_logits(params, cfg, tokens):
     return x @ params["wte"].T
 
 
+def gpt_lookup_rows(wte):
+    """The tied embedding as the paged forward's lookup table: ``wte`` (V,
+    units) with k rows side by side a row, k the fewest that fill whole
+    lanes of 128 (GPT-2-XL: 2 x 1,600 = 25 lanes; 1 where the units do),
+    (ceil(V / k), k * units). The chip holds a float32 (V, units) array
+    whose rows fill no whole lanes with the VOCABULARY minor, where a
+    gather of rows wants the units minor, so a forward that gathers from
+    ``wte`` first copies all of it (322 MB at GPT-2-XL); this table is
+    held with its rows minor, costs no padding, and is gathered from as it
+    is (``_lookup``)."""
+    V, d = wte.shape
+    k = LANES // math.gcd(d, LANES)
+    if k == 1:
+        return wte
+    return jnp.pad(wte, ((0, -V % k), (0, 0))).reshape(-1, k * d)
+
+
+def _lookup(rows, tokens, d):
+    """The (…, d) embeddings of `tokens` from a ``gpt_lookup_rows`` table:
+    the row that holds each token's, then its k-th part, chosen element by
+    element (a select the consumer fuses, where a second gather is a
+    launch of its own)."""
+    k = rows.shape[1] // d
+    held = rows[tokens // k]
+    which = jnp.broadcast_to((tokens % k)[..., None], tokens.shape + (d,))
+    return jax.lax.select_n(which, *(held[..., j * d:(j + 1) * d]
+                                     for j in range(k)))
+
+
 def gpt_forward_paged(params, cfg, tokens, lengths, block_tables,
-                      k_pools, v_pools, use_kernel=False,
+                      k_pools, v_pools, lookup_rows, use_kernel=False,
                       interpret=False, head="logits"):
     """Incremental decode forward over the paged KV cache.
 
     tokens (S, C) int32 — C new tokens per slot (C=1 decode, C>1
     chunked prefill); lengths (S,) int32 committed past positions;
     block_tables (S, MB) int32; k_pools/v_pools — per-layer lists of
-    ``(num_blocks, block_size, H, D)`` pool arrays.
+    ``(num_blocks, block_size, H, D)`` pool arrays; lookup_rows — the
+    table the tokens' embeddings are gathered from,
+    ``gpt_lookup_rows(params["wte"])`` held by the caller (the head
+    reads ``params["wte"]``).
 
     Returns ``(logits (S, C, V), new_k, new_v)`` where new_k/new_v are
     per-layer (S, C, H, D) chunk projections for the caller (the
@@ -164,7 +197,7 @@ def gpt_forward_paged(params, cfg, tokens, lengths, block_tables,
     D = d // H
     positions = jnp.clip(lengths[:, None] + jnp.arange(C)[None],
                          0, cfg["max_len"] - 1)
-    x = params["wte"][tokens] + params["wpe"][positions]
+    x = _lookup(lookup_rows, tokens, d) + params["wpe"][positions]
     new_k, new_v = [], []
     for i in range(cfg["num_layers"]):
         p = "h%d_" % i

@@ -26,7 +26,8 @@ from incubator_mxnet_tpu.generate import (GenerateEngine, GPTPagedLM,
                                           PagedKVCache,
                                           export_gpt_for_serving)
 from incubator_mxnet_tpu.generate import paged_kv
-from incubator_mxnet_tpu.generate.engine import prefill_slot, step_slots
+from incubator_mxnet_tpu.generate.engine import (forward_slots, prefill_slot,
+                                                 step_slots)
 from incubator_mxnet_tpu.generate.paged_kv import KVPoolExhausted
 from incubator_mxnet_tpu.models.gpt import (GPTDecoder, gpt_config,
                                             gpt_logits, gpt_param_shapes)
@@ -783,6 +784,28 @@ def test_gpt_full_forward_matches_incremental_paged_decode(target_lm):
         inc.append(logits[0])
     np.testing.assert_allclose(np.asarray(inc), full, atol=1e-4)
     cache.free(slot)
+
+
+def test_a_forward_over_the_held_lookup_table_is_the_full_forward(target_lm):
+    """The paged forward gathers its embeddings from the adapter's held
+    table (``gpt_lookup_rows``: rows of 24 units, 16 side by side, fill 3
+    lanes of 128) and its head reads ``wte``: a prefill round of two
+    prompts gives every position the logits of ``gpt_logits`` over the
+    same prefix, and the token head their argmax."""
+    params, rows = target_lm.weights()
+    assert rows.shape == (2, 384)
+    np.testing.assert_array_equal(rows.reshape(32, 24)[:29], params["wte"])
+    assert not np.asarray(rows.reshape(32, 24)[29:]).any()
+    prompts = np.asarray([[3, 5, 7, 2, 11, 1], [9, 8, 4, 4, 0, 2]], np.int32)
+    full = np.asarray(gpt_logits(params, _TCFG, jnp.asarray(prompts)))
+    cache = target_lm.make_cache(2, max_len=32)
+    slots = [cache.alloc(), cache.alloc()]
+    logits, *_new = forward_slots(target_lm, cache, slots, prompts)
+    np.testing.assert_allclose(logits, full, atol=1e-4)
+    read, *_new = forward_slots(target_lm, cache, slots, prompts,
+                                target_lm.forward_token)
+    np.testing.assert_array_equal(np.asarray(read["token"])[:, 0],
+                                  logits[:, -1].argmax(-1))
 
 
 def test_gpt_decoder_block_registers_flat_params():
@@ -1742,20 +1765,49 @@ def test_loading_a_gpt_checkpoint_walks_no_array_by_elements(tmp_path,
     assert got.shape == (1, 37)
 
 
+def test_a_swap_serves_the_tokens_of_an_adapter_built_on_the_new_weights(
+        tmp_path):
+    """A live weight push through the family's swap replaces ``wte``, and
+    with it the lookup table the forwards gather from: what is served after
+    it is, to the bit, what a fresh adapter on the new weights serves,
+    and not what the old weights served (a stale table would feed the old
+    embeddings to the new layers)."""
+    served = _served_gpt(tmp_path, name="swap")
+    prompt, feed = [3, 5, 7, 2, 11, 1], [4, 9, 30, 2]
+    before = _serve_by_hand(served.prefill_fn, served.step_fn,
+                            served.make_cache(2, 64), prompt, feed)
+    params = _params(gpt_config(served.config), 11, scale=0.2)
+    served.swap_params(params, 1)
+    got = _serve_by_hand(served.prefill_fn, served.step_fn,
+                         served.make_cache(2, 64), prompt, feed)
+    lm = GPTPagedLM(params, served.config)
+    want = _serve_by_hand(
+        lambda slot, tokens, cache: prefill_slot(
+            lm, cache, slot, tokens, served.prefill_chunk),
+        lambda tokens, cache, active: step_slots(
+            lm, cache, range(2), tokens.reshape(2, 1),
+            active.astype(np.int32)),
+        lm.make_cache(2, max_len=64), prompt, feed)
+    np.testing.assert_array_equal(got, want)
+    assert got.argmax(-1).tolist() != before.argmax(-1).tolist()
+
+
 def _old_export(cfg, model):
     """``gptdecode/s2`` as the tree before ISSUE 31 exported it: flat
     inputs, the params a list, 1 + 2L outputs."""
     from incubator_mxnet_tpu.compilecache import aot
-    from incubator_mxnet_tpu.models.gpt import gpt_forward_paged
+    from incubator_mxnet_tpu.models.gpt import (gpt_forward_paged,
+                                                gpt_lookup_rows)
     params = {k: jnp.asarray(v.data()._data)
               for k, v in model._collect_params_with_prefix().items()}
     names = sorted(params)
 
     def pure(input_vals, param_vals):
         tokens, lengths, tables, k_pool, v_pool = input_vals
+        named = dict(zip(names, param_vals))
         logits, nk, nv = gpt_forward_paged(
-            dict(zip(names, param_vals)), cfg, tokens, lengths, tables,
-            [k_pool], [v_pool])
+            named, cfg, tokens, lengths, tables, [k_pool], [v_pool],
+            gpt_lookup_rows(named["wte"]))
         return [logits] + nk + nv
     pool = jnp.zeros((8, 16, 2, 8), jnp.float32)
     ins = [jnp.zeros((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32),

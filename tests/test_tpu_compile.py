@@ -446,6 +446,40 @@ def test_the_token_head_step_returns_no_vocabulary_on_v5e(one_chip,
         < rows * vocab * 4
 
 
+@pytest.mark.parametrize("head,chunk", [("token", 1), ("logits", 32)],
+                         ids=["decode_step", "prefill_round"])
+def test_the_gpt2_xl_programs_copy_no_embedding_on_v5e(one_chip, monkeypatch,
+                                                       head, chunk):
+    """Cell 2's decode step and prefill round (4 rows of a 32-token
+    chunk). The chip holds the tied embedding, (50257, 1600) float32, with
+    the vocabulary minor: the head's product reads it as it is, a gather
+    of rows cannot. The programs gather from the adapter's lookup table
+    (``gpt_lookup_rows``: two rows side by side, 25,129 of 3,200, held
+    with the rows minor) and copy or transpose no array with the
+    vocabulary's dimension in any order; gathering from ``wte`` itself
+    copies all of it (322 MB) every forward."""
+    from incubator_mxnet_tpu.models import gpt
+
+    def copies(model, arguments):
+        text = model.lower(*arguments, head=head).compile().as_text()
+        return [shape for shape in re.findall(
+            r"= f32\[(\d+,\d+)\]\S* (?:copy|transpose)\(", text)
+            if {"50257", "25129"} & set(shape.split(","))]
+    model, arguments = _gpt2_xl_step(one_chip)
+    tokens = jax.ShapeDtypeStruct((arguments[0].shape[0], chunk), jnp.int32,
+                                  sharding=one_chip)
+    arguments = (tokens,) + arguments[1:]
+    assert copies(model, arguments) == []
+    forward = gpt.gpt_forward_paged
+
+    def from_wte(params, cfg, *arguments, **kw):     # the lookup from wte
+        *arguments, _rows = arguments
+        return forward(params, cfg, *arguments, params["wte"], **kw)
+    monkeypatch.setattr(gpt, "gpt_forward_paged", from_wte)
+    model, _arguments = _gpt2_xl_step(one_chip)
+    assert copies(model, arguments) == ["50257,1600"]
+
+
 def test_latent_prefill_chunk_fits_beside_the_weights_on_v5e(one_chip,
                                                               monkeypatch):
     """The prefill chunk the family sets, over a table of 16,384 cached
