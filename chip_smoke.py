@@ -231,7 +231,7 @@ def phase_generate(config=None, n_prompts=4, prompt_len=64, new_tokens=16):
     rng = np.random.RandomState(0)
     params = {n: (rng.randn(*s) * 0.02).astype(np.float32)
               for n, s in gpt_param_shapes(cfg).items()}
-    model = GPTPagedLM(params, cfg)           # use_kernel=False, as shipped
+    model = GPTPagedLM(params, cfg)
     engine = GenerateEngine(
         model, model.make_cache(n_prompts, max_len=prompt_len + new_tokens))
     prompts = [rng.randint(1, cfg["vocab_size"], prompt_len).tolist()

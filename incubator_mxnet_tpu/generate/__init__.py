@@ -5,6 +5,8 @@ through the continuous-batching plane, with
 
 - :mod:`.paged_kv` — block-table + free-list KV allocator that drops in
   behind the ``serving/kv_cache.py`` alloc/free/append surface,
+- :mod:`.adapters` — ``PagedLM``, the one adapter contract, and the
+  model adapters over it (what each model's forwards are and count),
 - :mod:`.engine` — the paged step (gather, forward, commit) and over it
   chunked prefill, greedy/temperature sampling, draft-model speculative
   decoding (Leviathan et al., ICML 2023), and block-diffusion decoding

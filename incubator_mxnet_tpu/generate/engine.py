@@ -20,13 +20,51 @@ for ~1 target forward, which is the decode speedup when the draft
 agrees often.
 
 All cache mutation happens here (commit a forward's K/V, truncate
-rejected suffixes); the model adapter is a pure shape-cached forward.
-``GPTPagedLM`` adapts ``models/gpt.py`` to that contract. The K/V pools
-live on the device (``paged_kv``): a forward is given them where it runs,
-returns the chunk's K and V as device arrays, and ``cache.commit`` stores
-those there; the host fetches only what it reads (a greedy step's token
-ids, a sampled step's logits; ``x0`` and its confidence; the expert
-loads).
+rejected suffixes, close full windows); the model adapter is a pure
+shape-cached forward. The K/V pools live on the device (``paged_kv``): a
+forward is given them where it runs, returns the chunk's additions as
+device arrays, and ``cache.commit`` stores those there; the host fetches
+only what it reads.
+
+THE ADAPTER CONTRACT. A model reaches the engine as a ``PagedLM``
+(``adapters.py``; ``GPTPagedLM``, ``SDARPagedLM``, ``MLAPagedLM``,
+``EvaPagedLM`` and ``OuroPagedLM`` are re-exported here), and the engine
+names no model:
+
+- a forward takes ``(tokens, *cache.forward_inputs(slots))``: tokens
+  (S, C), a host or a device array, each cache group's host lengths and
+  tables, the pools (device arrays, not shipped);
+- heads: ``forward`` -> ``(logits (S, C, V), *new)``, the logits
+  fetched; ``forward_token`` -> ``(read, *new)``, ``read["token"]`` (S, 1)
+  int32 the last position's argmax (where ``"token"`` is in ``heads``);
+  ``forward_kv`` -> ``(read, *new)``, the prefill's, without a head where
+  the model has the ``"none"`` one. `new`: what the chunk adds to each
+  cache entry, stacked over layers, on the device for ``cache.commit``;
+  `read`: device arrays the host fetches when it will, one forward behind
+  (``"expert_loads"``, ``"window_rows"``, ``"reached"``). One jitted
+  program a ``(head, fresh)`` and (S, C);
+- declarations the loops read: ``num_layers``, ``kv_entries``,
+  ``kv_groups``; ``block_length``, ``mask_id`` and ``forward_denoise``
+  (the block loop); ``window`` and ``close_windows`` (closings after a
+  commit);
+- tallies: ``tallies()`` the ``last_stats`` entries a call opens;
+  ``tally(stats, phase, name, read=, committed=)`` what a forward (or a
+  commit's window closings), a read behind or a commit adds to them and
+  to the catalog's counters; ``span_attrs`` / ``prefill_attrs`` what the
+  spans carry.
+
+Hooks that ``tests/benchmark/`` patches, which stay where they are:
+``EvaPagedLM._call(self, head, tokens, wlen, wtables, slen, *rest)``, the
+one path of every Eva forward; ``SDARPagedLM.forward_kv(self, tokens,
+*rest)``, the prefill's and the block loop's store pass;
+``GenerateEngine._fix_most_confident``, looked up when the denoising
+program is traced; and the model functions, looked up by module
+attribute when a program is traced.
+
+Adding a model: its file under ``models/`` (a paged forward), one
+``PagedLM`` subclass in ``adapters.py`` (config, heads, cache entries,
+``outputs``, its host counts and tallies) and its counters in
+``telemetry/catalog.py``; nothing in this module.
 
 Between two forwards of a greedy plain decode nothing is waited for
 (``_plain_loop``): the adapter's ``forward_token`` chooses the next token
@@ -45,23 +83,9 @@ returned, ``step_slots`` is both for one token a slot, ``prefill_slots``
 both for rounds of several prompts' padded chunks (``prefill_slot``: one
 prompt).
 
-A model adapter that declares a ``window`` (``EvaPagedLM`` over
-``models/eva_byte.py``) keeps a window of exact rows beside summaries of
-the windows that closed, in two groups of cache entries. The loops are the
-same: ``commit_slots``, told the adapter, asks it after every commit to
-close the windows the commit filled (``close_windows``: known from the
-cache's host lengths, nothing fetched).
-
-A model adapter that declares ``loop_steps`` (``OuroPagedLM`` over
-``models/ouro.py``) applies its layers T times a token with one set of
-weights, a loop inside its forward's program, and keeps a cache entry a
-layer whose row holds every loop step's keys (values). The loops are the
-same; ``last_stats["loop"]`` counts the steps, the layer passes and the
-cache rows by phase.
-
-A model adapter that declares a ``block_length`` (``SDARPagedLM`` over
-``models/sdar_moe.py``) is a block-diffusion decoder, and the engine
-drives it by blocks, not tokens (``_block_loop``; docs/GENERATE.md):
+A model that declares a ``block_length`` (``SDARPagedLM``) is a
+block-diffusion decoder, and the engine drives it by blocks, not tokens
+(``_block_loop``; docs/GENERATE.md):
 
     block:  the row's next B positions, the prompt's tail fixed, the
             rest MASK
@@ -80,18 +104,15 @@ the plain loop reads its tokens.
 import functools
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.pallas.paged_latent import (cache_row_width, latent_block_size,
-                                       latent_path,
-                                       paged_latent_decode_available,
-                                       paged_latent_prefill_available,
-                                       rows_walked)
 from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
-from .paged_kv import PagedKVCache, _env_int
+from . import adapters as _ad
+from .adapters import (EvaPagedLM, GPTPagedLM, MLAPagedLM, OuroPagedLM,
+                       SDARPagedLM)
+from .paged_kv import _env_int
 
 __all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM", "MLAPagedLM",
            "EvaPagedLM", "OuroPagedLM"]
@@ -103,59 +124,12 @@ def default_prefill_chunk():
     return _env_int("MXTPU_GEN_PREFILL_CHUNK", 32)
 
 
-def _host_bytes(args):
-    """Bytes of the host (numpy) arrays among `args`, lists looked into:
-    what a jitted call given them has to ship to the device. An array
-    that already lives on the device counts 0."""
-    total = 0
-    for a in args:
-        if isinstance(a, (list, tuple)):
-            total += _host_bytes(a)
-        elif isinstance(a, np.ndarray):
-            total += a.nbytes
-    return total
-
-
-def _dispatch(fn, params, args, programs=None, **attrs):
-    """The call of an adapter's forward under its ``lm.dispatch`` span; a
-    real span also counts the host bytes the call ships and carries
-    `attrs`. `programs`: the adapter's (S, C) -> shipped executable of
-    `fn`; one is preferred to the jitted `fn`, and retired when it
-    refuses its arguments (compiled for other pools or weights, or an
-    export of another signature)."""
-    sp = _tr.span("lm.dispatch")
-    if sp is not _tr.NULL_SPAN:     # counted only for a real span
-        sp.set_attr("h2d_bytes", _host_bytes(args))
-        for key, value in attrs.items():
-            sp.set_attr(key, value)
-    with sp:
-        program = programs.get(args[0].shape) if programs else None
-        if program is not None:
-            try:
-                return program(params, *args)
-            except TypeError:
-                del programs[args[0].shape]
-        return fn(params, *args)
-
-
-def _fetch(arrays):
-    """`arrays` (what the caller reads of a forward's outputs: a list or
-    a dict of them) as host arrays, under an ``lm.fetch`` span: the wait
-    for the device, then the copies, all started before the first is
-    waited for; the span counts the bytes copied."""
-    with _tr.span("lm.fetch") as sp:
-        out = jax.device_get(arrays)
-        sp.set_attr("d2h_bytes", sum(
-            a.nbytes for a in jax.tree_util.tree_leaves(out)))
-    return out
-
-
 def _fetch_behind(adapter, read, note):
     """`read` (what a forward that fetched nothing left on the device for
     the host: a dict of arrays, ``{}`` where there is no such forward) as
     host arrays, fetched once a later forward is launched; `note` is
     told (the expert loads' tally, one forward late)."""
-    host = _fetch(read)
+    host = _ad._fetch(read)
     if note is not None and host:
         note(adapter, host)
     return host
@@ -170,10 +144,9 @@ def forward_slots(adapter, cache, slots, tokens, call=None, note=None,
     ``forward_denoise`` / ``forward_kv``, the plain loop's
     ``forward_token``); `tokens` may then be a device array. `note`:
     called with the adapter after the forward (the engine's tallies of
-    what it last did: an expert layer's loads, a latent cache's attention
-    path). `pads`: the last rows are that many pad rows of a prefill
-    round, given length 0 in every group, so they read nothing of the
-    cache (and are committed nothing)."""
+    what it last did, ``adapter.tally``). `pads`: the last rows are that
+    many pad rows of a prefill round, given length 0 in every group, so
+    they read nothing of the cache (and are committed nothing)."""
     with _tr.span("kv.gather"):
         inputs = cache.forward_inputs(slots)
         if pads:
@@ -192,23 +165,22 @@ def commit_slots(cache, slots, *new_and_count, adapter=None, phase="decode",
     `count` (one number, or one a row) chunk positions of every row (row
     r belongs to ``slots[r]``) of `new` (a forward's additions, entry by
     entry: new_k, new_v) in the cache; the span counts the pool rows
-    written an entry. `adapter`: the forward's; one that declares
-    ``close_windows`` is then asked to close the windows this commit
-    filled (a ``gen.window_close`` span a closing, of the engine's `phase`;
-    `note` is told what was closed); `note` is told the positions stored
-    where the adapter declares ``loop_steps``."""
+    written an entry. `adapter`: the forward's; `note` is told the
+    positions stored, and an adapter that declares ``close_windows`` is
+    asked to close the windows this commit filled (a ``gen.window_close``
+    span a closing, of the engine's `phase`; `note` is told after a
+    closing)."""
     count = new_and_count[-1]
     rows = (len(slots) * count if isinstance(count, int)
             else int(np.sum(count)))
     with _tr.span("kv.commit", rows=rows):
         cache.commit(slots, *new_and_count)
-    if note is not None and getattr(adapter, "loop_steps", 0):
+    if note is not None:
         note(adapter, committed=rows)
     close = getattr(adapter, "close_windows", None)
     if close is not None:   # a prefill round's pad rows repeat a slot
-        closed = close(cache, list(dict.fromkeys(slots)), phase)
-        if note is not None and closed:
-            note(adapter, closed=closed)
+        if close(cache, list(dict.fromkeys(slots)), phase) and note:
+            note(adapter)
 
 
 def step_slots(adapter, cache, slots, tokens, count=1, note=None):
@@ -330,678 +302,19 @@ def prefill_slot(adapter, cache, slot, tokens_1d, chunk, note=None):
         pass
 
 
-class _PagedLM:
-    """What the adapters share: the cache of their layers. An adapter
-    sets ``config``, ``num_layers`` and ``kv_entries``: what a layer
-    caches, entry name -> the (shape, dtype) of a position (``k`` and
-    ``v`` of one per-head shape; one latent row ``c``), in the order its
-    forward takes the pools and returns the chunk's additions."""
-
-    def cache_spec(self):
-        return PagedKVCache.layer_spec(self.num_layers, self.kv_entries)
-
-    def make_cache(self, slots, max_len=None, **kw):
-        return PagedKVCache(slots, self.cache_spec(),
-                            max_len=max_len or self.config["max_len"], **kw)
-
-
-class GPTPagedLM(_PagedLM):
-    """Shape-cached jit adapter over ``gpt_forward_paged``.
-
-    ``forward(tokens, lengths, tables, k_pools, v_pools)`` takes host
-    tokens, lengths and tables and the cache's pools (device arrays: not
-    shipped), and returns ``(logits (S, C, V), new_k, new_v)``: the logits
-    a host array, new_k / new_v DEVICE arrays (layers, S, C, H, D) for
-    ``cache.commit``, one array each: on the chip's host a launch costs
-    some 50 us an output buffer, so the 2 x 48 per-layer arrays are
-    stacked in the program.
-
-    Two more forwards of the same arguments fetch NOTHING and return
-    ``(read, new_k, new_v)``, `read` a dict of the device arrays the host
-    may fetch when it will (``engine._fetch_behind``):
-
-    - ``forward_token`` (the token head): ``read["token"]`` (S, 1) int32,
-      the argmax of the last chunk position's logits, taken in the
-      program on the logits ``forward`` returns; shaped as the next
-      step's `tokens`, which it may be given as (any forward takes
-      `tokens` as a host or a device array). The logits are no output of
-      that program;
-    - ``forward_kv`` (prefill): the ``forward`` program, ``read`` empty.
-
-    One XLA program per head and (S, C) shape — the engine keeps shapes
-    fixed (padded prefill chunks, fixed spec width), so steady state is
-    two programs: prefill (S, chunk) and decode (S, 1) plus (1, k+1) for
-    speculative verify.
-
-    ``programs``: (S, C) -> a shipped executable of ``forward``'s program
-    (``lower(...)`` compiled), put there by an owner that ships them (the
-    serving family's bind / warm grid) as ``cache.programs`` holds the
-    commit's; a call prefers it to the jit and retires it when it refuses
-    its arguments. A program takes ``weights()``: ``params`` and the
-    embedding's lookup table derived from them, a call, so replacing the
-    dict (of the same shapes and dtypes) swaps the weights under every
-    program.
-    """
-
-    def __init__(self, params, config, use_kernel=False, interpret=False):
-        from ..models.gpt import gpt_config, gpt_forward_paged
-        self.config = gpt_config(config)
-        self.params = {n: jnp.asarray(v) for n, v in params.items()}
-        self.num_layers = self.config["num_layers"]
-        H = self.config["num_heads"]
-        self.kv_entries = dict.fromkeys(
-            "kv", ((H, self.config["units"] // H), jnp.float32))
-        self.programs = {}
-
-        def program(head):
-            def pure(weights, tokens, lengths, tables, kps, vps):
-                params, lookup_rows = weights
-                out, nk, nv = gpt_forward_paged(
-                    params, self.config, tokens, lengths, tables, kps, vps,
-                    lookup_rows, use_kernel=use_kernel, interpret=interpret,
-                    head=head)
-                if head == "token":
-                    out = out[:, None]
-                return out, jnp.stack(nk), jnp.stack(nv)
-            return jax.jit(pure)
-        self._fns = {head: program(head) for head in ("logits", "token")}
-
-    @property
-    def params(self):
-        """The weights by name, as a checkpoint holds them."""
-        return self._params
-
-    @params.setter
-    def params(self, params):
-        """Replacing the weights (a serving family's swap) derives their
-        lookup table at once (``models.gpt.gpt_lookup_rows``: at GPT-2-XL a
-        (25129, 3200) array of its own, so that no forward copies the
-        embedding into the order a gather reads), so no table is stale and
-        a forward compiles nothing of its own. Weights given as shapes (a
-        compile for a described chip) hold none."""
-        from ..models.gpt import gpt_lookup_rows
-        # the old table is freed before the new one is made
-        self._params, self._lookup_rows = params, None
-        if isinstance(params.get("wte"), jax.Array):
-            self._lookup_rows = gpt_lookup_rows(params["wte"])
-
-    def weights(self):
-        """What a program takes for its weights: ``(params,
-        lookup_rows)``."""
-        return self._params, self._lookup_rows
-
-    def lower(self, tokens, lengths, tables, k_pools, v_pools,
-              head="logits"):
-        """A forward's program lowered for arguments of these shapes:
-        ``forward``'s is what an owner compiles into ``programs``. The
-        params may be shapes (``jax.ShapeDtypeStruct``), and the lookup
-        table is given as the shape ``weights()`` derives."""
-        from ..models.gpt import gpt_lookup_rows
-        wte = self.params["wte"]
-        rows = jax.eval_shape(gpt_lookup_rows, wte)
-        rows = jax.ShapeDtypeStruct(rows.shape, rows.dtype,
-                                    sharding=wte.sharding)
-        return self._fns[head].lower((self.params, rows), tokens, lengths,
-                                     tables, k_pools, v_pools)
-
-    def forward(self, tokens, lengths, tables, k_pools, v_pools):
-        logits, nk, nv = _dispatch(
-            self._fns["logits"], self.weights(),
-            (tokens, lengths, tables, k_pools, v_pools), self.programs)
-        (logits,) = _fetch([logits])
-        return logits, nk, nv
-
-    def forward_token(self, tokens, lengths, tables, k_pools, v_pools):
-        token, nk, nv = _dispatch(
-            self._fns["token"], self.weights(),
-            (tokens, lengths, tables, k_pools, v_pools))
-        return {"token": token}, nk, nv
-
-    def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
-        _logits, nk, nv = _dispatch(
-            self._fns["logits"], self.weights(),
-            (tokens, lengths, tables, k_pools, v_pools), self.programs)
-        return {}, nk, nv
-
-
-class SDARPagedLM(_PagedLM):
-    """Shape-cached jit adapter over ``sdar_forward_paged``: the
-    block-diffusion decoder of ``models/sdar_moe.py``, served in
-    `dtype` (bfloat16: weights, activations and the K/V pools).
-
-    What it declares to the engine: ``block_length`` (B; the engine then
-    runs its block loop) and ``mask_id`` (the token a position holds
-    until a denoising forward fixes it). Three programs a shape, one a
-    kind of forward:
-
-    - ``forward`` — the ``GPTPagedLM`` contract, ``(logits (S, C, V),
-      new_k, new_v)``;
-    - ``forward_denoise(tokens, ..., masked=, steps_left=)`` — the block
-      loop's denoising forward: the argmax token ``x0`` of every position
-      and its softmax probability (the confidence), then the static
-      schedule applied in the program (``GenerateEngine.
-      _fix_most_confident``, traced: of each row's masked positions the
-      ``ceil(masked / steps_left)`` most confident take ``x0``).
-      ``steps_left`` is an int32 operand, so one program serves every
-      step. -> ``(read, next_tokens, next_masked)``, all on the device,
-      nothing fetched: the next forward's `tokens` and `masked` (S, C),
-      and ``read`` ``{"x0", "confidence", "masked" (next_masked),
-      "expert_loads"}`` for the host to fetch when it will
-      (``engine._fetch_behind``); the positions it fixed are ``masked &
-      ~read["masked"]``. No K and V come back: nothing is stored;
-    - ``forward_kv`` — prefill and a block's store pass: ``(read, new_k,
-      new_v)``, no final norm, no head, and nothing fetched:
-      ``read["expert_loads"]`` stays on the device for the host to fetch
-      when it will (``engine._fetch_behind``).
-
-    Tokens (and ``forward_denoise``'s mask) are host or device arrays,
-    lengths and tables host arrays, the pools the cache's device arrays;
-    ``forward``'s logits come back as a host array, new_k / new_v stay on
-    the device, (layers, S, C, Hkv, D) each, for ``cache.commit``. After
-    ``forward``, ``last_expert_loads`` holds the (layers, experts) routes
-    each expert got, on the host; after a forward that fetched nothing,
-    None.
-    """
-
-    def __init__(self, params, config, dtype="bfloat16"):
-        from ..models.sdar_moe import sdar_config, sdar_forward_paged
-        self.config = sdar_config(config)
-        self.dtype = jnp.dtype(dtype)
-        self.params = {n: jnp.asarray(v, self.dtype)
-                       for n, v in params.items()}
-        self.num_layers = self.config["num_layers"]
-        self.kv_entries = dict.fromkeys(
-            "kv", ((self.config["num_kv_heads"], self.config["head_dim"]),
-                   self.dtype))
-        self.block_length = int(self.config["block_length"])
-        self.mask_id = int(self.config["mask_id"])
-        self.last_expert_loads = None
-
-        def program(head):
-            def pure(params, tokens, lengths, tables, kps, vps):
-                out, nk, nv, loads = sdar_forward_paged(
-                    params, self.config, tokens, lengths, tables, kps, vps,
-                    head=head)
-                # -> (what the host reads, what stays on the device)
-                kv = (jnp.stack(nk), jnp.stack(nv))
-                return ((loads,) if out is None else (out, loads)), kv
-            return jax.jit(pure)
-        self._fns = {head: program(head) for head in ("logits", "none")}
-
-        def denoise(params, tokens, masked, steps_left, lengths, tables,
-                    kps, vps):
-            (x0, confidence), _nk, _nv, loads = sdar_forward_paged(
-                params, self.config, tokens, lengths, tables, kps, vps,
-                head="choice")
-            # looked up when traced: the schedule has one definition
-            fixed = GenerateEngine._fix_most_confident(masked, confidence,
-                                                       steps_left)
-            return (jnp.where(fixed, x0, tokens), masked & ~fixed, x0,
-                    confidence, loads)
-        self._fns["denoise"] = jax.jit(denoise)
-
-    def _call(self, head, *args):
-        """One forward, nothing fetched -> the program's outputs, all on
-        the device."""
-        self.last_expert_loads = None
-        return _dispatch(self._fns[head], self.params, args)
-
-    def forward(self, tokens, lengths, tables, k_pools, v_pools):
-        read, (nk, nv) = self._call("logits", tokens, lengths, tables,
-                                    k_pools, v_pools)
-        logits, self.last_expert_loads = _fetch(read)
-        return logits, nk, nv
-
-    def forward_denoise(self, tokens, lengths, tables, k_pools, v_pools, *,
-                        masked, steps_left):
-        nxt, masked, x0, confidence, loads = self._call(
-            "denoise", tokens, masked, np.int32(steps_left), lengths, tables,
-            k_pools, v_pools)
-        return ({"x0": x0, "confidence": confidence, "masked": masked,
-                 "expert_loads": loads}, nxt, masked)
-
-    def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
-        (loads,), (nk, nv) = self._call("none", tokens, lengths, tables,
-                                        k_pools, v_pools)
-        return {"expert_loads": loads}, nk, nv
-
-
-class MLAPagedLM(_PagedLM):
-    """Shape-cached jit adapter over ``mla_forward_paged``: the
-    latent-attention decoders of ``models/mla_moe.py`` (one residual
-    stream, or several hyper-connected ones; every expert held, or the
-    configuration's ``experts_held`` share of each expert layer), served in
-    `dtype` (bfloat16: weights, activations and the cache).
-
-    Its cache holds ONE entry a layer, ``c``: a row of ``kv_rank +
-    rope_dim`` values a position (the normalised latent and the rotated
-    key part; zeros up to whole lanes, ``paged_latent.cache_row_width``:
-    576 values in a row of 640), shared by all heads, and no per-head K or
-    V. It declares no ``block_length``: the engine decodes it a token a
-    step.
-
-    - ``forward`` — ``(logits (S, C, V), new_rows)``, the logits a host
-      array;
-    - ``forward_token`` — the token head, as ``GPTPagedLM``'s: ``(read,
-      new_rows)`` with ``read["token"]`` (S, 1) int32 and
-      ``read["expert_loads"]`` left on the device, nothing fetched (the
-      (S, V) float32 logits of a step, megabytes, are no output);
-    - ``forward_kv`` — prefill: ``(read, new_rows)``, the loads alone
-      left on the device, no final norm, no head (a chunk's logits,
-      positions x V float32, can pass a gigabyte).
-
-    Lengths and tables are host arrays, tokens a host or a device array,
-    the pools the cache's device arrays; new_rows stays on the device,
-    (layers, S, C, cache_row_width), for ``cache.commit``. After a forward
-    that fetched, ``last_expert_loads`` holds the forward's loads on the
-    host (None after one that did not): (expert layers, experts), the
-    routes each routed expert got; where the configuration holds a share,
-    the routes of the experts HELD here and two columns more, a layer's
-    ``routes_elsewhere`` and ``rows_moved`` (``split_loads`` parts them);
-    and after every forward ``last_latent_path`` which attention
-    path the chunk's width chose and the rows it counts, by
-    ``last_stats["mla"]``'s names: ``("expanded", {"expanded_rows": the
-    sequences' committed lengths summed, "expanded_kernel_forwards": 1
-    where the chunk's attention was the launch ``paged_latent_prefill``,
-    else 0})`` for a chunk, the cached rows
-    it expanded again; ``("absorbed", {"absorbed_rows_live": the same
-    sum, "absorbed_rows_read": the rows a layer's walk over the past
-    fetches for them})`` for a decode step (``paged_latent.rows_walked``:
-    the kernel's walk reads each sequence's own blocks, the ``lax`` walk
-    every sequence's tiles up to the longest one's).
-    """
-
-    def __init__(self, params, config, dtype="bfloat16"):
-        from ..models.mla_moe import mla_config, mla_forward_paged
-        self.config = mla_config(config)
-        self.dtype = jnp.dtype(dtype)
-        self.params = {n: jnp.asarray(v, self.dtype)
-                       for n, v in params.items()}
-        self.num_layers = self.config["num_layers"]
-        self.kv_entries = {"c": ((cache_row_width(
-            self.config["kv_rank"], self.config["rope_dim"]),), self.dtype)}
-        self.last_expert_loads = self.last_latent_path = None
-
-        def program(head):
-            def pure(params, tokens, lengths, tables, pools):
-                out, rows, loads = mla_forward_paged(
-                    params, self.config, tokens, lengths, tables, pools,
-                    head=head)
-                # -> (what the host reads, what stays on the device)
-                if head == "token":
-                    out = out[:, None]
-                return ((loads,) if out is None else (out, loads),
-                        jnp.stack(rows))
-            return jax.jit(pure)
-        self._fns = {head: program(head)
-                     for head in ("logits", "token", "none")}
-
-    def make_cache(self, slots, max_len=None, **kw):
-        """The cache of ``_PagedLM.make_cache`` in blocks sized by the
-        row's bytes (``paged_latent.latent_block_size``: 128 positions of
-        640 bfloat16 lanes), not by ``MXTPU_GEN_BLOCK_SIZE``: a row is a
-        twentieth of a per-head cache's position, and the decode kernel
-        copies the cache block by block. A `block_size` given wins."""
-        max_len = max_len or self.config["max_len"]
-        (shape, dtype), = self.kv_entries.values()
-        kw.setdefault("block_size", latent_block_size(
-            shape[0] * dtype.itemsize, max_len))
-        return super().make_cache(slots, max_len=max_len, **kw)
-
-    def lower(self, tokens, lengths, tables, pools, head="logits"):
-        """A forward's program lowered for arguments of these shapes."""
-        return self._fns[head].lower(self.params, tokens, lengths, tables,
-                                     pools)
-
-    def _call(self, head, tokens, lengths, tables, pools):
-        """One forward, nothing fetched -> (the head's outputs then the
-        expert loads, new_rows), all on the device."""
-        path = latent_path(tokens.shape[1])
-        read, rows = _dispatch(self._fns[head], self.params,
-                               (tokens, lengths, tables, pools),
-                               mla_path=path)
-        self.last_expert_loads = None
-        live = int(np.sum(lengths))
-        if path == "expanded":
-            cfg = self.config
-            counts = {
-                "expanded_rows": live,
-                "expanded_kernel_forwards": int(
-                    paged_latent_prefill_available(
-                        pools[0], tokens.shape[1], cfg["kv_rank"],
-                        cfg["nope_dim"], cfg["v_dim"]))}
-        else:
-            counts = {
-                "absorbed_rows_live": live,
-                "absorbed_rows_read": rows_walked(
-                    lengths, pools[0].shape[1], tables.shape[1],
-                    paged_latent_decode_available(pools[0]))}
-        self.last_latent_path = (path, counts)
-        return read, rows
-
-    def split_loads(self, loads):
-        """A forward's ``expert_loads`` as the host fetched them -> (the
-        routes each expert held here got (expert layers, experts held),
-        what a held share says of itself: ``{"routes_elsewhere",
-        "rows_moved"}`` summed over the layers, ``{}`` where every expert
-        is held)."""
-        held = self.config["experts_held"]
-        if held is None:
-            return loads, {}
-        return loads[:, :held[1]], {
-            "routes_elsewhere": int(loads[:, held[1]].sum()),
-            "rows_moved": int(loads[:, held[1] + 1].sum())}
-
-    def forward(self, tokens, lengths, tables, pools):
-        read, rows = self._call("logits", tokens, lengths, tables, pools)
-        logits, self.last_expert_loads = _fetch(read)
-        return logits, rows
-
-    def forward_token(self, tokens, lengths, tables, pools):
-        (token, loads), rows = self._call("token", tokens, lengths, tables,
-                                          pools)
-        return {"token": token, "expert_loads": loads}, rows
-
-    def forward_kv(self, tokens, lengths, tables, pools):
-        (loads,), rows = self._call("none", tokens, lengths, tables, pools)
-        return {"expert_loads": loads}, rows
-
-
-class EvaPagedLM(_PagedLM):
-    """Shape-cached jit adapter over ``eva_forward_paged``: the byte-level
-    decoder of ``models/eva_byte.py`` (EVA attention: a window of exact
-    keys and values beside a summary a chunk of every closed window),
-    served in `dtype` (bfloat16: weights, activations and the cache).
-
-    Its cache holds two GROUPS of entries a layer
-    (``paged_kv.PagedKVCache(groups=...)``): ``window``, the leading one
-    (``wk``, ``wv``: at most ``window`` rows a slot in blocks of 256, row
-    ``t % window``), and ``summary`` (``sk``, ``sv``: ``window // chunk``
-    rows a closing, a block a closing); a row holds all heads side by
-    side (H x D lanes). On a TPU a decode step walks each group by one
-    launch a layer (``ops/pallas/paged_heads.py``; `interpret` runs the
-    launches anywhere), elsewhere and for every wider chunk by the
-    ``lax`` gather. It declares ``window``: the engine
-    then keeps a prefill chunk from straddling a closing, and
-    ``commit_slots`` asks ``close_windows`` after every commit.
-
-    - ``forward`` — ``(logits (S, C, V), new_k, new_v)``: the served
-      head's logits (head 0: position t scores byte t + 1) on the host;
-      ``last_pred_logits`` (S, C, pred_heads, V) holds every head's;
-    - ``forward_token`` — ``(read, new_k, new_v)`` with ``read["token"]``
-      (S, 1) int32, head 0's argmax at the last chunk position, nothing
-      fetched;
-    - ``forward_kv`` — prefill: ``(read, new_k, new_v)``, no final norm,
-      no head; ``last_stream`` is the last layer's stream (S, C, d)
-      float32 on the device, what a pipeline stage hands on;
-      ``read["window_rows"]`` (S,) int32 is the program's smallest output,
-      left for ``prefill_slots`` to fetch one round behind: a chunk's keys,
-      values and temporaries hold some 0.8 GB of HBM until its commit has
-      run, so the host keeps to one chunk ahead of the device (the device
-      never waits: the next chunk is queued by then);
-    - ``close_windows(cache, slots, phase)`` — for each of `slots` whose
-      window group holds ``window`` rows (the cache's host lengths: no
-      fetch): one launch that reads the window's rows and pools them into
-      its summaries, their commit to the summary group, and the window
-      restarted in place; a ``gen.window_close`` span a closing. ->
-      ``{"windows_closed", "summary_rows_written", "window_rows_read"}``,
-      None where nothing closed.
-
-    Each group's lengths and tables are host arrays, tokens a host or a
-    device array, the pools the cache's device arrays; new_k / new_v stay
-    on the device, (layers, S, C, H D), for ``cache.commit``. A chunk that
-    finds every window empty (a prefill chunk of ``window`` positions)
-    takes a program that leaves the window pools unread. After every
-    forward ``last_eva`` holds what it read, by ``last_stats["eva"]``'s
-    names: ``window_rows_read`` (the live window rows and the chunk's
-    own), ``summary_rows_read``, ``positions`` (the contexts' lengths
-    after the chunk, summed over the rows).
-    """
-
-    def __init__(self, params, config, dtype="bfloat16", interpret=False):
-        from ..models.eva_byte import (eva_close_window, eva_config,
-                                       eva_forward_paged)
-        self.config = cfg = eva_config(config)
-        self.dtype = jnp.dtype(dtype)
-        self.params = {n: jnp.asarray(v, self.dtype)
-                       for n, v in params.items()}
-        self.num_layers = cfg["num_layers"]
-        self.window = int(cfg["window"])
-        self.summaries_per_window = self.window // int(cfg["chunk"])
-        # a row holds all heads side by side: H x D lanes
-        self.kv_entries = dict.fromkeys(
-            ("wk", "wv", "sk", "sv"), ((cfg["units"],), self.dtype))
-        self.kv_groups = {"window": ("wk", "wv"), "summary": ("sk", "sv")}
-        self.last_eva = self.last_pred_logits = self.last_stream = None
-
-        def program(head, fresh):
-            def pure(params, tokens, wlen, wtables, slen, stables, wk, wv,
-                     sk, sv):
-                out, nk, nv = eva_forward_paged(
-                    params, cfg, tokens, wlen, wtables, slen, stables, wk,
-                    wv, sk, sv, head=head, fresh=fresh, interpret=interpret)
-                if head == "token":
-                    out = out[:, None]
-                elif head == "none":    # the stream; the window rows after
-                    out = (out, wlen + tokens.shape[1])
-                return out, jnp.stack(nk), jnp.stack(nv)
-            return jax.jit(pure)
-        self._fns = {(head, fresh): program(head, fresh)
-                     for head in ("logits", "token", "none")
-                     for fresh in (False, True)}
-
-        def close(params, wtables, wk, wv):
-            sk, sv = eva_close_window(params, cfg, wtables, wk, wv)
-            return jnp.stack(sk), jnp.stack(sv)
-        self._close = jax.jit(close)
-
-    def cache_spec(self):
-        return PagedKVCache.layer_spec(self.num_layers, self.kv_entries,
-                                       groups=self.kv_groups)
-
-    def make_cache(self, slots, max_len=None, **kw):
-        """The grouped cache: the window group in blocks of 256 rows (a
-        whole window where it is shorter), the summary group a closing a
-        block and as many as ``max_len`` positions close."""
-        max_len = max_len or self.config["max_len"]
-        per = self.summaries_per_window
-        kw.setdefault("groups", {
-            "window": {"max_len": self.window,
-                       "block_size": min(self.window, 256)},
-            "summary": {"max_len": max(1, max_len // self.window) * per,
-                        "block_size": per}})
-        return super().make_cache(slots, max_len=max_len, **kw)
-
-    def lower(self, tokens, wlen, wtables, slen, stables, wk, wv, sk, sv,
-              head="logits", fresh=False):
-        """A forward's program lowered for arguments of these shapes."""
-        return self._fns[head, fresh].lower(
-            self.params, tokens, wlen, wtables, slen, stables, wk, wv, sk, sv)
-
-    def lower_close(self, wtables, wk, wv):
-        """The closing's program lowered likewise."""
-        return self._close.lower(self.params, wtables, wk, wv)
-
-    def _call(self, head, tokens, wlen, *rest):
-        """One forward, nothing fetched -> (out, new_k, new_v), all on
-        the device."""
-        slen = rest[1]
-        chunk = tokens.shape[1]
-        window_rows = int(wlen.sum()) + chunk * len(wlen)
-        self.last_eva = {
-            "window_rows_read": window_rows,
-            "summary_rows_read": int(slen.sum()),
-            "positions": int((slen // self.summaries_per_window).sum())
-            * self.window + window_rows}
-        fresh = chunk > 1 and not wlen.any()
-        return _dispatch(self._fns[head, fresh], self.params,
-                         (tokens, wlen) + rest)
-
-    def forward(self, *args):
-        logits, nk, nv = self._call("logits", *args)
-        (logits,) = _fetch([logits])
-        V = self.config["vocab_size"]
-        self.last_pred_logits = logits.reshape(logits.shape[:2] + (-1, V))
-        return logits[..., :V], nk, nv
-
-    def forward_token(self, *args):
-        token, nk, nv = self._call("token", *args)
-        return {"token": token}, nk, nv
-
-    def forward_kv(self, *args):
-        (self.last_stream, reached), nk, nv = self._call("none", *args)
-        return {"window_rows": reached}, nk, nv
-
-    def close_windows(self, cache, slots, phase):
-        full = cache.group_lengths("window")
-        due = [slot for slot in slots if full[slot] >= self.window]
-        if not due:
-            return None
-        per = self.summaries_per_window
-        for slot in due:        # one slot a launch: one compiled shape
-            with _tr.span("gen.window_close", slots=1, rows_written=per,
-                          phase=phase):
-                sk, sv = _dispatch(
-                    self._close, self.params,
-                    (cache.tables_array([slot], "window"),)
-                    + cache.group_pools("window"))
-                cache.commit([slot], sk, sv, per, group="summary")
-                cache.restart(slot, "window")
-        return {"windows_closed": len(due),
-                "summary_rows_written": per * len(due),
-                "window_rows_read": self.window * len(due)}
-
-
-class OuroPagedLM(_PagedLM):
-    """Shape-cached jit adapter over ``ouro_forward_paged``: the looped
-    decoder of ``models/ouro.py`` (its ``num_layers`` layers applied
-    ``loop_steps`` times a token with one set of weights, the loop a
-    ``fori_loop`` in the program), served in `dtype` (bfloat16: weights,
-    activations and the cache).
-
-    Its cache holds ONE ``k`` and ONE ``v`` entry a layer whose row is a
-    position's keys (values) of every loop step side by side: T x H D
-    lanes, step u in lanes ``[u H D, (u + 1) H D)``; one block table and
-    one length serve all T steps, a forward takes 2 x L pools and
-    ``cache.commit`` stores its T steps with the one program. Blocks are
-    sized by the bytes of ONE step's row (``paged_latent.
-    latent_block_size``), what a walk copies. It declares ``loop_steps``:
-    the engine then counts ``last_stats["loop"]``. A decode step walks
-    each layer's past at each step by the launch ``paged_heads_decode``
-    given the step (a TPU; `interpret` runs it anywhere), a chunk over
-    empty slots takes ``flash_attention_lse``, any other chunk the ``lax``
-    gather.
-
-    - ``forward`` — ``(logits (S, C, V), new_k, new_v)``, the logits a
-      host array;
-    - ``forward_token`` — ``(read, new_k, new_v)`` with ``read["token"]``
-      (S, 1) int32, the argmax at the last chunk position, nothing
-      fetched;
-    - ``forward_kv`` — prefill: ``(read, new_k, new_v)``, no head;
-      ``read["reached"]`` (S,) int32, the program's smallest output, left
-      for ``prefill_slots`` to fetch one round behind: a chunk's keys and
-      values of every step (L x C x T x H D x 2 of each) stay in HBM until
-      its commit has run, so the host keeps one chunk ahead.
-
-    Lengths and tables are host arrays, tokens a host or a device array,
-    the pools the cache's device arrays; new_k / new_v stay on the device,
-    (L, S, C, T H D), for ``cache.commit``. After every forward
-    ``last_loop`` holds what it did by ``last_stats["loop"]``'s names:
-    ``forwards`` 1, ``loop_steps`` T, ``layer_passes`` T x L,
-    ``cache_rows_read`` (the live rows of every (step, layer) entry the
-    chunk attended to: the committed lengths summed, times T x L) and
-    ``positions`` (the committed lengths summed; the commit adds the rows
-    it stored). The exit gate's leaves (``exit_w``, ``exit_b``) are the
-    reference's and no argument of a program.
-    """
-
-    def __init__(self, params, config, dtype="bfloat16", interpret=False):
-        from ..models.ouro import ouro_config, ouro_forward_paged
-        self.config = cfg = ouro_config(config)
-        self.dtype = jnp.dtype(dtype)
-        self.params = {n: jnp.asarray(v, self.dtype)
-                       for n, v in params.items()
-                       if not n.startswith("exit_")}
-        self.num_layers = cfg["num_layers"]
-        self.loop_steps = int(cfg["loop_steps"])
-        self.kv_entries = dict.fromkeys(
-            "kv", ((self.loop_steps * cfg["units"],), self.dtype))
-        self.last_loop = None
-
-        def program(head, fresh):
-            def pure(params, tokens, lengths, tables, kps, vps):
-                out, nk, nv = ouro_forward_paged(
-                    params, cfg, tokens, lengths, tables, kps, vps,
-                    head=head, fresh=fresh, interpret=interpret)
-                if head == "token":
-                    out = out[:, None]
-                return out, nk, nv
-            return jax.jit(pure)
-        self._fns = {(head, fresh): program(head, fresh)
-                     for head in ("logits", "token", "none")
-                     for fresh in (False, True)}
-
-    def make_cache(self, slots, max_len=None, **kw):
-        """The cache of ``_PagedLM.make_cache`` in blocks of ONE loop
-        step's rows worth ``paged_latent.BLOCK_BYTES`` (32 positions of
-        2,048 bfloat16 lanes): a decode walk copies a step's lanes of a
-        block at a time. A `block_size` given wins."""
-        max_len = max_len or self.config["max_len"]
-        kw.setdefault("block_size", latent_block_size(
-            self.config["units"] * self.dtype.itemsize, max_len))
-        return super().make_cache(slots, max_len=max_len, **kw)
-
-    def lower(self, tokens, lengths, tables, k_pools, v_pools,
-              head="logits", fresh=False):
-        """A forward's program lowered for arguments of these shapes."""
-        return self._fns[head, fresh].lower(self.params, tokens, lengths,
-                                            tables, k_pools, v_pools)
-
-    def _call(self, head, tokens, lengths, tables, k_pools, v_pools):
-        """One forward, nothing fetched -> (out, new_k, new_v), all on the
-        device."""
-        steps, layers = self.loop_steps, self.num_layers
-        live = int(np.sum(lengths))
-        self.last_loop = {"forwards": 1, "loop_steps": steps,
-                          "layer_passes": steps * layers,
-                          "cache_rows_read": live * steps * layers,
-                          "positions": live}
-        fresh = tokens.shape[1] > 1 and not np.any(lengths)
-        return _dispatch(self._fns[head, fresh], self.params,
-                         (tokens, lengths, tables, k_pools, v_pools),
-                         loop_steps=steps)
-
-    def forward(self, tokens, lengths, tables, k_pools, v_pools):
-        logits, nk, nv = self._call("logits", tokens, lengths, tables,
-                                    k_pools, v_pools)
-        (logits,) = _fetch([logits])
-        return logits, nk, nv
-
-    def forward_token(self, tokens, lengths, tables, k_pools, v_pools):
-        token, nk, nv = self._call("token", tokens, lengths, tables,
-                                   k_pools, v_pools)
-        return {"token": token}, nk, nv
-
-    def forward_kv(self, tokens, lengths, tables, k_pools, v_pools):
-        reached, nk, nv = self._call("none", tokens, lengths, tables,
-                                     k_pools, v_pools)
-        return {"reached": reached}, nk, nv
-
-
 class GenerateEngine:
     """Drives one model (plus optional draft) over paged KV caches.
 
-    model / draft: adapters with ``num_layers``, ``forward(...)``
-    (``GPTPagedLM`` contract). ``spec_k`` > 0 with a draft enables
-    speculative decoding (greedy only — temperature sampling with a
-    draft raises, the acceptance rule here is the deterministic
-    argmax-match variant).
+    model / draft: ``PagedLM`` adapters (the module's contract).
+    ``spec_k`` (4) > 0 with a draft enables speculative decoding (greedy
+    only — temperature sampling with a draft raises, the acceptance rule
+    here is the deterministic argmax-match variant).
 
-    A model with a token head (``forward_token``) is decoded greedily
-    without a wait between two forwards (``_plain_loop``): the forward
-    chooses the next token in its program, the next step is fed that
-    device array, and the host reads ids and expert loads one forward
-    behind. Chosen from what the engine sees (`temperature` <= 0, no
+    A model with a token head (``"token"`` in ``heads``) is decoded
+    greedily without a wait between two forwards (``_plain_loop``): the
+    forward chooses the next token in its program, the next step is fed
+    that device array, and the host reads ids and expert loads one
+    forward behind. Chosen from what the engine sees (`temperature` <= 0, no
     speculative rounds, no ``block_length``, the head), by no flag.
     Temperature sampling, speculative rounds and ``serving.DecodeLoop``
     read a forward's own outputs and wait for it.
@@ -1027,8 +340,7 @@ class GenerateEngine:
         self.cache = cache
         self.draft = draft
         self.draft_cache = draft_cache
-        self.spec_k = (spec_k if spec_k is not None
-                       else _env_int("MXTPU_GEN_SPEC_K", 4))
+        self.spec_k = 4 if spec_k is None else spec_k
         self.prefill_chunk = (prefill_chunk if prefill_chunk is not None
                               else default_prefill_chunk())
         if self.prefill_chunk < 1:
@@ -1070,87 +382,15 @@ class GenerateEngine:
                     % (self.prefill_chunk, window))
         self._position_bytes = position_bytes(model)
         self.last_stats = {}
-        # a model with an expert layer, a latent cache or windows that
-        # close: every forward of its is tallied into the call's
-        # ``last_stats["moe"]`` / ``["mla"]`` / ``["eva"]``
-        self._tallies = {}
+        self._tallies = {}          # the call's stats: what ``_note`` fills
         self._phase = "prefill"     # of the forward in flight: ``_run``
-        self._note = (self._note_forward
-                      if any(hasattr(model, said) for said in (
-                          "last_expert_loads", "last_latent_path",
-                          "last_eva", "last_loop")) else None)
 
     # ---------------------------------------------------------- plumbing
-    def _note_forward(self, model, read=None, closed=None, committed=None):
-        """What a forward of `model`'s says of itself, into this call's
-        ``last_stats``. `closed`: what a commit's window closings counted
-        (``EvaPagedLM.close_windows``), into ``"eva"`` under the phase.
-        `committed`: the positions a commit of a looped model stored, into
-        ``"loop"`` (rows written: one an entry, T x L a position).
-        Right after a forward (`read` None): ``last_eva`` (the rows it
-        read) likewise with the forward counted, ``last_latent_path``
-        (the attention path over a latent cache and the cached rows it
-        expanded, or walked) into ``"mla"``, and ``last_expert_loads``
-        (layers, experts: the routes each expert got) into ``"moe"`` if the
-        forward fetched them. One that fetched nothing left them on the device:
-        they are tallied from `read`, its outputs as the host fetched
-        them a forward later."""
-        if committed is not None:
-            loop = self._tallies["loop"][self._phase]
-            loop["cache_rows_written"] += (committed * model.loop_steps
-                                           * model.num_layers)
-            loop["positions"] += committed
-            return
-        if read is None and getattr(model, "last_loop", None):
-            loop = self._tallies["loop"][self._phase]
-            for key, count in model.last_loop.items():
-                loop[key] += count
-            _cat.gen_loop_steps.inc(model.last_loop["loop_steps"],
-                                    model=self.name, phase=self._phase)
-        split = getattr(model, "split_loads", None)
-        eva = closed
-        if closed is None and read is None and getattr(model, "last_eva",
-                                                       None):
-            eva = {"forwards": 1, **model.last_eva}
-        for key, count in (eva or {}).items():
-            self._tallies["eva"][self._phase][key] += count
-            getattr(_cat, "eva_" + key).inc(count, model=self.name,
-                                            phase=self._phase)
-        if closed is not None:
-            return
-        if read is None:
-            path = getattr(model, "last_latent_path", None)
-            if path is not None:
-                mla = self._tallies["mla"]
-                for key, count in {path[0] + "_forwards": 1,
-                                   **path[1]}.items():
-                    mla[key] += count
-                    getattr(_cat, "mla_" + key).inc(count, model=self.name)
-            loads = getattr(model, "last_expert_loads", None)
-        else:
-            loads = read.get("expert_loads")
-        if loads is None:
-            return
-        loads, share = split(loads) if split else (loads, {})
-        moe = self._tallies["moe"]
-        routes, hit = int(loads.sum()), int((loads > 0).sum())
-        # the totals, and the same a second time under the forward's phase
-        for tally in (moe, moe["by_phase"][self._phase]):
-            tally["forwards"] += 1
-            tally["routes"] += routes
-            tally["experts_hit"] += hit
-            for key, count in share.items():
-                tally[key] = tally.get(key, 0) + count
-        _cat.moe_routes.inc(routes, model=self.name, phase=self._phase)
-        _cat.moe_experts_hit.inc(hit, model=self.name, phase=self._phase)
-        for key, count in share.items():
-            getattr(_cat, "moe_" + key).inc(count, model=self.name)
-        # a share's layer may get no route at all: it has no fullest expert
-        routed = loads[loads.sum(axis=1) > 0]
-        if len(routed):
-            uneven = float(np.mean(routed.max(axis=1) / routed.mean(axis=1)))
-            moe["load_max_over_mean"].append(uneven)
-            _cat.moe_load_max_over_mean.observe(uneven, model=self.name)
+    def _note(self, model, read=None, committed=None):
+        """What a forward of `model`'s, a read of it one forward behind, a
+        commit or a commit's closings says of itself (``model.tally``),
+        into this call's ``last_stats`` under the phase in flight."""
+        model.tally(self._tallies, self._phase, self.name, read, committed)
 
     def _sample(self, logits_row):
         if self.temperature <= 0:
@@ -1217,27 +457,7 @@ class GenerateEngine:
         stats = {"prefill_seconds": 0.0, "decode_seconds": 0.0,
                  "prefill_tokens": 0, "decode_tokens": 0,
                  "proposed": 0, "accepted": 0}
-        if hasattr(self.model, "last_expert_loads"):
-            stats["moe"] = {"forwards": 0, "routes": 0, "experts_hit": 0,
-                            "load_max_over_mean": [],
-                            "by_phase": {phase: dict.fromkeys(
-                                ("forwards", "routes", "experts_hit"), 0)
-                                for phase in ("prefill", "decode")}}
-        if hasattr(self.model, "last_latent_path"):
-            stats["mla"] = dict.fromkeys(
-                ("absorbed_forwards", "expanded_forwards",
-                 "expanded_kernel_forwards", "expanded_rows",
-                 "absorbed_rows_live", "absorbed_rows_read"), 0)
-        if hasattr(self.model, "last_loop"):
-            stats["loop"] = {phase: dict.fromkeys(
-                ("forwards", "loop_steps", "layer_passes", "cache_rows_read",
-                 "cache_rows_written", "positions"), 0)
-                for phase in ("prefill", "decode")}
-        if hasattr(self.model, "last_eva"):
-            stats["eva"] = {phase: dict.fromkeys(
-                ("forwards", "windows_closed", "summary_rows_written",
-                 "window_rows_read", "summary_rows_read", "positions"), 0)
-                for phase in ("prefill", "decode")}
+        stats.update(self.model.tallies())
         self._tallies = stats
         for p in prompts:
             slot = self.cache.alloc()
@@ -1258,7 +478,7 @@ class GenerateEngine:
         rounds of several prompts a forward, each prompt's chunks in
         order), then the decode loop the model and the engine's options
         choose. ``self._phase`` says which of the two a forward belongs to
-        (``_note_forward``'s tallies by phase): what a forward leaves on
+        (``_note``'s tallies by phase): what a forward leaves on
         the device is read before its phase ends (``prefill_slots`` reads
         the last round's loads before its last yield, the loops their last
         step's), so a tally is its forward's phase's."""
@@ -1294,8 +514,8 @@ class GenerateEngine:
         that one reading. A prefill waits for no commit, so the last round
         waits for the last (``kv.sync``): the device's time for the prompts
         lies inside the rounds that launched it. A prompt's prefill is a
-        ``gen.prefill`` record (``slot``, ``tokens``; ``loop_steps`` over
-        a looped model), a child of ``gen.call``, from the start of its
+        ``gen.prefill`` record (``slot``, ``tokens``; the adapter's
+        ``prefill_attrs``), a child of ``gen.call``, from the start of its
         first round to the end of its last round's work (its last chunk's
         commit launched, the round's reads done) and the parent of the
         rounds it leads (their first row); the records of prompts that
@@ -1305,7 +525,6 @@ class GenerateEngine:
         ``stats`` gains ``prefill_forwards`` (rounds) and ``prefill_rows``
         (the prompt chunks they fed, pad rows not counted)."""
         B, chunk = self.block_length, self.prefill_chunk
-        loops = getattr(self.model, "loop_steps", 0)
         stats["prefill_forwards"] = stats["prefill_rows"] = 0
         work = []           # (sequence, the tokens it commits)
         for s in seqs:
@@ -1359,14 +578,13 @@ class GenerateEngine:
                     _cat.gen_prefill_seconds.observe(
                         time.monotonic() - began[i][1], model=self.name)
                     if call is not None:
-                        extra = ({"loop_steps": loops * chunks[i]} if loops
-                                 else {})
                         _tr.record_span(
                             "gen.prefill", call.trace_id,
                             parent_id=call.span_id, t0=began[i][0],
                             sampled=call.sampled, span_id=ids.get(i),
                             model=self.name, slot=s["slot"],
-                            tokens=len(prompt), **extra)
+                            tokens=len(prompt),
+                            **self.model.prefill_attrs(chunks[i]))
                 dt = time.monotonic() - t0
                 sp.set_duration(dt)
             stats["prefill_seconds"] += dt
@@ -1397,8 +615,7 @@ class GenerateEngine:
 
         ``stats`` gains ``decode_steps`` and ``decode_steps_fed_on_device``
         (of a call with no stop token: all but the first)."""
-        on_device = (self.temperature <= 0
-                     and hasattr(self.model, "forward_token"))
+        on_device = self.temperature <= 0 and "token" in self.model.heads
         stats["decode_steps"] = stats["decode_steps_fed_on_device"] = 0
 
         def take(rows, tokens):

@@ -171,7 +171,7 @@ def _build_gpt_decoder(config, params, quantize):
                                 *cache.forward_inputs(range(shape[0])))
         donation = ()
         if commit:      # of what the forward adds, entry by entry
-            _logits, *new = lowered.out_info
+            _logits, _reads, new = lowered.out_info
             lowered = cache.lower_commit(*new)
             donation = tuple(range(len(new)))
         return _aot.cached_compile(lowered, name=name, where="serving",
@@ -223,8 +223,12 @@ def _build_gpt_decoder(config, params, quantize):
         pools = ([0] * adapter.num_layers,) * len(adapter.kv_entries)
         args = (pools + (0,) * len(pools) + (0,) if commit else
                 ((adapter.params, 0), 0, 0, 0) + pools)   # as `weights()`
+        # a forward returns (logits, reads, new), ``adapters.PagedLM``'s
+        outs = (0, {}, (0,) * len(pools))
         compiled = _aot.deserialize_compiled(blob)
-        if compiled.in_tree != jax.tree_util.tree_structure((args, {})):
+        if compiled.in_tree != jax.tree_util.tree_structure((args, {})) or (
+                not commit and compiled.out_tree
+                != jax.tree_util.tree_structure(outs)):
             log.info("serving: executable %r was exported under another "
                      "calling convention; it recompiles on demand", name)
             return False

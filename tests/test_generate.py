@@ -1247,7 +1247,7 @@ def test_a_greedy_call_waits_for_nothing_between_two_forwards(lagged_lm):
     the device lies outside them."""
     import time
     from incubator_mxnet_tpu.telemetry import tracing
-    latent = hasattr(lagged_lm, "last_latent_path")
+    latent = "mla" in lagged_lm.tallies()
     name = "latent" if latent else "gpt"
     eng = GenerateEngine(lagged_lm, lagged_lm.make_cache(3, max_len=64),
                          prefill_chunk=_LAG_CHUNK, name=name)
@@ -1446,12 +1446,12 @@ def test_the_expert_tallies_by_phase_sum_to_the_totals(latent_lm):
     eng = GenerateEngine(latent_lm, latent_lm.make_cache(3, max_len=64),
                          prefill_chunk=_LAG_CHUNK, name="phased")
     phases = []
-    note = eng._note_forward
+    note = eng._note
 
-    def watched(model, read=None):
+    def watched(model, read=None, **kw):
         if read is not None and "expert_loads" in read:
             phases.append(eng._phase)
-        return note(model, read)
+        return note(model, read, **kw)
     eng._note = watched
     eng.generate(_LAG_PROMPTS, max_new_tokens=_LAG_NEW)
     moe = eng.last_stats["moe"]
@@ -1487,29 +1487,32 @@ def test_a_model_that_holds_every_expert_tallies_no_share(latent_lm):
         "by_phase"}
     assert all(set(tally) == {"forwards", "routes", "experts_hit"}
                for tally in eng.last_stats["moe"]["by_phase"].values())
+    # the loads of every expert, none held elsewhere: no share's numbers
+    stats = latent_lm.tallies()
     loads = np.arange(16).reshape(2, 8)
-    assert latent_lm.split_loads(loads)[1] == {}
-    assert latent_lm.split_loads(loads)[0] is loads
+    latent_lm.tally(stats, "decode", "whole_tally",
+                    read={"expert_loads": loads})
+    assert stats["moe"]["routes"] == int(loads.sum())
+    assert set(stats["moe"]["by_phase"]["decode"]) == {
+        "forwards", "routes", "experts_hit"}
 
 
 def test_a_share_whose_experts_get_no_route_has_no_fullest_expert(share_lm):
     """A forward in which no layer's held experts got a route appends no
     ``load_max_over_mean`` (0 / 0), and counts as a forward all the
     same."""
-    eng = GenerateEngine(share_lm, share_lm.make_cache(1, max_len=16),
-                         name="idle_share")
-    eng._tallies = {"moe": {"forwards": 0, "routes": 0, "experts_hit": 0,
-                            "load_max_over_mean": [], "routes_elsewhere": 0,
-                            "rows_moved": 0, "by_phase": {"prefill": {
-                                "forwards": 0, "routes": 0,
-                                "experts_hit": 0}}}}
+    stats = {"moe": {"forwards": 0, "routes": 0, "experts_hit": 0,
+                     "load_max_over_mean": [], "routes_elsewhere": 0,
+                     "rows_moved": 0, "by_phase": {"prefill": {
+                         "forwards": 0, "routes": 0, "experts_hit": 0}}}}
     idle = np.asarray([[0, 0, 0, 8, 0], [0, 0, 0, 8, 0]], np.int32)
-    eng._note_forward(share_lm, {"expert_loads": idle})
+    share_lm.tally(stats, "prefill", "idle_share",
+                   read={"expert_loads": idle})
     one = np.asarray([[0, 3, 1, 4, 32], [0, 0, 0, 8, 0]], np.int32)
-    eng._note_forward(share_lm, {"expert_loads": one})
+    share_lm.tally(stats, "prefill", "idle_share", read={"expert_loads": one})
     counted = {"forwards": 2, "routes": 4, "experts_hit": 2,
                "routes_elsewhere": 28, "rows_moved": 32}
-    assert eng._tallies["moe"] == dict(
+    assert stats["moe"] == dict(
         counted, load_max_over_mean=[3 / (4 / 3)],
         by_phase={"prefill": counted})
 

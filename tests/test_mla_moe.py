@@ -617,7 +617,7 @@ def test_where_the_launch_is_available_every_chunk_runs_it(weights, model,
     ``expanded_kernel_forwards`` beside ``expanded_forwards`` in
     ``last_stats["mla"]`` and on its counter, and the call serves the
     tokens of the ``lax`` path."""
-    from incubator_mxnet_tpu.generate import engine as engine_module
+    from incubator_mxnet_tpu.generate import adapters
     from incubator_mxnet_tpu.ops.pallas import paged_latent
     prompts = [_prompt(10), _prompt(6, seed=1)]
 
@@ -633,7 +633,7 @@ def test_where_the_launch_is_available_every_chunk_runs_it(weights, model,
     def launch(*args, **kw):
         launches.append(args[0].shape)
         return prefill(*args, **dict(kw, interpret=True))
-    for module in (paged_latent, engine_module):
+    for module in (paged_latent, adapters):
         monkeypatch.setattr(module, "paged_latent_prefill_available",
                             lambda *shapes: True)
     monkeypatch.setattr(paged_latent, "paged_latent_prefill", launch)

@@ -33,6 +33,7 @@ from incubator_mxnet_tpu import telemetry
 from incubator_mxnet_tpu.generate import (EvaPagedLM, GenerateEngine,
                                           GPTPagedLM, MLAPagedLM, OuroPagedLM,
                                           SDARPagedLM)
+from incubator_mxnet_tpu.generate import adapters
 from incubator_mxnet_tpu.generate import engine as engine_mod
 from incubator_mxnet_tpu.generate.engine import (position_bytes,
                                                  prefill_rounds,
@@ -256,18 +257,18 @@ def test_the_stats_and_counters_count_rounds_and_prompt_chunks(gpt,
     eng = GenerateEngine(gpt, gpt.make_cache(4, max_len=64), prefill_chunk=4,
                          name="rounds")
     dispatched = []
-    dispatch = engine_mod._dispatch
+    dispatch = adapters._dispatch
 
     def logged(fn, params, args, *rest, **kw):
         dispatched.append(args[0].shape)
         return dispatch(fn, params, args, *rest, **kw)
-    engine_mod._dispatch = logged
+    adapters._dispatch = logged
     try:
         rng = np.random.default_rng(2)
         eng.generate([rng.integers(1, 1100, n).tolist()
                       for n in (13, 2, 9, 1)], max_new_tokens=2)
     finally:
-        engine_mod._dispatch = dispatch
+        adapters._dispatch = dispatch
     st = eng.last_stats
     assert dispatched[:3] == [(3, 4)] * 3      # the rounds, then the steps
     assert (st["prefill_forwards"], st["prefill_rows"]) == (3, 6)

@@ -507,7 +507,9 @@ def test_the_denoising_program_fixes_what_the_schedule_fixes(model):
             model, eng.cache, slots, tokens,
             functools.partial(model.forward_denoise, masked=masked,
                               steps_left=steps_left))
-        assert model.last_expert_loads is None
+        stats = model.tallies()     # nothing fetched: nothing to tally
+        model.tally(stats, "decode", "denoise_read")
+        assert stats == model.tallies()
         assert all(isinstance(a, jax.Array) for a in read.values())
         host = jax.device_get(read)
         assert np.array_equal(host["x0"], logits.argmax(-1))
@@ -532,14 +534,14 @@ def test_the_block_loop_reads_every_forward_one_forward_behind(
     the prefill is read before the first block forward, which is the one
     forward not launched ahead (``last_stats``, the ``ahead`` attribute of
     the spans and the counter's label)."""
-    from incubator_mxnet_tpu.generate import engine as engine_mod
+    from incubator_mxnet_tpu.generate import adapters
     from incubator_mxnet_tpu.telemetry import tracing
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, 250, n).tolist() for n in (8, 9, 10, 11)]
     eng = _engine(model)
     want = eng.generate(prompts, max_new_tokens=10)
     log, owner = [], {}
-    dispatch, fetch = engine_mod._dispatch, engine_mod._fetch
+    dispatch, fetch = adapters._dispatch, adapters._fetch
 
     def logged_dispatch(fn, params, args, *rest, **kw):
         out = dispatch(fn, params, args, *rest, **kw)
@@ -553,8 +555,8 @@ def test_the_block_loop_reads_every_forward_one_forward_behind(
         log.append(("fetch", {owner[id(leaf)][0] for leaf in
                               jax.tree_util.tree_leaves(arrays)}))
         return fetch(arrays)
-    monkeypatch.setattr(engine_mod, "_dispatch", logged_dispatch)
-    monkeypatch.setattr(engine_mod, "_fetch", logged_fetch)
+    monkeypatch.setattr(adapters, "_dispatch", logged_dispatch)
+    monkeypatch.setattr(adapters, "_fetch", logged_fetch)
     _met.reset()
     tracing.clear_spans()
     assert eng.generate(prompts, max_new_tokens=10) == want
