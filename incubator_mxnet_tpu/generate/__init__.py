@@ -19,7 +19,7 @@ Importing this package registers the serving family.
 
 from .paged_kv import PagedKVCache
 from .engine import (EvaPagedLM, GenerateEngine, GPTPagedLM, MLAPagedLM,
-                     OuroPagedLM, SDARPagedLM)
+                     NemotronHPagedLM, OuroPagedLM, SDARPagedLM)
 from . import family  # noqa: F401  (registers the gpt_decoder family)
 from .family import export_gpt_for_serving
 
@@ -31,5 +31,6 @@ __all__ = [
     "MLAPagedLM",
     "EvaPagedLM",
     "OuroPagedLM",
+    "NemotronHPagedLM",
     "export_gpt_for_serving",
 ]

@@ -25,7 +25,7 @@ from ..telemetry import tracing as _tr
 from .paged_kv import PagedKVCache
 
 __all__ = ["PagedLM", "GPTPagedLM", "SDARPagedLM", "MLAPagedLM",
-           "EvaPagedLM", "OuroPagedLM"]
+           "EvaPagedLM", "OuroPagedLM", "NemotronHPagedLM"]
 
 
 def _host_bytes(args):
@@ -86,7 +86,12 @@ class PagedLM:
 
     A subclass sets ``kv_entries`` (entry name -> the (shape, dtype) of a
     position, in the order its forward takes the pools and returns the
-    chunk's additions) and defines ``outputs``. It may declare ``heads``,
+    chunk's additions) and defines ``outputs``. It may declare
+    ``state_entries`` (name -> the (shape, dtype) of a slot's state,
+    after the kv entries: its forward then takes the rows' slots, ``fed``
+    and the state pools, which its program is given donated, and returns
+    the pools it wrote after the kv additions), ``kv_layers`` (entry ->
+    the layers that hold it, where not every layer does), ``heads``,
     ``fresh_path`` (a chunk over empty slots has programs that read no
     pool), ``kv_groups``, ``block_row_bytes`` (the row a decode walk
     copies: blocks then hold ``latent_block_size`` of them), and define
@@ -104,6 +109,8 @@ class PagedLM:
     heads = ("logits", "token", "none")
     fresh_path = False
     kv_groups = {}
+    kv_layers = {}
+    state_entries = {}
     block_row_bytes = None
     opens = {}
 
@@ -123,7 +130,16 @@ class PagedLM:
     def _program(self, head, fresh):
         def pure(weights, tokens, *inputs):
             return self.outputs(head, fresh, weights, tokens, *inputs)
-        return jax.jit(pure)
+        states = len(self.state_entries)
+        if not states:
+            return jax.jit(pure)
+        # the state pools, the forward's last arguments, are written in
+        # place: (weights, tokens, *each group's lengths and tables, *kv
+        # pools, slots, fed, *state pools)
+        last = (2 + 2 * len(self.kv_groups or {None: 0})
+                + len(self.kv_entries) + 2 + states)
+        return jax.jit(pure, donate_argnums=tuple(
+            range(last - states, last)))
 
     def outputs(self, head, fresh, weights, tokens, *inputs):
         """The traced forward of `head` -> ``(out, reads, new)``: the
@@ -135,7 +151,9 @@ class PagedLM:
     # ------------------------------------------------------------ cache
     def cache_spec(self):
         return PagedKVCache.layer_spec(self.num_layers, self.kv_entries,
-                                       groups=self.kv_groups)
+                                       groups=self.kv_groups,
+                                       layers=self.kv_layers,
+                                       states=self.state_entries)
 
     def make_cache(self, slots, max_len=None, **kw):
         """A cache of this adapter's entries; a `block_size` given wins
@@ -570,3 +588,88 @@ class OuroPagedLM(PagedLM):
             for key, count in self._said.items():
                 loop[key] += count
             _cat.gen_loop_steps.inc(self.loop_steps, model=name, phase=phase)
+
+
+class NemotronHPagedLM(_ExpertLM):
+    """``models/nemotron_h.py``'s hybrid state-space decoder in `dtype`: a
+    ``k`` and a ``v`` entry on each attention layer alone (``kv_layers``),
+    and two STATE entries on each Mamba layer, ``ssm`` (H, P, N) float32
+    and ``conv`` (K - 1, Di + 2 G N) in `dtype`: device pools a slot a
+    row, handed to the forward with the rows' slots and ``fed``, written
+    in place and installed by the commit. `interpret` runs the decode
+    step's state launch and the grouped products anywhere.
+    ``last_stats["ssm"]`` counts by phase the forwards, the rows fed, the
+    real positions, the scan's sub-chunks, the state's bytes and the
+    attention layers' cached rows read."""
+
+    opens = dict(_ExpertLM.opens, ssm=_by_phase(
+        ("forwards", "rows", "positions", "scan_chunks", "state_bytes",
+         "cache_rows_read")))
+
+    def __init__(self, params, config, dtype="bfloat16", interpret=False):
+        from ..models.nemotron_h import (layer_kinds, nemotron_config,
+                                         state_shapes)
+        cfg = nemotron_config(config)
+        kinds = layer_kinds(cfg)
+        self.mamba_layers, self.attention_layers = kinds["M"], kinds["*"]
+        # declared before the programs are built: they donate the pools
+        shapes = state_shapes(cfg)
+        self.state_entries = {"ssm": (shapes["ssm"], jnp.dtype(jnp.float32)),
+                              "conv": (shapes["conv"], jnp.dtype(dtype))}
+        self.kv_entries = dict.fromkeys(
+            "kv", ((cfg["num_kv_heads"], cfg["head_dim"]), jnp.dtype(dtype)))
+        self.kv_layers = {"k": self.attention_layers,
+                          "v": self.attention_layers,
+                          "ssm": self.mamba_layers,
+                          "conv": self.mamba_layers}
+        super().__init__(params, cfg, dtype)
+        self.interpret = interpret
+        # a fed row's state, read once and written once a Mamba layer
+        self.row_state_bytes = 2 * len(self.mamba_layers) * sum(
+            int(np.prod(shape)) * dt.itemsize
+            for shape, dt in self.state_entries.values())
+        self._chunk = 1
+
+    def outputs(self, head, fresh, params, tokens, *inputs):
+        from ..models import nemotron_h
+        out, loads, nk, nv, ssm, conv = nemotron_h.nemotron_forward_paged(
+            params, self.config, tokens, *inputs, head=head,
+            interpret=self.interpret)
+        if head == "token":
+            out = out[:, None]
+        return (out, {"expert_loads": loads},
+                (jnp.stack(nk), jnp.stack(nv), ssm, conv))
+
+    def span_attrs(self, tokens):
+        self._chunk = tokens.shape[1]
+        return {"ssm_state_bytes": tokens.shape[0] * self.row_state_bytes}
+
+    def prefill_attrs(self, forwards):
+        return {"ssm_chunks": forwards * self._sub_chunks(self._chunk)}
+
+    def _sub_chunks(self, chunk):
+        """The scan's sub-chunks a row and Mamba layer of a forward
+        `chunk` wide (a decode step runs none)."""
+        return 0 if chunk == 1 else -(-chunk // self.config["ssm_chunk"])
+
+    def counts(self, tokens, lengths, tables, k_pools, v_pools, slots, fed,
+               *pools):
+        rows = int(np.count_nonzero(fed))
+        return {"forwards": 1, "rows": rows, "positions": int(np.sum(fed)),
+                "scan_chunks": tokens.shape[0] * len(self.mamba_layers)
+                * self._sub_chunks(tokens.shape[1]),
+                "state_bytes": rows * self.row_state_bytes,
+                "cache_rows_read": len(self.attention_layers)
+                * int(np.sum(lengths))}
+
+    def tally(self, stats, phase, name, read=None, committed=None):
+        if read is None and committed is None:
+            ssm = stats["ssm"][phase]
+            for key, count in self._said.items():
+                ssm[key] += count
+            _cat.gen_ssm_state_bytes.inc(self._said["state_bytes"],
+                                         model=name, phase=phase)
+            if self._said["scan_chunks"]:
+                _cat.gen_ssm_scan_chunks.inc(self._said["scan_chunks"],
+                                             model=name)
+        super().tally(stats, phase, name, read, committed)

@@ -28,12 +28,15 @@ only what it reads.
 
 THE ADAPTER CONTRACT. A model reaches the engine as a ``PagedLM``
 (``adapters.py``; ``GPTPagedLM``, ``SDARPagedLM``, ``MLAPagedLM``,
-``EvaPagedLM`` and ``OuroPagedLM`` are re-exported here), and the engine
-names no model:
+``EvaPagedLM``, ``OuroPagedLM`` and ``NemotronHPagedLM`` are re-exported
+here), and the engine names no model:
 
-- a forward takes ``(tokens, *cache.forward_inputs(slots))``: tokens
+- a forward takes ``(tokens, *cache.forward_inputs(slots, fed))``: tokens
   (S, C), a host or a device array, each cache group's host lengths and
-  tables, the pools (device arrays, not shipped);
+  tables, the pools (device arrays, not shipped); a cache with state
+  entries adds the rows' slots, ``fed`` (each row's real chunk positions)
+  and its state pools, which the forward writes in place and returns
+  after its kv additions;
 - heads: ``forward`` -> ``(logits (S, C, V), *new)``, the logits
   fetched; ``forward_token`` -> ``(read, *new)``, ``read["token"]`` (S, 1)
   int32 the last position's argmax (where ``"token"`` is in ``heads``);
@@ -64,7 +67,11 @@ attribute when a program is traced.
 Adding a model: its file under ``models/`` (a paged forward), one
 ``PagedLM`` subclass in ``adapters.py`` (config, heads, cache entries,
 ``outputs``, its host counts and tallies) and its counters in
-``telemetry/catalog.py``; nothing in this module.
+``telemetry/catalog.py``; nothing in this module. What this module knows
+of state entries: ``forward_slots(fed=)`` hands the cache each row's real
+positions (a prefill round's counts), ``position_bytes`` counts the layers
+an entry holds (``kv_layers``), and a model with state entries takes no
+draft.
 
 Between two forwards of a greedy plain decode nothing is waited for
 (``_plain_loop``): the adapter's ``forward_token`` chooses the next token
@@ -110,12 +117,12 @@ import numpy as np
 from ..telemetry import catalog as _cat
 from ..telemetry import tracing as _tr
 from . import adapters as _ad
-from .adapters import (EvaPagedLM, GPTPagedLM, MLAPagedLM, OuroPagedLM,
-                       SDARPagedLM)
+from .adapters import (EvaPagedLM, GPTPagedLM, MLAPagedLM,
+                       NemotronHPagedLM, OuroPagedLM, SDARPagedLM)
 from .paged_kv import _env_int
 
 __all__ = ["GenerateEngine", "GPTPagedLM", "SDARPagedLM", "MLAPagedLM",
-           "EvaPagedLM", "OuroPagedLM"]
+           "EvaPagedLM", "OuroPagedLM", "NemotronHPagedLM"]
 
 
 def default_prefill_chunk():
@@ -136,7 +143,7 @@ def _fetch_behind(adapter, read, note):
 
 
 def forward_slots(adapter, cache, slots, tokens, call=None, note=None,
-                  pads=0):
+                  pads=0, fed=None):
     """One adapter forward for `slots` (list) feeding `tokens` (S, C);
     returns ``(logits, *new)``, `new` what the chunk adds to each cache
     entry (new_k, new_v), WITHOUT committing. `call`: another forward of
@@ -146,9 +153,13 @@ def forward_slots(adapter, cache, slots, tokens, call=None, note=None,
     called with the adapter after the forward (the engine's tallies of
     what it last did, ``adapter.tally``). `pads`: the last rows are that
     many pad rows of a prefill round, given length 0 in every group, so
-    they read nothing of the cache (and are committed nothing)."""
+    they read nothing of the cache (and are committed nothing). `fed`: the
+    real chunk positions of each row (a prefill round's counts; None:
+    every position), which a cache with state entries hands the forward,
+    so that a row's padding moves no state."""
     with _tr.span("kv.gather"):
-        inputs = cache.forward_inputs(slots)
+        inputs = cache.forward_inputs(
+            slots, tokens.shape[1] if fed is None else fed)
         if pads:
             for lengths in inputs:      # the (S,) host arrays: a group's
                 if isinstance(lengths, np.ndarray) and lengths.ndim == 1:
@@ -209,11 +220,14 @@ def position_bytes(adapter):
     """What a prefill chunk adds to `adapter`'s cache a position, in bytes:
     every entry of the group a chunk writes (a grouped cache's leading
     group, ``kv_groups``; every entry where there are none), over the
-    layers."""
+    layers that hold it (``kv_layers``; every layer where it names none).
+    State entries add no rows."""
     groups = getattr(adapter, "kv_groups", None)
     written = next(iter(groups.values())) if groups else adapter.kv_entries
-    return adapter.num_layers * sum(
-        int(np.prod(shape)) * np.dtype(dtype).itemsize
+    layers = getattr(adapter, "kv_layers", None) or {}
+    return sum(
+        len(layers.get(name, range(adapter.num_layers)))
+        * int(np.prod(shape)) * np.dtype(dtype).itemsize
         for name, (shape, dtype) in adapter.kv_entries.items()
         if name in written)
 
@@ -282,7 +296,7 @@ def prefill_slots(adapter, cache, slots, prompts, chunk, rows, note=None):
             fed[i] += len(piece)
         fed_slots = [slots[i] for i in taken] + [slots[taken[0]]] * pads
         read, *new = forward_slots(adapter, cache, fed_slots, tokens, call,
-                                   note, pads)
+                                   note, pads, counts)
         commit_slots(cache, fed_slots, *new, counts, adapter=adapter,
                      phase="prefill", note=note)
         if call is not None:
@@ -353,6 +367,12 @@ class GenerateEngine:
                 "speculative decoding is greedy-only: the accept rule "
                 "compares draft tokens to target argmax; run with "
                 "temperature=0 or drop the draft model")
+        if self.draft is not None and any(
+                getattr(m, "state_entries", None) for m in (model, draft)):
+            raise ValueError(
+                "a model whose cache holds state entries takes no draft "
+                "model: a rejected suffix would have to be rolled out of "
+                "its recurrent state, and the cache keeps no snapshots")
         self.block_length = int(getattr(model, "block_length", 0) or 0)
         self.denoise_steps = int(denoise_steps or self.block_length)
         if self.block_length:

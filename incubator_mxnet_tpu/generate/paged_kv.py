@@ -30,26 +30,41 @@ The paged extras feed the flash-decode kernel:
 - ``layer_spec(num_layers, entries)`` — the spec of a decoder's cache:
   every layer holds the named `entries` (per-head keys and values ``k``
   and ``v``; one latent row ``c`` shared by all heads), ``<entry><i>``
-  for layer i,
-- ``forward_inputs(slots)`` — what a forward over `slots` reads of the
-  cache: lengths, block tables, then every layer's pool entry by entry
-  (K pools, V pools),
+  for layer i; ``layers=`` puts an entry on some layers alone (a hybrid
+  model's keys and values on its attention layers), ``states=`` adds
+  state entries likewise,
+- ``forward_inputs(slots, fed)`` — what a forward over `slots` reads of
+  the cache: lengths, block tables, then every layer's pool entry by
+  entry (K pools, V pools); a cache with state entries adds the rows'
+  slot ids, `fed` (the real positions of each row's chunk) and the state
+  pools entry by entry,
 - ``commit(slots, *new, count)`` — store what a forward produced, entry
   by entry in the same order (``commit(slots, new_k, new_v, count)``;
-  ``lower_commit``: that program lowered, for an owner that ships
+  then the state pools the forward wrote, which are installed as they
+  are; ``lower_commit``: the kv program lowered, for an owner that ships
   executables; ``sync()`` waits for the commits launched so far),
 - ``tables_array(slots)`` — an ``(S, max_blocks_per_slot)`` int32 block
   table, padded with block 0 (padded fetches are masked by ``lengths``
   so any valid pool row is safe),
 - ``truncate(slot, new_len)`` — roll a sequence back (speculative
-  decode rejects draft tokens by truncating the drafted suffix),
+  decode rejects draft tokens by truncating the drafted suffix; refused
+  where there are state entries: a recurrence has no rows to give back),
 - ``fragmentation()`` — unused fraction of mapped block capacity.
 
-State-kind entries stay dense host ``(slots,) + shape`` arrays (they are
-replaced, not appended — paging buys nothing). The kv entries of a spec
-that names no groups share one block table per slot: they advance in
-lockstep (the KVCache contract), so their block layouts are identical by
-construction.
+**State entries.** A ``"state"`` entry is one value a slot that a model
+REPLACES as it goes (a state-space layer's recurrent state, its conv
+window), not rows appended: a dense ``(slots,) + shape`` DEVICE pool in
+the entry's dtype, paging buys nothing. A slot's rows are zeroed when it
+is allocated (one donated launch over every state pool, before the next
+forward reads them: ``state_resets`` counts the slots), written in place
+by the forward that reads them (the forward's program is given the pools
+donated and hands them back), and installed by ``commit``; they never
+cross from the host. ``set_state`` / ``state`` are the ``KVCache``
+contract's slow surface: a launch, a fetched copy.
+
+The kv entries of a spec that names no groups share one block table per
+slot: they advance in lockstep (the KVCache contract), so their block
+layouts are identical by construction.
 
 **Groups.** A spec may put its kv entries into named GROUPS
 (``layer_spec(..., groups=...)``, the geometry of each under
@@ -204,18 +219,31 @@ def _store_one(pool, row, value, order):
 def _by_layer(names):
     """Entry names ``<entry><layer>`` grouped by entry in the order they
     come, each in layer order: ``(["k0", "k1"], ["v0", "v1"])``. None
-    unless every entry has every layer 0..L-1."""
+    unless every entry has the same layers (every layer, or the subset
+    ``layer_spec(layers=)`` gave them)."""
     layers = {}
     for name in names:
         m = re.fullmatch(r"(.*?)(\d+)", name)
         if m is None:
             return None
         layers.setdefault(m.group(1), set()).add(int(m.group(2)))
-    if not layers or any(found != set(range(len(names) // len(layers)))
-                         for found in layers.values()):
+    found = next(iter(layers.values()), None)
+    if found is None or any(got != found for got in layers.values()):
         return None
-    return tuple(["%s%d" % (entry, i) for i in range(len(found))]
-                 for entry, found in layers.items())
+    return tuple(["%s%d" % (entry, i) for i in sorted(found)]
+                 for entry in layers)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _zero_rows(pools, rows):
+    """Every state pool with its rows `rows` (a slot past the pools:
+    none) set to 0, in place."""
+    return tuple(p.at[rows].set(0, mode="drop") for p in pools)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_row(pool, slot, value):
+    return pool.at[slot].set(value.astype(pool.dtype))
 
 
 class _Rows:
@@ -260,23 +288,33 @@ class PagedKVCache:
     """
 
     @staticmethod
-    def layer_spec(num_layers, entries, groups=None):
+    def layer_spec(num_layers, entries, groups=None, layers=None,
+                   states=None):
         """The spec of a decoder's cache: each of `num_layers` holds the
         `entries`, name -> (shape, dtype) of a position (``{"k": ..., "v":
         ...}`` of one per-head shape; one latent row ``{"c": ((640,),
         bfloat16)}``), under the names ``<entry><layer>`` that
         ``forward_inputs`` and ``commit`` find them by, in this order.
         `groups`: ``{group: the names of its entries}``, every entry in
-        one; the entry's spec then names its group."""
+        one; the entry's spec then names its group. `states`: state
+        entries, name -> (shape, dtype) of a slot's value, named likewise.
+        `layers`: ``{entry: the layers that hold it}`` (an entry not named
+        there: every layer)."""
         group_of = {name: (group,) for group, names in (groups or {}).items()
                     for name in names}
         if groups and set(group_of) != set(entries):
             raise ValueError("groups %r do not part the entries %s"
                              % (groups, sorted(entries)))
-        return {"%s%d" % (name, i): ("kv", tuple(shape), dtype)
-                + group_of.get(name, ())
-                for i in range(num_layers)
-                for name, (shape, dtype) in entries.items()}
+        layers = layers or {}
+        spec = {}
+        for i in range(num_layers):
+            for kind, declared in (("kv", entries), ("state", states or {})):
+                for name, (shape, dtype) in declared.items():
+                    if i in layers.get(name, range(num_layers)):
+                        spec["%s%d" % (name, i)] = (
+                            (kind, tuple(shape), dtype)
+                            + group_of.get(name, ()))
+        return spec
 
     def __init__(self, slots, spec, max_len=512, block_size=None,
                  num_blocks=None, name="default", groups=None):
@@ -320,14 +358,22 @@ class PagedKVCache:
             else:
                 full = (self.slots,) + shape
             self.spec[ent_name] = (kind, shape, dtype)
-            self.data[ent_name] = (np.zeros(full, dtype) if kind == "state"
-                                   else _device_zeros(full, dtype))
+            self.data[ent_name] = _device_zeros(full, dtype)
         # a forward reads and `commit` stores the entries by layer:
         # <entry><i>, as `layer_spec` names them
         self._orders = {n: device_order(self.data[n])
                         for n in self._group_of}
         for rows in self._groups.values():
             rows.layer_names = _by_layer(rows.entries)
+        self._state_names = [n for n, ent in self.spec.items()
+                             if ent[0] == "state"]
+        # the state entries by layer, as a forward takes their pools; ()
+        # where there are none or they are not named by layer (a forward
+        # is then given no slots)
+        self._state_layers = (_by_layer(self._state_names) or ()
+                              if self._state_names else ())
+        self._resets = set()        # slots allocated, rows not yet zeroed
+        self.state_resets = 0       # slots whose state rows were zeroed
         # (S, C) -> a compiled `store_program` put there by an owner that
         # ships executables (the serving family's warm grid); `commit`
         # takes the jitted one where there is none
@@ -369,11 +415,25 @@ class PagedKVCache:
         for rows in self._groups.values():
             rows.lengths[slot] = 0
             rows.tables[slot] = []
-        for name, (kind, _shape, _dtype) in self.spec.items():
-            if kind == "state":
-                self.data[name][slot] = 0
+        if self._state_names:
+            self._resets.add(slot)
         self._note_blocks()
         return slot
+
+    def _zero_resets(self):
+        """Zero the state rows of the slots allocated since the last time:
+        one launch over every state pool, whatever their number."""
+        if not self._resets:
+            return
+        names = self._state_names
+        fresh = sorted(self._resets)
+        rows = np.full(self.slots, self.slots, np.int32)
+        rows[:len(fresh)] = fresh
+        self.data.update(zip(names, _zero_rows(
+            tuple(self.data[n] for n in names), rows)))
+        self.state_resets += len(fresh)
+        _cat.gen_ssm_state_resets.inc(len(fresh), model=self.name)
+        self._resets = set()
 
     def free(self, slot):
         if slot not in self._live:
@@ -412,11 +472,15 @@ class PagedKVCache:
         if kind != "state":
             raise ValueError("%r is a %r entry, not state" % (name, kind))
         self._check(slot)
-        self.data[name][slot] = np.asarray(value).reshape(shape)
+        self._zero_resets()
+        self.data[name] = _set_row(self.data[name], np.int32(slot),
+                                   np.asarray(value).reshape(shape))
 
     def state(self, name, slot):
+        """A slot's value of a state entry, fetched to the host."""
         self._check(slot)
-        return self.data[name][slot]
+        self._zero_resets()
+        return np.asarray(self.data[name][slot])
 
     def _row_at(self, rows, slot, pos):
         """The flat pool row of row `pos`, `slot`'s next, in every kv
@@ -488,6 +552,10 @@ class PagedKVCache:
         *new, count = new_and_count
         rows_of = self._rows(group)
         names = self._layers(rows_of)
+        if rows_of is self._lead and self._state_layers:
+            new, written = new[:len(names)], new[len(names):]
+            for entry_names, pools in zip(self._state_layers, written):
+                self.data.update(zip(entry_names, pools))
         # per layer: a sequence of (S, C, ...) arrays, or one stacked
         chunk = (new[0].shape[2] if hasattr(new[0], "shape")
                  else new[0][0].shape[1])
@@ -557,7 +625,7 @@ class PagedKVCache:
         (and so every forward before it has run): `commit` itself waits
         for nothing."""
         pools, _orders = self._layer_pools()
-        jax.block_until_ready(pools)
+        jax.block_until_ready((pools, self._state_pools()))
 
     def advance(self, slot, group=None):
         self._check(slot)
@@ -609,20 +677,34 @@ class PagedKVCache:
         cache's own array (read it, do not write it)."""
         return self._rows(group).lengths
 
-    def forward_inputs(self, slots):
+    def _state_pools(self):
+        """Every state pool, entry by entry, each a list by layer."""
+        return tuple([self.data[n] for n in entry]
+                     for entry in self._state_layers)
+
+    def forward_inputs(self, slots, fed=1):
         """What a forward over `slots` reads of the cache, in the order a
         paged forward takes them: the committed lengths (S,) and the block
         tables (S, max_blocks_per_slot), int32 host arrays it ships, and
         every layer's pool entry by entry (the K pools, the V pools), the
         device arrays (not shipped). A grouped cache hands each group's
-        lengths and tables, group by group, then the pools likewise."""
+        lengths and tables, group by group, then the pools likewise. A
+        cache with state entries then hands the rows' slots (S,) and `fed`
+        (one number, or one a row: the chunk positions of the row that are
+        real, a pad's 0), int32 host arrays, and every state pool entry by
+        entry, its rows zeroed where a slot was allocated since."""
         slots = list(slots)
         pools, _orders = self._layer_pools()
         shipped = tuple(
             a for group, rows in self._groups.items()
             for a in (rows.lengths[slots].astype(np.int32),
                       self.tables_array(slots, group)))
-        return shipped + pools
+        if not self._state_layers:
+            return shipped + pools
+        self._zero_resets()
+        fed = np.broadcast_to(np.asarray(fed, np.int32), (len(slots),))
+        return (shipped + pools + (np.asarray(slots, np.int32), fed.copy())
+                + self._state_pools())
 
     def tables_array(self, slots=None, group=None):
         """Block tables as an (S, max_blocks_per_slot) int32 array for
@@ -644,6 +726,12 @@ class PagedKVCache:
         cache the positions given up must all be live rows of the leading
         group: what a restart closed is not rolled back."""
         self._check(slot)
+        if self._state_names:
+            raise ValueError(
+                "cache %r holds state entries (%s): a recurrent state has "
+                "no rows to give back, so a sequence cannot be truncated "
+                "(that would take snapshots of the state)"
+                % (self.name, ", ".join(self._state_names)))
         new_len = int(new_len)
         if new_len < 0:
             raise ValueError("new_len must be >= 0, got %r" % new_len)

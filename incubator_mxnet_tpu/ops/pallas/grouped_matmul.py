@@ -32,6 +32,8 @@ Measured on a v5e at 512 routes over 128 experts of 2048 x 768 bfloat16
 hit, where the launch XLA makes of ``ragged_dot`` takes 1.56-1.82 ms.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -86,62 +88,86 @@ def tile_plan(group_sizes, rows, tile_rows=TILE_ROWS):
     return dest, jnp.minimum(tile_group, groups - 1), used.reshape(1)
 
 
-def _kernel(tile_group_ref, used_ref, x_ref, w_ref, o_ref):
+def _kernel(tile_group_ref, used_ref, x_ref, w_ref, o_ref, out_in=False):
     del tile_group_ref      # read by the index_maps
     live = pl.program_id(1) < used_ref[0]
 
     @pl.when(live)
     def _product():
-        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
-                             preferred_element_type=jnp.float32
-                             ).astype(o_ref.dtype)
+        if out_in:          # w block (1, block_n, K): contract both minors
+            o_ref[...] = jax.lax.dot_general(
+                x_ref[...], w_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        else:
+            o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
+                                 preferred_element_type=jnp.float32
+                                 ).astype(o_ref.dtype)
 
     @pl.when(jnp.logical_not(live))
     def _zeros():
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def grouped_matmul_tiles(x_tiles, w, tile_group, used, interpret=False):
+def grouped_matmul_tiles(x_tiles, w, tile_group, used, interpret=False,
+                         out_in=False):
     """The launch itself, on rows already laid out in tiles.
-    x_tiles (T * tile_rows, K); w (G, K, N) -> (T * tile_rows, N) float32."""
+    x_tiles (T * tile_rows, K); w (G, K, N) -> (T * tile_rows, N) float32.
+    `out_in`: w is held (G, N, K), a group's matrix as a linear layer
+    keeps it (out, in), and each tile takes ``x w[g]^T``."""
     rows, k = x_tiles.shape
     tile_rows = rows // tile_group.shape[0]
-    n = w.shape[2]
+    n = w.shape[1] if out_in else w.shape[2]
     # one weight block is (K, block_n): whole when it is 4 MB at most, so
     # that two of them (the pipeline's) stay far under the scoped VMEM
     block_n = n
     while k * block_n * w.dtype.itemsize > (4 << 20) and block_n % 256 == 0:
         block_n //= 2
+    params = {}
+    held = k * block_n * w.dtype.itemsize
+    if held > (4 << 20):
+        # a block no halving brings to 4 MB (N is no multiple of 256: a
+        # relu^2 expert of 1,856 x 2,688, 10 MB): room for two of it and of
+        # the row tiles, above the default scoped VMEM
+        tiles = tile_rows * (k * x_tiles.dtype.itemsize + block_n * 4)
+        params["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=2 * (held + tiles) + (4 << 20))
+    weights = (pl.BlockSpec((1, block_n, k),
+                            lambda j, t, tg, used: (tg[t], j, 0)) if out_in
+               else pl.BlockSpec((1, k, block_n),
+                                 lambda j, t, tg, used: (tg[t], 0, j)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n // block_n, rows // tile_rows),
         in_specs=[
             pl.BlockSpec((tile_rows, k), lambda j, t, tg, used: (t, 0)),
-            pl.BlockSpec((1, k, block_n),
-                         lambda j, t, tg, used: (tg[t], 0, j)),
+            weights,
         ],
         out_specs=pl.BlockSpec((tile_rows, block_n),
                                lambda j, t, tg, used: (t, j)),
     )
     return pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, out_in=True) if out_in else _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, n), jnp.float32),
         interpret=interpret,
         name="moe_grouped_matmul",
+        **params,
     )(tile_group, used, x_tiles, w)
 
 
-def grouped_matmul(x, w, group_sizes, use_kernel=None, interpret=False):
+def grouped_matmul(x, w, group_sizes, use_kernel=None, interpret=False,
+                   out_in=False):
     """x (M, K) sorted by group; w (G, K, N); group_sizes (G,) summing to
     M. -> (M, N) float32: ``jax.lax.ragged_dot``'s contract, as one
     Pallas launch on a TPU (`use_kernel` None: where the backend is one;
     `interpret` runs the launch anywhere) and as ``ragged_dot`` itself
-    elsewhere, the numerics oracle and the CPU path."""
+    elsewhere, the numerics oracle and the CPU path. `out_in`: w is held
+    (G, N, K) (``grouped_matmul_tiles``)."""
     if use_kernel is None:
         use_kernel = grouped_matmul_available()
     if not (use_kernel or interpret):
-        return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
+        return jax.lax.ragged_dot(x, jnp.swapaxes(w, 1, 2) if out_in else w,
+                                  group_sizes.astype(jnp.int32),
                                   preferred_element_type=jnp.float32)
     rows = x.shape[0]
     tile_rows = tile_rows_for(rows, w.shape[0])
@@ -149,4 +175,5 @@ def grouped_matmul(x, w, group_sizes, use_kernel=None, interpret=False):
     # padding rows read row 0: what they produce is never gathered back
     src = jnp.zeros((tile_group.shape[0] * tile_rows,), jnp.int32
                     ).at[dest].set(jnp.arange(rows, dtype=jnp.int32))
-    return grouped_matmul_tiles(x[src], w, tile_group, used, interpret)[dest]
+    return grouped_matmul_tiles(x[src], w, tile_group, used, interpret,
+                                out_in)[dest]

@@ -200,7 +200,7 @@ def _pass_layout(sizes, slots, tile_rows):
 
 
 def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
-                 gated, use_kernel, interpret):
+                 gated, use_kernel, interpret, up_out_in=False):
     """The routed part of a layer that holds the experts ``[first, first +
     count)`` alone -> (the held routes' weighted outputs summed a token
     (N, d) float32, stats).
@@ -216,7 +216,8 @@ def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
     pass lays its routes out in the grouped product's tiles ONCE
     (``_pass_layout``; off the TPU the layout is the order itself and the
     product ``ragged_dot``), gathers the tokens' rows into that layout,
-    runs the three products on it and brings the rows home by ONE product
+    runs the expert's products on it (three, two
+    where `gate_w` is None) and brings the rows home by ONE product
     with the (N, slots) matrix that holds a route's weight where the slot
     is the token's: one gather in, one weighted sum out, no scatter (a
     TPU walks a scatter's updates one by one: 0.9 ms of a 14 ms decode
@@ -254,13 +255,16 @@ def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
         token = route // k
         moved = x[token]
 
-        def product(rows, w):
+        def product(rows, w, out_in=False):
             if tiled:
                 return grouped_matmul_tiles(rows, w, tile_group, used,
-                                            interpret)
-            return jax.lax.ragged_dot(rows, w, sizes,
-                                      preferred_element_type=jnp.float32)
-        y = product(gated(product(moved, gate_w), product(moved, up_w)),
+                                            interpret, out_in)
+            return jax.lax.ragged_dot(
+                rows, jnp.swapaxes(w, 1, 2) if out_in else w, sizes,
+                preferred_element_type=jnp.float32)
+        y = product(gated(None if gate_w is None
+                          else product(moved, gate_w),
+                          product(moved, up_w, up_out_in)),
                     down_w)
         home = jnp.where(live[None, :] & (token[None, :] == tokens[:, None]),
                          flat_w[route][None, :], 0.0)       # (N, slots)
@@ -274,7 +278,8 @@ def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
 
 def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
                  interpret=False, return_stats=False, scoring="softmax",
-                 choice_bias=None, route_scale=1.0, shared=None, held=None):
+                 choice_bias=None, route_scale=1.0, shared=None, held=None,
+                 up_out_in=False):
     """Dropless top-k mixture of gated-SiLU experts, no biases.
 
     x : (N, d) tokens
@@ -293,6 +298,13 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     (d, fs), up_w, down_w (fs, d))`` of a shared expert that every token
     visits: dense, so computed by the plain products, added once a token
     and not a route (``expert_load`` counts routed experts only).
+
+    A `gate_w` of None (the routed or the shared expert's): experts that
+    are NOT gated, two products, ``relu(x up_w[e])^2 down_w[e]``.
+    `up_out_in`: the routed experts' `up_w` is held (E, f, d), out by in
+    as a linear layer keeps it (a TPU keeps an (E, d, f) array with d
+    minor where f is no multiple of 128, and its product would copy every
+    expert into the other order each forward; Nemotron-H's f is 1,856).
 
     The N x k routes are sorted by expert (a stable sort, so a token's
     routes keep their order inside a group) and each of the three expert
@@ -340,32 +352,35 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     if held is not None:
         first, count = held
         if not (0 <= first and 1 <= count and first + count <= experts
-                and gate_w.shape[0] == count):
+                and up_w.shape[0] == count):
             raise ValueError(
                 "held=(%d, %d) must lie inside the router's %d experts and "
                 "come with %d experts' weights (got %d)"
-                % (first, count, experts, count, gate_w.shape[0]))
+                % (first, count, experts, count, up_w.shape[0]))
 
-    def grouped(rows, w, sizes):
+    def grouped(rows, w, sizes, out_in=False):
         return grouped_matmul(rows, w, sizes, use_kernel=use_kernel,
-                              interpret=interpret)
+                              interpret=interpret, out_in=out_in)
 
     def gated(gate, up):
+        if gate is None:
+            return jnp.square(jax.nn.relu(up)).astype(x.dtype)
         return (jax.nn.silu(gate) * up).astype(x.dtype)
     top_e, weights = _routes(
         jnp.dot(x, router_w, preferred_element_type=jnp.float32), k, scoring,
         choice_bias, route_scale)
     if held is not None:
         out, stats = _held_routes(x, top_e, weights, experts, gate_w, up_w,
-                                  down_w, held, gated, use_kernel, interpret)
+                                  down_w, held, gated, use_kernel, interpret,
+                                  up_out_in)
     else:
         expert_of_route = top_e.reshape(n * k)
         order = jnp.argsort(expert_of_route, stable=True)   # sorted -> route
         load = jnp.zeros((experts,), jnp.int32).at[expert_of_route].add(1)
         stats = {"expert_load": load}
         rows = x[order // k]                                # (N k, d)
-        hidden = gated(grouped(rows, gate_w, load),
-                       grouped(rows, up_w, load))
+        hidden = gated(None if gate_w is None else grouped(rows, gate_w, load),
+                       grouped(rows, up_w, load, up_out_in))
         y = grouped(hidden, down_w, load)                   # (N k, d) f32
         back = jnp.zeros((n * k,), jnp.int32).at[order].set(
             jnp.arange(n * k, dtype=jnp.int32))             # route -> sorted
@@ -373,7 +388,8 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
     if shared is not None:
         s_gate, s_up, s_down = shared
         out = out + jnp.dot(
-            gated(jnp.dot(x, s_gate, preferred_element_type=jnp.float32),
+            gated(None if s_gate is None
+                  else jnp.dot(x, s_gate, preferred_element_type=jnp.float32),
                   jnp.dot(x, s_up, preferred_element_type=jnp.float32)),
             s_down, preferred_element_type=jnp.float32)
     out = out.astype(x.dtype)

@@ -296,6 +296,21 @@ gen_loop_steps = _m.counter(
     "Loop steps run by a looped model's forwards (OuroPagedLM: every "
     "layer applied loop_steps times a token with one set of weights; a "
     "forward counts loop_steps), by model and phase (prefill|decode)")
+gen_ssm_state_bytes = _m.counter(
+    "mxtpu_gen_ssm_state_bytes_total",
+    "Bytes of recurrent state a state-space model's forwards read and "
+    "wrote (NemotronHPagedLM: each fed row's ssm state and conv window, "
+    "read once and written once a Mamba layer), by model and phase "
+    "(prefill|decode)")
+gen_ssm_scan_chunks = _m.counter(
+    "mxtpu_gen_ssm_scan_chunks_total",
+    "Sub-chunks of the chunked (SSD) scan that prefill forwards ran, a "
+    "row and Mamba layer each, by model")
+gen_ssm_state_resets = _m.counter(
+    "mxtpu_gen_ssm_state_resets_total",
+    "Slots whose state entries were zeroed after an allocation (one "
+    "launch over every state pool before the next forward), by model: "
+    "the cache's name")
 gen_tokens_committed = _m.counter(
     "mxtpu_gen_tokens_committed_total",
     "Tokens committed to sequences by model and phase (prefill|decode) "

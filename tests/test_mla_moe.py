@@ -391,8 +391,14 @@ def test_layer_spec_names_the_entries_a_layer_declares():
     cache = PagedKVCache(1, spec, max_len=8, block_size=4)
     _lengths, _tables, k_pools, v_pools = cache.forward_inputs([0])
     assert len(k_pools) == len(v_pools) == 2
-    mixed = PagedKVCache(1, {"c0": ("kv", (6,)), "c2": ("kv", (6,))},
-                         max_len=8)
+    # an entry on some layers alone (layers 0 and 2) is read by its names
+    some = PagedKVCache(1, {"c0": ("kv", (6,)), "c2": ("kv", (6,))},
+                        max_len=8)
+    _lengths, _tables, c_pools = some.forward_inputs([0])
+    assert c_pools == [some.data["c0"], some.data["c2"]]
+    # entries that do not line up layer for layer are refused
+    mixed = PagedKVCache(1, {"c0": ("kv", (6,)), "c2": ("kv", (6,)),
+                             "d0": ("kv", (6,))}, max_len=8)
     with pytest.raises(ValueError, match="<entry><i>"):
         mixed.forward_inputs([0])
 

@@ -536,7 +536,7 @@ def test_int8_matmul_kernel_numerics():
 _KERNEL_FILES = ["flash_attention.py", "flash_decode.py",
                  "fused_bottleneck.py", "fused_norm.py", "fused_optim.py",
                  "grouped_matmul.py", "int8_matmul.py", "paged_heads.py",
-                 "paged_latent.py"]
+                 "paged_latent.py", "ssm_state.py"]
 
 
 def _pallas_calls(tree):

@@ -197,6 +197,48 @@ def test_grouped_matmul_of_full_groups_compiles_for_v5e(one_chip, rows, k, n):
     assert "moe_grouped_matmul" in text
 
 
+def test_grouped_matmul_of_relu2_experts_compiles_for_v5e(one_chip):
+    """The chat-batch cell's up product at a decode step (256 rows x 6
+    routes), 64 held experts of 2688 x 1856 with the up weights held out by
+    in (1856, 2688): 1856 is no multiple of 256, so a weight block is a
+    whole expert of 10 MB, and the launch asks for room for two of them;
+    it copies no weight."""
+    from incubator_mxnet_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    x = jax.ShapeDtypeStruct((1536, 2688), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((64, 1856, 2688), jnp.bfloat16,
+                             sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda x, w, s: grouped_matmul(x, w, s, use_kernel=True,
+                                                   out_in=True),
+                    x, w, sizes)
+    assert "moe_grouped_matmul" in text
+    assert not re.search(r"= bf16\[64,[0-9,]+\][^ ]* copy\(", text)
+
+
+def test_the_state_step_compiles_in_place_at_published_widths(one_chip):
+    """``ssm_state_step`` over 256 slots of Nemotron-H's 64 heads of 64 x
+    128 float32 state (537 MB a Mamba layer), the pool donated: one
+    launch, the pool aliased to its output and no temporary of its
+    size."""
+    from incubator_mxnet_tpu.ops.pallas.ssm_state import (pool_row_shape,
+                                                          ssm_state_step)
+    f32 = jnp.float32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    S, H, P, N, G = 256, 64, 64, 128, 8
+    # two heads a row: (32, 128, 128), whole (8, 128) tiles
+    assert pool_row_shape(H, P, G, N) == (32, 128, 128)
+    compiled = jax.jit(
+        lambda *a: ssm_state_step(*a, use_kernel=True), donate_argnums=0
+    ).lower(sds((S, 32, 128, 128)), sds((S,), jnp.int32), sds((S, H, P)),
+            sds((S, H)), sds((H,)), sds((S, G, N)), sds((S, G, N))).compile()
+    assert compiled.as_text().count("ssm_state_step") >= 1
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == S * H * P * N * 4
+    assert memory.temp_size_in_bytes < S * H * P * N
+
+
 @pytest.mark.parametrize("program", ["choice", "denoise"])
 def test_sdar_block_forward_compiles_for_v5e(one_chip, monkeypatch, program):
     """One layer of the cell's denoising forward (16 rows x 4 positions
