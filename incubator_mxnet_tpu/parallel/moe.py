@@ -180,9 +180,11 @@ def _pass_layout(sizes, slots, tile_rows):
     slots, read from the SLOT's side, so that the layout is filled by
     gathers alone: -> (the row of the order in each slot (slots,), whether
     the slot holds one (slots,) bool, the group of each tile (T,), the
-    tiles used (1,)). Tiles past the used ones repeat the last used group
-    (``grouped_matmul_tiles`` fetches nothing for them); with tiles of one
-    slot the layout is the order itself."""
+    tiles used (1,), the slots of padding before each group's first route
+    (G,), which turn a route's row into its slot). Tiles past the used
+    ones repeat the last used group (``grouped_matmul_tiles`` fetches
+    nothing for them); with tiles of one slot the layout is the order
+    itself."""
     groups = sizes.shape[0]
     per_group = (sizes + tile_rows - 1) // tile_rows
     tile_end = jnp.cumsum(per_group)                # inclusive, by group
@@ -191,12 +193,28 @@ def _pass_layout(sizes, slots, tile_rows):
                        jnp.maximum(used - 1, 0))
     tile_group = jnp.minimum(jnp.sum(tile[:, None] >= tile_end[None, :],
                                      axis=1, dtype=jnp.int32), groups - 1)
-    group = jnp.repeat(tile_group, tile_rows, total_repeat_length=slots)
+    group = jnp.repeat(tile_group, tile_rows)      # slots, with no scatter
     slot = jnp.arange(slots, dtype=jnp.int32)
     rank = slot - (tile_end - per_group)[group] * tile_rows
     live = (rank < sizes[group]) & (slot // tile_rows < used)
-    return ((jnp.cumsum(sizes) - sizes)[group] + rank, live, tile_group,
-            used.reshape(1))
+    first_row = jnp.cumsum(sizes) - sizes
+    return (first_row[group] + rank, live, tile_group, used.reshape(1),
+            (tile_end - per_group) * tile_rows - first_row)
+
+
+# TPU v5e peaks (benchmarks/peaks.py): bfloat16 FLOPs and HBM bytes a second
+_MXU_FLOPS_PER_S, _HBM_BYTES_PER_S = 197e12, 819e9
+
+
+def gathers_home(tokens, top_k, slots, width):
+    """Whether a pass's (slots, width) float32 rows come home cheaper by
+    each token's gather of its own `top_k` rows (4 tokens top_k width
+    bytes at the HBM's peak) than by the (tokens, slots) float32 product
+    at HIGHEST (six bfloat16 passes, 12 tokens slots width FLOPs at the
+    MXU's peak): where the layout holds more than some 80 slots a route
+    of a token. All four are static shapes."""
+    return (4 * tokens * top_k * width / _HBM_BYTES_PER_S
+            < 12 * tokens * slots * width / _MXU_FLOPS_PER_S)
 
 
 def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
@@ -216,12 +234,21 @@ def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
     pass lays its routes out in the grouped product's tiles ONCE
     (``_pass_layout``; off the TPU the layout is the order itself and the
     product ``ragged_dot``), gathers the tokens' rows into that layout,
-    runs the expert's products on it (three, two
-    where `gate_w` is None) and brings the rows home by ONE product
-    with the (N, slots) matrix that holds a route's weight where the slot
-    is the token's: one gather in, one weighted sum out, no scatter (a
-    TPU walks a scatter's updates one by one: 0.9 ms of a 14 ms decode
-    step at 128 rows x 7,168, PERF.md section 6, PR 35)."""
+    runs the expert's products on it (three, two where `gate_w` is None)
+    and brings the rows home with no scatter (a TPU walks a scatter's
+    updates one by one: 0.9 ms of a 14 ms decode step at 128 rows x
+    7,168, PERF.md section 6), in float32, those of other passes and
+    chips under weight 0. Where a pass's layout is wide for its routes
+    (``gathers_home``) a route's slot is its place in the order (the
+    order's inverse, a second sort) less the pass's start plus its
+    group's padding, and each token gathers its own k rows and sums them:
+    one gather in, one gather and a weighted sum of k rows a token out.
+    Where it is narrow the rows come home by ONE product with the (N,
+    slots) matrix that holds a route's weight where the slot is the
+    token's, at HIGHEST: its work grows with the layout where the
+    gather's grows with the routes (224 GFLOP a prefill round of 2,048
+    tokens over 20,352 slots, against 132 MB gathered: PERF.md section
+    6)."""
     from ..ops.pallas.grouped_matmul import (grouped_matmul_available,
                                              grouped_matmul_tiles,
                                              tile_rows_for)
@@ -242,14 +269,24 @@ def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
     tiled = use_kernel or interpret
     tile_rows = tile_rows_for(bound, count) if tiled else 1
     slots = (bound + count * (tile_rows - 1)) // tile_rows * tile_rows
-    flat_w = weights.reshape(routes)
-    tokens = jnp.arange(n, dtype=jnp.int32)
+    gather = gathers_home(n, k, slots, x.shape[1])
+    if gather:
+        # each token's routes by rank, (k, N): gathered in this order, the
+        # rows of one rank lie side by side as the sum over the ranks reads
+        place = jnp.argsort(order[:routes]).astype(jnp.int32).reshape(n, k).T
+        group = key.reshape(n, k).T
+        held_here = group < count
+        group = jnp.minimum(group, count - 1)
+    else:
+        flat_w = weights.reshape(routes)
+        tokens = jnp.arange(n, dtype=jnp.int32)
 
     def one_pass(p, out):
         lo = p * bound
         sizes = (jnp.clip(end, lo, lo + bound)
                  - jnp.clip(end - load, lo, lo + bound))
-        row, live, tile_group, used = _pass_layout(sizes, slots, tile_rows)
+        row, live, tile_group, used, shift = _pass_layout(sizes, slots,
+                                                          tile_rows)
         # a slot's route; a tile's padding reads token 0 under weight 0
         route = jnp.where(live, order[lo + row], 0)
         token = route // k
@@ -266,6 +303,12 @@ def _held_routes(x, top_e, weights, experts, gate_w, up_w, down_w, held,
                           else product(moved, gate_w),
                           product(moved, up_w, up_out_in)),
                     down_w)
+        if gather:
+            at = place - lo                 # a route's row in this pass
+            mine = held_here & (at >= 0) & (at < bound)
+            rows = y[jnp.where(mine, at + shift[group], 0)]  # (k, N, d)
+            return out + jnp.sum(
+                rows * jnp.where(mine, weights.T, 0.0)[:, :, None], axis=0)
         home = jnp.where(live[None, :] & (token[None, :] == tokens[:, None]),
                          flat_w[route][None, :], 0.0)       # (N, slots)
         return out + jnp.dot(home, y, precision="highest")
@@ -329,7 +372,10 @@ def moe_dropless(x, router_w, gate_w, up_w, down_w, top_k, use_kernel=None,
 
     the routes that fall on the held experts (``_held_routes``: picked out
     of the N k first, computed ``share_bound`` routes a pass, none dropped
-    under any skew) and the shared expert, which every chip computes
+    under any skew, each pass's rows brought home by every token's gather
+    of its own k rows, or by one (N, slots) product where the pass's
+    layout is narrow for its routes: ``gathers_home``, from the static
+    shapes) and the shared expert, which every chip computes
     alike. What the absent experts would add is another chip's to compute
     and an exchange's to sum: neither is here, and ``out_here`` is what
     the caller carries on. Summed over the shares of a layer, the shared

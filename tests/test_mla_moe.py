@@ -922,6 +922,92 @@ def test_a_skewed_router_drops_nothing_and_an_idle_share_adds_nothing():
         assert int(stats["rows_moved"]) == 0
 
 
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["ragged_dot", "tiles_interpreted"])
+@pytest.mark.parametrize("tokens,slots,gather", [(40, (224, 336), False),
+                                                 (160, (864, 1792), True)],
+                         ids=["product_home", "gather_home"])
+def test_a_skewed_shares_rows_come_home_alike_by_gather_or_product(
+        tokens, slots, gather, interpret):
+    """Every token's 8 routes on the 8 held experts, in two passes, each
+    pass's rows brought home by ONE (N, slots) product where its layout
+    has few slots a route of a token (40 tokens, 28 or 42) and by each
+    token's gather of its own 8 rows where it has many (160 tokens, 108
+    or 224), off the TPU's layout and in the launch's tiles. Either way
+    the layer is the float64 loop's and the layer's that holds all 24
+    experts."""
+    from incubator_mxnet_tpu.parallel.moe import gathers_home
+    assert gathers_home(tokens, 8, slots[interpret], 16) is gather
+    rng = np.random.default_rng(12)
+    x, router_w, gate_w, up_w, down_w, shared, bias = _share_layer(
+        rng, tokens=tokens)
+    planted = bias.at[8:16].add(5.0)         # the held range: all chosen
+    out, stats = _held(x, router_w, gate_w, up_w, down_w, planted, 8, 8,
+                       interpret=interpret)
+    assert int(stats["rows_moved"]) == 2 * slots[interpret]   # two passes
+    want, _chosen = _sigmoid_loop(x, router_w, gate_w, up_w, down_w, 8,
+                                  planted, 2.827)
+    np.testing.assert_allclose(out, want, atol=3e-5)
+    whole = moe_dropless(x, router_w, gate_w, up_w, down_w, 8,
+                         scoring="sigmoid", choice_bias=planted,
+                         route_scale=2.827)
+    np.testing.assert_allclose(out, whole, atol=3e-5)
+
+
+def _eqns(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs inside it (a loop's
+    body, a launch's kernel)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)        # a closed jaxpr's
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("n,k,experts,count,d,f,slots,gather", [
+    (2048, 6, 128, 64, 2688, 1856, 20352, True),
+    (256, 6, 128, 64, 2688, 1856, 2496, True),
+    (2048, 8, 384, 12, 7168, 2048, 2432, True),
+    (128, 8, 384, 12, 7168, 2048, 240, False)],
+    ids=["cell9_prefill", "cell9_decode", "cell6_prefill", "cell6_decode"])
+def test_the_cells_shares_bring_their_rows_home_by_the_cheaper_form(
+        n, k, experts, count, d, f, slots, gather):
+    """Cell 9's (relu² experts, up weights out by in) and cell 6's (gated)
+    expert layers at a prefill round and a decode step, traced at their
+    widths through the launch's tile layout with no chip. Where a pass's
+    layout is wide for its routes, its (slots, d) result comes home by
+    ONE gather of each token's k rows, (k, N, d), and the program has no
+    product with an operand of `slots` columns (the (N, slots) matrix:
+    224 GFLOP at HIGHEST at cell 9's prefill round); at cell 6's decode
+    step, 240 slots for 128 tokens' 8 routes, by that one product. No
+    form scatters."""
+    from incubator_mxnet_tpu.parallel.moe import gathers_home
+    assert gathers_home(n, k, slots, d) is gather
+    s = jax.ShapeDtypeStruct
+    relu2 = k == 6
+    gate = None if relu2 else s((count, d, f), jnp.bfloat16)
+    up = s((count, f, d) if relu2 else (count, d, f), jnp.bfloat16)
+
+    def layer(x, router_w, gate_w, up_w, down_w):
+        return moe_dropless(x, router_w, gate_w, up_w, down_w, k,
+                            interpret=True, scoring="sigmoid",
+                            held=(0, count), up_out_in=relu2)
+    jaxpr = jax.make_jaxpr(layer)(
+        s((n, d), jnp.bfloat16), s((d, experts), jnp.float32), gate, up,
+        s((count, f, d), jnp.bfloat16)).jaxpr
+    eqns = list(_eqns(jaxpr))
+    assert not [e for e in eqns if "scatter" in e.primitive.name]
+    products = [e for e in eqns if e.primitive.name == "dot_general"
+                and any(v.aval.shape == (n, slots) for v in e.invars)]
+    homes = [e.outvars[0].aval.shape for e in eqns
+             if e.primitive.name == "gather"
+             and e.invars[0].aval.shape == (slots, d)]
+    assert (len(products), homes) == ((0, [(k, n, d)]) if gather
+                                      else (1, []))
+
+
 def test_a_layer_that_holds_every_expert_is_the_layer_with_no_share():
     rng = np.random.default_rng(13)
     x, router_w, gate_w, up_w, down_w, shared, bias = _share_layer(rng)
